@@ -21,19 +21,6 @@ runtime (DCN summation server, CPU reducer) is native C++.
 
 __version__ = "0.1.0"
 
-import sys as _sys
-
-if "jax" in _sys.modules:
-    # jax is already loaded (an interactive session, a test harness):
-    # install the API-rename aliases now, before any user code calls
-    # jax.shard_map directly. Cold jax-less processes skip this — the
-    # jax-consuming subpackages (comm/jax/ops/models/parallel) each call
-    # ensure() at import, so nobody pays jax's import cost for the
-    # server/torch-only paths. See common/jax_compat.py.
-    from byteps_tpu.common.jax_compat import ensure as _ensure_jax_compat
-
-    _ensure_jax_compat()
-
 from byteps_tpu.common.config import Config, get_config  # noqa: F401,E402
 
 

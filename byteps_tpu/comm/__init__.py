@@ -6,10 +6,6 @@ Reference analogs: ``byteps/common/nccl_manager.cc`` (intra-node NCCL) →
 push/pull) → ``comm/dcn.py`` (DCN parameter-server client).
 """
 
-from byteps_tpu.common.jax_compat import ensure as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from byteps_tpu.comm.mesh import device_mesh, local_device_count  # noqa: F401
 from byteps_tpu.comm.ici import (  # noqa: F401
     allreduce_flat,

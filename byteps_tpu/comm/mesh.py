@@ -11,7 +11,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from byteps_tpu.common.config import get_config
 
@@ -32,4 +32,6 @@ def device_mesh(
         axis_names = (cfg.dp_axis,) if len(shape) == 1 else tuple(
             f"ax{i}" for i in range(len(shape))
         )
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+    # Auto axes, as parallel/mesh.py: jax.make_mesh defaults to Explicit
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(shape))

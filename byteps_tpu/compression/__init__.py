@@ -12,10 +12,6 @@ framework adapters, e.g.::
      "scaling": True, "k": 0.01, "seed": 0}
 """
 
-from byteps_tpu.common.jax_compat import ensure as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from byteps_tpu.compression.base import (  # noqa: F401
     Compressor,
     from_params,

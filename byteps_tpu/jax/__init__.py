@@ -31,10 +31,6 @@ Two aggregation paths (SURVEY §7 phase 2/3):
 
 from __future__ import annotations
 
-from byteps_tpu.common.jax_compat import ensure as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 import dataclasses
 import threading
 from typing import Any, Dict, List, Optional

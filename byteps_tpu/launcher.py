@@ -83,8 +83,20 @@ def _spawn_workers(cmd: List[str]) -> int:
     )
     if cfg.jax_distributed:
         cmd = _wrap_jax_distributed(cmd)
+    # one worker process drives all local TPU devices; several copies of
+    # a jax command would each claim the chip, and all but one would fail
+    # or hang. local_size > 1 is the localhost simulation.
+    on_cpu = local_size > 1 and "JAX_PLATFORMS" not in os.environ
+    if on_cpu:
+        log.warning(
+            "BYTEPS_LOCAL_SIZE=%d with JAX_PLATFORMS unset: starting the "
+            "workers with JAX_PLATFORMS=cpu (a chip belongs to one "
+            "process); set JAX_PLATFORMS yourself to choose otherwise",
+            local_size)
     for i in range(local_size):
         env = dict(os.environ)
+        if on_cpu:
+            env["JAX_PLATFORMS"] = "cpu"
         env["BYTEPS_LOCAL_RANK"] = str(i)
         env["BYTEPS_LOCAL_SIZE"] = str(local_size)
         if single_host_sim:
@@ -200,9 +212,16 @@ class Supervisor:
         from byteps_tpu.common.metrics import get_registry
 
         cfg = get_config()
-        self._argv = list(argv) if argv else [
-            sys.executable, "-m", "byteps_tpu.launcher", "--child-worker"]
         self._base_env = dict(base_env or {})
+        if argv:
+            self._argv = list(argv)
+        else:
+            self._argv = [sys.executable, "-m", "byteps_tpu.launcher",
+                          "--child-worker"]
+            # the stock child is a host-side wire worker whose
+            # Checkpointer import pulls in jax; a chip belongs to one
+            # process, and the supervising trainer may hold it
+            self._base_env.setdefault("JAX_PLATFORMS", "cpu")
         self.restart_limit = (restart_limit if restart_limit is not None
                               else cfg.supervisor_restart_limit)
         self._backoff_s = (backoff_ms if backoff_ms is not None

@@ -7,10 +7,6 @@ benchmark/bench.py workloads (BASELINE configs: ResNet-50, BERT, GPT-2) and
 the flagship for the driver's compile checks.
 """
 
-from byteps_tpu.common.jax_compat import ensure as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from byteps_tpu.models.gpt import (GPTConfig, gpt_init, gpt_forward,
                                    gpt_hidden, gpt_loss, gpt_pp_loss)
 from byteps_tpu.models.gpt import gpt_param_specs

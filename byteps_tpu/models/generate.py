@@ -186,12 +186,19 @@ def _attn_cached_half(x, p, cache_k, cache_v, pos0, head_dim, tp_axis,
     # softmax pass over the stored cache (int8 read directly, dequant
     # per block in VMEM with _cache_read's rounding), dead blocks
     # skipped past the fill level.
+    from byteps_tpu.ops.backend import note_fallback
     from byteps_tpu.ops.flash_decode import (
         decode_supported, flash_decode, use_pallas)
 
     S_max = (cache_k.q if isinstance(cache_k, _QuantSlot)
              else cache_k).shape[1]
-    if T == 1 and use_pallas() and decode_supported(S_max, head_dim):
+    flash = T == 1 and use_pallas()
+    if flash and not decode_supported(S_max, head_dim):
+        note_fallback("flash_decode", (S_max, head_dim),
+                      "cache length must tile into 8..256 key blocks and "
+                      "head_dim be <= 256")
+        flash = False
+    if flash:
         if isinstance(cache_k, _QuantSlot):
             o = flash_decode(q, cache_k.q, cache_v.q, pos0,
                              k_scale=cache_k.scale, v_scale=cache_v.scale)

@@ -134,19 +134,11 @@ def graft_lora(base_params: Dict[str, Any], adapters: Dict[str, Any],
     return out
 
 
-@jax.custom_jvp
 def _fence(xs):
-    """``optimization_barrier`` with a differentiation rule (this jax
-    has none built in): identity forward, tangents pass straight
-    through. The barrier only pins compiler scheduling/fusion — there
-    is nothing to differentiate."""
+    """``optimization_barrier``: identity that pins compiler
+    scheduling/fusion. It differentiates (tangents pass through their
+    own barrier)."""
     return jax.lax.optimization_barrier(xs)
-
-
-@_fence.defjvp
-def _fence_jvp(primals, tangents):
-    (xs,), (ts,) = primals, tangents
-    return jax.lax.optimization_barrier(xs), ts
 
 
 def lora_delta(x: jnp.ndarray, p: Dict[str, Any], name: str,

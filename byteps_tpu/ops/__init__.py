@@ -8,10 +8,6 @@ Backend selection: Pallas on TPU, jnp elsewhere; override with
 ``BYTEPS_KERNEL_BACKEND=pallas|jnp``.
 """
 
-from byteps_tpu.common.jax_compat import ensure as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from byteps_tpu.ops.chunked_ce import chunked_ce_nll, dense_ce_nll
 from byteps_tpu.ops.flash_attention import (
     attention_jnp,

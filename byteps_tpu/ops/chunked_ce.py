@@ -69,10 +69,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from byteps_tpu.common.jax_compat import ensure as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 
 def _f32_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """`a @ b` with f32 accumulation — the head_dot contract's dot."""

@@ -134,8 +134,13 @@ def _train_blocks(Sq: int, Sk: int, D: int, itemsize: int,
     return bq, bk
 
 
+from byteps_tpu.ops.backend import interpret as _interpret  # noqa: E402
+from byteps_tpu.ops.backend import note_fallback as _note_fallback  # noqa: E402
 from byteps_tpu.ops.backend import use_pallas  # noqa: E402 (re-export)
-from byteps_tpu.ops.backend import tpu_compiler_params as _compiler_params  # noqa: E402
+
+
+_UNSUPPORTED = ("sequence lengths must tile into 8..256 blocks and "
+                f"head_dim be <= {_MAX_HEAD_DIM}")
 
 
 def supported(Sq: int, Sk: int, D: int) -> bool:
@@ -173,7 +178,7 @@ def _out_struct(shape, dtype, *args):
 
 
 def _unify_vma(*xs):
-    """pvary every array to the union of the group's varying axes, so the
+    """pcast every array to the union of the group's varying axes, so the
     pallas_call boundary sees one consistent vma. (Interpret mode under
     check_vma=True still rejects kernel-internal program_id mixing — a
     known jax limitation whose error message recommends check_vma=False;
@@ -184,9 +189,18 @@ def _unify_vma(*xs):
         return xs
     union = frozenset().union(*vmas)
     return tuple(
-        jax.lax.pvary(x, tuple(union - v)) if union - v else x
+        jax.lax.pcast(x, tuple(union - v), to="varying") if union - v else x
         for x, v in zip(xs, vmas)
     )
+
+
+def _zero_cotangent(x):
+    """Zeros typed like ``x``, varying axes included: a custom-VJP rule
+    must return each cotangent with its primal's vma, and the offsets
+    reach the core already pcast to the q/k/v union (`_unify_vma`)."""
+    z = jnp.zeros(x.shape, x.dtype)
+    vma = tuple(getattr(jax.typeof(x), "vma", ()) or ())
+    return jax.lax.pcast(z, vma, to="varying") if vma else z
 
 
 def _read_offsets(qoff_ref, koff_ref):
@@ -328,7 +342,7 @@ def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
             pltpu.VMEM((bq, 1), jnp.float32),    # l (row sum)
             pltpu.VMEM((bq, D), jnp.float32),    # acc
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qoff, koff, q3, k3, v3)
@@ -501,7 +515,7 @@ def _bwd(q3, k3, v3, o3, lse, qoff, koff, do3, dlse,
         out_shape=_out_struct((BH, Sq, D), q3.dtype,
                               q3, k3, v3, do3, lse, delta, dlse, qoff, koff),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qoff, koff, q3, k3, v3, do3, lse, delta, dlse)
@@ -542,7 +556,7 @@ def _bwd(q3, k3, v3, o3, lse, qoff, koff, do3, dlse,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qoff, koff, q3, k3, v3, do3, lse, delta, dlse)
@@ -575,8 +589,7 @@ def _flash_core_bwd(causal, interpret, heads, kv_heads, res, cts):
     dlse = jnp.asarray(dlse, jnp.float32)
     dq, dk, dv = _bwd(q3, k3, v3, o3, lse, qoff, koff, do3, dlse,
                       causal, interpret, heads, kv_heads)
-    zero = jnp.zeros((1, 1), jnp.float32)
-    return dq, dk, dv, zero, zero
+    return dq, dk, dv, _zero_cotangent(qoff), _zero_cotangent(koff)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -622,10 +635,9 @@ def flash_attention_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             "flash_attention()/attention_jnp() which fall back")
     qoff = jnp.asarray(q_offset, jnp.float32).reshape(1, 1)
     koff = jnp.asarray(k_offset, jnp.float32).reshape(1, 1)
-    interpret = jax.default_backend() != "tpu"
     q3, k3, v3, qoff, koff = _unify_vma(_to3(q), _to3(k), _to3(v),
                                         qoff, koff)
-    o3, lse3 = _flash_core(q3, k3, v3, qoff, koff, causal, interpret,
+    o3, lse3 = _flash_core(q3, k3, v3, qoff, koff, causal, _interpret(),
                            H, Hkv)
     o = _from3(o3, B, H)
     lse = lse3.reshape(B, H, Sq).transpose(0, 2, 1)           # (B, Sq, H)
@@ -704,10 +716,16 @@ def attention_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     A per-batch ``(B,)`` ``q_offset`` vector (the serve tier's packed
     decode) always takes the jnp twin — the kernel's grid masking is
     scalar-offset only."""
-    if (jnp.ndim(q_offset) == 0 and use_pallas()
-            and supported(q.shape[1], k.shape[1], q.shape[-1])):
-        return flash_attention_lse(q, k, v, q_offset, k_offset,
-                                   causal=causal)
+    if use_pallas():
+        if jnp.ndim(q_offset) != 0:
+            _note_fallback("attention_lse", q.shape + k.shape,
+                           "per-row q_offset; the kernel masks by one "
+                           "scalar offset")
+        elif not supported(q.shape[1], k.shape[1], q.shape[-1]):
+            _note_fallback("attention_lse", q.shape + k.shape, _UNSUPPORTED)
+        else:
+            return flash_attention_lse(q, k, v, q_offset, k_offset,
+                                       causal=causal)
     return attention_lse_jnp(q, k, v, q_offset, k_offset, causal=causal)
 
 
@@ -723,13 +741,15 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
-    if not (use_pallas() and supported(Sq, Sk, D)):
-        if k.shape[2] != H:
-            o, _ = attention_lse_jnp(q, k, v, 0, 0, causal=causal)
+    if use_pallas():
+        if supported(Sq, Sk, D):
+            o, _ = flash_attention_lse(q, k, v, 0, 0, causal=causal)
             return o
-        return attention_jnp(q, k, v, causal=causal)
-    o, _ = flash_attention_lse(q, k, v, 0, 0, causal=causal)
-    return o
+        _note_fallback("flash_attention", q.shape + k.shape, _UNSUPPORTED)
+    if k.shape[2] != H:
+        o, _ = attention_lse_jnp(q, k, v, 0, 0, causal=causal)
+        return o
+    return attention_jnp(q, k, v, causal=causal)
 
 
 def merge_attention(o_a, lse_a, o_b, lse_b):

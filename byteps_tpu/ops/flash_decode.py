@@ -53,8 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from byteps_tpu.ops.backend import interpret as _interpret
 from byteps_tpu.ops.backend import use_pallas  # noqa: F401 (re-export)
-from byteps_tpu.ops.backend import tpu_compiler_params as _compiler_params
 from byteps_tpu.ops.flash_attention import (
     _MAX_HEAD_DIM,
     _NEG,
@@ -177,7 +177,7 @@ def _decode(q4, k4, v4, ks, vs, pos, interpret: bool):
             pltpu.VMEM((Hkv, G, 1), jnp.float32),    # l
             pltpu.VMEM((Hkv, G, D), jnp.float32),    # acc
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -211,6 +211,5 @@ def flash_decode(q, k_cache, v_cache, pos, k_scale=None, v_scale=None):
     if k_scale is not None:
         ks = k_scale.astype(jnp.float32)      # stored (B, S, Hkv) layout
         vs = v_scale.astype(jnp.float32)
-    interpret = jax.default_backend() != "tpu"
-    o = _decode(q4, k_cache, v_cache, ks, vs, pos, interpret)
+    o = _decode(q4, k_cache, v_cache, ks, vs, pos, _interpret())
     return o.reshape(B, 1, H, D)

@@ -39,8 +39,8 @@ def _block(L: int) -> int:
     return L
 
 
-from byteps_tpu.ops.backend import kernel_backend as _backend
-from byteps_tpu.ops.backend import tpu_smem as _smem  # noqa: E402
+from byteps_tpu.ops.backend import interpret as _interpret  # noqa: E402
+from byteps_tpu.ops.backend import kernel_backend as _backend  # noqa: E402
 
 
 def packed_words(n: int) -> int:
@@ -165,7 +165,7 @@ def _unpack_sum_pallas(words: jnp.ndarray, scales: jnp.ndarray,
             in_specs=[
                 pl.BlockSpec((K, bl), lambda i: (0, i)),
                 pl.BlockSpec((K, 1), lambda i: (0, 0),
-                             memory_space=_smem()),
+                             memory_space=pltpu.SMEM),
             ],
             out_specs=pl.BlockSpec((_BITS, bl), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((_BITS, L), jnp.float32),
@@ -186,7 +186,7 @@ def _unpack_sum_pallas(words: jnp.ndarray, scales: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((_GRID_K_BLOCK, bl), lambda j, k: (k, j)),
             pl.BlockSpec((_GRID_K_BLOCK, 1), lambda j, k: (k, 0),
-                         memory_space=_smem()),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((_BITS, bl), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((_BITS, L), jnp.float32),
@@ -204,8 +204,7 @@ def onebit_pack(x: jnp.ndarray,
     n = x.shape[0]
     L = packed_words(n)
     xp = jnp.pad(x.astype(jnp.float32), (0, L * _BITS - n))
-    return _pack_pallas(xp.reshape(_BITS, L),
-                        interpret=jax.default_backend() != "tpu")
+    return _pack_pallas(xp.reshape(_BITS, L), interpret=_interpret())
 
 
 def onebit_unpack_sum(words: jnp.ndarray, scales: jnp.ndarray, n: int,
@@ -214,8 +213,7 @@ def onebit_unpack_sum(words: jnp.ndarray, scales: jnp.ndarray, n: int,
     backend = backend or _backend()
     if backend == "jnp":
         return _unpack_sum_jnp(words, scales, n)
-    out = _unpack_sum_pallas(words, scales,
-                             interpret=jax.default_backend() != "tpu")
+    out = _unpack_sum_pallas(words, scales, interpret=_interpret())
     return out.reshape(-1)[:n]
 
 

@@ -53,9 +53,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from byteps_tpu.ops.backend import interpret as _interpret
 from byteps_tpu.ops.backend import kernel_backend as _backend
+from byteps_tpu.ops.backend import note_fallback as _note_fallback
 
 _LANES = 128
+
+
+_UNSUPPORTED = ("needs a lane-aligned plane (flat size % 128 == 0) and a "
+                "ring axis spanning every device")
 
 
 def kernels_supported(shape, n: int) -> bool:
@@ -174,8 +180,8 @@ def _rotate_pallas(x: jnp.ndarray, n: int, axis: str, gather: bool,
     out_shape = ((n,) + x.shape) if gather else x.shape
     return pl.pallas_call(
         functools.partial(_rotate_kernel, n=n, axis=axis, gather=gather),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
         scratch_shapes=[
             pltpu.SemaphoreType.DMA,          # local own-row copy
@@ -246,13 +252,13 @@ def _presum_pallas(x: jnp.ndarray, n: int, axis: str,
     rowshape = x.shape[1:]
     out, _comm = pl.pallas_call(
         functools.partial(_presum_kernel, n=n, axis=axis),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             # wire buffers (send stage row 0, per-hop landing rows 1..n-1)
             # — outputs only because pallas scratch has no HBM space;
             # discarded
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(rowshape, x.dtype),
@@ -278,10 +284,12 @@ def ring_collect(x: jnp.ndarray, axis: str, n: int,
     backend = backend or _backend()
     if n == 1:
         return x
-    if backend == "jnp" or not kernels_supported(x.shape[1:], n):
+    if backend == "jnp":
         return _collect_jnp(x, axis, n)
-    return _rotate_pallas(x, n, axis, gather=False,
-                          interpret=jax.default_backend() != "tpu")
+    if not kernels_supported(x.shape[1:], n):
+        _note_fallback("ring_collect", x.shape, _UNSUPPORTED)
+        return _collect_jnp(x, axis, n)
+    return _rotate_pallas(x, n, axis, gather=False, interpret=_interpret())
 
 
 def ring_allgather(x: jnp.ndarray, axis: str, n: int,
@@ -291,10 +299,12 @@ def ring_allgather(x: jnp.ndarray, axis: str, n: int,
     backend = backend or _backend()
     if n == 1:
         return x[None]
-    if backend == "jnp" or not kernels_supported(x.shape, n):
+    if backend == "jnp":
         return _allgather_jnp(x, axis, n)
-    return _rotate_pallas(x, n, axis, gather=True,
-                          interpret=jax.default_backend() != "tpu")
+    if not kernels_supported(x.shape, n):
+        _note_fallback("ring_allgather", x.shape, _UNSUPPORTED)
+        return _allgather_jnp(x, axis, n)
+    return _rotate_pallas(x, n, axis, gather=True, interpret=_interpret())
 
 
 def ring_presum(x: jnp.ndarray, axis: str, n: int,
@@ -306,7 +316,9 @@ def ring_presum(x: jnp.ndarray, axis: str, n: int,
     backend = backend or _backend()
     if n == 1:
         return x[0]
-    if backend == "jnp" or not kernels_supported(x.shape[1:], n):
+    if backend == "jnp":
         return _presum_jnp(x, axis, n)
-    return _presum_pallas(x, n, axis,
-                          interpret=jax.default_backend() != "tpu")
+    if not kernels_supported(x.shape[1:], n):
+        _note_fallback("ring_presum", x.shape, _UNSUPPORTED)
+        return _presum_jnp(x, axis, n)
+    return _presum_pallas(x, n, axis, interpret=_interpret())

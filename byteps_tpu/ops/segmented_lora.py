@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from byteps_tpu.models.lora import _fence
-from byteps_tpu.ops.backend import use_pallas
+from byteps_tpu.ops.backend import interpret, use_pallas
 
 __all__ = ["segmented_lora_delta"]
 
@@ -108,6 +108,7 @@ def _delta_pallas(x, a_slab, b_slab, slots):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, S, d_out), x.dtype),
+        interpret=interpret(),
     )(slots, x, a_slab, b_slab)
 
 

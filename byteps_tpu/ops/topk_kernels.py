@@ -33,7 +33,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from byteps_tpu.ops.backend import interpret as _interpret
 from byteps_tpu.ops.backend import kernel_backend as _backend
+from byteps_tpu.ops.backend import note_fallback as _note_fallback
 
 _LANES = 128
 
@@ -43,6 +45,9 @@ def _lane_block(rows: int) -> int:
         if rows % bl == 0:
             return bl
     return rows
+
+
+_UNSUPPORTED = "rows must be a multiple of 128 lanes and block > 1"
 
 
 def kernels_supported(block: int, rows: int) -> bool:
@@ -212,7 +217,7 @@ def block_roundtrip(x: jnp.ndarray, J: int, g: int,
     out, res = _roundtrip_pallas(
         xf.reshape(J * g, 128),
         None if e is None else e.astype(jnp.float32).reshape(J * g, 128),
-        J, g, interpret=jax.default_backend() != "tpu")
+        J, g, interpret=_interpret())
     return out.reshape(-1), res.reshape(-1)
 
 
@@ -223,9 +228,12 @@ def block_select(x2d: jnp.ndarray,
     """(block, rows) f32 → per-lane (local row (rows,) i32, value (rows,))."""
     backend = backend or _backend()
     block, rows = x2d.shape
-    if backend == "jnp" or not kernels_supported(block, rows):
+    if backend == "jnp":
         return _select_jnp(x2d)
-    lo, va = _select_pallas(x2d, interpret=jax.default_backend() != "tpu")
+    if not kernels_supported(block, rows):
+        _note_fallback("block_select", (block, rows), _UNSUPPORTED)
+        return _select_jnp(x2d)
+    lo, va = _select_pallas(x2d, interpret=_interpret())
     return lo[0], va[0]
 
 
@@ -235,8 +243,11 @@ def block_reconstruct_sum(locals_: jnp.ndarray, vals: jnp.ndarray,
     """(K, rows) winner rows + values → Σ_k dense (block, rows) f32."""
     backend = backend or _backend()
     K, rows = locals_.shape
-    if backend == "jnp" or not kernels_supported(block, rows):
+    if backend == "jnp":
+        return _reconstruct_sum_jnp(locals_, vals, block)
+    if not kernels_supported(block, rows):
+        _note_fallback("block_reconstruct_sum", (block, rows), _UNSUPPORTED)
         return _reconstruct_sum_jnp(locals_, vals, block)
     return _reconstruct_pallas(
         locals_.astype(jnp.int32), vals.astype(jnp.float32), block,
-        interpret=jax.default_backend() != "tpu")
+        interpret=_interpret())

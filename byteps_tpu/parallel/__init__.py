@@ -11,10 +11,6 @@ Everything here is shard_map-first: functions take axis *names* and are
 called inside ``jax.shard_map`` over a mesh built by :func:`make_mesh`.
 """
 
-from byteps_tpu.common.jax_compat import ensure as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from byteps_tpu.parallel.mesh import MeshAxes, make_mesh, factor_devices
 from byteps_tpu.parallel.partitioner import (FAMILY_RULES, LOGICAL_AXES,
                                              Partitioner, resolve_spec,

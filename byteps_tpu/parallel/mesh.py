@@ -22,7 +22,7 @@ import math
 from typing import Dict, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,7 +104,10 @@ def make_mesh(axes: MeshAxes, devices: Optional[Sequence] = None) -> Mesh:
                 devices=devices,
             )
             return Mesh(grid, tuple(names))
-    return jax.make_mesh(tuple(sizes), tuple(names), devices=devices)
+    # Auto axes: every shard_map/PartitionSpec here assumes them, and
+    # jax.make_mesh defaults to Explicit (sharding-in-types)
+    return jax.make_mesh(tuple(sizes), tuple(names), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def factor_devices(n: int, want_tp: int = 2, want_sp: int = 2,

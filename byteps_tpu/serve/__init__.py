@@ -46,10 +46,6 @@ pure throughput levers, never content changes (tests/test_serve.py).
 Measured by ``bench.py --mode serve`` (docs/serving.md).
 """
 
-from byteps_tpu.common.jax_compat import ensure as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from byteps_tpu.serve.adapter_pool import AdapterPool  # noqa: E402,F401
 from byteps_tpu.serve.kv_wire import (  # noqa: E402,F401
     BlockPayload,

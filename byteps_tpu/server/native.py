@@ -1,14 +1,17 @@
 """ctypes binding for the native DCN summation service.
 
-Builds ``libbyteps_tpu_server.so`` on first use if missing (``make`` +
-``g++`` are part of the supported toolchain; no pybind11 in this image, so
-the boundary is a C API + ctypes, reference analog: the ctypes-free
-``byteps/server/__init__.py`` loading the prebuilt native lib).
+Runs ``make`` on first use in each process, which (re)builds
+``libbyteps_tpu_server.so`` whenever it is missing or older than a source
+(``make`` + ``g++`` are part of the supported toolchain; no pybind11 in
+this image, so the boundary is a C API + ctypes, reference analog: the
+ctypes-free ``byteps/server/__init__.py`` loading the prebuilt native
+lib).
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -54,11 +57,22 @@ class WorkerEvictedError(RuntimeError):
 
 
 def _build() -> None:
-    log.info("building native server library (one-time)…")
-    subprocess.run(
-        ["make", "-C", _CSRC, "-j4"], check=True,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-    )
+    """Bring the library up to date with the sources beside it. ``make``
+    decides: it rebuilds when any source or header is newer than the
+    ``.so`` and is a no-op when fresh — so a tree copied with a library
+    built at some other commit cannot be loaded stale. The lock (on the
+    Makefile, so no extra file) keeps concurrent loaders — tests and
+    benches spawn server processes — from interleaving compiles."""
+    with open(os.path.join(_CSRC, "Makefile")) as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        proc = subprocess.run(
+            ["make", "-C", _CSRC, "-j4"],
+            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"building the native server library failed (make exit "
+            f"{proc.returncode}):\n{proc.stdout[-4000:]}")
 
 
 def load_lib() -> ctypes.CDLL:
@@ -66,8 +80,7 @@ def load_lib() -> ctypes.CDLL:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO):
-            _build()
+        _build()
         try:
             lib = ctypes.CDLL(_SO)
         except OSError:
@@ -77,32 +90,6 @@ def load_lib() -> ctypes.CDLL:
             os.remove(_SO)
             _build()
             lib = ctypes.CDLL(_SO)
-        try:
-            # staleness probe: a prebuilt .so predating the newest API
-            # generation (bps_codec_encode — the what-if simulator's
-            # codec-calibration surface; implies bps_client_join, the
-            # membership API, and bps_client_pull3 too) would otherwise
-            # be dlopen'd with a mismatched bps_server_start signature
-            lib.bps_codec_encode
-        except AttributeError:
-            log.warning(
-                "native library predates the codec-calibration API; "
-                "rebuilding")
-            os.remove(_SO)
-            _build()
-            lib = ctypes.CDLL(_SO)
-            try:
-                lib.bps_codec_encode
-            except AttributeError:
-                # dlopen matched the ALREADY-MAPPED stale object by path
-                # (nothing dlcloses the first handle), so the rebuild
-                # cannot take effect in this process — fail loudly
-                # instead of crashing on the argtypes below
-                raise RuntimeError(
-                    "stale libbyteps_tpu_server.so was already mapped "
-                    "into this process and cannot be replaced by a "
-                    "rebuild; restart the process (the rebuilt library "
-                    "now on disk will load cleanly)") from None
         lib.bps_server_start.argtypes = [
             ctypes.c_uint16, ctypes.c_int, ctypes.c_int, ctypes.c_int,
             ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,

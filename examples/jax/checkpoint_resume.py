@@ -19,6 +19,7 @@ import optax
 
 import byteps_tpu.jax as bps
 from byteps_tpu.checkpoint import Checkpointer
+from byteps_tpu.common.compile_cache import enable_compile_cache
 from byteps_tpu.models import GPTConfig
 from byteps_tpu.models.train import make_gpt_train_step, synthetic_batch
 from byteps_tpu.parallel import MeshAxes, make_mesh
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/byteps_tpu_ckpt")
     ap.add_argument("--steps", type=int, default=6)
     args = ap.parse_args()
+    enable_compile_cache()
 
     n = len(jax.devices())
     mesh = make_mesh(MeshAxes(dp=n))

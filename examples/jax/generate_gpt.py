@@ -17,6 +17,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from byteps_tpu.common.compile_cache import enable_compile_cache
 from byteps_tpu.models import GPTConfig, gpt_init, make_generate_fn
 
 
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--kv-heads", type=int, default=None,
                     help="GQA: kv heads in the cache (default = all)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     import dataclasses
 

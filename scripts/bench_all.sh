@@ -61,7 +61,12 @@ if ! bash scripts/proc_smoke.sh >&2; then
   TREND_LEGS_RC=1
 fi
 run_trend_leg --mode hybrid              # sharded-wire hierarchical race (+BENCH_hybrid.json)
-run_trend_leg --mode ici                 # compressed ICI tier race: staged vs ring vs native psum (+BENCH_ici.json)
+# --mode ici needs >= 4 devices and no longer re-executes itself, so this
+# runbook picks the 8 virtual CPU devices for it, before the backend
+# initializes (counts and bit-exactness, not a device number). On a host
+# with >= 4 chips run `python bench.py --mode ici` by hand instead.
+JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
+  run_trend_leg --mode ici               # compressed ICI tier race: staged vs ring vs native psum (+BENCH_ici.json)
 
 # Perf-trend regression gate LAST: the legs above rewrote
 # BENCH_{throttled,chaos,hybrid,serve}.json in place; compare the fresh

@@ -2,8 +2,13 @@
 
 The pins behind ops/chunked_ce.py's numerics claims:
 
-* single-device, single-vocab-chunk → BIT-EXACT with the dense chain
-  (same op order: max, exp-shift, sum, log);
+* single-device, single-vocab-chunk, ONE row block → BIT-EXACT with the
+  dense chain (same op order: max, exp-shift, sum, log). With several
+  row blocks the pin is a few ulp of the logit magnitude: jax 0.9.0's
+  CPU backend picks its GEMM blocking from the row count, so an 8-row
+  block's ``h @ head`` differs from the same rows of the full product
+  by up to 1 ulp (measured: 1.9e-6 at |logit| ≈ 19), which is the whole
+  difference — see ``_assert_rowblock_close``;
 * vocab sub-chunking / the tp vocab-parallel combine → f32-roundoff
   tolerance (the sum-exp association order changes);
 * gradients (recompute-in-backward custom VJP) → f32-roundoff tolerance
@@ -37,6 +42,21 @@ def _rand(seed, shape, dtype=jnp.float32):
     return jax.random.normal(jax.random.PRNGKey(seed), shape, dtype)
 
 
+def _assert_rowblock_close(got, want, h, head, bias=None):
+    """Row-blocked chunked CE vs dense: equal to within 4 ulp AT THE
+    LOGIT MAGNITUDE. Not `==` any more: the row-block GEMM and the full
+    GEMM accumulate in different orders on this CPU backend (module
+    docstring), so each logit — hence each nll, a difference of two
+    logit-sized terms — may move by an ulp or two of max|logit|
+    (measured 2). A real defect (a leaked pad row, a wrong target) is
+    orders of magnitude larger."""
+    logits = jnp.einsum("...d,dv->...v", h.astype(jnp.float32), head)
+    if bias is not None:
+        logits = logits + bias
+    tol = 4 * np.spacing(np.float32(jnp.abs(logits).max()))
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() <= tol
+
+
 @pytest.fixture(scope="module")
 def hht():
     d, V = 24, 96
@@ -47,19 +67,29 @@ def hht():
     return h, head, tgt, bias
 
 
-def test_fwd_bit_exact_dense(hht):
+# 64 >= the 51 rows: one row block, the GEMM is the dense one → `==`;
+# 8: seven row blocks → the ulp bound (reason in _assert_rowblock_close)
+@pytest.mark.parametrize("row_block", [64, 8])
+def test_fwd_bit_exact_dense(hht, row_block):
     h, head, tgt, _ = hht
-    got = jax.jit(lambda h, hd: chunked_ce_nll(h, hd, tgt, row_block=8))(
-        h, head)
+    got = jax.jit(lambda h, hd: chunked_ce_nll(
+        h, hd, tgt, row_block=row_block))(h, head)
     want = jax.jit(lambda h, hd: dense_ce_nll(h, hd, tgt))(h, head)
-    assert (np.asarray(got) == np.asarray(want)).all()
+    if row_block >= tgt.size:
+        assert (np.asarray(got) == np.asarray(want)).all()
+    else:
+        _assert_rowblock_close(got, want, h, head)
 
 
-def test_fwd_bit_exact_with_bias(hht):
+@pytest.mark.parametrize("row_block", [64, 8])
+def test_fwd_bit_exact_with_bias(hht, row_block):
     h, head, tgt, bias = hht
-    got = chunked_ce_nll(h, head, tgt, bias=bias, row_block=8)
+    got = chunked_ce_nll(h, head, tgt, bias=bias, row_block=row_block)
     want = dense_ce_nll(h, head, tgt, bias=bias)
-    assert (np.asarray(got) == np.asarray(want)).all()
+    if row_block >= tgt.size:
+        assert (np.asarray(got) == np.asarray(want)).all()
+    else:
+        _assert_rowblock_close(got, want, h, head, bias)
 
 
 def test_grads_match_dense(hht):
@@ -101,7 +131,7 @@ def test_ragged_row_blocks(hht):
     h, head, tgt, _ = hht          # N = 51 rows, row_block 16 → pad 13
     got = chunked_ce_nll(h, head, tgt, row_block=16)
     want = dense_ce_nll(h, head, tgt)
-    assert (np.asarray(got) == np.asarray(want)).all()
+    _assert_rowblock_close(got, want, h, head)
     gc = jax.grad(lambda hd: chunked_ce_nll(h, hd, tgt,
                                             row_block=16).sum())(head)
     gd = jax.grad(lambda hd: dense_ce_nll(h, hd, tgt).sum())(head)
@@ -117,7 +147,7 @@ def test_bf16_activations(hht):
     got = chunked_ce_nll(hb, head, tgt, row_block=8)
     want = dense_ce_nll(hb, head, tgt)
     assert got.dtype == jnp.float32
-    assert (np.asarray(got) == np.asarray(want)).all()
+    _assert_rowblock_close(got, want, hb, head)
     gc = jax.grad(lambda h_: chunked_ce_nll(h_, head, tgt,
                                             row_block=8).mean())(hb)
     gd = jax.grad(lambda h_: dense_ce_nll(h_, head, tgt).mean())(hb)
@@ -178,7 +208,7 @@ def test_tp_indivisible_vocab_falls_back(hht):
         mesh=mesh, in_specs=(P(), P()), out_specs=P(),
         check_vma=True))(h, head)
     want = dense_ce_nll(h, head, tgt)
-    assert (np.asarray(got) == np.asarray(want)).all()
+    _assert_rowblock_close(got, want, h, head)
 
 
 def test_shape_validation(hht):

@@ -316,8 +316,6 @@ def test_rejoin_with_partially_live_server_set(monkeypatch):
     config_mod.reset_config()
     port0 = BASE_PORT + 8
     port1 = port0 + 1
-    start_server(port=port0, num_workers=1, engine_threads=2,
-                 async_mode=False, lease_ms=400)
     proc = subprocess.Popen(
         [sys.executable, "-c",
          "from byteps_tpu.server import start_server;"
@@ -337,6 +335,17 @@ def test_rejoin_with_partially_live_server_set(monkeypatch):
                   for _ in range(3))
     w = w2 = None
     try:
+        # Server 1 (the python child) first, and server 0 only once it
+        # listens (the connect retries every 50 ms): a server's leases
+        # run from ITS start, and on this image the child needs ~450 ms
+        # to import and bind — longer than the 400 ms lease. Started the
+        # other way round, server 0 had evicted the heartbeat-less
+        # worker before its first push in 19 runs of 20 (the A0
+        # failure). The lease is not widened: the test's own eviction
+        # waits depend on it.
+        NativeClient("127.0.0.1", port1).close()
+        start_server(port=port0, num_workers=1, engine_threads=2,
+                     async_mode=False, lease_ms=400)
         w = PSWorker(servers=servers, worker_id=0, health_interval_ms=0)
         w.init_key(0, 64)   # key 0 -> server 0
         w.init_key(1, 64)   # key 1 -> server 1

@@ -43,18 +43,6 @@ def test_ring_attention_matches_plain(sp_mesh, causal):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.skipif(
-    not __import__(
-        "byteps_tpu.common.jax_compat", fromlist=["native_vma"]
-    ).native_vma(),
-    reason="needs the VMA type system (jax.shard_map + check_vma, "
-    "jax ≥ 0.6 VMA semantics): this test pins that the psum'd scalar's "
-    "transpose seeds ONE cotangent. Pre-VMA jax (this image's 0.4.37 "
-    "bridges via jax.experimental.shard_map, check_rep=False) transposes "
-    "psum to psum, so each grad legitimately comes out sp_size× — the "
-    "semantics the train factories' no-VMA grad assembly "
-    "(models/train.py _novma_collective_fix) was built to correct for; "
-    "the property under test does not exist on that API.")
 def test_ring_attention_grads_match_plain(sp_mesh):
     q, k, v = _rand_qkv(jax.random.PRNGKey(1))
 

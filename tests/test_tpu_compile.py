@@ -1,0 +1,192 @@
+"""The main path's Pallas kernels compile for the chip — without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is
+described, not attached (``jax.experimental.topologies``): what Mosaic
+refuses on the chip — a slice off the tiling, too much VMEM, a kernel it
+cannot partition — it refuses here, at no chip time. Interpret mode, which
+every other kernel test uses on CPU, shows none of that. Shapes are
+``chip_smoke.py``'s: GPT-2-medium attention (16 heads of 64, S=1024, batch
+8, bf16), the serve tier's 32-token prefill chunk, solo generate's decode
+step, and 4 MB gradient partitions.
+
+A compile is not a run: nothing here says a kernel is right or fast.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from byteps_tpu.ops import backend
+from byteps_tpu.ops import onebit_kernels as ob
+from byteps_tpu.ops import ring_collective_kernels as rk
+from byteps_tpu.ops import topk_kernels as tk
+# (the package re-exports functions named like these two modules)
+from byteps_tpu.ops.flash_attention import _flash_core, flash_attention
+from byteps_tpu.ops.flash_decode import flash_decode
+
+BF16, F32, I8, U32, I32 = (jnp.bfloat16, jnp.float32, jnp.int8, jnp.uint32,
+                           jnp.int32)
+PART = (4 << 20) // 4            # elements of one 4 MB f32 partition
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e 2x2 host, or skip. The persistent compile cache is
+    off around these tests: a compile for a described device is written to
+    it but cannot be read back without a chip, so a later run would warn
+    (on-chip-measurement guide, section 2)."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The dispatchers ask ops/backend.py one platform question; here the
+    backend is the CPU, so the test answers it (the guide: steer such code
+    in the test, not through an option of the program)."""
+    monkeypatch.setattr(backend, "_on_tpu", lambda: True)
+    monkeypatch.delenv("BYTEPS_KERNEL_BACKEND", raising=False)
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _qkv(bh, sq, sk, d, kv_bh=None):
+    kv = _sds((kv_bh or bh, sk, d), BF16)
+    return _sds((bh, sq, d), BF16), kv, kv
+
+
+def _flash_fwd(heads, kv_heads):
+    def f(q, k, v):
+        off = jnp.zeros((1, 1), F32)
+        return _flash_core(q, k, v, off, off, True, backend.interpret(),
+                           heads, kv_heads)
+    return f
+
+
+def _flash_fwd_bwd(heads, kv_heads):
+    fwd = _flash_fwd(heads, kv_heads)
+
+    def loss(q, k, v):
+        o, lse = fwd(q, k, v)
+        return o.astype(F32).sum() + lse.sum()
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _attention_bshd(q, k, v):
+    # through the dispatcher, (B, S, H, D) layout, as models/gpt.py calls it
+    return flash_attention(q, k, v, causal=True)
+
+
+def _decode(quant):
+    def f(q, k, v, *scales):
+        return flash_decode(q, k, v, jnp.int32(100), *scales)
+    B, S, H, D = 1, 1024, 16, 64
+    kv = _sds((B, S, H, D), I8 if quant else BF16)
+    args = [_sds((B, 1, H, D), BF16), kv, kv]
+    if quant:
+        args += [_sds((B, S, H), F32)] * 2
+    return f, args
+
+
+# (id, function, argument shapes, Pallas calls expected in the program)
+ONE_CHIP = [
+    ("flash_fwd_gpt2m", _flash_fwd(16, 16), _qkv(128, 1024, 1024, 64), 1),
+    ("flash_fwd_bwd_gpt2m", _flash_fwd_bwd(16, 16),
+     _qkv(128, 1024, 1024, 64), 3),
+    ("flash_fwd_bwd_gqa32_4_d128_s2048", _flash_fwd_bwd(32, 4),
+     _qkv(32, 2048, 2048, 128, kv_bh=4), 3),
+    # the paged prefill chunk: 32 new tokens against the widest and the
+    # narrowest gathered view
+    ("flash_fwd_prefill_chunk_wide", _flash_fwd(16, 16),
+     _qkv(16, 32, 1024, 64), 1),
+    ("flash_fwd_prefill_chunk_narrow", _flash_fwd(16, 16),
+     _qkv(16, 32, 32, 64), 1),
+    ("flash_attention_dispatch", _attention_bshd,
+     [_sds((8, 1024, 16, 64), BF16)] * 3, 1),
+    ("flash_decode_dense", *_decode(False), 1),
+    ("flash_decode_int8", *_decode(True), 1),
+    ("onebit_pack", ob.onebit_pack, [_sds((PART,), F32)], 1),
+    *[(f"onebit_unpack_sum_k{k}",
+       functools.partial(ob.onebit_unpack_sum, n=PART),
+       [_sds((k, ob.packed_words(PART)), U32), _sds((k,), F32)], 1)
+      for k in (1, 4, 8, 16)],
+    ("topk_select", tk.block_select, [_sds((128, 8192), F32)], 1),
+    ("topk_reconstruct_k4",
+     functools.partial(tk.block_reconstruct_sum, block=128),
+     [_sds((4, 8192), I32), _sds((4, 8192), F32)], 1),
+    ("topk_roundtrip_ef",
+     lambda x, e: tk.block_roundtrip(x, 64, 128, e=e),
+     [_sds((PART,), F32)] * 2, 1),
+]
+
+# the ring transport kernels issue remote DMAs: four chips, inside shard_map
+RING = [
+    ("ring_collect", lambda x: rk._rotate_pallas(
+        x[0], 4, "dp", gather=False, interpret=backend.interpret())[None],
+     (4, 4, 8, 128)),
+    # what the ring TIER actually hands the kernel: onebit's (n, words)
+    # payload stack of a 1 MB segment. Mosaic refuses the row slice of a
+    # 2-D stack ("Slice shape along dimension 0 must be aligned to tiling
+    # (4), but is 1") — seen on four real chips in PR 21, so
+    # BYTEPS_ICI_TIER=ring does not compile there (ROADMAP A6). Strict:
+    # the day the kernel takes this shape, this entry says so.
+    pytest.param(
+        ("ring_collect_onebit_payload", lambda x: rk._rotate_pallas(
+            x[0], 4, "dp", gather=False,
+            interpret=backend.interpret())[None], (4, 4, 8192)),
+        marks=pytest.mark.xfail(strict=True, reason="ROADMAP A6")),
+    ("ring_allgather", lambda x: rk._rotate_pallas(
+        x[0], 4, "dp", gather=True, interpret=backend.interpret())[None],
+     (4, 8, 128)),
+    ("ring_presum", lambda x: rk._presum_pallas(
+        x[0], 4, "dp", interpret=backend.interpret())[None],
+     (4, 4, 8, 128)),
+]
+
+
+def _n_pallas(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def _case_id(case):
+    return case[0] if isinstance(case, tuple) else None
+
+
+@pytest.mark.parametrize("case", ONE_CHIP + RING, ids=_case_id)
+def test_kernel_compiles_for_v5e(topo, as_on_tpu, case):
+    if len(case) == 4:
+        _, fn, args, n_calls = case
+        one = SingleDeviceSharding(topo.devices[0])
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+                for a in args]
+    else:
+        _, body, shape = case
+        n_calls = 1
+        mesh = Mesh(topo.devices, ("dp",))
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
+                           out_specs=P("dp"), check_vma=False)
+        args = [jax.ShapeDtypeStruct(shape, F32,
+                                     sharding=NamedSharding(mesh, P("dp")))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _n_pallas(compiled) == n_calls, compiled.as_text()[:2000]

@@ -111,6 +111,21 @@ def test_accum_steps_matches_full_batch():
     np.testing.assert_allclose(acc, base, rtol=2e-4, atol=2e-4)
 
 
+def test_accum_steps_on_one_device_mesh_matches_full_batch():
+    """The one-device mesh has every axis at size 1: the batch spec still
+    names (slice_, dp) while the params are not cast to them, so the
+    accumulator's scan carry has to type the loss by the batch's axes
+    too (a TypeError under check_vma before PR 21 — chip_smoke's
+    one-device reference for the four-chip step is this path)."""
+    tokens, targets = synthetic_batch(jax.random.PRNGKey(4), CFG, 8, 32)
+    mesh = make_mesh(MeshAxes(), devices=jax.devices()[:1])
+    tx = optax.adam(1e-2)
+    base, _ = _run(make_gpt_train_step(CFG, mesh, tx), tokens, targets)
+    acc, _ = _run(make_gpt_train_step(CFG, mesh, tx, accum_steps=4),
+                  tokens, targets)
+    np.testing.assert_allclose(acc, base, rtol=2e-4, atol=2e-4)
+
+
 @pytest.mark.slow
 def test_accum_steps_with_zero_and_compression():
     tokens, targets = synthetic_batch(jax.random.PRNGKey(5), CFG, 8, 32)
