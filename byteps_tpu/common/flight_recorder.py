@@ -210,6 +210,10 @@ class FlightRecorder:
             "steps": steps,
             "fault_events": events,
             "metrics": get_registry().snapshot(),
+            # what the program was doing last: the tail of the tracer's
+            # always-on span ring (name, start_s, dur_s, id, parent,
+            # args; seconds on time.monotonic())
+            "spans": _last_spans(),
             # the run's resolved knobs ride every dump: a post-mortem is
             # a valid (degraded) what-if simulator input on its own
             # (sim/extract.cost_model_from_flight_dump)
@@ -258,6 +262,13 @@ class FlightRecorder:
             # must never add a second failure on top of the first
             log.warning("flight-recorder dump failed: %s", e)
             return None
+
+
+def _last_spans(n: int = 256) -> List[Any]:
+    # imported here: the tracer imports this module for its FAULT instants
+    from byteps_tpu.common.tracing import get_tracer
+
+    return json_safe(get_tracer().spans()[-n:])
 
 
 def _config_snapshot() -> Dict[str, Any]:

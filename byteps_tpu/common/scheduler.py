@@ -27,6 +27,7 @@ chrome-trace events are emitted (SURVEY §5.1), giving dPRO-style timelines.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 import itertools
@@ -716,13 +717,20 @@ class PipelineScheduler:
         t_issue = time.perf_counter()
         if task.queued_at:
             self._m_dwell[si].observe((t_issue - task.queued_at) * 1e6)
-        t0 = self._tracer._now_us() if self._tracer else 0.0
-        try:
-            result = stage.fn(task)
-            task.payload = result
-            failed = None
-        except BaseException as e:  # noqa: BLE001 - propagate via handle
-            failed = e
+        args = {"key": task.partition.key,
+                "priority": task.partition.priority,
+                "length": task.partition.length}
+        with (self._tracer.span(f"{task.name}.p{task.partition.part_idx}",
+                                stage.name, args)
+              if self._tracer else contextlib.nullcontext()):
+            try:
+                result = stage.fn(task)
+                task.payload = result
+                failed = None
+            except BaseException as e:  # noqa: BLE001 - propagate via handle
+                failed = e
+                args.update(error=type(e).__name__,
+                            attempt=task.stage_attempts)
         self._m_run[si].observe((time.perf_counter() - t_issue) * 1e6)
         retrying = (
             failed is not None
@@ -742,21 +750,6 @@ class PipelineScheduler:
                 log.error("stage %s failed for %s.%d: %s",
                           stage.name, task.name, task.partition.part_idx,
                           failed)
-        if self._tracer:
-            self._tracer.complete_event(
-                name=f"{task.name}.p{task.partition.part_idx}",
-                stage=stage.name,
-                start_us=t0,
-                dur_us=self._tracer._now_us() - t0,
-                args={
-                    "key": task.partition.key,
-                    "priority": task.partition.priority,
-                    "length": task.partition.length,
-                    **({"error": type(failed).__name__,
-                        "attempt": task.stage_attempts}
-                       if failed is not None else {}),
-                },
-            )
         with self._lock:
             self._busy[si] -= 1
             if failed is None and stage.releases_credit:

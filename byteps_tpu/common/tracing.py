@@ -11,12 +11,30 @@ metadata, so dPRO-style per-stage attribution works on the TPU build.
 
 Device-side work is additionally coverable by ``jax.profiler`` XLA traces;
 this recorder is the framework-level (scheduler/transport) view.
+
+One span primitive, three consumers (docs/observability.md §spans):
+
+- an always-on, bounded in-memory **ring** (``spans()``), whatever
+  ``BYTEPS_TRACE_ON`` and the step window say — what a benchmark reader or
+  a post-mortem cuts to a window. An entry is the tuple ``(name, start_s,
+  dur_s, span_id, parent_id, args)``; times are seconds on
+  ``time.monotonic()``'s clock (``TraceRecorder.clock``), so a reader
+  compares them with its own ``time.monotonic()`` stamps directly.
+  ``BYTEPS_METRICS_ON=0`` stills it, like the registry;
+- a ``jax.profiler.TraceAnnotation`` for the span's lifetime, so that under
+  any profiler session the span sits on the host line of the same xplane as
+  the device ops (the profiler's clock is this clock plus one constant per
+  session);
+- the step-windowed chrome-trace dump, as before.
 """
 
 from __future__ import annotations
 
+import collections
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -27,6 +45,10 @@ from byteps_tpu.common.logging import get_logger
 from byteps_tpu.common.metrics import json_safe
 
 log = get_logger("tracing")
+
+# the saturated serving cell makes ~830 iterations x ~8 spans in a 50 s
+# window; an entry is one small tuple
+RING_SPANS = 65536
 
 
 class TraceRecorder:
@@ -56,14 +78,20 @@ class TraceRecorder:
         self._events: List[Dict[str, Any]] = []
         self._lock = threading.Lock()
         self._step = 0
-        # Timestamps are ABSOLUTE epoch microseconds, advanced by the
-        # monotonic clock (immune to wall-clock steps mid-run): the server
-        # trace records CLOCK_REALTIME, so worker and server events land on
-        # one timeline without post-hoc shifting (same host; cross-host uses
-        # the recorded ping clock offset — see merge_traces).
-        self._epoch0_ns = time.time_ns()
-        self._perf0_ns = time.perf_counter_ns()
+        # Chrome timestamps are ABSOLUTE epoch microseconds, advanced by
+        # the monotonic clock (immune to wall-clock steps mid-run): the
+        # server trace records CLOCK_REALTIME, so worker and server events
+        # land on one timeline without post-hoc shifting (same host;
+        # cross-host uses the recorded ping clock offset — see
+        # merge_traces). One constant takes the ring's clock there.
+        self._epoch_minus_mono_us = (time.time_ns()
+                                     - time.monotonic_ns()) / 1e3
         self._dumped = False
+        self._ring: Optional[collections.deque] = (
+            collections.deque(maxlen=RING_SPANS)
+            if get_config().metrics_on else None)
+        self._ids = itertools.count(1)
+        self._open = threading.local()     # .sid: the thread's open span
 
     # -- step lifecycle -----------------------------------------------------
     def step(self) -> None:
@@ -147,12 +175,30 @@ class TraceRecorder:
             and self.start_step <= self._step <= self.end_step
         )
 
+    #: the ring's clock: entries compare with ``time.monotonic()`` stamps
+    clock = staticmethod(time.monotonic)
+
     def _now_us(self) -> float:
-        return (
-            self._epoch0_ns + (time.perf_counter_ns() - self._perf0_ns)
-        ) / 1e3
+        return self._epoch_minus_mono_us + time.monotonic_ns() / 1e3
 
     # -- event emission -----------------------------------------------------
+    def emit(self, name: str, stage: str, start_s: float, dur_s: float,
+             args: Any = None) -> int:
+        """One finished span whose ends the caller stamped itself on
+        ``clock`` (the serve scheduler's per-request phases, from the
+        stamps its results are computed from). Returns the span's id."""
+        sid = next(self._ids)
+        self._record(name, stage, start_s, dur_s, sid, 0, args)
+        return sid
+
+    def _record(self, name, stage, start_s, dur_s, sid, parent, args):
+        if self._ring is not None:
+            self._ring.append((name, start_s, dur_s, sid, parent, args))
+        if self.active:
+            self._chrome_x(name, stage,
+                           self._epoch_minus_mono_us + start_s * 1e6,
+                           dur_s * 1e6, args)
+
     def complete_event(
         self,
         name: str,
@@ -161,8 +207,17 @@ class TraceRecorder:
         dur_us: float,
         args: Optional[Dict[str, Any]] = None,
     ) -> None:
-        if not self.active:
-            return
+        """``emit`` in the chrome trace's units (epoch microseconds)."""
+        if self._ring is not None:
+            self._ring.append((
+                name, (start_us - self._epoch_minus_mono_us) / 1e6,
+                dur_us / 1e6, next(self._ids), 0, args))
+        if self.active:
+            self._chrome_x(name, stage, start_us, dur_us, args)
+
+    def _chrome_x(self, name, stage, start_us, dur_us, args) -> None:
+        if args is not None and not isinstance(args, dict):
+            args = {"args": args}
         ev = {
             "name": name,
             "cat": "byteps",
@@ -179,9 +234,22 @@ class TraceRecorder:
         with self._lock:
             self._events.append(ev)
 
-    def span(self, name: str, stage: str, args: Optional[Dict[str, Any]] = None):
-        """Context manager emitting one complete event."""
+    def span(self, name: str, stage: str, args: Any = None):
+        """Context manager recording one span: ring, profiler annotation
+        and (inside the step window) chrome event. Spans opened inside
+        it on the same thread carry its id as their parent. ``args`` is
+        kept as given on the ring (a small tuple is cheapest) and made
+        JSON-safe only on the chrome path."""
         return _Span(self, name, stage, args)
+
+    def spans(self, since: Optional[float] = None) -> List[tuple]:
+        """A copy of the ring, oldest first: ``(name, start_s, dur_s,
+        span_id, parent_id, args)``; with ``since``, the entries that
+        start at or after that ``clock`` time."""
+        ring = list(self._ring) if self._ring is not None else []
+        if since is not None:
+            ring = [e for e in ring if e[1] >= since]
+        return ring
 
     def instant(self, name: str, stage: str, args: Optional[Dict[str, Any]] = None) -> None:
         if stage == "FAULT":
@@ -243,22 +311,51 @@ class TraceRecorder:
         return path
 
 
+_TraceAnnotation = None
+
+
+def _resolve_annotation():
+    """``jax.profiler.TraceAnnotation`` once the process has imported jax
+    (without jax there is no profiler session to be seen in, and this
+    module stays importable by a process that never loads it)."""
+    global _TraceAnnotation
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    _TraceAnnotation = getattr(prof, "TraceAnnotation", None)
+    return _TraceAnnotation
+
+
 class _Span:
+    __slots__ = ("rec", "name", "stage", "args", "t0", "sid", "parent",
+                 "_ann")
+
     def __init__(self, rec: TraceRecorder, name: str, stage: str, args):
         self.rec = rec
         self.name = name
         self.stage = stage
         self.args = args
-        self.t0 = 0.0
 
     def __enter__(self):
-        self.t0 = self.rec._now_us()
+        rec = self.rec
+        open_ = rec._open
+        self.parent = getattr(open_, "sid", 0)
+        self.sid = open_.sid = next(rec._ids)
+        ann = _TraceAnnotation or _resolve_annotation()
+        if ann is not None:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        self.rec.complete_event(
-            self.name, self.stage, self.t0, self.rec._now_us() - self.t0, self.args
-        )
+        t1 = time.monotonic()
+        rec = self.rec
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        rec._open.sid = self.parent
+        rec._record(self.name, self.stage, self.t0, t1 - self.t0,
+                    self.sid, self.parent, self.args)
         return False
 
 

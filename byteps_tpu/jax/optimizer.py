@@ -609,27 +609,28 @@ def DistributedOptimizer(
         agg_n = n * n_dcn
 
         mom = state.momentum
-        if spec.enabled and mom is not None:
-            # Nesterov momentum before compression (reference:
-            # nesterov_momentum.cc decorator)
-            flat, sizes = _flatten_concat(grads)
-            flat, mom = momentum_step(flat, mom, spec.mu)
-            grads_in = _unconcat_unflatten(flat, grads, sizes)
-        else:
-            grads_in = grads
+        with jax.named_scope("grad_aggregate"):
+            if spec.enabled and mom is not None:
+                # Nesterov momentum before compression (reference:
+                # nesterov_momentum.cc decorator)
+                flat, sizes = _flatten_concat(grads)
+                flat, mom = momentum_step(flat, mom, spec.mu)
+                grads_in = _unconcat_unflatten(flat, grads, sizes)
+            else:
+                grads_in = grads
 
-        if spec.enabled and state.ef is not None:
-            agg, new_ef = push_pull_inside(
-                grads_in, agg_axis, agg_n, average, spec, rng,
-                ef_residual=state.ef, partition_bytes=partition_bytes,
-                two_way=spec.two_way,
-            )
-        else:
-            agg = push_pull_inside(
-                grads_in, agg_axis, agg_n, average, spec, rng,
-                partition_bytes=partition_bytes, two_way=spec.two_way,
-            )
-            new_ef = state.ef
+            if spec.enabled and state.ef is not None:
+                agg, new_ef = push_pull_inside(
+                    grads_in, agg_axis, agg_n, average, spec, rng,
+                    ef_residual=state.ef, partition_bytes=partition_bytes,
+                    two_way=spec.two_way,
+                )
+            else:
+                agg = push_pull_inside(
+                    grads_in, agg_axis, agg_n, average, spec, rng,
+                    partition_bytes=partition_bytes, two_way=spec.two_way,
+                )
+                new_ef = state.ef
 
         if cfg.trace_on:
             # Per-execution dispatch-site marker (SURVEY §5.1): the fused
@@ -648,7 +649,8 @@ def DistributedOptimizer(
                 total_elems=total, chunks=nchunks,
             )
 
-        updates, new_inner = tx.update(agg, state.inner, params)
+        with jax.named_scope("optimizer_update"):
+            updates, new_inner = tx.update(agg, state.inner, params)
         if new_ef is not None:
             new_ef = new_ef.reshape(ef_shape)
         if mom is not None:

@@ -21,6 +21,7 @@ from typing import Callable, Dict, Optional
 import jax
 
 from byteps_tpu.common.logging import get_logger
+from byteps_tpu.common.tracing import get_tracer
 from byteps_tpu.common.tuner import AutoTuner
 
 log = get_logger("jax.tuned_step")
@@ -90,10 +91,11 @@ class AutoTunedStep:
             step = self._build(self._pb)
             self._compiled[self._pb] = step
             self.retraces += 1
-        if self.tuner.converged:
-            return step(*args)
         t0 = time.perf_counter()
-        out = step(*args)
+        with get_tracer().span("train.dispatch", "TRAIN"):
+            out = step(*args)
+        if self.tuner.converged:
+            return out
         jax.block_until_ready(out)
         self.tuner.record_step(time.perf_counter() - t0)
         return out

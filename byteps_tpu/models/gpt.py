@@ -328,12 +328,16 @@ def transformer_block(x, p, head_dim: int, tp_axis=None, sp_axis=None,
     (contiguous or zigzag sequence layout), optional RoPE
     (``rope_base > 0``), layernorm or rmsnorm (``norm_fn``), optional
     llama-style bias-free projections (``use_bias=False``)."""
-    x = x + _attention(norm_fn(x, p["ln1_g"], p.get("ln1_b"), norm_eps), p,
-                       head_dim, tp_axis, sp_axis, causal=causal,
-                       seq_layout=seq_layout, rope_base=rope_base,
-                       use_bias=use_bias)
-    return x + _mlp(norm_fn(x, p["ln2_g"], p.get("ln2_b"), norm_eps), p,
-                    tp_axis, use_bias=use_bias)
+    # named scopes are for an operator's xprof op profile; they change no
+    # compiled program (docs/observability.md §spans)
+    with jax.named_scope("block/attn"):
+        x = x + _attention(norm_fn(x, p["ln1_g"], p.get("ln1_b"), norm_eps),
+                           p, head_dim, tp_axis, sp_axis, causal=causal,
+                           seq_layout=seq_layout, rope_base=rope_base,
+                           use_bias=use_bias)
+    with jax.named_scope("block/mlp"):
+        return x + _mlp(norm_fn(x, p["ln2_g"], p.get("ln2_b"), norm_eps), p,
+                        tp_axis, use_bias=use_bias)
 
 
 def block_init(rng, d: int, ff: int, hd: int, n_layers: int,
@@ -529,16 +533,17 @@ def _readout_nll(params, h: jnp.ndarray, targets: jnp.ndarray,
     ``head_dot`` + ``log_softmax`` chain, bit-identical to the chunked
     path on single-device f32 configs and the golden it is pinned
     against."""
-    h = norm_fn(h, params["lnf_g"], params.get("lnf_b"), norm_eps)
-    head = (params["lm_head"] if "lm_head" in params
-            else params["wte"].T).astype(jnp.float32)
-    if chunked:
-        from byteps_tpu.ops.chunked_ce import chunked_ce_nll
+    with jax.named_scope("readout_ce"):
+        h = norm_fn(h, params["lnf_g"], params.get("lnf_b"), norm_eps)
+        head = (params["lm_head"] if "lm_head" in params
+                else params["wte"].T).astype(jnp.float32)
+        if chunked:
+            from byteps_tpu.ops.chunked_ce import chunked_ce_nll
 
-        return chunked_ce_nll(
-            h, head, targets,
-            tp_axis=tp_axis if chunked == "vocab_parallel" else None)
-    return _nll(head_dot(h, head), targets)
+            return chunked_ce_nll(
+                h, head, targets,
+                tp_axis=tp_axis if chunked == "vocab_parallel" else None)
+        return _nll(head_dot(h, head), targets)
 
 
 def gpt_hidden(params, tokens: jnp.ndarray, cfg: GPTConfig,
@@ -552,7 +557,8 @@ def gpt_hidden(params, tokens: jnp.ndarray, cfg: GPTConfig,
     them)."""
     rope_base = resolve_rope(cfg)
     norm_fn, norm_eps = resolve_norm(cfg)
-    x = _embed(params, tokens, cfg, sp_axis, seq_layout)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, cfg, sp_axis, seq_layout)
 
     def apply_block(x, p):
         return transformer_block(x, p, cfg.head_dim, tp_axis, sp_axis,

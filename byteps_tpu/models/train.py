@@ -34,6 +34,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from byteps_tpu.common.flight_recorder import get_flight_recorder
+from byteps_tpu.common.tracing import get_tracer
 from byteps_tpu.jax.optimizer import DistributedOptimizer, dp_state_specs
 from byteps_tpu.models.bert import BertConfig, bert_init, bert_mlm_loss
 from byteps_tpu.models.gpt import (
@@ -385,7 +386,10 @@ class _TickingStep:
         self._jitted = jitted
 
     def __call__(self, *args, **kwargs):
-        out = self._jitted(*args, **kwargs)
+        # the host's share of a step: what the caller's step time holds
+        # beyond this span is its wait in block_until_ready
+        with get_tracer().span("train.dispatch", "TRAIN"):
+            out = self._jitted(*args, **kwargs)
         # relative tick: the recorder may already be ahead (eager
         # rounds, a previous model) — a private 1-based counter
         # would be dropped there (FlightRecorder.tick docstring)

@@ -336,8 +336,11 @@ def _chunked_ce_fwd(h2, head, bias, tgt, tp_axis, row_block, vocab_block):
 
 def _chunked_ce_bwd(tp_axis, row_block, vocab_block, res, g):
     h2, head, bias, tgt, lse = res
-    dh2, dhead, dbias = _bwd_scan(h2, head, bias, tgt, lse, g, tp_axis,
-                                  row_block, vocab_block)
+    # the forward inherits its caller's scope; a custom_vjp's backward
+    # is traced apart from it
+    with jax.named_scope("readout_ce"):
+        dh2, dhead, dbias = _bwd_scan(h2, head, bias, tgt, lse, g, tp_axis,
+                                      row_block, vocab_block)
     if bias is None:
         dbias = None
     # int targets take a symbolic-zero (float0) cotangent

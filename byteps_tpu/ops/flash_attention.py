@@ -345,6 +345,7 @@ def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(qoff, koff, q3, k3, v3)
 
 
@@ -518,6 +519,7 @@ def _bwd(q3, k3, v3, o3, lse, qoff, koff, do3, dlse,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qoff, koff, q3, k3, v3, do3, lse, delta, dlse)
 
     # dkv iterates every (group member, q block) for its kv head: the q
@@ -559,6 +561,7 @@ def _bwd(q3, k3, v3, o3, lse, qoff, koff, do3, dlse,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qoff, koff, q3, k3, v3, do3, lse, delta, dlse)
     return dq, dk, dv
 

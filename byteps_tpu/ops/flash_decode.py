@@ -180,6 +180,7 @@ def _decode(q4, k4, v4, ks, vs, pos, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(*operands)
     return out
 

@@ -809,28 +809,35 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
         # (quantizing first in quant mode, so attention reads the same
         # lossy values the dense _cache_write→_cache_read roundtrip
         # produces)
-        if pool.k_scale is not None:
-            kq, ks = _quantize_block(k)
-            vq, vs = _quantize_block(v)
-            pool = PoolState(
-                k=pool.k.at[li, blk, off].set(kq[:, 0]),
-                v=pool.v.at[li, blk, off].set(vq[:, 0]),
-                k_scale=pool.k_scale.at[li, blk, off].set(ks[:, 0]),
-                v_scale=pool.v_scale.at[li, blk, off].set(vs[:, 0]),
-            )
-        else:
-            pool = PoolState(
-                k=pool.k.at[li, blk, off].set(k[:, 0].astype(pool.k.dtype)),
-                v=pool.v.at[li, blk, off].set(v[:, 0].astype(pool.v.dtype)),
-            )
+        with jax.named_scope("paged/scatter_kv"):
+            if pool.k_scale is not None:
+                kq, ks = _quantize_block(k)
+                vq, vs = _quantize_block(v)
+                pool = PoolState(
+                    k=pool.k.at[li, blk, off].set(kq[:, 0]),
+                    v=pool.v.at[li, blk, off].set(vq[:, 0]),
+                    k_scale=pool.k_scale.at[li, blk, off].set(ks[:, 0]),
+                    v_scale=pool.v_scale.at[li, blk, off].set(vs[:, 0]),
+                )
+            else:
+                pool = PoolState(
+                    k=pool.k.at[li, blk, off].set(
+                        k[:, 0].astype(pool.k.dtype)),
+                    v=pool.v.at[li, blk, off].set(
+                        v[:, 0].astype(pool.v.dtype)),
+                )
         length = pos + 1                       # new key included
-        kk = _gather_view(pool.k[li],
-                          None if pool.k_scale is None else pool.k_scale[li],
-                          tables, length, x.dtype, block_size)
-        vv = _gather_view(pool.v[li],
-                          None if pool.v_scale is None else pool.v_scale[li],
-                          tables, length, x.dtype, block_size)
-        o, _ = attention_lse(q, kk, vv, pos, 0, causal=True)
+        with jax.named_scope("paged/gather_kv"):
+            kk = _gather_view(
+                pool.k[li],
+                None if pool.k_scale is None else pool.k_scale[li],
+                tables, length, x.dtype, block_size)
+            vv = _gather_view(
+                pool.v[li],
+                None if pool.v_scale is None else pool.v_scale[li],
+                tables, length, x.dtype, block_size)
+        with jax.named_scope("paged/attention"):
+            o, _ = attention_lse(q, kk, vv, pos, 0, causal=True)
         o = o.reshape(R, 1, h_loc * head_dim)
         attn_out = row_parallel_matmul(o, p["wo"].astype(x.dtype), tp_axis,
                                        _bias(p, "bo", x, use_bias))
@@ -905,50 +912,52 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     def chunk(params, pool, tokens, pos0, table):
         quant = pool.k_scale is not None
         S = table.shape[0] * block_size
-        keep = (jnp.arange(S) < pos0)
-        gk = pool.k[:, table].reshape(L, 1, S, *pool.k.shape[-2:])
-        gv = pool.v[:, table].reshape(L, 1, S, *pool.v.shape[-2:])
-        gk = jnp.where(keep[None, None, :, None, None], gk,
-                       jnp.zeros((), gk.dtype))
-        gv = jnp.where(keep[None, None, :, None, None], gv,
-                       jnp.zeros((), gv.dtype))
-        if quant:
-            gks = pool.k_scale[:, table].reshape(L, 1, S, -1)
-            gvs = pool.v_scale[:, table].reshape(L, 1, S, -1)
-            gks = jnp.where(keep[None, None, :, None], gks, 0.0)
-            gvs = jnp.where(keep[None, None, :, None], gvs, 0.0)
+        with jax.named_scope("paged/gather_kv"):
+            keep = (jnp.arange(S) < pos0)
+            gk = pool.k[:, table].reshape(L, 1, S, *pool.k.shape[-2:])
+            gv = pool.v[:, table].reshape(L, 1, S, *pool.v.shape[-2:])
+            gk = jnp.where(keep[None, None, :, None, None], gk,
+                           jnp.zeros((), gk.dtype))
+            gv = jnp.where(keep[None, None, :, None, None], gv,
+                           jnp.zeros((), gv.dtype))
+            if quant:
+                gks = pool.k_scale[:, table].reshape(L, 1, S, -1)
+                gvs = pool.v_scale[:, table].reshape(L, 1, S, -1)
+                gks = jnp.where(keep[None, None, :, None], gks, 0.0)
+                gvs = jnp.where(keep[None, None, :, None], gvs, 0.0)
         cache = KVCache(k=gk, v=gv, length=pos0,
                         k_scale=gks if quant else None,
                         v_scale=gvs if quant else None)
         logits, cache = gpt_apply_cached(params, tokens, cache, cfg,
                                          tp_axis, readout=with_readout)
         # scatter the C newly written rows back into the pool
-        positions = pos0 + jnp.arange(C)
-        blk = jnp.take(table, positions // block_size)
-        off = positions % block_size
-        h = cache.k.shape[-2]
-        newk = jax.lax.dynamic_slice(
-            cache.k, (0, 0, pos0, 0, 0),
-            (L, 1, C, h, cfg.head_dim))[:, 0]
-        newv = jax.lax.dynamic_slice(
-            cache.v, (0, 0, pos0, 0, 0),
-            (L, 1, C, h, cfg.head_dim))[:, 0]
-        if quant:
-            newks = jax.lax.dynamic_slice(
-                cache.k_scale, (0, 0, pos0, 0), (L, 1, C, h))[:, 0]
-            newvs = jax.lax.dynamic_slice(
-                cache.v_scale, (0, 0, pos0, 0), (L, 1, C, h))[:, 0]
-            pool = PoolState(
-                k=pool.k.at[:, blk, off].set(newk),
-                v=pool.v.at[:, blk, off].set(newv),
-                k_scale=pool.k_scale.at[:, blk, off].set(newks),
-                v_scale=pool.v_scale.at[:, blk, off].set(newvs),
-            )
-        else:
-            pool = PoolState(
-                k=pool.k.at[:, blk, off].set(newk),
-                v=pool.v.at[:, blk, off].set(newv),
-            )
+        with jax.named_scope("paged/scatter_kv"):
+            positions = pos0 + jnp.arange(C)
+            blk = jnp.take(table, positions // block_size)
+            off = positions % block_size
+            h = cache.k.shape[-2]
+            newk = jax.lax.dynamic_slice(
+                cache.k, (0, 0, pos0, 0, 0),
+                (L, 1, C, h, cfg.head_dim))[:, 0]
+            newv = jax.lax.dynamic_slice(
+                cache.v, (0, 0, pos0, 0, 0),
+                (L, 1, C, h, cfg.head_dim))[:, 0]
+            if quant:
+                newks = jax.lax.dynamic_slice(
+                    cache.k_scale, (0, 0, pos0, 0), (L, 1, C, h))[:, 0]
+                newvs = jax.lax.dynamic_slice(
+                    cache.v_scale, (0, 0, pos0, 0), (L, 1, C, h))[:, 0]
+                pool = PoolState(
+                    k=pool.k.at[:, blk, off].set(newk),
+                    v=pool.v.at[:, blk, off].set(newv),
+                    k_scale=pool.k_scale.at[:, blk, off].set(newks),
+                    v_scale=pool.v_scale.at[:, blk, off].set(newvs),
+                )
+            else:
+                pool = PoolState(
+                    k=pool.k.at[:, blk, off].set(newk),
+                    v=pool.v.at[:, blk, off].set(newv),
+                )
         return logits, pool
 
     return chunk

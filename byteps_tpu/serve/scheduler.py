@@ -105,6 +105,7 @@ from byteps_tpu.common.faults import FaultPlan, WorkerKilledError, plan_from_env
 from byteps_tpu.common.flight_recorder import get_flight_recorder
 from byteps_tpu.common.logging import get_logger
 from byteps_tpu.common.metrics import get_registry
+from byteps_tpu.common.tracing import get_tracer
 from byteps_tpu.models.generate import gpt_apply_cached, init_cache
 from byteps_tpu.models.gpt import GPTConfig
 from byteps_tpu.models.speculative import _verify_commit
@@ -204,7 +205,7 @@ class _Run:
 
     __slots__ = ("req", "full_input", "emitted", "pending", "cache_len",
                  "prefill_done", "state", "t_submit", "t_origin", "t_admit",
-                 "t_first", "t_last", "preemptions", "spec_rounds",
+                 "t_first", "t_last", "t_phase", "preemptions", "spec_rounds",
                  "draft_cache", "tok_s", "idx_seq", "streamed", "tenant",
                  "slot")
 
@@ -227,6 +228,9 @@ class _Run:
         self.t_admit = 0.0
         self.t_first: Optional[float] = None
         self.t_last = self.t_origin
+        # start of the lifecycle phase the run is in (queued → prefill →
+        # decode): each transition closes it as a serve.request.* span
+        self.t_phase = self.t_origin
         self.preemptions = 0
         self.spec_rounds = 0
         self.draft_cache = None
@@ -377,6 +381,7 @@ class Scheduler:
         # waiting a prompt's worth of prefill chunks with the batch
         # underfull (the pool pressure valve is preemption either way)
         self._admit_cap = self.max_batch + max(1, self.max_batch // 4)
+        self._iteration = 0            # the serve.iteration span's number
         _reg = get_registry()
         self._m = {
             "admitted": _reg.counter("serve.admitted"),
@@ -400,8 +405,12 @@ class Scheduler:
             "migrated_tokens": _reg.counter("serve.migration.tokens"),
             "recompute_tokens": _reg.counter(
                 "serve.migration.recompute_tokens"),
-            "iterations": _reg.counter("serve.iterations"),
             "ttft_ms": _reg.histogram("serve.ttft_ms"),
+            # the two parts of TTFT, observed where the phases' spans are
+            # emitted: waiting for a slot, and chunked prefill behind
+            # older prompts
+            "queue_wait_ms": _reg.histogram("serve.queue_wait_ms"),
+            "prefill_ms": _reg.histogram("serve.prefill_ms"),
             "token_ms": _reg.histogram("serve.token_ms"),
             "request_ms": _reg.histogram("serve.request_ms"),
             "batch_occupancy": _reg.histogram("serve.batch_occupancy"),
@@ -683,6 +692,7 @@ class Scheduler:
         run.t_origin = ticket.t_origin
         run.t_first = ticket.t_first
         run.t_last = ticket.tok_s[-1] if ticket.tok_s else ticket.t_origin
+        run.t_phase = self._clock()        # its decode phase here starts now
         run.tok_s = list(ticket.tok_s)
         run.preemptions = ticket.preemptions
         run.spec_rounds = ticket.spec_rounds
@@ -875,6 +885,22 @@ class Scheduler:
             run.slot = None
 
     # -- internals ----------------------------------------------------------
+    def _phase(self, run: _Run, name: str, now: float,
+               cut: bool = False) -> float:
+        """Close the lifecycle phase ``run`` is in (queued → prefill →
+        decode, and again after a preemption) as one
+        ``serve.request.<name>`` span ending ``now``, from the stamps the
+        results are computed from: an unpreempted request's queued +
+        prefill is its ``ttft_s`` to the last bit. Returns its length in
+        ms."""
+        dur = now - run.t_phase
+        tag = ("preempted",) if cut else \
+            ("resumed",) if run.preemptions else ()
+        get_tracer().emit(f"serve.request.{name}", "SERVE", run.t_phase,
+                          dur, (run.req.rid, *tag))
+        run.t_phase = now
+        return dur * 1e3
+
     def _commit_token(self, run: _Run, tok: int, now: float) -> None:
         """Append one generated token, stamp latencies, finish when the
         request is done (max_new reached or eos emitted)."""
@@ -898,6 +924,7 @@ class Scheduler:
             self._finish(run, now)
 
     def _finish(self, run: _Run, now: float) -> None:
+        self._phase(run, "decode", now)
         self.cache.release(run.req.rid)
         self._release_adapter(run)
         self._running.remove(run)
@@ -931,6 +958,8 @@ class Scheduler:
         # that caused this evict) — the migrate-vs-recompute headline's
         # "recompute" side (bench.py --mode serve, migrate leg)
         self._m["recompute_tokens"].inc(run.cache_len)
+        self._phase(run, "prefill" if run.state == "prefill" else "decode",
+                    self._clock(), cut=True)
         self.cache.release(run.req.rid)
         self._release_adapter(run)
         run.state = "queued"
@@ -1134,8 +1163,16 @@ class Scheduler:
                         f"plan at op {self._plan.step}")
                 if inj.kind == "hang":
                     time.sleep(inj.rule.latency_ms / 1e3)
-        self._m["iterations"].inc()
-        now = self._clock()
+        tr = get_tracer()
+        self._iteration += 1
+        with tr.span("serve.iteration", "SERVE", (self._iteration,)):
+            with tr.span("serve.admit", "SERVE"):
+                progress = self._admit(self._clock())
+            return self._lanes(tr) or progress
+
+    def _admit(self, now: float) -> bool:
+        """§1 of an iteration: admission. True when a request was
+        admitted."""
         progress = False
 
         # tenant-scoped fault rules (tenant<T>:slow|hang): one
@@ -1236,6 +1273,7 @@ class Scheduler:
             run.cache_len = hit_tokens
             run.state = "prefill"
             run.t_admit = now
+            self._m["queue_wait_ms"].observe(self._phase(run, "queued", now))
             self._running.append(run)
             self._charge_admission(run, reserve)
             self._m["admitted"].inc()
@@ -1243,6 +1281,12 @@ class Scheduler:
                 self._tenant_m(run.tenant)["admitted"].inc()
             self._m["queue_depth"].set(len(self._waiting))
             progress = True
+        return progress
+
+    def _lanes(self, tr) -> bool:
+        """§2–4 of an iteration: one prefill chunk, the speculative
+        rounds, the packed decode step. True when any advanced."""
+        progress = False
 
         # 2. prefill lane: ONE chunk for the oldest prefilling request
         for run in list(self._running):
@@ -1279,19 +1323,21 @@ class Scheduler:
                     len(run.full_input) - run.prefill_done)
             toks = run.full_input[run.prefill_done:run.prefill_done + C]
             final = run.prefill_done + C == len(run.full_input)
-            # the chunk scatters C rows — CoW any shared page in its
-            # span (a no-op by construction: admission already CoW'd
-            # the divergence block; enforced, not assumed)
-            self.cache.ensure_writable(run.req.rid, run.prefill_done,
-                                       run.prefill_done + C)
-            # intermediate chunks skip the vocab readout — only the
-            # final chunk's last-position logits are ever read
-            logits, self.cache.state = self._prefill_fn(C, final)(
-                self._params_for(run), self.cache.state,
-                jnp.asarray(toks)[None],
-                jnp.int32(run.prefill_done),
-                jnp.asarray(self.cache.table_row(run.req.rid,
-                                                 self._width(run.req.rid))))
+            W = self._width(run.req.rid)
+            with tr.span("serve.prefill_dispatch", "SERVE",
+                         (run.req.rid, C, W, final)):
+                # the chunk scatters C rows — CoW any shared page in its
+                # span (a no-op by construction: admission already CoW'd
+                # the divergence block; enforced, not assumed)
+                self.cache.ensure_writable(run.req.rid, run.prefill_done,
+                                           run.prefill_done + C)
+                # intermediate chunks skip the vocab readout — only the
+                # final chunk's last-position logits are ever read
+                logits, self.cache.state = self._prefill_fn(C, final)(
+                    self._params_for(run), self.cache.state,
+                    jnp.asarray(toks)[None],
+                    jnp.int32(run.prefill_done),
+                    jnp.asarray(self.cache.table_row(run.req.rid, W)))
             run.prefill_done += C
             run.cache_len = run.prefill_done
             self._m["prefill_tokens"].inc(C)
@@ -1317,20 +1363,25 @@ class Scheduler:
                     run.streamed = full
             progress = True
             if run.prefill_done == len(run.full_input):
-                # device-side last-position slice: only vocab floats
-                # cross to host, not the whole (1, C, vocab) chunk
-                picked = self._pick(
-                    logits[:, -1],
-                    jnp.asarray([run.req.seed], jnp.int32),
-                    jnp.asarray([run.cache_len], jnp.int32),
-                    jnp.asarray([run.req.temperature], jnp.float32))
                 run.state = "decode"
                 if (run.req.spec is not None
                         and run.req.spec.kind == "draft"
                         and self.role != "prefill"):
                     self._build_draft_cache(run)
-                self._commit_token(run, int(np.asarray(picked)[0]),
-                                   self._clock())
+                with tr.span("serve.prefill_sync", "SERVE"):
+                    # device-side last-position slice: only vocab floats
+                    # cross to host, not the whole (1, C, vocab) chunk;
+                    # the host blocks on the device here
+                    tok = int(np.asarray(self._pick(
+                        logits[:, -1],
+                        jnp.asarray([run.req.seed], jnp.int32),
+                        jnp.asarray([run.cache_len], jnp.int32),
+                        jnp.asarray([run.req.temperature],
+                                    jnp.float32)))[0])
+                now = self._clock()
+                self._m["prefill_ms"].observe(
+                    self._phase(run, "prefill", now))
+                self._commit_token(run, tok, now)
                 if run.state == "decode" and self.role == "prefill":
                     # prefill is this replica's whole job: the request
                     # parks (blocks pinned) until the router migrates
@@ -1345,22 +1396,26 @@ class Scheduler:
         for run in [r for r in self._running
                     if r.state == "decode" and r.req.spec is not None]:
             if run.state == "decode":   # an earlier round may preempt
-                self._spec_round(run, self._clock())
+                with tr.span("serve.spec_round", "SERVE", (run.req.rid,)):
+                    self._spec_round(run, self._clock())
                 progress = True
 
         # 4. packed decode for the non-speculative decoders
-        packed: List[_Run] = []
-        for run in list(self._running):
-            if run.state != "decode" or run.req.spec is not None:
-                continue
-            if len(packed) >= self.max_batch:
-                break
-            if self._ensure_or_preempt(run, run.cache_len + 1,
-                                       run.cache_len, run.cache_len + 1):
-                if run.state == "decode":     # survived any preemptions
-                    packed.append(run)
-        packed = [r for r in packed if r.state == "decode"]
-        if packed:
+        with tr.span("serve.decode_pack", "SERVE"):
+            packed: List[_Run] = []
+            for run in list(self._running):
+                if run.state != "decode" or run.req.spec is not None:
+                    continue
+                if len(packed) >= self.max_batch:
+                    break
+                if self._ensure_or_preempt(run, run.cache_len + 1,
+                                           run.cache_len,
+                                           run.cache_len + 1):
+                    if run.state == "decode":  # survived any preemptions
+                        packed.append(run)
+            packed = [r for r in packed if r.state == "decode"]
+            if not packed:
+                return progress
             R = self.max_batch
             W = max(self._width(r.req.rid) for r in packed)
             toks = np.zeros(R, np.int32)
@@ -1374,6 +1429,7 @@ class Scheduler:
                 tables[i] = self.cache.table_row(run.req.rid, W)
                 seeds[i] = run.req.seed
                 temps[i] = run.req.temperature
+            extra = ()
             if self.adapter_pool is not None:
                 # heterogeneous-adapter decode: each row gathers its
                 # adapter's A/B slabs by pool slot inside the ONE
@@ -1384,25 +1440,24 @@ class Scheduler:
                 for i, run in enumerate(packed):
                     if run.slot is not None:
                         slots[i] = run.slot
-                logits, self.cache.state = self._decode_step()(
-                    self.params, self.cache.state, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(tables),
-                    self.adapter_pool.slabs, jnp.asarray(slots))
-            else:
-                logits, self.cache.state = self._decode_step()(
-                    self.params, self.cache.state, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(tables))
-            picked = np.asarray(self._pick(
+                extra = (self.adapter_pool.slabs, jnp.asarray(slots))
+        with tr.span("serve.decode_dispatch", "SERVE", (len(packed), W)):
+            logits, self.cache.state = self._decode_step()(
+                self.params, self.cache.state, jnp.asarray(toks),
+                jnp.asarray(pos), jnp.asarray(tables), *extra)
+            picked = self._pick(
                 logits, jnp.asarray(seeds), jnp.asarray(pos + 1),
-                jnp.asarray(temps)))
+                jnp.asarray(temps))
+        with tr.span("serve.decode_sync", "SERVE"):
+            picked = np.asarray(picked)    # the host blocks on the device
+        with tr.span("serve.commit", "SERVE"):
             now = self._clock()
             for i, run in enumerate(packed):
                 run.cache_len += 1
                 self._commit_token(run, int(picked[i]), now)
-            self._m["decode_tokens"].inc(len(packed))
-            self._m["batch_occupancy"].observe(len(packed))
-            progress = True
-        return progress
+        self._m["decode_tokens"].inc(len(packed))
+        self._m["batch_occupancy"].observe(len(packed))
+        return True
 
     def _build_draft_cache(self, run: _Run,
                            tokens: Optional[np.ndarray] = None) -> None:

@@ -10,6 +10,7 @@ wire counters + credit pools and whose flight-recorder post-mortem
 carries per-step stage dwell p50/p99 and the recent FAULT events.
 """
 
+import collections
 import time
 
 import numpy as np
@@ -126,6 +127,47 @@ def test_metrics_hot_path_per_op_budget():
     per_obs = (time.perf_counter() - t0) / N
     assert per_inc < 25e-6, f"counter inc {per_inc*1e6:.2f}us/op"
     assert per_obs < 50e-6, f"histogram observe {per_obs*1e6:.2f}us/op"
+
+
+def test_span_hot_path_per_span_budget():
+    """The always-on span ring shares the registry's contract: pin one
+    span (two clock reads, a profiler annotation outside any session, a
+    ring append; typical is ~1.5 us) under the same generous bound as a
+    histogram observe, and the serve scheduler's spans per iteration at
+    the number the cost estimate in docs/observability.md multiplies it
+    by. If this fails, someone made the span path build a dict, take a
+    lock or log."""
+    import jax  # noqa: F401 — the annotation is live once jax is loaded
+
+    from byteps_tpu.common.tracing import TraceRecorder, get_tracer
+    from byteps_tpu.models import GPTConfig, gpt_init
+    from byteps_tpu.serve import Request, Scheduler
+
+    rec = TraceRecorder(enabled=False)
+    N = 20000
+    t0 = time.perf_counter()
+    for i in range(N):
+        with rec.span("bench.span", "S", (i,)):
+            pass
+    per_span = (time.perf_counter() - t0) / N
+    assert per_span < 50e-6, f"span {per_span*1e6:.2f}us"
+
+    cfg = GPTConfig.tiny()
+    sched = Scheduler(gpt_init(jax.random.PRNGKey(0), cfg), cfg,
+                      max_batch=2, prefill_chunk=8, block_size=4)
+    rng = np.random.default_rng(0)
+    sched.serve([Request(rid=i, max_new=6,
+                         prompt=rng.integers(0, cfg.vocab_size, 10)
+                         .astype(np.int32)) for i in range(4)])
+    ring = get_tracer().spans()
+    per_iteration = collections.Counter()
+    for e in ring:
+        if e[0] == "serve.iteration":
+            per_iteration[e[3]] += 1
+        elif not e[0].startswith("serve.request."):
+            per_iteration[e[4]] += 1
+    assert max(per_iteration.values()) <= 8
+    assert len(ring) / len(per_iteration) <= 10
 
 
 def test_metrics_overhead_under_two_percent_of_dcn_round(monkeypatch):

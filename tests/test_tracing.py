@@ -144,3 +144,126 @@ def test_fault_instants_feed_flight_recorder_even_when_trace_off():
     evs = get_flight_recorder().events()
     assert [e["event"] for e in evs] == ["failover"]
     assert evs[0]["args"] == {"server": 1}
+
+
+# ---- the always-on span ring (docs/observability.md §spans) -----------------
+def test_ring_records_with_trace_off_and_carries_id_and_parent():
+    import time
+
+    rec = TraceRecorder(enabled=False)
+    before = time.monotonic()
+    with rec.span("outer", "S", (7,)) as outer:
+        with rec.span("inner", "S"):
+            pass
+        with rec.span("inner", "S", {"k": 1}):
+            pass
+    sid = rec.emit("stamped", "S", before, 0.25, ("r1", "resumed"))
+    after = time.monotonic()
+    assert rec.dump() is None and rec._events == []     # nothing traced
+    inner1, inner2, out, stamped = rec.spans()
+    assert out[0] == "outer" and out[3] == outer.sid and out[4] == 0
+    assert out[5] == (7,) and inner2[5] == {"k": 1}
+    assert inner1[4] == inner2[4] == out[3]             # their parent
+    assert len({inner1[3], inner2[3], out[3], sid}) == 4
+    assert stamped == ("stamped", before, 0.25, sid, 0, ("r1", "resumed"))
+    # the ring's clock is time.monotonic(): a reader cuts it to a window
+    # with its own stamps
+    assert rec.clock is time.monotonic
+    assert before <= out[1] <= inner1[1] <= inner2[1] <= after
+    assert inner2[1] + inner2[2] <= out[1] + out[2] <= after
+    assert rec.spans(since=inner2[1]) == [inner2]
+    assert rec.spans(since=after) == []
+    # a sibling opened after the parent closed has no parent
+    with rec.span("later", "S"):
+        pass
+    assert rec.spans()[-1][4] == 0
+
+
+def test_ring_is_bounded():
+    from byteps_tpu.common import tracing
+
+    rec = TraceRecorder(enabled=False)
+    for i in range(tracing.RING_SPANS + 10):
+        rec.emit("e", "S", 0.0, 0.0, (i,))
+    ring = rec.spans()
+    assert len(ring) == tracing.RING_SPANS
+    assert ring[0][5] == (10,) and ring[-1][5] == (tracing.RING_SPANS + 9,)
+
+
+def test_metrics_off_stills_the_ring(monkeypatch):
+    from byteps_tpu.common import config as config_mod
+
+    monkeypatch.setenv("BYTEPS_METRICS_ON", "0")
+    config_mod.reset_config()
+    rec = TraceRecorder(enabled=False)
+    with rec.span("a", "S"):
+        pass
+    rec.complete_event("b", "S", 0.0, 1.0)
+    assert rec.spans() == []
+
+
+def test_ring_and_chrome_dump_agree_inside_the_step_window(tmp_path):
+    rec = TraceRecorder(enabled=True, trace_dir=str(tmp_path),
+                        start_step=1, end_step=2)
+    rec.step()
+    with rec.span("it", "SERVE", (3, "resumed")):
+        pass
+    rec.complete_event("x", "PUSH", rec._now_us(), 5.0, {"key": 1})
+    it, x = rec.spans()
+    doc = json.load(open(rec.dump()))
+    ev = {e["name"]: e for e in doc["traceEvents"]}
+    assert ev["it"]["args"] == {"args": [3, "resumed"]}
+    assert ev["x"]["args"] == {"key": 1} and ev["x"]["dur"] == 5.0
+    # one constant takes the ring's clock to the chrome trace's
+    for entry, name in ((it, "it"), (x, "x")):
+        assert abs(rec._epoch_minus_mono_us + entry[1] * 1e6
+                   - ev[name]["ts"]) < 1.0
+    assert abs(x[2] - 5e-6) < 1e-12
+
+
+def test_post_mortem_carries_the_last_spans():
+    from byteps_tpu.common.flight_recorder import get_flight_recorder
+    from byteps_tpu.common.tracing import get_tracer
+
+    with get_tracer().span("serve.iteration", "SERVE", (1,)):
+        pass
+    pm = get_flight_recorder().post_mortem(reason="test", dump=False)
+    assert pm["spans"][-1][0] == "serve.iteration"
+    assert pm["spans"][-1][5] == [1]
+    json.dumps(pm)
+
+
+def test_program_spans_sit_on_the_profilers_host_plane(tmp_path):
+    """Under a real jax.profiler session (CPU backend) a program span's
+    name is on the xplane's host plane, and the profiler's clock is the
+    ring's plus one constant: pairs agree within 50 us."""
+    import glob
+    import statistics
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    rec = TraceRecorder(enabled=False)
+    x = jnp.ones((64, 64))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(12):
+            with rec.span("prog.work", "S", (i,)):
+                (x @ x).block_until_ready()
+            time.sleep(0.002 * (i % 3))      # uneven gaps
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    starts = sorted(
+        ev.start_ns for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+        if ev.name == "prog.work")
+    ring = [e for e in rec.spans() if e[0] == "prog.work"]
+    assert len(starts) == len(ring) == 12
+    offsets = [s - e[1] * 1e9 for s, e in zip(starts, ring)]
+    mid = statistics.median(offsets)
+    assert sum(abs(o - mid) <= 50e3 for o in offsets) >= 11, offsets
