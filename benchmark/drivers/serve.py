@@ -1,14 +1,18 @@
 """Serving cells: the benchmark's own loop over ``Scheduler.step()`` (the
 body of ``Scheduler.serve``), with a span around each step and each wait for
-an arrival. Every request is submitted up front with its ``arrival_s``; the
-scheduler times each from when it was due, so this generator cannot run
-late, and no lateness is reported."""
+an arrival. Every request known before the run is submitted up front with
+its ``arrival_s``; the scheduler times each from when it was due, so this
+generator cannot run late, and no lateness is reported. A ``backlog`` mix is
+kept full besides: between two steps the loop reads how many requests wait
+and submits whole cycles, due at once, when they are fewer than the mix's
+``queued_min`` (``traffic_gen.Backlog``)."""
 
 from __future__ import annotations
 
 import functools
+import re
 import time
-from typing import Dict
+from typing import Callable, Dict, List
 
 from benchmark import harness, metrics, traffic_gen
 from benchmark.configs import gpt2_reference
@@ -17,6 +21,8 @@ SPANS = ("serve.step", "idle.wait_arrival", "serve.submit")
 HISTOGRAMS = ("serve.ttft_ms", "serve.token_ms", "serve.batch_occupancy")
 COUNTERS = ("serve.completed", "serve.prefill_tokens", "serve.decode_tokens",
             "serve.preempted")
+QUEUE_DEPTH = re.compile(r"^serve\.r\d+\.queue_depth$")
+SLICES = 10                # the window's committed tokens, by tenth of it
 
 
 def _reading(now: float) -> Dict:
@@ -27,6 +33,100 @@ def _reading(now: float) -> Dict:
             "histograms": {k: snap["histograms"].get(k, {"count": 0})
                            for k in HISTOGRAMS},
             "counters": {k: snap["counters"].get(k, 0) for k in COUNTERS}}
+
+
+def _program_gauges():
+    """(waiting, tokens): two cheap reads of the program's registry, for
+    between two steps. ``waiting()`` is the one ``serve.r<n>.queue_depth``
+    gauge (this process has made one Scheduler), the number of requests
+    submitted and not admitted; ``tokens()`` the count of generated tokens
+    committed so far, as ``_reading`` counts them."""
+    from byteps_tpu.common.metrics import get_registry
+
+    reg = get_registry()
+    depth, = (k for k in reg.snapshot_scalars("serve.r")["gauges"]
+              if QUEUE_DEPTH.match(k))
+    gauge = reg.gauge(depth)
+    hists = [reg.histogram(k) for k in ("serve.ttft_ms", "serve.token_ms")]
+    return (lambda: int(gauge.value()),
+            lambda: sum(x.count() for x in hists))
+
+
+def drive(h, sched, spec: Dict, submit: Callable, initial: List,
+          backlog=None, waiting: Callable = None, tokens: Callable = None,
+          reading: Callable = _reading) -> Dict:
+    """The loop of one run: ``initial`` submitted up front, the window
+    opened ``ramp_seconds`` later and closed ``h.seconds`` after that, the
+    scheduler stepped until then (``drain``: until it has finished). With a
+    ``backlog`` (``traffic_gen.Backlog``), ``waiting()`` is read before
+    every step and what ``backlog.refill`` answers is submitted. Returns
+    the two readings, every request submitted, and what the queue did."""
+    base = time.monotonic() + 0.05
+    with h.span("serve.submit"):
+        for r in initial:
+            submit(r, base)
+    reqs = list(initial)
+    due = sorted(base + r.due_s for r in reqs)
+    t_open = base + float(spec["ramp_seconds"])
+    t_close = t_open + h.seconds
+    start = end = queued_min = None
+    nxt = idle = refills = 0
+    refill_s = 0.0
+    slices: List[int] = []
+    while True:
+        now = time.monotonic()
+        if start is None and now >= t_open:
+            start = reading(h.open_window())
+        if start is not None and end is None:
+            if now >= t_close:
+                end = reading(time.monotonic())
+                h.close_window()
+                if not spec["drain"]:
+                    break
+            elif h.trace_due(start["t"], now):
+                h.start_trace()
+        if end is not None and sched.finished:
+            break
+        if backlog is not None:
+            depth = waiting()
+            if start is not None and end is None:
+                queued_min = depth if queued_min is None \
+                    else min(queued_min, depth)
+                if now >= start["t"] + len(slices) * h.seconds / SLICES:
+                    slices.append(tokens())
+            if depth < backlog.queued_min:
+                t0 = time.monotonic()
+                with h.span("serve.submit"):
+                    more = backlog.refill(depth)
+                    for r in more:
+                        submit(r, base)
+                reqs.extend(more)
+                refills += 1
+                refill_s += time.monotonic() - t0
+        with h.span("serve.step"):
+            progress = sched.step()
+        if progress:
+            idle = 0
+            continue
+        now = time.monotonic()
+        while nxt < len(due) and due[nxt] <= now:
+            nxt += 1
+        marks = [m for m in (due[nxt] if nxt < len(due) else None,
+                             t_open if start is None else None,
+                             t_close if end is None else None)
+                 if m is not None]
+        if nxt >= len(due):
+            idle += 1
+            if idle > 10000:
+                raise RuntimeError("serve: no progress and nothing due")
+        with h.span("idle.wait_arrival"):
+            time.sleep(max(0.0, min(min(marks) - now, 0.05))
+                       if marks else 1e-4)
+    if backlog is not None:
+        slices.append(tokens())
+    return {"start": start, "end": end, "reqs": reqs, "refills": refills,
+            "refill_ms_total": refill_s * 1e3, "queued_min": queued_min,
+            "tokens_by_slice": [b - a for a, b in zip(slices, slices[1:])]}
 
 
 def run(h) -> Dict:
@@ -63,52 +163,18 @@ def run(h) -> Dict:
             sched.step()
         sched.results.pop(f"warm{i}")
 
-    reqs = traffic_gen.chat_schedule(spec, h.seed, h.seconds, vocab,
-                                     cfg.max_seq)
-    base = time.monotonic() + 0.05
-    with h.span("serve.submit"):
-        for r in reqs:
-            sched.submit(Request(rid=r.rid, prompt=r.prompt,
-                                 max_new=r.max_new,
-                                 arrival_s=base + r.due_s))
-    due = sorted(base + r.due_s for r in reqs)
-    t_open = base + float(spec["ramp_seconds"])
-    t_close = t_open + h.seconds
-    start = end = None
-    nxt = idle = 0
-    while True:
-        now = time.monotonic()
-        if start is None and now >= t_open:
-            start = _reading(h.open_window())
-        if start is not None and end is None:
-            if now >= t_close:
-                end = _reading(time.monotonic())
-                h.close_window()
-                if not spec["drain"]:
-                    break
-            elif h.trace_due(start["t"], now):
-                h.start_trace()
-        if end is not None and sched.finished:
-            break
-        with h.span("serve.step"):
-            progress = sched.step()
-        if progress:
-            idle = 0
-            continue
-        now = time.monotonic()
-        while nxt < len(due) and due[nxt] <= now:
-            nxt += 1
-        marks = [m for m in (due[nxt] if nxt < len(due) else None,
-                             t_open if start is None else None,
-                             t_close if end is None else None)
-                 if m is not None]
-        if nxt >= len(due):
-            idle += 1
-            if idle > 10000:
-                raise RuntimeError("serve: no progress and nothing due")
-        with h.span("idle.wait_arrival"):
-            time.sleep(max(0.0, min(min(marks) - now, 0.05))
-                       if marks else 1e-4)
+    def submit(r, base):
+        sched.submit(Request(rid=r.rid, prompt=r.prompt, max_new=r.max_new,
+                             arrival_s=base + r.due_s))
+
+    source = (spec, h.seed, h.seconds, vocab, cfg.max_seq)
+    backlog = traffic_gen.Backlog(*source) \
+        if spec["arrivals"]["kind"] == "backlog" else None
+    seen = drive(h, sched, spec, submit,
+                 backlog.initial if backlog else
+                 traffic_gen.chat_schedule(*source),
+                 backlog, *_program_gauges())
+    start, end, reqs = seen["start"], seen["end"], seen["reqs"]
     peak = h.memory_peak_bytes()
     h.reduce_trace(SPANS)
 
@@ -158,15 +224,19 @@ def run(h) -> Dict:
     tol = float(spec["logit_tolerance"])
     lengths_ok = all(len(results[r]["emitted"]) == by_rid[r].max_new
                      for r in results)
+    # tokens/s of a queue that ran empty is the arrival rate, not the
+    # program's: a backlog that did not hold is not a result
+    saturated = backlog is None or seen["queued_min"] > 0
     return {
         "correct": (bool(sample) and max(gaps) <= tol and failed == 0
-                    and leaked == 0 and lengths_ok),
+                    and leaked == 0 and lengths_ok and saturated),
         "attempted": attempted, "failed": failed,
         "end_to_end": e2e, "memory_peak_bytes": peak,
         "ttft_ms": chat["ttft_ms"], "itl_ms": chat["itl_ms"],
         "histograms": {"start": start["histograms"],
                        "end": end["histograms"]},
         "requests_completed": completed, "elapsed_s": elapsed,
+        "queued_min_in_window": seen["queued_min"],
         "notes": {"checked_requests": sample, "max_logit_gap": max(gaps)
                   if gaps else None, "logit_tolerance": tol,
                   "requests": len(reqs), "completed_in_window": completed,
@@ -176,5 +246,9 @@ def run(h) -> Dict:
                   "prefill_tokens_in_window":
                       end["counters"]["serve.prefill_tokens"]
                       - start["counters"]["serve.prefill_tokens"],
+                  "queued_min_in_window": seen["queued_min"],
+                  "refills": seen["refills"],
+                  "refill_ms_total": seen["refill_ms_total"],
+                  "tokens_by_slice": seen["tokens_by_slice"],
                   "cache_dir": h.cache_dir},
     }

@@ -179,8 +179,7 @@ class Run:
                 os.makedirs(os.path.dirname(keep) or ".", exist_ok=True)
                 with open(keep, "w") as f:
                     json.dump(trace, f)
-            if trace["devices"] or not self.rehearse:
-                self.reduced = trace_reduce.Reduced(trace, self.chips)
+            self.reduced = trace_reduce.Reduced(trace, self.chips)
         finally:
             shutil.rmtree(self._trace_dir, ignore_errors=True)
 

@@ -4,6 +4,7 @@ one whose answers can be worked out on paper."""
 
 import json
 import os
+import types
 
 import pytest
 
@@ -87,3 +88,99 @@ def test_categorise_reads_the_instruction(recorded):
     assert tr.categorise(
         "%f.1 = (f32[2]{0}, f32[2]{0}) fusion(f32[2]{0:T(8,128)} %p), "
         "kind=kOutput, calls=%c") == ("f.1", "convolution fusion")
+
+
+# ---- a device that ran nothing while the profiler was on -------------------
+IDLE_SPANS = [["bench.window", 1000.0, 4000.0],
+              ["serve.step", 1100.0, 900.0],                 # 1100..2000
+              ["idle.wait_arrival", 2000.0, 2500.0],         # 2000..4500
+              ["serve.step", 4500.0, 1000.0]]                # past the end
+IDLE = {
+    "no device plane": {"devices": {}, "spans": IDLE_SPANS},
+    "a plane with no event": {"devices": {"0": []}, "spans": IDLE_SPANS},
+    "every event outside the window": {"devices": {"0": [
+        ["copy.1", "data formatting", 100.0, 800.0],         # before
+        ["fusion.1", "convolution fusion", 5000.0, 50.0],    # at its end
+    ]}, "spans": IDLE_SPANS},
+}
+
+
+@pytest.mark.parametrize("shape", sorted(IDLE))
+def test_an_idle_device_reduces_to_busy_zero(shape):
+    from benchmark.readers import (attention_roofline, program_idle_ms,
+                                   trace_ms_per_step,
+                                   trace_named_ms_per_step, trace_share)
+
+    r = tr.Reduced(IDLE[shape], chips=1)
+    assert (r.w0, r.w1) == (1000.0, 5000.0)
+    assert r.window_s == pytest.approx(4000e-9)
+    assert r.busy_s == 0 and r.busy0_s == 0
+    assert r.seconds(["data formatting", "convolution fusion"]) == 0
+    assert r.count("serve.step") == 1
+    b = r.breakdown()
+    assert b["device_ops"] == []
+    # the whole window is one gap, by the host span that covers it
+    assert dict(b["idle_gaps"]) == {
+        "idle.wait_arrival": pytest.approx(2500e-9),
+        "serve.step": pytest.approx((900 + 500) * 1e-9),
+        tr.NO_SPAN: pytest.approx(100e-9)}
+    assert sum(s for _, s in b["idle_gaps"]) == pytest.approx(r.window_s)
+    json.dumps(b)
+    # shares of nothing and kernels that did not run are left out
+    run = types.SimpleNamespace(reduced=r, chips=1)
+    assert trace_share.read(run, {}, ["data formatting"]) is None
+    assert trace_named_ms_per_step.read(
+        run, {}, ["flash_fwd"], "serve.step") is None
+    assert attention_roofline.read(
+        run, {}, "serve.step", ["custom-call"]) is None
+    assert trace_ms_per_step.read(run, {}, "serve.step",
+                                  ["all-reduce"]) == 0.0
+    assert program_idle_ms.idle_ms(
+        [], r, ["serve.admit"], "serve.iteration", "serve.iteration",
+        "serve.step") is None
+
+
+def test_four_chips_of_which_none_ran_anything():
+    r = tr.Reduced({"devices": {str(i): [] for i in range(4)},
+                    "spans": IDLE_SPANS}, chips=4)
+    assert r.busy_s == 0 and r.device_ids == ["0", "1", "2", "3"]
+
+
+def test_a_trace_nobody_can_place_is_still_an_error(tmp_path):
+    # no bench.window span and no device event: nothing says what was traced
+    with pytest.raises(ValueError):
+        tr.Reduced({"devices": {}, "spans": [["serve.step", 0.0, 1.0]]}, 1)
+    # the profiler wrote no file at all: a broken run, not an idle one
+    with pytest.raises(FileNotFoundError):
+        tr.find_xplane(str(tmp_path))
+
+
+def test_a_traced_run_of_an_idle_device_still_prints_its_line(monkeypatch,
+                                                              tmp_path):
+    from benchmark import harness
+
+    args = types.SimpleNamespace(seed=1, seconds=50.0, trace=1,
+                                 rehearse=False)
+    traffic = harness.load_json(harness.HERE, "traffic",
+                                "chat-backlog-sat.json")
+    run = harness.Run(args, {"name": "gpt2l-serve-batch-sat", "chips": 1},
+                      {}, traffic, {}, 0.0)
+    run.device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    run._trace_dir = str(tmp_path)
+    monkeypatch.setattr(tr, "find_xplane", lambda d: "x")
+    monkeypatch.setattr(tr, "load",
+                        lambda path, names: IDLE["no device plane"])
+    monkeypatch.delenv("BENCH_KEEP_TRACE", raising=False)
+    run.reduce_trace(["serve.step"])
+    line = run.result_line({
+        "correct": True, "attempted": 3, "failed": 0, "end_to_end": {},
+        "queued_min_in_window": 130, "requests_completed": 3,
+        "elapsed_s": 50.0})
+    assert line["device"]["busy_s"] == 0
+    assert line["device"]["window_s"] == pytest.approx(4000e-9)
+    assert line["breakdown"]["device_ops"] == []
+    assert line["metrics"]["sat_queued_min"] == {"value": 130.0,
+                                                 "unit": "seqs"}
+    assert line["metrics"]["compiles_in_window"]["value"] == 0
+    assert "sat_copy_share_pct" not in line["metrics"]
+    json.dumps(line)

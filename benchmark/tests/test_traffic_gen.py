@@ -1,7 +1,10 @@
 """The cycle builder and the balanced order (run by hand:
 ``python -m pytest benchmark/tests -q``; not part of tier-1)."""
 
+import hashlib
+
 import numpy as np
+import pytest
 
 from benchmark import harness, traffic_gen
 
@@ -79,10 +82,86 @@ def test_every_group_of_eight_is_balanced():
 def test_backlog_is_due_at_zero_and_outlasts_the_run():
     spec = _spec("chat-backlog-sat")
     run = traffic_gen.chat_schedule(spec, 3, 48.0, 50257, 1024)
-    assert all(r.due_s == 0.0 for r in run)
+    assert all(r.due_s == 0.0 and r.measured for r in run)
     assert len(run) == int(np.ceil(
         spec["arrivals"]["requests_per_second_of_run"]
         * (spec["ramp_seconds"] + 48.0)))
+
+
+def _digest(run):
+    h = hashlib.sha256()
+    for r in run:
+        h.update(repr((r.rid, r.due_s, r.max_new, r.measured)).encode())
+        h.update(r.prompt.tobytes())
+    return len(run), h.hexdigest()
+
+
+# what chat_schedule(spec, seed, 50.0, 50257, 1024) gave at PR 25, before a
+# backlog could be topped up: rids, due times, shapes and prompt bytes
+PR25 = {
+    ("chat-backlog-sat", 1): (260, "1d50d6207a51f4bd02df0edc0892b589"
+                                   "26c1100a01e6e330777342eb34b1aae6"),
+    ("chat-backlog-sat", 2147483999): (260, "e1dd651ddbee31a61bbdcde5f0d91beb"
+                                            "6962ff0b1a099f90e44461c9d2807ae9"),
+    ("chat-open-0.8knee", 1): (84, "548b813bada1c0262ef06e3880bb5599"
+                                   "a0846fcd2fe935608300543c0695d03b"),
+    ("chat-open-0.8knee", 2147483999): (84, "044c20faa5505c21737a80e0c0482a19"
+                                            "b808f8ff0a69f775ea2f7e1ed0bd668f"),
+}
+
+
+@pytest.mark.parametrize("mix,seed", sorted(PR25))
+def test_the_requests_known_up_front_are_those_of_pr25(mix, seed):
+    run = traffic_gen.chat_schedule(_spec(mix), seed, 50.0, 50257, 1024)
+    assert _digest(run) == PR25[mix, seed]
+    if mix == "chat-backlog-sat":
+        assert _digest(traffic_gen.Backlog(
+            _spec(mix), seed, 50.0, 50257, 1024).initial) == PR25[mix, seed]
+
+
+@pytest.mark.parametrize("waiting,want", [
+    (400, 0), (129, 0), (128, 0),          # at queued_min: not yet
+    (127, 32), (96, 32), (95, 64), (1, 128), (0, 128)])
+def test_top_up_fires_under_queued_min_in_whole_cycles(waiting, want):
+    assert traffic_gen.top_up(waiting, 128, 32) == want
+    assert (waiting + want >= 128) and want % 32 == 0
+
+
+def test_a_refill_continues_where_the_last_submission_stopped():
+    spec = _spec("chat-backlog-sat")
+    qmin = spec["arrivals"]["queued_min"]
+    cycle = traffic_gen.chat_cycle(spec)
+    assert qmin == 128 and len(cycle) == spec["cycle"] == 32
+    b = traffic_gen.Backlog(spec, 7, 50.0, 50257, 1024)
+    assert len(b.initial) == 260 and b.refill(qmin) == []
+    first, second = b.refill(qmin - 1), b.refill(qmin - 40)
+    assert (len(first), len(second)) == (32, 64)
+    made = b.initial + first + second
+    assert [r.rid for r in made] == list(range(260 + 96))
+    # 260 is not a multiple of 32: the refill goes on at 260 mod 32
+    assert [(len(r.prompt), r.max_new) for r in made] == \
+        [cycle[i % 32] for i in range(len(made))]
+    assert all(r.due_s == 0.0 and r.prompt.dtype == np.int32
+               and r.prompt.max() < 50257 for r in made)
+    # request i is a function of the seed and i alone, however it was
+    # reached: one refill of three cycles gives the same 96
+    c = traffic_gen.Backlog(spec, 7, 50.0, 50257, 1024)
+    again = c.refill(qmin - 65)
+    assert len(again) == 96 and all(
+        x.rid == y.rid and np.array_equal(x.prompt, y.prompt)
+        for x, y in zip(first + second, again))
+    other = traffic_gen.Backlog(spec, 8, 50.0, 50257, 1024).refill(0)
+    assert not np.array_equal(other[0].prompt, first[0].prompt)
+
+
+def test_the_rehearsal_has_a_queued_min_of_its_own():
+    spec = _spec("chat-backlog-sat")
+    small = harness.merged(spec, spec["rehearsal"])
+    assert 0 < small["arrivals"]["queued_min"] < \
+        spec["arrivals"]["queued_min"]
+    # a mix without the parameter is never refilled
+    del small["arrivals"]["queued_min"]
+    assert traffic_gen.Backlog(small, 1, 3.0, 256, 128).refill(0) == []
 
 
 def test_warmup_touches_every_program_the_multiset_can_need():

@@ -129,7 +129,8 @@ def window(trace: Dict) -> Tuple[float, float]:
             return start, start + dur
     evs = [e for d in trace["devices"].values() for e in d]
     if not evs:
-        raise ValueError("a trace with no device event")
+        raise ValueError("a trace with no bench.window span and no device "
+                         "event: nothing says what was traced")
     return (min(e[2] for e in evs), max(e[2] + e[3] for e in evs))
 
 
@@ -226,14 +227,14 @@ class Reduced:
     def __init__(self, trace: Dict, chips: int):
         self.trace = trace
         self.w0, self.w1 = window(trace)
+        # a device that ran nothing while the profiler was on leaves a
+        # plane with no "XLA Ops" line, or no plane: no event, busy 0
         ids = sorted(trace["devices"], key=int)[:chips]
-        if not ids:
-            raise ValueError("the trace holds no device plane")
         self.device_ids = ids
-        self.first = trace["devices"][ids[0]]     # device 0: categories
+        self.first = trace["devices"][ids[0]] if ids else []   # device 0
         self.window_s = (self.w1 - self.w0) / 1e9
         self.busy_s = sum(busy_ns(trace["devices"][i], self.w0, self.w1)
-                          for i in ids) / len(ids) / 1e9
+                          for i in ids) / max(len(ids), 1) / 1e9
         self.busy0_s = busy_ns(self.first, self.w0, self.w1) / 1e9
 
     def seconds(self, categories) -> float:

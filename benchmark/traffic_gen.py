@@ -75,10 +75,29 @@ def chat_cycle(spec: Dict):
     return list(zip(prompts, outputs))
 
 
+def chat_requests(spec: Dict, rng: np.random.Generator, vocab: int,
+                  max_seq: int):
+    """Requests ``0, 1, 2, ...`` without end, all due at 0: request ``i``
+    has the shape ``cycle[i % len(cycle)]`` and the next ``prompt_len``
+    token ids of ``rng``, so its prompt is a pure function of the
+    generator's seed and ``i``."""
+    cycle = chat_cycle(spec)
+    i = 0
+    while True:
+        plen, new = cycle[i % len(cycle)]
+        if plen + new > max_seq:
+            raise ValueError(f"prompt {plen} + output {new} > {max_seq}")
+        yield ChatRequest(
+            rid=i, due_s=0.0, max_new=int(new), measured=True,
+            prompt=rng.integers(0, vocab, plen).astype(np.int32))
+        i += 1
+
+
 def chat_schedule(spec: Dict, seed: int, seconds: float, vocab: int,
                   max_seq: int) -> List[ChatRequest]:
-    """Every request of one run, in due order; request ``i`` has the shape
-    ``cycle[i % len(cycle)]`` and token ids from the seed.
+    """Every request of one run that is known before it starts, in due
+    order; request ``i`` has the shape ``cycle[i % len(cycle)]`` and token
+    ids from the seed.
 
     ``arrivals.kind == "paced"``: an open loop at ``rate_per_s``; request
     ``i`` is due at ``(i + 0.5 + u) / rate``, ``u`` uniform in ``+-jitter``
@@ -86,35 +105,61 @@ def chat_schedule(spec: Dict, seed: int, seconds: float, vocab: int,
     ramp_seconds + seconds)`` are the measured ones: the same requests for
     every seed. None arrives after the window.
 
-    ``arrivals.kind == "backlog"``: ``requests_per_second_of_run * (ramp +
-    seconds)`` requests, all due at 0; the window opens after
-    ``ramp_seconds`` and whatever commits a token inside it counts.
+    ``arrivals.kind == "backlog"``: the first ``requests_per_second_of_run
+    * (ramp + seconds)`` requests of ``Backlog``, all due at 0; the window
+    opens after ``ramp_seconds`` and whatever commits a token inside it
+    counts.
     """
-    rng = np.random.default_rng(seed)
     arr = spec["arrivals"]
     ramp = float(spec["ramp_seconds"])
-    cycle = chat_cycle(spec)
-    if arr["kind"] == "paced":
-        rate, jit = float(arr["rate_per_s"]), float(arr["jitter"])
-        n = int(np.ceil(rate * (ramp + seconds) - 0.5))
-        due = [(i + 0.5 + rng.uniform(-jit, jit)) / rate for i in range(n)]
-        measured = [ramp <= (i + 0.5) / rate < ramp + seconds
-                    for i in range(n)]
-    elif arr["kind"] == "backlog":
-        n = int(np.ceil(arr["requests_per_second_of_run"] * (ramp + seconds)))
-        due, measured = [0.0] * n, [True] * n
-    else:
+    if arr["kind"] == "backlog":
+        return Backlog(spec, seed, seconds, vocab, max_seq).initial
+    if arr["kind"] != "paced":
         raise ValueError(f"unknown arrivals kind {arr['kind']!r}")
-    out: List[ChatRequest] = []
-    for i in range(n):
-        plen, new = cycle[i % len(cycle)]
-        if plen + new > max_seq:
-            raise ValueError(f"prompt {plen} + output {new} > {max_seq}")
-        out.append(ChatRequest(
-            rid=i, due_s=float(due[i]), max_new=int(new),
-            prompt=rng.integers(0, vocab, plen).astype(np.int32),
-            measured=measured[i]))
-    return out
+    rng = np.random.default_rng(seed)
+    rate, jit = float(arr["rate_per_s"]), float(arr["jitter"])
+    n = int(np.ceil(rate * (ramp + seconds) - 0.5))
+    due = [(i + 0.5 + rng.uniform(-jit, jit)) / rate for i in range(n)]
+    source = chat_requests(spec, rng, vocab, max_seq)
+    return [dataclasses.replace(
+        next(source), due_s=float(due[i]),
+        measured=ramp <= (i + 0.5) / rate < ramp + seconds)
+        for i in range(n)]
+
+
+def top_up(waiting: int, queued_min: int, cycle: int) -> int:
+    """How many requests a driver submits when it sees ``waiting`` of them
+    queued: none at ``queued_min`` or above, else the fewest whole cycles
+    that bring the queue back to ``queued_min``."""
+    if waiting >= queued_min:
+        return 0
+    return cycle * -(-(queued_min - waiting) // cycle)
+
+
+class Backlog:
+    """A ``backlog`` mix: ``initial``, the ``requests_per_second_of_run *
+    (ramp + seconds)`` requests due at 0 before the run starts, and after
+    them as many whole cycles as the run asks for, each time the driver
+    sees fewer than ``queued_min`` requests waiting (``refill``). Request
+    ``i`` is the same whenever it is made, so a run that never refills
+    submits exactly ``initial``."""
+
+    def __init__(self, spec: Dict, seed: int, seconds: float, vocab: int,
+                 max_seq: int):
+        arr = spec["arrivals"]
+        self._source = chat_requests(spec, np.random.default_rng(seed),
+                                     vocab, max_seq)
+        self._cycle = int(spec["cycle"])
+        self.queued_min = int(arr.get("queued_min", 0))
+        n = int(np.ceil(arr["requests_per_second_of_run"]
+                        * (float(spec["ramp_seconds"]) + seconds)))
+        self.initial = [next(self._source) for _ in range(n)]
+
+    def refill(self, waiting: int) -> List[ChatRequest]:
+        """The next requests for a queue of ``waiting``: ``top_up`` of
+        them, continuing where the last submission stopped."""
+        return [next(self._source) for _ in
+                range(top_up(waiting, self.queued_min, self._cycle))]
 
 
 def warmup_shapes(spec: Dict, block_size: int, chunk: int, max_seq: int):
