@@ -1,0 +1,251 @@
+"""Paged decode attention — the Pallas kernel that reads the serve
+tier's KV pool in place.
+
+``serve/paged_cache.py``'s packed decode step used to index the pool by
+the block table into a dense ``(R, W·bs, h, D)`` copy of K and of V, per
+layer, every step, and hand that to the jnp ``attention_lse``, at the
+width of the widest resident request for every row of the batch, padded
+rows included (PERF.md §5). This kernel attends over the pool where it
+lies:
+
+* **No slice of the pool is materialised.** The whole ``(L, NB, bs,
+  Hkv·D)`` pool is the operand (``memory_space=ANY``: it stays in HBM);
+  the layer and the physical block are picked in the DMA's source
+  slice, from a scalar-prefetched layer index and block table. A block
+  is one dense tile-aligned ``(bs, Hkv·D)`` plane, 40 KB contiguous at
+  GPT-2-large's sizes.
+* **Dead blocks cost nothing.** One invocation walks the rows; row ``r``
+  walks ``ceil(length[r] / T)`` chunks of ``T = ppb·bs`` keys, and a
+  page is fetched (one DMA for K, one for V) only where it starts below
+  the fill level. A padded row (length 1, scratch table) reads one
+  page. Chunks are double buffered across rows: while chunk ``c`` is
+  computed, chunk ``c+1`` — or the next row's first — is in flight.
+* **Garbage never reaches the output.** The gathered view zeroed every
+  position at or past the fill level; here scores there take ``_NEG``
+  and their ``p`` is forced to 0, and V's rows there are selected to 0
+  before ``p·V``, so whatever a recycled block or a stale buffer holds
+  (NaN, inf) cannot enter (0 × NaN).
+
+A decode query is one row per head, which gives the MXU nothing to
+tile head by head. So the heads go through it together: the caller's
+``q (R, H, D)`` is laid out block-diagonally as ``(R, H, Hkv·D)`` (row
+``h`` holds its query in its kv head's ``D`` columns and zeros
+elsewhere), scores are ONE ``(H, Hkv·D) × (T, Hkv·D)ᵀ`` matmul per
+chunk — the zeros pick each head's own keys, and the ``G = H/Hkv`` query
+heads of a group ride their kv head's columns (GQA reads the narrow
+pool once) — and ``p·V`` is one ``(H, T) × (T, Hkv·D)`` matmul whose
+diagonal blocks are the output; the wrapper picks them out. The MXU
+does ``Hkv`` times the needed FLOPs and is still far from the limit:
+the chunk's time is its weight-tile loads, the same number as bytes/32
+KB. Online softmax ``(m, l, acc)`` in VMEM with keys on lanes, f32
+scores and accumulation, K and V in the pool's dtype (``p`` rounded to
+it for ``p·V``, as the MXU rounds the twin's), output in ``q.dtype``:
+the contract of ``ops/flash_decode.py``, and of ``_gather_view`` +
+``attention_lse_jnp`` restricted to the live prefix (pinned in
+``tests/test_paged_attention.py``). Quantised pools (int8 + per-row
+scales) are not taken: :func:`unsupported_reason` says so and the step
+keeps its jnp twin.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from byteps_tpu.ops.backend import interpret as _interpret
+from byteps_tpu.ops.flash_attention import _NEG, _out_struct, _unify_vma
+
+__all__ = ["paged_attention_decode", "unsupported_reason"]
+
+_CHUNK_TOKENS = 128                  # keys per chunk: one MXU tile wide
+_BUFFER_BUDGET = 8 * 1024 * 1024     # VMEM for the 2 slots of K and of V
+
+
+def _pages_per_chunk(W: int, bs: int, row_bytes: int) -> int:
+    """Pages fetched and computed per loop iteration: ``_CHUNK_TOKENS``
+    keys, never more than the table is wide, the four chunk buffers
+    inside their budget (0: not even one page fits)."""
+    return min(W, max(1, _CHUNK_TOKENS // bs),
+               _BUFFER_BUDGET // (4 * bs * row_bytes))
+
+
+def unsupported_reason(block_size: int, kv_heads: int, head_dim: int,
+                       dtype) -> Optional[str]:
+    """Why the kernel does not take a pool of these shapes (``None``: it
+    does) — the dispatcher's ``note_fallback`` text. A block must be
+    whole VMEM tiles, because it is DMA'd as it lies."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating):
+        return "int8 pool: the kernel does not dequantise"
+    rows = 8 * 4 // dtype.itemsize            # sublanes of one tile
+    if block_size % rows != 0:
+        return f"block_size must be a multiple of {rows} for {dtype.name}"
+    if (kv_heads * head_dim) % 128 != 0:
+        return "kv_heads * head_dim must be a multiple of 128"
+    if _pages_per_chunk(1, block_size,
+                        kv_heads * head_dim * dtype.itemsize) < 1:
+        return "one block of K and V does not fit the VMEM buffers"
+    return None
+
+
+def _kernel(len_ref, tab_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sems, m_scr, l_scr, acc_scr, *,
+            scale: float, R: int, W: int, bs: int, ppb: int):
+    H, HD = acc_scr.shape
+    T = bs * ppb
+    layer = layer_ref[0]
+
+    def page_copies(r, c, slot, i):
+        blk = tab_ref[r * W + jnp.minimum(c * ppb + i, W - 1)]
+        rows = pl.ds(i * bs, bs)
+        return (pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                      kbuf.at[slot, rows], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                      vbuf.at[slot, rows], sems.at[1, slot]))
+
+    def dma(r, c, slot, wait: bool):
+        # a page is live where it starts below the row's fill level;
+        # start and wait walk the SAME predicate, so every DMA issued
+        # is awaited and no dead page is ever fetched
+        for i in range(ppb):
+            @pl.when((c * ppb + i) * bs < len_ref[r])
+            def _(i=i):
+                for cp in page_copies(r, c, slot, i):
+                    cp.wait() if wait else cp.start()
+
+    def chunk(r, c, slot, length):
+        k = kbuf[slot]                                    # (T, HD)
+        v = vbuf[slot]
+        q = q_ref[r]                                      # (H, HD)
+        ct = jnp.promote_types(q.dtype, k.dtype)
+        s = jax.lax.dot_general(
+            q.astype(ct), k.astype(ct), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (H, T)
+        live = c * T + jax.lax.broadcasted_iota(
+            jnp.int32, (H, T), 1) < length
+        s = jnp.where(live, s, _NEG)
+        m_prev = m_scr[...]                               # (H, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        # rows at or past the fill level: a partly filled block's tail,
+        # or a page this chunk never fetched — either may hold NaN/inf
+        rows_live = c * T + jax.lax.broadcasted_iota(
+            jnp.int32, (T, HD), 0) < length
+        v = jnp.where(rows_live, v, jnp.zeros((), v.dtype))
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # (H, HD)
+        m_scr[...] = m_new
+
+    dma(0, 0, 0, wait=False)
+
+    def row(r, n):
+        length = len_ref[r]
+        nchunks = jnp.maximum(pl.cdiv(length, T), 1)
+        m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        def step(c, n):
+            slot = jax.lax.rem(n, 2)
+            last = c + 1 == nchunks
+
+            @pl.when(jnp.logical_not(last))
+            def _():
+                dma(r, c + 1, 1 - slot, wait=False)
+
+            @pl.when(jnp.logical_and(last, r + 1 < R))
+            def _():
+                dma(r + 1, 0, 1 - slot, wait=False)
+
+            dma(r, c, slot, wait=True)
+            chunk(r, c, slot, length)
+            return n + 1
+
+        n = jax.lax.fori_loop(0, nchunks, step, n)
+        l = l_scr[...]
+        o_ref[r] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
+                    ).astype(o_ref.dtype)
+        return n
+
+    jax.lax.fori_loop(0, R, row, jnp.int32(0))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged(qbd, k_pool, v_pool, tables, lengths, layer, scale: float,
+           interpret: bool):
+    """qbd: (R, H, Hkv·D) block-diagonal queries; pools (L, NB, bs,
+    Hkv·D) → (R, H, Hkv·D), row ``h``'s output in its kv head's block."""
+    R, H, HD = qbd.shape
+    bs = k_pool.shape[2]
+    W = tables.shape[1]
+    ppb = _pages_per_chunk(W, bs, HD * k_pool.dtype.itemsize)
+    operands = _unify_vma(
+        lengths.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), qbd, k_pool, v_pool)
+    whole = pl.BlockSpec((R, H, HD), lambda i, *_: (0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale, R=R, W=W, bs=bs, ppb=ppb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,       # lengths, tables, layer
+            grid=(1,),
+            in_specs=[whole,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb * bs, HD), k_pool.dtype),
+                pltpu.VMEM((2, ppb * bs, HD), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),      # (k|v, slot)
+                pltpu.VMEM((H, 1), jnp.float32),      # m
+                pltpu.VMEM((H, 1), jnp.float32),      # l
+                pltpu.VMEM((H, HD), jnp.float32),     # acc
+            ]),
+        out_shape=_out_struct((R, H, HD), qbd.dtype, *operands),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attn_decode",
+    )(*operands)
+
+
+def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer):
+    """One layer's packed decode attention over the paged pool.
+
+    ``q (R, H, D)``: one query per row; ``k_pool``/``v_pool`` the WHOLE
+    ``(L, NB, bs, Hkv·D)`` pools (a float dtype) and ``layer`` which of
+    the ``L`` to read; ``tables (R, W)`` int32 physical blocks;
+    ``lengths (R,)`` int32 live keys per row (``pos + 1``, at least 1,
+    at most ``W·bs``): row ``r`` attends to logical positions ``[0,
+    lengths[r])``, position ``p`` living at ``(tables[r, p // bs], p %
+    bs)``. Returns ``o (R, H, D)`` in ``q.dtype``. Table entries past a
+    row's last live block are never read, and nothing stored at or past
+    a fill level can reach the output. Callers gate on
+    :func:`unsupported_reason` / ``backend.use_pallas``."""
+    R, H, D = q.shape
+    bs, HD = k_pool.shape[2:]
+    Hkv = HD // D
+    if HD != Hkv * D or H % Hkv != 0:
+        raise ValueError(f"q heads ({H}) x head_dim ({D}) do not fit a "
+                         f"pool row of {HD}")
+    why = unsupported_reason(bs, Hkv, D, k_pool.dtype)
+    if why is not None:
+        raise ValueError(f"paged_attention_decode: {why}; gate on "
+                         "unsupported_reason()")
+    # head h belongs to kv head h // G (group-major, as flash_decode)
+    own = (jnp.arange(H)[:, None] // (H // Hkv)
+           == jnp.arange(Hkv)[None, :])[None, :, :, None]   # (1, H, Hkv, 1)
+    qbd = jnp.where(own, q[:, :, None, :], jnp.zeros((), q.dtype))
+    o = _paged(qbd.reshape(R, H, HD), k_pool, v_pool, tables, lengths,
+               layer, 1.0 / (D ** 0.5), _interpret())
+    # the diagonal blocks; a select, so an off-diagonal product (some
+    # other head's V) never meets arithmetic
+    o = jnp.where(own, o.reshape(R, H, Hkv, D), jnp.zeros((), o.dtype))
+    return o.sum(axis=2)

@@ -174,8 +174,9 @@ class KVBlockCodec:
     def from_pool(cls, cache) -> "KVBlockCodec":
         """Codec matching a :class:`~byteps_tpu.serve.paged_cache.
         PagedKVCache`'s pool layout."""
-        L, _, bs, h, D = cache.state.k.shape
-        return cls(L, bs, h, D, np.dtype(cache.state.k.dtype), cache.quant)
+        L, _, bs, _ = cache.state.k.shape     # minor axis: h_kv * head_dim
+        return cls(L, bs, cache.kv_heads, cache.cfg.head_dim,
+                   np.dtype(cache.state.k.dtype), cache.quant)
 
     @property
     def frame_bytes(self) -> int:

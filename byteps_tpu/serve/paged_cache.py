@@ -10,15 +10,18 @@ is a preallocated pool of fixed-size KV *blocks* plus a per-request
 device batch, memory is allocated block-at-a-time as requests grow,
 and a freed request's blocks immediately serve the next admission.
 
-Numerics are the point, not just memory: the paged views reproduce the
-dense cache's contract exactly. A gathered per-request view zero-fills
-every position at or past the request's fill level (the dense cache is
-zero-initialized and written only below ``length``), attention masks
-with the same global-offset causal rule through the SAME
-``attention_lse`` twin (extended to per-batch offset vectors), and
-quantized pools reuse ``_quantize_block``'s absmax arithmetic — so a
-request served out of the paged pool emits tokens bit-identical to a
-solo ``make_generate_fn`` run (pinned in tests/test_serve.py).
+Numerics are the point, not just memory: the paged paths reproduce the
+dense cache's contract exactly. Nothing at or past a request's fill
+level is ever read as data (the dense cache is zero-initialized and
+written only below ``length``): a gathered per-request view zero-fills
+those positions and the paged-attention kernel masks them, attention
+applies the same global-offset causal rule per row (the kernel by each
+row's ``length``, the ``attention_lse`` twin by a per-batch offset
+vector), and quantized pools reuse ``_quantize_block``'s absmax
+arithmetic — so on the jnp backend a request served out of the paged
+pool emits tokens bit-identical to a solo ``make_generate_fn`` run
+(pinned in tests/test_serve.py), and the kernel is held to the twin
+(tests/test_paged_attention.py).
 
 Pages are SHARED, not owned: every physical block carries a refcount
 and a radix/prefix index maps token content → committed prefill blocks
@@ -44,7 +47,13 @@ Three layers:
   references it, so a padded batch slot can't corrupt live state.
 * :func:`make_paged_decode_fn` — ONE jitted packed decode step:
   R requests at heterogeneous positions, per-row rope/masks, scatter
-  the new token's K/V into the pool, gather per-request views, attend.
+  the new token's K/V into the pool, then attend over the pool IN
+  PLACE through the block tables (``ops/paged_attention.py``: each row
+  reads its live blocks and no dense copy of K or V is made). Off the
+  Pallas backend, or for a pool the kernel does not take (int8, a
+  block that is not whole tiles), the step keeps the kernel's jnp
+  twin: gather zero-masked per-request views, ``attention_lse``
+  (:func:`decode_uses_paged_attn` decides, from backend and shapes).
 * :func:`make_paged_prefill_fn` — chunked prefill/verify for one
   request: gather its blocks into a dense :class:`KVCache` view, run
   the stock ``gpt_apply_cached`` (bit-identical to the single-request
@@ -76,7 +85,12 @@ from byteps_tpu.models.gpt import (
     resolve_rope,
     rope_rotate,
 )
+from byteps_tpu.ops.backend import note_fallback, use_pallas
 from byteps_tpu.ops.flash_attention import attention_lse
+from byteps_tpu.ops.paged_attention import (
+    paged_attention_decode,
+    unsupported_reason as paged_attn_unsupported,
+)
 from byteps_tpu.ops.segmented_lora import segmented_lora_delta
 from byteps_tpu.parallel.tp import col_parallel_matmul, row_parallel_matmul
 
@@ -85,10 +99,17 @@ class PoolState(NamedTuple):
     """The device half of the paged cache — a pytree so the jitted
     decode/prefill steps thread it functionally.
 
-    k/v: ``(n_layers, num_blocks, block_size, h_kv, head_dim)`` in
+    k/v: ``(n_layers, num_blocks, block_size, h_kv * head_dim)`` in
     ``cfg.dtype``, or int8 with ``k_scale``/``v_scale``
     ``(n_layers, num_blocks, block_size, h_kv)`` fp32 absmax scales
-    (generate.py's _QuantSlot layout, block-paged).
+    (generate.py's _QuantSlot layout, block-paged). A token's heads lie
+    side by side on the minor axis, so a block is one dense, tile-
+    aligned ``(block_size, h_kv * head_dim)`` plane: the device stores a
+    ``(..., h_kv, head_dim)`` tail with ``head_dim`` under 128 padded
+    or in a dimension order of its own, and every program that touches
+    the pool then converts all of it on the way in and out (PERF.md §6,
+    PR 28). The layout is private to this module: views, payloads and
+    the wire keep ``(..., h_kv, head_dim)``.
     """
 
     k: jnp.ndarray
@@ -159,13 +180,14 @@ class PagedKVCache:
         self.pool_blocks = pool_blocks
         self.quant = quant
         h = h_loc if h_loc is not None else cfg.kv_heads
-        shape = (cfg.n_layers, pool_blocks, block_size, h, cfg.head_dim)
+        self.kv_heads = h
+        shape = (cfg.n_layers, pool_blocks, block_size, h * cfg.head_dim)
         if quant:
             self.state = PoolState(
                 k=jnp.zeros(shape, jnp.int8),
                 v=jnp.zeros(shape, jnp.int8),
-                k_scale=jnp.zeros(shape[:-1], jnp.float32),
-                v_scale=jnp.zeros(shape[:-1], jnp.float32),
+                k_scale=jnp.zeros(shape[:-1] + (h,), jnp.float32),
+                v_scale=jnp.zeros(shape[:-1] + (h,), jnp.float32),
             )
         else:
             self.state = PoolState(
@@ -593,8 +615,13 @@ class PagedKVCache:
         blocks = self._tables[rid][lo:hi]
         idx = jnp.asarray(blocks, jnp.int32)
         st = self.state
-        k = jax.device_get(st.k[:, idx])          # (L, n, bs, h, D)
+        # payloads carry (L, bs, h, D); the pool's minor axis is h * D,
+        # so the host reshape is a view of the same bytes
+        tail = (self.kv_heads, self.cfg.head_dim)
+        k = jax.device_get(st.k[:, idx])          # (L, n, bs, h * D)
         v = jax.device_get(st.v[:, idx])
+        k = k.reshape(k.shape[:-1] + tail)
+        v = v.reshape(v.shape[:-1] + tail)
         ks = vs = None
         if st.k_scale is not None:
             ks = jax.device_get(st.k_scale[:, idx])
@@ -614,11 +641,15 @@ class PagedKVCache:
         if not block_ids:
             return
         idx = jnp.asarray(list(block_ids), jnp.int32)
-        k = jnp.asarray(np.stack([np.asarray(p.k) for p in payloads],
-                                 axis=1))
-        v = jnp.asarray(np.stack([np.asarray(p.v) for p in payloads],
-                                 axis=1))
         st = self.state
+        L, _, bs, hd = st.k.shape
+
+        def stacked(arrs):            # (L, bs, h, D) each → (L, n, bs, h*D)
+            return jnp.asarray(np.stack(
+                [np.asarray(a).reshape(L, bs, hd) for a in arrs], axis=1))
+
+        k = stacked(p.k for p in payloads)
+        v = stacked(p.v for p in payloads)
         if st.k_scale is not None:
             ks = jnp.asarray(np.stack(
                 [np.asarray(p.k_scale) for p in payloads], axis=1))
@@ -679,18 +710,18 @@ class PagedKVCache:
         return moved
 
 
-def _gather_view(pool_l, scale_l, table, length, dtype, block_size):
+def _gather_view(pool_l, scale_l, table, length, dtype, head_dim):
     """One layer's attention-ready per-request view(s).
 
-    pool_l: (NB, bs, h, D); table: (..., n_blocks) int32; length:
+    pool_l: (NB, bs, h*D); table: (..., n_blocks) int32; length:
     broadcastable per-row fill level. Returns (..., n_blocks*bs, h, D)
     in ``dtype`` with positions >= length zeroed — exactly the dense
     cache's state (zero-init, written only below the fill level), so
     freed-block garbage can never reach the masked lanes and the packed
     view is bit-comparable to a solo run's cache."""
-    g = pool_l[table]                       # (..., nb, bs, h, D)
-    S = g.shape[-4] * g.shape[-3]
-    g = g.reshape(g.shape[:-4] + (S,) + g.shape[-2:])
+    g = pool_l[table]                       # (..., nb, bs, h*D)
+    S = g.shape[-3] * g.shape[-2]
+    g = g.reshape(g.shape[:-3] + (S, -1, head_dim))
     if scale_l is not None:
         s = scale_l[table]
         s = s.reshape(s.shape[:-3] + (S,) + s.shape[-1:])
@@ -698,6 +729,28 @@ def _gather_view(pool_l, scale_l, table, length, dtype, block_size):
     g = g.astype(dtype)
     keep = jnp.arange(S) < jnp.asarray(length)[..., None]
     return jnp.where(keep[..., None, None], g, jnp.zeros((), dtype))
+
+
+def decode_uses_paged_attn(cfg: GPTConfig, block_size: int,
+                           kv_heads: int, quant: bool) -> bool:
+    """Whether the packed decode step built for this pool attends
+    through ``ops/paged_attention.py``'s kernel or keeps the gathered
+    view and ``attention_lse``: decided from the backend and the pool's
+    shapes alone (no knob), asked at trace time by the step and at build
+    time by the scheduler's ``serve.decode_steps_paged_attn`` counter.
+    Backend Pallas but shapes the kernel does not take: the twin, said
+    once through ``note_fallback``."""
+    if not use_pallas():
+        return False
+    dtype = jnp.int8 if quant else cfg.dtype
+    why = paged_attn_unsupported(block_size, kv_heads, cfg.head_dim,
+                                 dtype)
+    if why is not None:
+        note_fallback("paged_attn_decode",
+                      (block_size, kv_heads, cfg.head_dim,
+                       jnp.dtype(dtype).name), why)
+        return False
+    return True
 
 
 @functools.lru_cache(maxsize=64)
@@ -711,9 +764,10 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     position ``pos[r]`` (cache fill level — keys [0, pos) are live).
     Padded rows pass pos=0 with an all-scratch table row; their math is
     garbage-in/garbage-out into scratch block 0 and the caller ignores
-    their logits. The gathered key width is ``tables.shape[1] *
-    block_size`` — callers pass width-bucketed tables so short requests
-    don't pay max_seq-wide gathers, and jit retraces once per bucket.
+    their logits. Callers pass width-bucketed tables and jit retraces
+    once per bucket: the paged-attention kernel reads each row's live
+    blocks whatever the width, the jnp twin gathers ``tables.shape[1] *
+    block_size`` keys for every row.
     Table rows may alias SHARED prefix pages (refcount > 1): those are
     read-only by host contract — the scheduler CoWs the write-target
     block (``ensure_writable``) before this step scatters into
@@ -814,30 +868,39 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                 kq, ks = _quantize_block(k)
                 vq, vs = _quantize_block(v)
                 pool = PoolState(
-                    k=pool.k.at[li, blk, off].set(kq[:, 0]),
-                    v=pool.v.at[li, blk, off].set(vq[:, 0]),
+                    k=pool.k.at[li, blk, off].set(kq.reshape(R, -1)),
+                    v=pool.v.at[li, blk, off].set(vq.reshape(R, -1)),
                     k_scale=pool.k_scale.at[li, blk, off].set(ks[:, 0]),
                     v_scale=pool.v_scale.at[li, blk, off].set(vs[:, 0]),
                 )
             else:
                 pool = PoolState(
                     k=pool.k.at[li, blk, off].set(
-                        k[:, 0].astype(pool.k.dtype)),
+                        k.reshape(R, -1).astype(pool.k.dtype)),
                     v=pool.v.at[li, blk, off].set(
-                        v[:, 0].astype(pool.v.dtype)),
+                        v.reshape(R, -1).astype(pool.v.dtype)),
                 )
         length = pos + 1                       # new key included
-        with jax.named_scope("paged/gather_kv"):
-            kk = _gather_view(
-                pool.k[li],
-                None if pool.k_scale is None else pool.k_scale[li],
-                tables, length, x.dtype, block_size)
-            vv = _gather_view(
-                pool.v[li],
-                None if pool.v_scale is None else pool.v_scale[li],
-                tables, length, x.dtype, block_size)
-        with jax.named_scope("paged/attention"):
-            o, _ = attention_lse(q, kk, vv, pos, 0, causal=True)
+        if decode_uses_paged_attn(cfg, block_size, kv_loc,
+                                  pool.k_scale is not None):
+            # the pool is read where it lies: the WHOLE pool is the
+            # kernel's operand (a pool.k[li] operand could become a
+            # pool-sized copy per layer), the layer picked in its DMAs
+            with jax.named_scope("paged/attention"):
+                o = paged_attention_decode(q[:, 0], pool.k, pool.v,
+                                           tables, length, li)
+        else:
+            with jax.named_scope("paged/gather_kv"):
+                kk = _gather_view(
+                    pool.k[li],
+                    None if pool.k_scale is None else pool.k_scale[li],
+                    tables, length, x.dtype, head_dim)
+                vv = _gather_view(
+                    pool.v[li],
+                    None if pool.v_scale is None else pool.v_scale[li],
+                    tables, length, x.dtype, head_dim)
+            with jax.named_scope("paged/attention"):
+                o, _ = attention_lse(q, kk, vv, pos, 0, causal=True)
         o = o.reshape(R, 1, h_loc * head_dim)
         attn_out = row_parallel_matmul(o, p["wo"].astype(x.dtype), tp_axis,
                                        _bias(p, "bo", x, use_bias))
@@ -914,8 +977,8 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
         S = table.shape[0] * block_size
         with jax.named_scope("paged/gather_kv"):
             keep = (jnp.arange(S) < pos0)
-            gk = pool.k[:, table].reshape(L, 1, S, *pool.k.shape[-2:])
-            gv = pool.v[:, table].reshape(L, 1, S, *pool.v.shape[-2:])
+            gk = pool.k[:, table].reshape(L, 1, S, -1, cfg.head_dim)
+            gv = pool.v[:, table].reshape(L, 1, S, -1, cfg.head_dim)
             gk = jnp.where(keep[None, None, :, None, None], gk,
                            jnp.zeros((), gk.dtype))
             gv = jnp.where(keep[None, None, :, None, None], gv,
@@ -938,10 +1001,10 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
             h = cache.k.shape[-2]
             newk = jax.lax.dynamic_slice(
                 cache.k, (0, 0, pos0, 0, 0),
-                (L, 1, C, h, cfg.head_dim))[:, 0]
+                (L, 1, C, h, cfg.head_dim)).reshape(L, C, -1)
             newv = jax.lax.dynamic_slice(
                 cache.v, (0, 0, pos0, 0, 0),
-                (L, 1, C, h, cfg.head_dim))[:, 0]
+                (L, 1, C, h, cfg.head_dim)).reshape(L, C, -1)
             if quant:
                 newks = jax.lax.dynamic_slice(
                     cache.k_scale, (0, 0, pos0, 0), (L, 1, C, h))[:, 0]

@@ -112,6 +112,7 @@ from byteps_tpu.models.speculative import _verify_commit
 from byteps_tpu.serve.paged_cache import (
     PagedKVCache,
     PoolExhausted,
+    decode_uses_paged_attn,
     make_paged_decode_fn,
     make_paged_prefill_fn,
 )
@@ -352,6 +353,7 @@ class Scheduler:
         # the dedicated replica's cold-start and HBM win, asserted in
         # tests/test_serve_disagg.py
         self._decode_fn = None
+        self._decode_paged_attn = False   # set with _decode_fn
         self._pick = _make_pick_fn(cfg.vocab_size)
         self._draft_steps: Dict[int, Any] = {}
         self._plan = fault_plan if fault_plan is not None \
@@ -390,6 +392,8 @@ class Scheduler:
             "resumed": _reg.counter("serve.resumed"),
             "prefill_tokens": _reg.counter("serve.prefill_tokens"),
             "decode_tokens": _reg.counter("serve.decode_tokens"),
+            "decode_steps_paged_attn": _reg.counter(
+                "serve.decode_steps_paged_attn"),
             "spec_rounds": _reg.counter("serve.spec_rounds"),
             "spec_tokens": _reg.counter("serve.spec_tokens"),
             "prefix_hits": _reg.counter("serve.prefix_hits"),
@@ -744,6 +748,12 @@ class Scheduler:
                             ap.n_slots)
             self._decode_fn = make_paged_decode_fn(
                 self.cfg, self.cache.block_size, self.tp_axis, lora_sig)
+            # the same question the step asks when it is traced: does
+            # its attention read the pool in place (the Pallas kernel)
+            # or gather a dense view (serve.decode_steps_paged_attn)
+            self._decode_paged_attn = decode_uses_paged_attn(
+                self.cfg, self.cache.block_size, self.cache.kv_heads,
+                self.cache.quant)
         return self._decode_fn
 
     def _params_for(self, run: _Run):
@@ -1456,6 +1466,8 @@ class Scheduler:
                 run.cache_len += 1
                 self._commit_token(run, int(picked[i]), now)
         self._m["decode_tokens"].inc(len(packed))
+        if self._decode_paged_attn:
+            self._m["decode_steps_paged_attn"].inc()
         self._m["batch_occupancy"].observe(len(packed))
         return True
 

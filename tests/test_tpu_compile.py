@@ -27,6 +27,7 @@ from byteps_tpu.ops import topk_kernels as tk
 # (the package re-exports functions named like these two modules)
 from byteps_tpu.ops.flash_attention import _flash_core, flash_attention
 from byteps_tpu.ops.flash_decode import flash_decode
+from byteps_tpu.ops.paged_attention import paged_attention_decode
 
 BF16, F32, I8, U32, I32 = (jnp.bfloat16, jnp.float32, jnp.int8, jnp.uint32,
                            jnp.int32)
@@ -109,6 +110,16 @@ def _decode(quant):
     return f, args
 
 
+def _paged_decode(W):
+    # the serve cells' packed decode step: GPT-2-large's pool (36 layers,
+    # 513 blocks of 16 tokens x 20 heads x 64), batch 8, table width W
+    def f(q, k, v, tables, lengths):
+        return paged_attention_decode(q, k, v, tables, lengths, 35)
+    pool = _sds((36, 513, 16, 20 * 64), BF16)
+    return f, [_sds((8, 20, 64), BF16), pool, pool, _sds((8, W), I32),
+               _sds((8,), I32)]
+
+
 # (id, function, argument shapes, Pallas calls expected in the program)
 ONE_CHIP = [
     ("flash_fwd_gpt2m", _flash_fwd(16, 16), _qkv(128, 1024, 1024, 64), 1),
@@ -126,6 +137,8 @@ ONE_CHIP = [
      [_sds((8, 1024, 16, 64), BF16)] * 3, 1),
     ("flash_decode_dense", *_decode(False), 1),
     ("flash_decode_int8", *_decode(True), 1),
+    ("paged_attn_decode_gpt2l_w64", *_paged_decode(64), 1),
+    ("paged_attn_decode_gpt2l_w2", *_paged_decode(2), 1),
     ("onebit_pack", ob.onebit_pack, [_sds((PART,), F32)], 1),
     *[(f"onebit_unpack_sum_k{k}",
        functools.partial(ob.onebit_unpack_sum, n=PART),
