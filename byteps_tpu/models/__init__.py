@@ -17,6 +17,7 @@ from byteps_tpu.models.bert import (
     BertConfig, bert_init, bert_forward, bert_hidden, bert_mlm_loss,
     bert_param_specs,
 )
+from byteps_tpu.models.joyai import JoyAIConfig, joyai_init, joyai_loss
 from byteps_tpu.models.moe_gpt import (
     MoEGPTConfig, moe_gpt_init, moe_gpt_loss, moe_gpt_param_specs,
     moe_gpt_pp_loss,
@@ -42,6 +43,7 @@ __all__ = [
     "KVCache", "gpt_apply_cached", "init_cache", "make_generate_fn",
     "BertConfig", "bert_init", "bert_forward", "bert_hidden",
     "bert_mlm_loss", "bert_param_specs",
+    "JoyAIConfig", "joyai_init", "joyai_loss",
     "MoEGPTConfig", "moe_gpt_init", "moe_gpt_loss", "moe_gpt_param_specs",
     "moe_gpt_pp_loss",
     "ResNetConfig", "resnet_init", "resnet_forward", "resnet_loss",

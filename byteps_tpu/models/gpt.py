@@ -184,8 +184,12 @@ def _positions(S_loc: int, sp_axis, seq_layout: str) -> jnp.ndarray:
 
 
 def rope_rotate(x: jnp.ndarray, pos: jnp.ndarray,
-                base: float = 10000.0) -> jnp.ndarray:
-    """Rotary position embedding (half-split convention), (B, S, H, D)
+                base: float = 10000.0,
+                interleaved: bool = False) -> jnp.ndarray:
+    """Rotary position embedding, (B, S, H, D): the half-split convention
+    (dims ``i`` and ``i + D/2`` turn together), or with ``interleaved`` the
+    adjacent-pair one (dims ``2i`` and ``2i + 1``; ``rope_interleave`` in a
+    DeepSeek-family config),
     with global positions ``pos`` — either ``(S,)`` shared across the
     batch (training / single-request decode) or ``(B, S)`` per-row (the
     serve tier's packed decode, where one batch holds requests at
@@ -204,6 +208,12 @@ def rope_rotate(x: jnp.ndarray, pos: jnp.ndarray,
         cos = jnp.cos(ang)[None, :, None, :]
         sin = jnp.sin(ang)[None, :, None, :]
     xf = x.astype(jnp.float32)
+    if interleaved:
+        pairs = xf.reshape(*xf.shape[:-1], half, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(xf.shape)
+        return out.astype(x.dtype)
     x1, x2 = xf[..., :half], xf[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                           axis=-1)

@@ -24,6 +24,7 @@ works compressed: dp x {tp, sp, pp, ep} and their products.
 
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -68,14 +69,15 @@ def _check_seq_layout(seq_layout, sp=None):
             "this mesh the permuted inputs would just be scrambled tokens")
 
 
-def _resolve_init_params(init_params, cfg, pspecs):
-    """Fresh :func:`gpt_init` weights, or the caller's ``init_params``
-    (e.g. imported via ``models.import_hf``) validated — tree structure
-    AND leaf shapes — against what the config would initialize, so a
-    config/weights mismatch fails here instead of as a shape error deep
-    inside the jitted step."""
+def _resolve_init_params(init_params, cfg, pspecs, init_fn=gpt_init):
+    """Fresh ``init_fn`` (:func:`gpt_init`) weights, or the caller's
+    ``init_params`` (e.g. imported via ``models.import_hf``, or made on
+    the device from a seed) validated — tree structure AND leaf shapes —
+    against what the config would initialize, so a config/weights
+    mismatch fails here instead of as a shape error deep inside the
+    jitted step."""
     if init_params is None:
-        return gpt_init(jax.random.PRNGKey(0), cfg)
+        return init_fn(jax.random.PRNGKey(0), cfg)
     want = jax.tree_util.tree_structure(pspecs)
     got = jax.tree_util.tree_structure(init_params)
     if want != got:
@@ -83,7 +85,7 @@ def _resolve_init_params(init_params, cfg, pspecs):
             "init_params tree structure does not match the config's "
             f"parameter tree:\n  config expects {want}\n  got {got}")
     expect = jax.eval_shape(
-        lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+        lambda: init_fn(jax.random.PRNGKey(0), cfg))
     bad = []
 
     def _cmp(path, e, g):
@@ -351,7 +353,8 @@ def _shard_params_state(mesh, tx, params, pspecs, dp, state_axes=(),
     return params, opt_state, ospecs
 
 
-def _finalize_step(build_jit, partition_bytes, dp, tunable=True):
+def _finalize_step(build_jit, partition_bytes, dp, tunable=True,
+                   stats_names=()):
     """Return the jitted step, auto-tuned when BYTEPS_AUTO_TUNE=1.
 
     The tuned wrapper re-invokes ``build_jit`` with new partition sizes as
@@ -359,10 +362,19 @@ def _finalize_step(build_jit, partition_bytes, dp, tunable=True):
     transposed to the fused path where a move costs one cached retrace).
     ``tunable=False`` (ZeRO-1 mode) skips the tuner: the zero path
     aggregates the whole flat gradient in one scatter, so partition size
-    changes nothing and every 'move' would retrace an identical program."""
+    changes nothing and every 'move' would retrace an identical program.
+    ``stats_names`` (a model whose step returns a stats vector, see
+    :class:`_TickingStep`) cannot be tuned — the tuner hands the jitted
+    function's outputs straight to the caller — and says so rather than
+    leave BYTEPS_AUTO_TUNE=1 without effect in silence."""
     from byteps_tpu.common.config import get_config
 
     cfg = get_config()
+    if cfg.auto_tune and dp is not None and tunable and stats_names:
+        raise ValueError(
+            "BYTEPS_AUTO_TUNE=1 cannot tune a step that returns a stats "
+            f"vector ({', '.join(stats_names)}): AutoTunedStep passes the "
+            "jitted outputs through; unset it for this model")
     if cfg.auto_tune and dp is not None and tunable:
         from byteps_tpu.jax.tuned_step import AutoTunedStep
 
@@ -370,7 +382,7 @@ def _finalize_step(build_jit, partition_bytes, dp, tunable=True):
         # rely on the factory returning the instance: not wrapped
         return AutoTunedStep(build_jit,
                              partition_bytes or cfg.partition_bytes)
-    return _TickingStep(build_jit(partition_bytes))
+    return _TickingStep(build_jit(partition_bytes), stats_names)
 
 
 class _TickingStep:
@@ -380,10 +392,22 @@ class _TickingStep:
     debug-callback marker, which costs a host sync and stays gated on
     BYTEPS_TRACE_ON. Everything else is the jitted function's own:
     ``step.lower(...).compile()`` gives the program's text and memory
-    analysis ahead of time."""
+    analysis ahead of time.
 
-    def __init__(self, jitted):
+    With ``stats_names`` the jitted step returns one more output, a small
+    f32 vector with one value per name (what the model counted inside the
+    step: routed pairs, the loss's terms). The caller still gets ``(loss,
+    params, opt_state)``; the vector is kept and observed into the
+    registry histograms of those names on a LATER call, once it is ready
+    — a step the caller waited for is — so reading it never waits on the
+    device. :meth:`flush_stats` observes what is still held."""
+
+    _HELD_MAX = 8       # steps of run-ahead before a read may wait
+
+    def __init__(self, jitted, stats_names=()):
         self._jitted = jitted
+        self._stats_names = tuple(stats_names)
+        self._held = collections.deque()
 
     def __call__(self, *args, **kwargs):
         # the host's share of a step: what the caller's step time holds
@@ -394,7 +418,24 @@ class _TickingStep:
         # rounds, a previous model) — a private 1-based counter
         # would be dropped there (FlightRecorder.tick docstring)
         get_flight_recorder().tick()
+        if self._stats_names:
+            self.flush_stats(ready_only=True)
+            self._held.append(out[-1])
+            out = out[:-1]
         return out
+
+    def flush_stats(self, ready_only: bool = False) -> None:
+        """Observe the held stats vectors, oldest first; with
+        ``ready_only`` stop at the first the device has not finished
+        (unless more than ``_HELD_MAX`` are held)."""
+        from byteps_tpu.common.metrics import get_registry
+
+        reg = get_registry()
+        while self._held and (not ready_only or self._held[0].is_ready()
+                              or len(self._held) > self._HELD_MAX):
+            for name, v in zip(self._stats_names,
+                               np.asarray(self._held.popleft())):
+                reg.histogram(name).observe(float(v))
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)
@@ -862,6 +903,7 @@ def make_gpt_moe_train_step(
     zero_1: bool = False,
     seq_layout: str = "contiguous",
     chunked_ce=True,
+    init_params: Optional[Dict[str, Any]] = None,
 ):
     """Expert-parallel MoE GPT train step over a (dp, ep[, tp][, sp]) mesh.
 
@@ -886,9 +928,23 @@ def make_gpt_moe_train_step(
     per-device router statistics, so its VALUE legitimately depends on
     how tokens shard; the nll is exact across layouts.)
 
+    ``cfg`` picks the model (:func:`_moe_family`): a ``MoEGPTConfig``
+    (Switch/GShard capacity routing, expert-parallel over ep) or a
+    ``JoyAIConfig`` (``models/joyai.py``: latent attention, dropless
+    sigmoid top-k routing over the experts held here, a shared expert,
+    the MTP loss). A model may name *buffers* among its leaves (the
+    routing correction bias): they ride in ``params`` — no gradient is
+    taken, the optimizer holds no state for them and decays nothing of
+    them; the model's own ``step_buffers`` rule moves them after the
+    step, or they come back unchanged — and a stats vector its loss
+    returns (``_TickingStep``). ``init_params`` starts from the caller's
+    weights (the model's init tree, e.g. made on the device from a seed)
+    instead of ``PRNGKey(0)`` ones built on the host.
+
     Returns ``(step, params, opt_state, batch_sharding)``.
     """
-    from byteps_tpu.models.moe_gpt import moe_gpt_init, moe_gpt_loss
+    (model_init, model_loss, buffer_keys, stats_names,
+     step_buffers) = _moe_family(cfg)
 
     part = Partitioner.for_config(cfg, mesh)
     dp, ep = part.dp, part.ep
@@ -901,24 +957,34 @@ def make_gpt_moe_train_step(
     _check_seq_layout(seq_layout, sp)
     use_vma = compression_params is None and not zero_1
     ep_size = mesh.shape[ep] if ep is not None else 1
-    if ep is not None and cfg.n_experts % ep_size != 0:
+    if ep is not None and getattr(cfg, "n_experts", ep_size) % ep_size != 0:
         raise ValueError(
             f"n_experts={cfg.n_experts} not divisible by ep={ep_size}"
         )
-    pspecs = part.param_specs(cfg)
-    params = moe_gpt_init(jax.random.PRNGKey(0), cfg)
+    all_specs = part.param_specs(cfg)
+    params = _resolve_init_params(init_params, cfg, all_specs,
+                                  init_fn=model_init)
+    # the optimizer's view: the tree less its buffers (all of it where a
+    # model has none; ``pspecs`` is that view's specs from here on)
+    pspecs = _drop_buffers(all_specs, buffer_keys)
+    trainable = _drop_buffers(params, buffer_keys)
     state_axes, tx_kw, zero_numel = _dist_state_setup(
-        mesh, params, pspecs, dp, zero_1, slc=slc)
-    params, opt_state, ospecs = _shard_params_state(
+        mesh, trainable, pspecs, dp, zero_1, slc=slc)
+    trainable, opt_state, ospecs = _shard_params_state(
         mesh,
         _make_tx(mesh, base_tx, compression_params, partition_bytes, dp,
                  dcn=slc, **tx_kw),
-        params, pspecs, dp, state_axes=state_axes, zero_numel=zero_numel,
+        trainable, pspecs, dp, state_axes=state_axes, zero_numel=zero_numel,
         slc=slc,
     )
+    if buffer_keys:
+        params = _with_buffers(jax.device_put(params, jax.tree.map(
+            lambda s: NamedSharding(mesh, s), all_specs)), trainable)
+    else:
+        params = trainable
     batch_spec = part.batch_spec()
     resym = _make_resymmetrize(pspecs, dp, slc)
-    loss_fn = functools.partial(moe_gpt_loss, cfg=cfg, ep_axis=ep,
+    loss_fn = functools.partial(model_loss, cfg=cfg, ep_axis=ep,
                                 tp_axis=tp, sp_axis=sp, remat=remat,
                                 seq_layout=seq_layout,
                                 chunked_ce=chunked_ce)
@@ -927,11 +993,15 @@ def make_gpt_moe_train_step(
         tx = _make_tx(mesh, base_tx, compression_params, pb, dp, dcn=slc,
                       **tx_kw)
 
-        def per_device_step(params, opt_state, tokens, targets):
+        def per_device_step(all_params, opt_state, tokens, targets):
+            params = _drop_buffers(all_params, buffer_keys)
             grad_params = _pcast_dp(params, dp, mesh, use_vma, slc)
-            loss, grads = jax.value_and_grad(loss_fn)(
-                grad_params, tokens, targets
-            )
+            out, grads = jax.value_and_grad(
+                lambda p: loss_fn(_with_buffers(all_params, p), tokens,
+                                  targets), has_aux=bool(stats_names)
+            )(grad_params)
+            loss, (stats, buffer_aux) = (out if stats_names
+                                         else (out, (None, None)))
             if not use_vma:
                 grads = _novma_collective_fix(
                     grads, pspecs, mesh, (tp, sp), extra_sum_axes=(ep,))
@@ -951,22 +1021,76 @@ def make_gpt_moe_train_step(
             if axes:
                 loss = jax.lax.pmean(loss, axes)
             loss = _collapse_vma(loss)
+            params = _with_buffers(all_params, params)
+            if step_buffers is not None:
+                # the model's own rule for its buffers, from what the loss
+                # counted over every rank's tokens
+                if axes:
+                    buffer_aux = jax.lax.psum(buffer_aux, axes)
+                params = step_buffers(params, _collapse_vma(buffer_aux), cfg)
+            if stats_names:
+                if axes:    # one device's counts and loss terms, averaged
+                    stats = jax.lax.pmean(stats, axes)
+                return loss, params, opt_state, _collapse_vma(stats)
             return loss, params, opt_state
 
         sharded = jax.shard_map(
             per_device_step,
             mesh=mesh,
-            in_specs=(pspecs, ospecs, batch_spec, batch_spec),
-            out_specs=(P(), pspecs, ospecs),
+            in_specs=(all_specs, ospecs, batch_spec, batch_spec),
+            out_specs=(P(), all_specs, ospecs)
+            + ((P(),) if stats_names else ()),
             check_vma=use_vma,
         )
         return jax.jit(sharded, donate_argnums=(0, 1))
 
     return (
         _finalize_step(build_jit, partition_bytes, dp or slc,
-                       tunable=not zero_1),
+                       tunable=not zero_1, stats_names=stats_names),
         params, opt_state, NamedSharding(mesh, batch_spec),
     )
+
+
+def _moe_family(cfg):
+    """``(init, loss, buffer_keys, stats_names, step_buffers)`` of the
+    model a config of the ``moe_gpt`` family names
+    (``parallel/partitioner.py``'s table). A model with ``stats_names``
+    returns ``(loss, (stats, buffer_aux))``; ``step_buffers(params,
+    buffer_aux, cfg)`` is its rule for its buffers after a step."""
+    if type(cfg).__name__ == "JoyAIConfig":
+        from byteps_tpu.models import joyai
+        return (joyai.joyai_init, joyai.joyai_loss, joyai.BUFFER_KEYS,
+                joyai.STEP_STATS, joyai.joyai_step_buffers)
+    from byteps_tpu.models.moe_gpt import moe_gpt_init, moe_gpt_loss
+    return moe_gpt_init, moe_gpt_loss, (), (), None
+
+
+def _drop_buffers(tree, buffer_keys):
+    """``tree`` (dicts and lists of leaves) without the dict entries whose
+    key is in ``buffer_keys``: the optimizer's view, which tree maps,
+    optimizer state and gradient assembly see. The tree itself where
+    there are no such keys."""
+    if not buffer_keys:
+        return tree
+    if isinstance(tree, dict):
+        return {k: _drop_buffers(v, buffer_keys) for k, v in tree.items()
+                if k not in buffer_keys}
+    if isinstance(tree, (list, tuple)):
+        return type(tree)(_drop_buffers(v, buffer_keys) for v in tree)
+    return tree
+
+
+def _with_buffers(full, trainable):
+    """``trainable`` (a :func:`_drop_buffers` view of ``full``, or
+    something of its structure) with the entries it lacks taken from
+    ``full``."""
+    if isinstance(full, dict):
+        return {k: _with_buffers(v, trainable[k]) if k in trainable else v
+                for k, v in full.items()}
+    if isinstance(full, (list, tuple)):
+        return type(full)(_with_buffers(f, t)
+                          for f, t in zip(full, trainable))
+    return trainable
 
 
 def make_gpt_moe_pp_train_step(

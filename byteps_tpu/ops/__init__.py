@@ -15,6 +15,7 @@ from byteps_tpu.ops.flash_attention import (
     flash_attention_lse,
     merge_attention,
 )
+from byteps_tpu.ops.grouped_matmul import grouped_matmul, grouped_matmul_jnp
 from byteps_tpu.ops.onebit_kernels import (
     onebit_pack,
     onebit_unpack,
@@ -25,5 +26,6 @@ from byteps_tpu.ops.onebit_kernels import (
 __all__ = [
     "attention_jnp", "chunked_ce_nll", "dense_ce_nll", "flash_attention",
     "flash_attention_lse", "merge_attention",
+    "grouped_matmul", "grouped_matmul_jnp",
     "onebit_pack", "onebit_unpack", "onebit_unpack_sum", "packed_words",
 ]

@@ -11,7 +11,9 @@ Design (flash-attention-2 schedule, TPU-shaped):
 * Layout ``(B*H, S, D)`` — batch×heads is the embarrassingly parallel
   grid axis; ``S`` is tiled into (bq, bk) blocks sized to the MXU
   (128 where the sequence allows); ``D`` (head_dim ≤ 256) stays whole so
-  every matmul in the kernel is an MXU op on full tiles.
+  every matmul in the kernel is an MXU op on full tiles. v (and so o,
+  do, dv) may have a width ``Dv`` of its own — latent attention scores
+  on 192 and mixes values of 128 — which only the block shapes see.
 * Forward: grid ``(BH, nq, nk)``, innermost ``nk`` sequential
   ("arbitrary") with the online-softmax state ``(m, l, acc)`` carried in
   VMEM scratch — scores for one ``(bq, bk)`` tile only ever exist in
@@ -93,7 +95,8 @@ def _env_prefer() -> Tuple[int, ...]:
 
 def _train_blocks(Sq: int, Sk: int, D: int, itemsize: int,
                   prefer: Tuple[int, ...],
-                  n_inter: int = 2) -> Optional[Tuple[int, int]]:
+                  n_inter: int = 2,
+                  Dv: Optional[int] = None) -> Optional[Tuple[int, int]]:
     """(bq, bk) for the train kernels — or None when either sequence has
     no dividing tile (the documented None→jnp-fallback contract that
     ``_pick_block``/``supported()`` establish; callers not pre-gated by
@@ -106,12 +109,19 @@ def _train_blocks(Sq: int, Sk: int, D: int, itemsize: int,
     ``n_inter`` models the kernel's live (bq, bk) f32 intermediates:
     2 for the forward (s, p), 4 for the backwards (s, p, dp, ds) — the
     backward call sites pass 4, which is what steers them to 512 tiles
-    while the forward keeps whole-sequence k-tiles."""
+    while the forward keeps whole-sequence k-tiles.
+
+    ``D`` is the q/k width, ``Dv`` the value width where it differs
+    (latent attention: 192 / 128); q and k blocks are D wide, v, o and do
+    blocks Dv wide. With ``Dv == D`` the sums below are the ones the
+    GPT-2 retune was measured at."""
+    Dv = D if Dv is None else Dv
+
     def fits(bq: int, bk: int) -> bool:
         inter = n_inter * bq * bk * 4
         # q,(k,v)(,do) blocks double-buffered by the pallas pipeline
-        io = 2 * 2 * (2 * bq + 2 * bk) * D * itemsize
-        scratch = (bq + 2 * bk) * D * 4             # f32 accumulators
+        io = 2 * 2 * (bq + bk) * (D + Dv) * itemsize
+        scratch = (bq * Dv + bk * (D + Dv)) * 4     # f32 accumulators
         return inter + io + scratch <= _VMEM_BUDGET
 
     prefer = _env_prefer() + prefer
@@ -240,7 +250,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # pairs into an f32 accumulator either way)
         q = q_ref[0]                                         # (bq, D)
         k = k_ref[0]                                         # (bk, D)
-        v = v_ref[0]                                         # (bk, D)
+        v = v_ref[0]                                         # (bk, Dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # (bq, bk)
@@ -260,7 +270,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # same order as the bf16 output rounding); f32 inputs keep f32 p
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
             p.astype(v_ref.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bq, D)
+            preferred_element_type=jnp.float32)              # (bq, Dv)
         m_scr[:] = m_new
 
     if causal:
@@ -304,11 +314,12 @@ def _kv_index(heads: int, kv_heads: int):
                                              "heads", "kv_heads"))
 def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
          heads: int, kv_heads: int):
-    """q3: (B·H, S, D), k3/v3: (B·Hkv, S, D) →
-    (o (B·H, Sq, D), lse (B·H, Sq, 1) f32)."""
+    """q3: (B·H, S, D), k3: (B·Hkv, S, D), v3: (B·Hkv, S, Dv) →
+    (o (B·H, Sq, Dv), lse (B·H, Sq, 1) f32). The softmax scale is
+    ``D ** -0.5``, the q/k width."""
     BH, Sq, D = q3.shape
-    Sk = k3.shape[1]
-    blocks = _train_blocks(Sq, Sk, D, q3.dtype.itemsize, _FWD_PREFER)
+    Sk, Dv = k3.shape[1], v3.shape[2]
+    blocks = _train_blocks(Sq, Sk, D, q3.dtype.itemsize, _FWD_PREFER, Dv=Dv)
     if blocks is None:
         raise ValueError(
             f"flash forward kernel has no dividing tile for Sq={Sq}, "
@@ -327,20 +338,20 @@ def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bk, D), lambda b, qi, ki: (kv(b), ki, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, qi, ki: (kv(b), ki, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, qi, ki: (kv(b), ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, qi, ki: (b, qi, 0)),
         ],
         out_shape=[
-            _out_struct((BH, Sq, D), q3.dtype, q3, k3, v3, qoff, koff),
+            _out_struct((BH, Sq, Dv), q3.dtype, q3, k3, v3, qoff, koff),
             _out_struct((BH, Sq, 1), jnp.float32, q3, k3, v3, qoff, koff),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),    # m (row max)
             pltpu.VMEM((bq, 1), jnp.float32),    # l (row sum)
-            pltpu.VMEM((bq, D), jnp.float32),    # acc
+            pltpu.VMEM((bq, Dv), jnp.float32),   # acc
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -371,8 +382,8 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         # the dq GEMM boundary
         q = q_ref[0]                                         # (bq, D)
         k = k_ref[0]                                         # (bk, D)
-        v = v_ref[0]                                         # (bk, D)
-        do = do_ref[0]                                       # (bq, D)
+        v = v_ref[0]                                         # (bk, Dv)
+        do = do_ref[0]                                       # (bq, Dv)
         lse = lse_ref[0]                                     # (bq, 1)
         delta = dl_ref[0]                                    # (bq, 1)
         dlse = dlse_ref[0]                                   # (bq, 1)
@@ -430,8 +441,8 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         # input-dtype operands on every MXU dot (see _fwd_kernel note)
         q = q_ref[0]                                         # (bq, D)
         k = k_ref[0]                                         # (bk, D)
-        v = v_ref[0]                                         # (bk, D)
-        do = do_ref[0]                                       # (bq, D)
+        v = v_ref[0]                                         # (bk, Dv)
+        do = do_ref[0]                                       # (bq, Dv)
         lse = lse_ref[0]                                     # (bq, 1)
         delta = dl_ref[0]                                    # (bq, 1)
         dlse = dlse_ref[0]                                   # (bq, 1)
@@ -445,7 +456,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             p = jnp.where(s > _NEG / 2, p, 0.0)
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do_ref.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bk, D)
+            preferred_element_type=jnp.float32)               # (bk, Dv)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)               # (bq, bk)
@@ -479,9 +490,9 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd(q3, k3, v3, o3, lse, qoff, koff, do3, dlse,
          causal: bool, interpret: bool, heads: int, kv_heads: int):
     BH, Sq, D = q3.shape
-    BHkv, Sk = k3.shape[0], k3.shape[1]
+    BHkv, Sk, Dv = k3.shape[0], k3.shape[1], v3.shape[2]
     blocks = _train_blocks(Sq, Sk, D, q3.dtype.itemsize, _BWD_PREFER,
-                           n_inter=4)
+                           n_inter=4, Dv=Dv)
     if blocks is None:
         raise ValueError(
             f"flash backward kernel has no dividing tile for Sq={Sq}, "
@@ -506,8 +517,8 @@ def _bwd(q3, k3, v3, o3, lse, qoff, koff, do3, dlse,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bk, D), lambda b, qi, ki: (kv(b), ki, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, qi, ki: (kv(b), ki, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, qi, ki: (kv(b), ki, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, qi, ki: (b, qi, 0)),
@@ -538,25 +549,25 @@ def _bwd(q3, k3, v3, o3, lse, qoff, koff, do3, dlse,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bq, D), lambda b, ki, j: (qrow(b, j), j % nq, 0)),
             pl.BlockSpec((1, bk, D), lambda b, ki, j: (b, ki, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, ki, j: (b, ki, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, ki, j: (qrow(b, j), j % nq, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, ki, j: (b, ki, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, ki, j: (qrow(b, j), j % nq, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, ki, j: (qrow(b, j), j % nq, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, ki, j: (qrow(b, j), j % nq, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, ki, j: (qrow(b, j), j % nq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, ki, j: (b, ki, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, ki, j: (b, ki, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, ki, j: (b, ki, 0)),
         ],
         out_shape=[
             _out_struct((BHkv, Sk, D), k3.dtype,
                         q3, k3, v3, do3, lse, delta, dlse, qoff, koff),
-            _out_struct((BHkv, Sk, D), v3.dtype,
+            _out_struct((BHkv, Sk, Dv), v3.dtype,
                         q3, k3, v3, do3, lse, delta, dlse, qoff, koff),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -15,6 +15,16 @@ Inside ``shard_map`` each device owns ``E / ep_size`` experts
 (expert-stacked weights sharded ``P('ep')`` on their leading axis) and
 every device routes its OWN tokens to all E experts — dp and ep compose:
 dp replicas each contribute their local batch's slots.
+
+A second path, :func:`moe_ffn_dropless` (end of the file), routes as
+DeepSeek-V3-family models do — sigmoid scores, top-k on score + a
+correction bias, hundreds of experts, NO dropped token — by sorting the
+(token, expert) pairs by expert and running grouped matrix products
+(``ops/grouped_matmul.py``) over the experts this device holds. It is told
+which experts those are and runs no exchange; it also counts the picks
+of every routed expert, from which :func:`noaux_bias_step` moves the
+correction bias between steps. The capacity path above is unchanged and
+stays ``MoEGPTConfig``'s.
 """
 
 from __future__ import annotations
@@ -217,3 +227,195 @@ def moe_specs(ep_axis: Optional[str], tp_axis: Optional[str] = None,
     from byteps_tpu.parallel.partitioner import resolve_specs, rules_from_axes
     return resolve_specs(moe_logical_specs(mlp),
                          rules_from_axes(tp_axis=tp_axis, ep_axis=ep_axis))
+
+
+# --------------------------------------------------------------------------
+# Dropless many-expert routing (DeepSeek-V3 style): sigmoid scores, top-k on
+# score + correction bias, pairs sorted by expert, grouped matrix products
+# over the experts held here. The Switch path above is unchanged.
+# --------------------------------------------------------------------------
+def sigmoid_topk_route(xt: jnp.ndarray, wg: jnp.ndarray, bias: jnp.ndarray,
+                       k: int, scale: float):
+    """``(idx (T, k) int32, weight (T, k) f32)`` of ``noaux_tc`` routing
+    without a group limit: scores ``s = sigmoid(x·wg)`` in f32 (the
+    product at full precision: a pick must not turn on bf16 rounding),
+    the ``k`` experts with the largest ``s + bias``, and weights taken
+    from ``s`` alone — ``scale · s_i / Σ_picked s``. ``bias`` is a buffer:
+    it steers the picks and takes no gradient."""
+    s = jax.nn.sigmoid(jax.lax.dot_general(
+        xt.astype(jnp.float32), wg.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32))
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias)[None, :], k)
+    picked = jnp.take_along_axis(s, idx, axis=-1)
+    weight = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), weight
+
+
+def _rows_of(x, index):
+    """``x[index]`` with zero rows where ``index`` is out of range."""
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _dispatch(x, row_token, pair_row):
+    """``xs[r] = x[row_token[r]]`` (zero where the row holds no pair).
+    Its transpose is a gather too: token ``t``'s pairs sit in the rows
+    ``pair_row[t, :]``, so no scatter runs in either direction."""
+    del pair_row
+    return _rows_of(x, row_token)
+
+
+def _dispatch_fwd(x, row_token, pair_row):
+    return _rows_of(x, row_token), pair_row
+
+
+def _dispatch_bwd(pair_row, dxs):
+    dx = sum(_rows_of(dxs, pair_row[:, j]).astype(jnp.float32)
+             for j in range(pair_row.shape[1]))
+    return dx.astype(dxs.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, weight, row_pair, pair_row):
+    """``out[t] = Σ_j weight[t, j] · ys[pair_row[t, j]]`` (a pair whose
+    expert is not held has no row and adds nothing), f32 accumulation."""
+    del row_pair
+    return sum(weight[:, j, None] * _rows_of(ys, pair_row[:, j])
+               .astype(jnp.float32)
+               for j in range(pair_row.shape[1])).astype(ys.dtype)
+
+
+def _combine_fwd(ys, weight, row_pair, pair_row):
+    return (_combine(ys, weight, row_pair, pair_row),
+            (ys, weight, row_pair, pair_row))
+
+
+def _combine_bwd(res, dout):
+    ys, weight, row_pair, pair_row = res
+    k = pair_row.shape[1]
+    row_w = _rows_of(weight.reshape(-1), row_pair)
+    dys = (row_w[:, None] * _rows_of(dout, row_pair // k)
+           .astype(jnp.float32)).astype(ys.dtype)
+    dof = dout.astype(jnp.float32)
+    dweight = jnp.stack(
+        [jnp.sum(_rows_of(ys, pair_row[:, j]).astype(jnp.float32) * dof,
+                 axis=-1) for j in range(k)], axis=-1)
+    return dys, dweight.astype(weight.dtype), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
+                     first_expert: int = 0, row_tile: Optional[int] = None):
+    """Sigmoid top-k MoE feed-forward in which no token is ever dropped,
+    over the experts THIS device holds.
+
+    ``params``: ``wg (d, E)`` the router over all ``E`` routed experts,
+    ``router_bias (E,)`` the correction bias (a buffer), and the gated
+    expert stacks ``w1``/``w3 (held, d, ff)``, ``w2 (held, ff, d)`` of the
+    ``held`` experts ``first_expert .. first_expert + held - 1``. Every
+    token is routed over all ``E``; the ``T·k`` (token, expert) pairs are
+    sorted by expert, the pairs of held experts laid out group after
+    group — each group padded with zero rows to the row tile of
+    ``ops/grouped_matmul.py`` — and gate/up, SwiGLU and down run as three
+    grouped products; the rows are then gathered back and added with
+    their weights. The row buffer is sized for the worst case, all ``T·k``
+    pairs held here, so its shape is static and nothing can overflow; the
+    kernels visit only the tiles that hold pairs. What the experts held
+    elsewhere would add is left out (under expert parallelism their
+    owners compute it; this layer runs no exchange).
+
+    Returns ``(y, stats, load)``: ``y`` shaped like ``x``, ``stats`` f32
+    ``(3,)`` = pairs computed here (the rows of the buffer that hold a
+    pair), pairs in all (those plus the pairs routed elsewhere: ``T·k``
+    unless a held pair lost its row), heaviest held expert over the mean
+    held expert; ``load`` f32 ``(E,)`` the tokens each of ALL routed
+    experts was picked by (what :func:`noaux_bias_step` balances)."""
+    from byteps_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
+
+    tm = ROW_TILE if row_tile is None else row_tile
+    held = params["w1"].shape[0]
+    lead, d = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, d)
+    T = xt.shape[0]
+    idx, weight = sigmoid_topk_route(xt, params["wg"], params["router_bias"],
+                                     top_k, scale)
+    P_ = T * top_k
+    local = idx.reshape(P_) - first_expert
+    is_held = (local >= 0) & (local < held)
+    local = jnp.where(is_held, local, held)          # the rest sort last
+    onehot = local[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]
+    before = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
+    counts = before[-1]                                        # (held,)
+    rank = jnp.sum(jnp.where(onehot, before - 1, 0), axis=1)   # in its group
+    padded = -(-counts // tm) * tm
+    starts = jnp.cumsum(padded) - padded
+    n_rows = (-(-P_ // tm) + held) * tm            # static worst case
+    pair_row = jnp.where(is_held, jnp.take(starts, local, mode="clip") + rank,
+                         n_rows).reshape(T, top_k)
+    # row -> pair, by gathers alone: the stable sort's position j of group
+    # g's r-th pair is (pairs before g) + r
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    rows = jnp.arange(n_rows, dtype=jnp.int32)
+    group = jnp.minimum(jnp.searchsorted(starts + padded, rows, side="right"),
+                        held - 1)
+    within = rows - jnp.take(starts, group)
+    sorted_pos = jnp.take(jnp.cumsum(counts) - counts, group) + within
+    row_pair = jnp.where(within < jnp.take(counts, group),
+                         jnp.take(order, sorted_pos, mode="clip"), P_)
+
+    xs = _dispatch(xt, row_pair // top_k, pair_row)
+    gate = grouped_matmul(xs, params["w1"], padded, tm)
+    up = grouped_matmul(xs, params["w3"], padded, tm)
+    ys = grouped_matmul(jax.nn.silu(gate) * up, params["w2"], padded, tm)
+    y = _combine(ys, weight, row_pair, pair_row)
+
+    # counted from the row buffer the kernels ran over, not from the
+    # router's ids: a held pair that got no row is missing from both
+    here = jnp.sum(row_pair < P_).astype(jnp.float32)
+    stats = jnp.stack([
+        here, here + jnp.sum(~is_held).astype(jnp.float32),
+        jnp.max(counts).astype(jnp.float32) * held / jnp.maximum(here, 1.0)])
+    load = jnp.sum(idx.reshape(P_, 1) == jnp.arange(
+        params["wg"].shape[1], dtype=jnp.int32)[None, :], axis=0)
+    return y.reshape(*lead, d).astype(x.dtype), stats, load.astype(jnp.float32)
+
+
+def noaux_bias_step(bias: jnp.ndarray, load: jnp.ndarray, rate: float):
+    """The ``noaux_tc`` balancing rule (auxiliary-loss-free balancing,
+    DeepSeek-V3 section 2.1.2), applied between steps: an expert picked by
+    more tokens than the mean expert has its correction bias lowered by
+    ``rate``, one picked by fewer raised by ``rate`` — ``bias + rate ·
+    sign(mean(load) - load)``. ``load (E,)`` is the step's count of picks
+    per routed expert (summed over the data-parallel ranks by the
+    caller)."""
+    return bias + rate * jnp.sign(jnp.mean(load) - load).astype(bias.dtype)
+
+
+def moe_dropless_init(rng, d: int, ff: int, n_routed: int, held: int,
+                      std: float = 0.02, bias_std: float = 0.0):
+    """Params of :func:`moe_ffn_dropless`: a router over ``n_routed``
+    experts, its correction bias (drawn once at ``bias_std`` and never
+    trained: a buffer) and the gated stacks of the ``held`` experts."""
+    k = jax.random.split(rng, 5)
+    return {
+        "wg": jax.random.normal(k[0], (d, n_routed), jnp.float32) * std,
+        "router_bias": jax.random.normal(k[4], (n_routed,),
+                                         jnp.float32) * bias_std,
+        "w1": jax.random.normal(k[1], (held, d, ff), jnp.float32) * std,
+        "w3": jax.random.normal(k[3], (held, d, ff), jnp.float32) * std,
+        "w2": jax.random.normal(k[2], (held, ff, d), jnp.float32) * std,
+    }
+
+
+def moe_dropless_logical_specs():
+    """Logical axes of :func:`moe_dropless_init`'s leaves. The stacks hold
+    this device's experts already, so nothing shards over ``expert``."""
+    return {"wg": (None, None), "router_bias": (None,),
+            "w1": (None, "embed", "mlp"), "w3": (None, "embed", "mlp"),
+            "w2": (None, "mlp", "embed")}

@@ -170,6 +170,9 @@ def _logical_specs_for(cfg: Any, params: Any = None) -> Any:
     if name == "MoEGPTConfig":
         from byteps_tpu.models.moe_gpt import moe_gpt_logical_specs
         return moe_gpt_logical_specs(cfg)
+    if name == "JoyAIConfig":
+        from byteps_tpu.models.joyai import joyai_logical_specs
+        return joyai_logical_specs(cfg)
     if name == "T5Config":
         from byteps_tpu.models.t5 import t5_logical_specs
         return t5_logical_specs(cfg)
@@ -188,7 +191,8 @@ def _logical_specs_for(cfg: Any, params: Any = None) -> Any:
 
 
 _FAMILY_BY_CONFIG = {
-    "GPTConfig": "gpt", "MoEGPTConfig": "moe_gpt", "T5Config": "t5",
+    "GPTConfig": "gpt", "MoEGPTConfig": "moe_gpt", "JoyAIConfig": "moe_gpt",
+    "T5Config": "t5",
     "BertConfig": "bert", "ViTConfig": "vit", "ResNetConfig": "resnet",
 }
 

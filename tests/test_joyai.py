@@ -1,0 +1,399 @@
+"""JoyAI-LLM-Flash for training (models/joyai.py, parallel/moe.py's dropless
+path, ops/grouped_matmul.py, the flash kernels at a value width of their
+own) against the plain reference (benchmark/configs/joyai_reference.py), at
+tiny sizes on the CPU: d 64, 4 heads of 24/8/16, q_lora 48, kv_lora 32, 16
+experts top-4, 1 dense + 2 expert layers + MTP. Everything runs in float32
+here (the program's jnp twins at XLA:CPU's exact f32 products), so the
+tolerances are f32 rounding over a few hundred sums, not bf16's."""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from byteps_tpu.models.joyai import (
+    BUFFER_KEYS, STEP_STATS, JoyAIConfig, joyai_init, joyai_loss)
+from byteps_tpu.parallel.moe import moe_dropless_init, moe_ffn_dropless
+
+_REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "benchmark", "configs", "joyai_reference.py")
+_spec = importlib.util.spec_from_file_location("joyai_reference", _REF)
+ref = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(ref)
+
+CFG = JoyAIConfig.tiny()
+
+
+def _ref_kw(cfg):
+    return dict(n_heads=cfg.n_heads, nope=cfg.qk_nope_dim,
+                rope=cfg.qk_rope_dim, v_dim=cfg.v_head_dim,
+                theta=cfg.rope_base, eps=cfg.norm_eps, top_k=cfg.top_k,
+                scale=cfg.routed_scaling, first_expert=cfg.first_expert)
+
+
+def _batch(cfg, B=2, S=16, seed=1):
+    toks = np.random.default_rng(seed).integers(0, cfg.vocab_size,
+                                                (B, S + 1)).astype(np.int32)
+    return jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
+
+
+def test_reference_imports_nothing_from_the_program():
+    with open(_REF) as f:
+        assert "byteps_tpu" not in f.read().split('"""', 2)[2]
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_loss_and_every_gradient_leaf_match_the_reference(remat):
+    params = joyai_init(jax.random.PRNGKey(3), CFG)
+    tok, tgt = _batch(CFG)
+
+    def mine(p):
+        return joyai_loss(p, tok, tgt, CFG, remat=remat)[0]
+
+    def theirs(p):
+        return ref.loss(p, tok, tgt, mtp_weight=CFG.mtp_loss_weight,
+                        **_ref_kw(CFG))
+
+    (l1, g1), (l2, g2) = (jax.value_and_grad(f)(params)
+                          for f in (mine, theirs))
+    # f32 on both sides; sums of a few hundred terms of O(1e-2): 1e-5
+    # absolute on a loss of ~5, and on gradients 2e-5 of the leaf's largest
+    # entry plus 1e-7. A bf16 router moves picks (loss by ~1e-3) and a
+    # dropped pair moves that token's row of every expert gradient by O(1)
+    # of its size.
+    assert abs(float(l1) - float(l2)) < 1e-5
+    flat1 = jax.tree_util.tree_leaves_with_path(g1)
+    flat2 = jax.tree.leaves(g2)
+    assert len(flat1) == len(flat2)
+    for (path, a), b in zip(flat1, flat2):
+        tol = 2e-5 * float(jnp.abs(b).max()) + 1e-7
+        assert float(jnp.abs(a - b).max()) <= tol, jax.tree_util.keystr(path)
+    # the bias steers picks and takes no gradient
+    for p in g1["blocks"][1:]:
+        assert float(jnp.abs(p["moe"]["router_bias"]).max()) == 0.0
+
+
+def test_stats_count_pairs_and_split_the_loss():
+    params = joyai_init(jax.random.PRNGKey(3), CFG)
+    tok, tgt = _batch(CFG)
+    loss, (stats, loads) = joyai_loss(params, tok, tgt, CFG)
+    here, total, load, main, mtp = (float(v) for v in stats)
+    T, moe_layers = tok.size, CFG.n_layers - CFG.first_k_dense + CFG.n_mtp
+    assert len(stats) == len(STEP_STATS)
+    assert total == T * CFG.top_k * moe_layers
+    assert here == total            # every expert is held
+    assert load >= 1.0
+    assert abs(float(loss) - (main + CFG.mtp_loss_weight * mtp)) < 1e-6
+    r_main, r_mtp = ref.losses(params, tok, tgt, **_ref_kw(CFG))
+    assert abs(main - float(r_main)) < 1e-5
+    assert abs(mtp - float(r_mtp)) < 1e-5
+    # one row of picks per expert layer, over ALL routed experts
+    assert loads.shape == (moe_layers, CFG.n_routed_experts)
+    assert float(loads.sum()) == total
+
+
+def _layer(seed=0, T=24, d=32, ff=16, E=16, held=16, bias_std=0.0):
+    p = moe_dropless_init(jax.random.PRNGKey(seed), d, ff, E, held,
+                          std=0.2, bias_std=bias_std)
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (T, d), jnp.float32)
+    return x, p
+
+
+def test_bias_changes_the_picks_and_weights_still_come_from_scores():
+    x, p = _layer()
+    base = moe_ffn_dropless(x, p, 4, 2.5, row_tile=8)[0]
+    # large enough on two experts to put them in every token's picks
+    p2 = dict(p, router_bias=p["router_bias"].at[jnp.array([3, 11])].set(5.0))
+    y = moe_ffn_dropless(x, p2, 4, 2.5, row_tile=8)[0]
+    want = ref.moe_layer(x, p2, top_k=4, scale=2.5)
+    assert float(jnp.abs(y - base).max()) > 1e-2          # the picks moved
+    np.testing.assert_allclose(y, want, atol=2e-5, rtol=2e-5)
+    # had the weights been taken from s + b, they would be dominated by 5.0
+    s = jax.nn.sigmoid(x @ p2["wg"])
+    _, idx = jax.lax.top_k(s + p2["router_bias"], 4)
+    assert bool(jnp.all(jnp.any(idx == 3, -1) & jnp.any(idx == 11, -1)))
+
+
+def test_every_token_to_one_held_expert_drops_nothing():
+    # the router's column 5 towers over the rest: all T tokens pick expert
+    # 5 first, T pairs in one group — a capacity would overflow
+    x, p = _layer(T=40)
+    x = jnp.abs(x)
+    p = dict(p, wg=p["wg"].at[:, 5].set(3.0))
+    y, stats, load = moe_ffn_dropless(x, p, 4, 2.5, row_tile=8)
+    want = ref.moe_layer(x, p, top_k=4, scale=2.5)
+    np.testing.assert_allclose(y, want, atol=2e-5, rtol=2e-5)
+    assert float(stats[0]) == float(stats[1]) == 40 * 4
+    assert float(load[5]) == 40 and float(load.sum()) == 40 * 4
+    assert float(stats[2]) >= 4.0      # 40 of 160 pairs on one of 16
+
+
+def test_shares_sum_to_the_uncut_layer():
+    """The parts that all E/experts_held shares give, with the shared expert
+    counted once, add up to the uncut reference's layer output."""
+    cfg = JoyAIConfig.tiny(n_layers=2)        # 1 dense + 1 expert layer
+    full = joyai_init(jax.random.PRNGKey(5), cfg)["blocks"][1]
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 12, cfg.d_model))
+    held = 4
+    total = jnp.zeros_like(x)
+    pairs = 0.0
+    for first in range(0, cfg.n_routed_experts, held):
+        share = dict(full["moe"], **{k: full["moe"][k][first:first + held]
+                                     for k in ("w1", "w3", "w2")})
+        y, stats, _ = moe_ffn_dropless(
+            x, share, cfg.top_k, cfg.routed_scaling, first_expert=first,
+            row_tile=8)
+        total = total + y
+        pairs += float(stats[0])
+    sh = full["shared"]
+    shared = (jax.nn.silu(x @ sh["w1"]) * (x @ sh["w3"])) @ sh["w2"]
+    want = (ref.moe_layer(x, full["moe"], top_k=cfg.top_k,
+                          scale=cfg.routed_scaling)
+            + ref._swiglu(x, sh["w1"], sh["w3"], sh["w2"]))
+    np.testing.assert_allclose(total + shared, want, atol=2e-5, rtol=2e-5)
+    assert pairs == x.shape[0] * x.shape[1] * cfg.top_k   # each pair once
+
+
+def test_a_share_matches_the_reference_given_the_same_share():
+    cfg = JoyAIConfig.tiny(experts_held=4, first_expert=8)
+    params = joyai_init(jax.random.PRNGKey(7), cfg)
+    tok, tgt = _batch(cfg)
+    loss, (stats, _) = joyai_loss(params, tok, tgt, cfg)
+    want = ref.loss(params, tok, tgt, mtp_weight=cfg.mtp_loss_weight,
+                    **_ref_kw(cfg))
+    assert abs(float(loss) - float(want)) < 1e-5
+    # the rows the kernels ran over are the pairs the reference routes to
+    # the held experts, one for one; with those routed elsewhere, all T*k
+    held = ref.forward(params, tok, tgt, **_ref_kw(cfg))[2]
+    assert 0 < float(stats[0]) == float(held) < float(stats[1])
+    assert float(stats[1]) == tok.size * cfg.top_k * 3
+
+
+@pytest.mark.parametrize("router_dtype,same", [(jnp.float32, True),
+                                               (jnp.bfloat16, False)])
+def test_router_picks_are_the_references_and_a_bf16_router_differs(
+        router_dtype, same):
+    """What the benchmark's ``correct`` asks at the published widths: on the
+    reference's own router input the program's router picks what the
+    reference picks, token for token; rounded to bf16 it does not."""
+    from byteps_tpu.parallel.moe import sigmoid_topk_route
+
+    cfg = JoyAIConfig.tiny(n_routed_experts=64, experts_held=64, top_k=8,
+                           max_seq=128)
+    params = joyai_init(jax.random.PRNGKey(13), cfg)
+    tok, tgt = _batch(cfg, B=4, S=128)
+    h, idx = ref.forward(params, tok, tgt, **_ref_kw(cfg))[3]
+    moe = params["blocks"][cfg.first_k_dense]["moe"]
+    mine, _ = sigmoid_topk_route(
+        h.reshape(-1, cfg.d_model).astype(router_dtype).astype(jnp.float32),
+        moe["wg"].astype(router_dtype), moe["router_bias"], cfg.top_k,
+        cfg.routed_scaling)
+    differ = int(jnp.sum(jnp.any(
+        jnp.sort(mine, -1) != jnp.sort(idx.reshape(mine.shape), -1), -1)))
+    assert (differ == 0) if same else (differ > 0)
+
+
+# -- kernels, interpreted ---------------------------------------------------
+@pytest.fixture
+def pallas(monkeypatch):
+    monkeypatch.setenv("BYTEPS_KERNEL_BACKEND", "pallas")
+
+
+def test_flash_kernels_with_a_value_width_of_their_own(pallas):
+    """MLA's shapes in miniature (q/k 48 wide, v 32): forward and the three
+    gradients against attention_jnp."""
+    from byteps_tpu.ops.flash_attention import attention_jnp, flash_attention
+
+    k = jax.random.split(jax.random.PRNGKey(0), 4)
+    B, S, H, D, Dv = 2, 64, 4, 48, 32
+    q = jax.random.normal(k[0], (B, S, H, D))
+    kk = jax.random.normal(k[1], (B, S, H, D))
+    v = jax.random.normal(k[2], (B, S, H, Dv))
+    w = jax.random.normal(k[3], (B, S, H, Dv))
+
+    def scalar(fn):
+        return lambda q, k, v: (fn(q, k, v, causal=True) * w).sum()
+
+    o = flash_attention(q, kk, v)
+    assert o.shape == (B, S, H, Dv)
+    np.testing.assert_allclose(o, attention_jnp(q, kk, v), atol=2e-6)
+    got = jax.grad(scalar(flash_attention), (0, 1, 2))(q, kk, v)
+    want = jax.grad(scalar(attention_jnp), (0, 1, 2))(q, kk, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+@pytest.mark.parametrize("sizes", [[16, 0, 8, 24, 0], [0, 0, 0, 0, 0],
+                                   [8, 8, 8, 8, 8], [0, 56, 0, 0, 8]])
+def test_grouped_product_against_its_twin(pallas, sizes):
+    from byteps_tpu.ops.grouped_matmul import (
+        grouped_matmul, grouped_matmul_jnp)
+
+    k = jax.random.split(jax.random.PRNGKey(1), 3)
+    tm, G, K, N, M = 8, 5, 16, 24, 64
+    gs = jnp.asarray(sizes, jnp.int32)
+    lhs = jax.random.normal(k[0], (M, K))
+    rhs = jax.random.normal(k[1], (G, K, N))
+    w = jax.random.normal(k[2], (M, N))
+
+    def scalar(fn):
+        return lambda a, b: (fn(a, b) * w).sum()
+
+    def kern(a, b):
+        return grouped_matmul(a, b, gs, tm)
+
+    def twin(a, b):
+        return grouped_matmul_jnp(a, b, gs)
+
+    out = jax.jit(kern)(lhs, rhs)
+    np.testing.assert_allclose(out, twin(lhs, rhs), atol=1e-5)
+    assert float(jnp.abs(out[sum(sizes):]).max(initial=0.0)) == 0.0
+    got = jax.jit(jax.grad(scalar(kern), (0, 1)))(lhs, rhs)
+    want = jax.grad(scalar(twin), (0, 1))(lhs, rhs)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+def test_dropless_layer_through_the_kernels(pallas):
+    x, p = _layer(bias_std=0.01)
+
+    def f(x, p):
+        y = moe_ffn_dropless(x, p, 4, 2.5, row_tile=8)[0]
+        return (y * y).sum()
+
+    def g(x, p):
+        return (ref.moe_layer(x, p, top_k=4, scale=2.5) ** 2).sum()
+
+    (l1, g1), (l2, g2) = (jax.value_and_grad(fn, (0, 1))(x, p)
+                          for fn in (f, g))
+    assert abs(float(l1) - float(l2)) < 1e-4 * abs(float(l2))
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(
+            jnp.abs(b).max()) + 1e-7)
+
+
+# -- the normal train step --------------------------------------------------
+def test_trains_through_make_gpt_moe_train_step():
+    from byteps_tpu.common.metrics import get_registry
+    from byteps_tpu.models.train import make_gpt_moe_train_step
+    from byteps_tpu.parallel import MeshAxes, make_mesh
+
+    mesh = make_mesh(MeshAxes(dp=2), devices=jax.devices()[:2])
+    init = joyai_init(jax.random.PRNGKey(11), CFG)
+    bias0 = [np.asarray(b["moe"]["router_bias"]) for b in init["blocks"][1:]]
+    # weight decay on: a bias the optimizer saw would shrink
+    step, params, opt_state, bsh = make_gpt_moe_train_step(
+        CFG, mesh, optax.adamw(1e-2, weight_decay=0.1), remat=True,
+        init_params=init)
+    tok, tgt = _batch(CFG, B=4)
+    want = ref.loss(init, tok, tgt, mtp_weight=CFG.mtp_loss_weight,
+                    **_ref_kw(CFG))
+    tok, tgt = (jax.device_put(a, bsh) for a in (tok, tgt))
+    losses = []
+    for _ in range(4):
+        loss, params, opt_state = step(params, opt_state, tok, tgt)
+        losses.append(float(loss))
+    assert abs(losses[0] - float(want)) < 1e-5
+    assert losses[-1] < losses[0] - 0.5
+    for b, b0 in zip(params["blocks"][1:], bias0):
+        np.testing.assert_array_equal(np.asarray(b["moe"]["router_bias"]), b0)
+    # no optimizer state for a buffer: adam's moments have the leaves of
+    # the tree less its buffers
+    n_buf = sum(1 for path, _ in jax.tree_util.tree_leaves_with_path(params)
+                if getattr(path[-1], "key", None) in BUFFER_KEYS)
+    n_params = len(jax.tree.leaves(params))
+    mu = [s for s in jax.tree.leaves(
+        opt_state.inner, is_leaf=lambda s: hasattr(s, "mu"))
+        if hasattr(s, "mu")][0].mu
+    assert n_buf == 3 and len(jax.tree.leaves(mu)) == n_params - n_buf
+    # the stats of every step, one observation each, no earlier than a
+    # step later
+    step.flush_stats()
+    hist = get_registry().snapshot()["histograms"]
+    T = tok.size // 2                   # one device's tokens: a pmean
+    assert hist["moe.pairs_total"]["count"] == 4
+    assert hist["moe.pairs_total"]["sum"] == 4 * T * CFG.top_k * 3
+    terms = (hist["train.loss_main"]["sum"]
+             + CFG.mtp_loss_weight * hist["train.loss_mtp"]["sum"])
+    assert abs(terms / 4 - np.mean(losses)) < 1e-4
+
+
+def test_bias_update_is_the_references_rule_through_the_train_step():
+    """``router_bias_update_rate`` > 0: after a step every expert layer's
+    bias is the reference's ``bias_update`` of the bias before it, from the
+    loads of BOTH ranks' tokens; the picks of the second step then follow
+    the moved bias."""
+    from byteps_tpu.models.train import make_gpt_moe_train_step
+    from byteps_tpu.parallel import MeshAxes, make_mesh
+
+    rate = 0.05
+    cfg = dataclasses.replace(CFG, router_bias_update_rate=rate)
+    mesh = make_mesh(MeshAxes(dp=2), devices=jax.devices()[:2])
+    init = joyai_init(jax.random.PRNGKey(13), cfg)
+    step, params, opt_state, bsh = make_gpt_moe_train_step(
+        cfg, mesh, optax.sgd(0.0), remat=True, init_params=init)
+    tok, tgt = _batch(cfg, B=4)
+    picks = ref.forward(init, tok, tgt, all_picks=True, **_ref_kw(cfg))
+    layers = [b["moe"] for b in init["blocks"][1:]] + [
+        init["mtp"]["block"]["moe"]]
+    # before the step: it donates the tree it was given
+    before = [np.asarray(m["router_bias"]) for m in layers]
+    want = [np.asarray(ref.bias_update(m["router_bias"], idx, rate))
+            for m, idx in zip(layers, picks)]
+    _, params, opt_state = step(params, opt_state,
+                                *(jax.device_put(a, bsh) for a in (tok, tgt)))
+    got = [b["moe"]["router_bias"] for b in params["blocks"][1:]] + [
+        params["mtp"]["block"]["moe"]["router_bias"]]
+    moved = 0
+    for g, w, b0 in zip(got, want, before):
+        np.testing.assert_array_equal(np.asarray(g), w)
+        moved += int(np.sum(np.asarray(g) != b0))
+    assert moved > len(layers) * cfg.n_routed_experts // 2
+
+
+def test_bias_update_unloads_an_overloaded_expert():
+    # expert 5 starts in every token's picks; each balancing step lowers
+    # its bias by the rate until tokens leave it, and the idle experts rise
+    from byteps_tpu.parallel.moe import noaux_bias_step
+
+    x, p = _layer(T=40)
+    p = dict(p, router_bias=p["router_bias"].at[5].set(1.0))
+    loads = []
+    for _ in range(24):
+        _, _, load = moe_ffn_dropless(x, p, 4, 2.5, row_tile=8)
+        loads.append(float(load[5]))
+        p = dict(p, router_bias=noaux_bias_step(p["router_bias"], load, 0.05))
+    assert loads[0] == 40 and loads[-1] < 20
+    assert float(load.sum()) == 40 * 4           # still top-4 of every token
+    np.testing.assert_allclose(
+        moe_ffn_dropless(x, p, 4, 2.5, row_tile=8)[0],
+        ref.moe_layer(x, p, top_k=4, scale=2.5), atol=2e-5, rtol=2e-5)
+
+
+def test_auto_tune_is_refused_not_ignored(monkeypatch):
+    from byteps_tpu.common import config as bps_config
+    from byteps_tpu.models.train import make_gpt_moe_train_step
+    from byteps_tpu.parallel import MeshAxes, make_mesh
+
+    monkeypatch.setenv("BYTEPS_AUTO_TUNE", "1")
+    bps_config.reset_config()
+    try:
+        with pytest.raises(ValueError, match="BYTEPS_AUTO_TUNE"):
+            make_gpt_moe_train_step(
+                CFG, make_mesh(MeshAxes(dp=2), devices=jax.devices()[:2]),
+                optax.adamw(1e-2))
+    finally:
+        monkeypatch.delenv("BYTEPS_AUTO_TUNE")
+        bps_config.reset_config()
+
+
+def test_unknown_axes_are_refused():
+    params = joyai_init(jax.random.PRNGKey(3), CFG)
+    tok, tgt = _batch(CFG)
+    with pytest.raises(NotImplementedError, match="ep axis"):
+        joyai_loss(params, tok, tgt, CFG, ep_axis="ep")

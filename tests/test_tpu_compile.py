@@ -27,6 +27,7 @@ from byteps_tpu.ops import topk_kernels as tk
 # (the package re-exports functions named like these two modules)
 from byteps_tpu.ops.flash_attention import _flash_core, flash_attention
 from byteps_tpu.ops.flash_decode import flash_decode
+from byteps_tpu.ops.grouped_matmul import grouped_matmul
 from byteps_tpu.ops.paged_attention import paged_attention_decode
 
 BF16, F32, I8, U32, I32 = (jnp.bfloat16, jnp.float32, jnp.int8, jnp.uint32,
@@ -120,6 +121,19 @@ def _paged_decode(W):
                _sds((8,), I32)]
 
 
+def _gmm_fwd_bwd(lhs, w_up, w_down, sizes):
+    # one expert stack up and one down, as parallel/moe.py chains them
+    def loss(lhs, w_up, w_down):
+        h = grouped_matmul(lhs, w_up, sizes)
+        return grouped_matmul(h, w_down, sizes).astype(F32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(lhs, w_up, w_down)
+
+
+# the JoyAI-LLM-Flash cell's routed experts: 16 held, 2048 -> 768 -> 2048,
+# the worst-case row buffer of 4 x 4096 tokens x top-8 (+ a tile a group)
+_GMM = [_sds((135168, 2048), BF16), _sds((16, 2048, 768), BF16),
+        _sds((16, 768, 2048), BF16), _sds((16,), I32)]
+
 # (id, function, argument shapes, Pallas calls expected in the program)
 ONE_CHIP = [
     ("flash_fwd_gpt2m", _flash_fwd(16, 16), _qkv(128, 1024, 1024, 64), 1),
@@ -127,6 +141,15 @@ ONE_CHIP = [
      _qkv(128, 1024, 1024, 64), 3),
     ("flash_fwd_bwd_gqa32_4_d128_s2048", _flash_fwd_bwd(32, 4),
      _qkv(32, 2048, 2048, 128, kv_bh=4), 3),
+    # latent attention with k and v materialised: q/k 192 wide, v 128
+    # (JoyAI-LLM-Flash: 4 sequences x 32 heads, S 4096)
+    ("flash_fwd_bwd_mla_qk192_v128_s4096", _flash_fwd_bwd(32, 32),
+     [_sds((128, 4096, 192), BF16)] * 2 + [_sds((128, 4096, 128), BF16)], 3),
+    ("moe_gmm_fwd_joyai", lambda lhs, w, _, sizes:
+     grouped_matmul(lhs, w, sizes), _GMM, 1),
+    # the first product forward (the second's output is not needed for
+    # its gradient), then dx and dw of each
+    ("moe_gmm_fwd_bwd_joyai", _gmm_fwd_bwd, _GMM, 5),
     # the paged prefill chunk: 32 new tokens against the widest and the
     # narrowest gathered view
     ("flash_fwd_prefill_chunk_wide", _flash_fwd(16, 16),
