@@ -239,6 +239,18 @@ def _block_step(x, p, cache_k, cache_v, pos0, cfg, tp_axis, ep_axis,
     return x, cache_k, cache_v
 
 
+def _embed(params, tokens, positions, cfg: GPTConfig):
+    """The cached paths' input embedding: ``tokens`` (B, T) at global
+    ``positions`` ((T,), or (B, 1) where every row decodes at its own) →
+    (B, T, d) in ``cfg.dtype``. One copy for ``gpt_apply_cached`` and the
+    serve tier's paged steps, which must stay bit-identical to it."""
+    resolve_rope(cfg)   # validate the position scheme decode-side too
+    if cfg.pos_embedding == "rope":
+        return params["wte"][tokens].astype(cfg.dtype)
+    return (params["wte"][tokens]
+            + jnp.take(params["wpe"], positions, axis=0)).astype(cfg.dtype)
+
+
 def gpt_apply_cached(params, tokens: jnp.ndarray, cache: KVCache,
                      cfg: GPTConfig, tp_axis: Optional[str] = None,
                      ep_axis: Optional[str] = None,
@@ -258,16 +270,10 @@ def gpt_apply_cached(params, tokens: jnp.ndarray, cache: KVCache,
     only need the cache side, and at real vocab sizes the readout is
     the single largest weight stream in the step.
     """
-    resolve_rope(cfg)   # validate the position scheme decode-side too
     norm_fn, norm_eps = resolve_norm(cfg)
     B, T = tokens.shape
     pos0 = cache.length
-    if cfg.pos_embedding == "rope":
-        x = params["wte"][tokens].astype(cfg.dtype)
-    else:
-        pos = pos0 + jnp.arange(T)
-        x = (params["wte"][tokens]
-             + jnp.take(params["wpe"], pos, axis=0)).astype(cfg.dtype)
+    x = _embed(params, tokens, pos0 + jnp.arange(T), cfg)
 
     quant = cache.k_scale is not None
     new_k, new_v, new_ks, new_vs = [], [], [], []

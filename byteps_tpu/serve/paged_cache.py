@@ -55,9 +55,12 @@ Three layers:
   twin: gather zero-masked per-request views, ``attention_lse``
   (:func:`decode_uses_paged_attn` decides, from backend and shapes).
 * :func:`make_paged_prefill_fn` — chunked prefill/verify for one
-  request: gather its blocks into a dense :class:`KVCache` view, run
-  the stock ``gpt_apply_cached`` (bit-identical to the single-request
-  prefill by construction), scatter the newly written rows back.
+  request, layer by layer: gather THIS request's blocks of one layer
+  into a dense zero-masked view, run the stock ``_block_step`` of
+  ``models/generate.py`` on it (what ``gpt_apply_cached`` runs, so the
+  chunk is bit-identical to the single-request prefill by
+  construction), scatter the chunk's newly written rows of that layer
+  back in place. No other block of the pool is read, moved or written.
 """
 
 from __future__ import annotations
@@ -72,9 +75,10 @@ import numpy as np
 
 from byteps_tpu.common.metrics import get_registry
 from byteps_tpu.models.generate import (
-    KVCache,
+    _QuantSlot,
+    _block_step,
+    _embed,
     _quantize_block,
-    gpt_apply_cached,
 )
 from byteps_tpu.models.gpt import (
     GPTConfig,
@@ -925,13 +929,7 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     # measured ~45 ms/step of pure memcpy at serving sizes on CPU
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, pool, toks, pos, tables, slabs=None, slots=None):
-        tok2 = toks[:, None]                                  # (R, 1)
-        if cfg.pos_embedding == "rope":
-            x = params["wte"][tok2].astype(cfg.dtype)
-        else:
-            x = (params["wte"][tok2]
-                 + jnp.take(params["wpe"], pos[:, None],
-                            axis=0)).astype(cfg.dtype)
+        x = _embed(params, toks[:, None], pos[:, None], cfg)  # (R, 1, d)
         blk = jnp.take_along_axis(
             tables, (pos // block_size)[:, None], axis=1)[:, 0]
         off = pos % block_size
@@ -951,76 +949,87 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     """Build the jitted per-request prefill/verify chunk.
 
     ``chunk(params, pool, tokens (1, C), pos0, table (W,)) ->
-    (logits (1, C, vocab) f32, new pool)``: gather the request's blocks
-    into a dense :class:`KVCache` view (zero past ``pos0``, int8 +
-    scales in quant mode), run the STOCK ``gpt_apply_cached`` — the
-    same computation a solo ``make_generate_fn`` prefill performs — and
-    scatter the C newly written cache rows back into the pool. The
-    dense view's length is ``table.shape[0] * block_size`` (callers
-    bucket W). Like the decode step, the table may alias shared prefix
-    pages below ``pos0`` — read via the gather only; the C written rows
-    land at/after ``pos0`` in blocks the host made private first. Also the speculative verify forward: C proposed tokens
-    in, per-position logits out, and only the committed prefix of the
-    written rows is ever counted live (the fill level rewinds exactly
-    like ``speculative.py``'s cache contract). ``with_readout=False``
-    skips the vocab projection (an intermediate prefill chunk's logits
-    are never read — at real vocab sizes that projection is the
-    biggest weight stream in the chunk) and returns ``(None, pool)``.
-    lru-cached like :func:`make_paged_decode_fn`."""
+    (logits (1, C, vocab) f32, new pool)``: for each layer, gather the
+    request's blocks of that layer into a dense ``(1, W * block_size,
+    h, D)`` view (zero at and past ``pos0``; int8 + scales as a
+    ``_QuantSlot`` in quant mode; callers bucket W), run the STOCK
+    ``_block_step`` on it — the layer ``gpt_apply_cached`` and so a
+    solo ``make_generate_fn`` prefill runs, between the same embedding
+    and readout — and scatter the C rows it wrote at ``pos0`` into the
+    pool with ``.at[li, blk, off].set``, the decode step's form. The
+    pool is touched per layer and per block, never as a whole: a gather
+    or scatter over all layers at once makes XLA convert the entire
+    pool to another layout and back around it, six pool-sized passes a
+    chunk (PERF.md §6, PR 31; tests/test_tpu_compile.py holds the
+    compiled chunk to none). Like the decode step, the table may alias
+    shared prefix pages below ``pos0`` — read via the gather only; the
+    C written rows land at/after ``pos0`` in blocks the host made
+    private first. Also the speculative verify forward: C proposed
+    tokens in, per-position logits out, and only the committed prefix
+    of the written rows is ever counted live (the fill level rewinds
+    exactly like ``speculative.py``'s cache contract).
+    ``with_readout=False`` skips the vocab projection (an intermediate
+    prefill chunk's logits are never read — at real vocab sizes that
+    projection is the biggest weight stream in the chunk) and returns
+    ``(None, pool)``. lru-cached like :func:`make_paged_decode_fn`."""
     C = chunk_len
-    L = cfg.n_layers
+    norm_fn, norm_eps = resolve_norm(cfg)
+
+    def _view(pool_a, li, table, keep, *tail):
+        # this request's (1, W * bs, h[, D]) view of one layer, zero past
+        # the fill level. ONE indexing expression: pool_a[li][table]
+        # would materialise the whole layer slice before gathering
+        g = pool_a[li, table].reshape((1, keep.shape[0], -1) + tail)
+        return jnp.where(keep.reshape((1, -1) + (1,) * (g.ndim - 2)), g,
+                         jnp.zeros((), g.dtype))
+
+    def _put(pool_a, at, cache_a, pos0):
+        # the C rows _block_step wrote at pos0, onto the pool's flat minor
+        # axis and into their blocks: in place, as the decode step's is
+        rows = jax.lax.dynamic_slice_in_dim(cache_a, pos0, C, axis=1)
+        return pool_a.at[at].set(rows[0].reshape(C, -1))
+
+    # jitted, the layer index DATA: layers of one shape share one trace.
+    # A replica traces and lowers a chunk program for every tail chunk x
+    # table width x readout before it serves, compile cache or not, and
+    # 36 traces of the block are most of a second of host time in each
+    @jax.jit
+    def _layer(x, p, pool, li, pos0, table, keep, blk, off):
+        quant = pool.k_scale is not None
+        with jax.named_scope("paged/gather_kv"):
+            ck = _view(pool.k, li, table, keep, cfg.head_dim)
+            cv = _view(pool.v, li, table, keep, cfg.head_dim)
+            if quant:
+                ck = _QuantSlot(ck, _view(pool.k_scale, li, table, keep))
+                cv = _QuantSlot(cv, _view(pool.v_scale, li, table, keep))
+        x, ck, cv = _block_step(x, p, ck, cv, pos0, cfg, tp_axis, None,
+                                norm_fn=norm_fn, norm_eps=norm_eps)
+        with jax.named_scope("paged/scatter_kv"):
+            at = (li, blk, off)
+            if quant:
+                pool = PoolState(
+                    k=_put(pool.k, at, ck.q, pos0),
+                    v=_put(pool.v, at, cv.q, pos0),
+                    k_scale=_put(pool.k_scale, at, ck.scale, pos0),
+                    v_scale=_put(pool.v_scale, at, cv.scale, pos0))
+            else:
+                pool = PoolState(k=_put(pool.k, at, ck, pos0),
+                                 v=_put(pool.v, at, cv, pos0))
+        return x, pool
 
     # pool donated for the same reason as the decode step
     @functools.partial(jax.jit, donate_argnums=(1,))
     def chunk(params, pool, tokens, pos0, table):
-        quant = pool.k_scale is not None
-        S = table.shape[0] * block_size
-        with jax.named_scope("paged/gather_kv"):
-            keep = (jnp.arange(S) < pos0)
-            gk = pool.k[:, table].reshape(L, 1, S, -1, cfg.head_dim)
-            gv = pool.v[:, table].reshape(L, 1, S, -1, cfg.head_dim)
-            gk = jnp.where(keep[None, None, :, None, None], gk,
-                           jnp.zeros((), gk.dtype))
-            gv = jnp.where(keep[None, None, :, None, None], gv,
-                           jnp.zeros((), gv.dtype))
-            if quant:
-                gks = pool.k_scale[:, table].reshape(L, 1, S, -1)
-                gvs = pool.v_scale[:, table].reshape(L, 1, S, -1)
-                gks = jnp.where(keep[None, None, :, None], gks, 0.0)
-                gvs = jnp.where(keep[None, None, :, None], gvs, 0.0)
-        cache = KVCache(k=gk, v=gv, length=pos0,
-                        k_scale=gks if quant else None,
-                        v_scale=gvs if quant else None)
-        logits, cache = gpt_apply_cached(params, tokens, cache, cfg,
-                                         tp_axis, readout=with_readout)
-        # scatter the C newly written rows back into the pool
-        with jax.named_scope("paged/scatter_kv"):
-            positions = pos0 + jnp.arange(C)
-            blk = jnp.take(table, positions // block_size)
-            off = positions % block_size
-            h = cache.k.shape[-2]
-            newk = jax.lax.dynamic_slice(
-                cache.k, (0, 0, pos0, 0, 0),
-                (L, 1, C, h, cfg.head_dim)).reshape(L, C, -1)
-            newv = jax.lax.dynamic_slice(
-                cache.v, (0, 0, pos0, 0, 0),
-                (L, 1, C, h, cfg.head_dim)).reshape(L, C, -1)
-            if quant:
-                newks = jax.lax.dynamic_slice(
-                    cache.k_scale, (0, 0, pos0, 0), (L, 1, C, h))[:, 0]
-                newvs = jax.lax.dynamic_slice(
-                    cache.v_scale, (0, 0, pos0, 0), (L, 1, C, h))[:, 0]
-                pool = PoolState(
-                    k=pool.k.at[:, blk, off].set(newk),
-                    v=pool.v.at[:, blk, off].set(newv),
-                    k_scale=pool.k_scale.at[:, blk, off].set(newks),
-                    v_scale=pool.v_scale.at[:, blk, off].set(newvs),
-                )
-            else:
-                pool = PoolState(
-                    k=pool.k.at[:, blk, off].set(newk),
-                    v=pool.v.at[:, blk, off].set(newv),
-                )
+        positions = pos0 + jnp.arange(C)
+        blk = jnp.take(table, positions // block_size)
+        off = positions % block_size
+        keep = jnp.arange(table.shape[0] * block_size) < pos0
+        x = _embed(params, tokens, positions, cfg)
+        for li, p in enumerate(params["blocks"]):
+            x, pool = _layer(x, p, pool, jnp.int32(li), pos0, table, keep,
+                             blk, off)
+        logits = (_readout(params, x, norm_fn, norm_eps) if with_readout
+                  else None)
         return logits, pool
 
     return chunk

@@ -17,9 +17,18 @@ import jax.numpy as jnp
 from byteps_tpu.common.faults import FaultPlan, parse_fault_spec
 from byteps_tpu.common.metrics import get_registry
 from byteps_tpu.models import GPTConfig, gpt_init
-from byteps_tpu.models.generate import make_generate_fn
+from byteps_tpu.models.generate import (
+    gpt_apply_cached,
+    init_cache,
+    make_generate_fn,
+)
 from byteps_tpu.serve import Request, Router, Scheduler, SpecPolicy
-from byteps_tpu.serve.paged_cache import PagedKVCache, PoolExhausted
+from byteps_tpu.serve.paged_cache import (
+    PagedKVCache,
+    PoolExhausted,
+    PoolState,
+    make_paged_prefill_fn,
+)
 
 CFG = GPTConfig.tiny()
 
@@ -114,6 +123,75 @@ def test_submit_validation(params):
                              prompt=np.arange(4, dtype=np.int32),
                              max_new=4, temperature=1.0,
                              spec=SpecPolicy("lookup")))
+
+
+# ---- the prefill chunk against the dense cache it stands for ----------------
+def _bits(a):
+    """An array's bytes, so NaN poison compares equal to itself."""
+    a = np.asarray(a)
+    return a.view(f"u{a.dtype.itemsize}")
+
+
+# jitted like the chunk: op by op, XLA's CPU backend rounds the int8 scales'
+# division another way in the last bit
+_dense_step = jax.jit(gpt_apply_cached, static_argnames=("cfg", "readout"))
+
+
+@pytest.mark.parametrize("with_readout", [True, False],
+                         ids=["readout", "no_readout"])
+@pytest.mark.parametrize("pos0", [8, 6], ids=["on_block", "off_block"])
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_prefill_chunk_is_the_dense_step_and_touches_only_its_rows(
+        params, quant, pos0, with_readout):
+    """One chunk of C tokens at ``pos0`` through the paged pool against
+    ``gpt_apply_cached`` on the dense cache the table stands for: logits
+    and the C written rows bit-equal, and every other slot of a poisoned
+    pool — the blocks shared below ``pos0``, the request's own rows past
+    the chunk, strangers' blocks, scratch — bit-unchanged."""
+    bs, C, NB = 4, 4, 12
+    table = np.array([5, 2, 9, 7], np.int32)
+    S = table.size * bs
+    L, hD = CFG.n_layers, CFG.kv_heads * CFG.head_dim
+    toks = np.random.default_rng(3).integers(
+        0, CFG.vocab_size, pos0 + C).astype(np.int32)
+    _, dense = _dense_step(params, jnp.asarray(toks[None, :pos0]),
+                          init_cache(CFG, 1, max_seq=S, quant=quant),
+                          cfg=CFG, readout=False)
+
+    # the pool: poison everywhere, the prefix's rows where the table says
+    live = np.arange(pos0)
+    at = (slice(None), table[live // bs], live % bs)
+    fields = {}
+    for name in ("k", "v") + (("k_scale", "v_scale") if quant else ()):
+        src = np.asarray(getattr(dense, name))[:, 0]      # (L, S, h[, D])
+        minor = CFG.kv_heads if name.endswith("scale") else hD
+        a = np.full((L, NB, bs, minor),
+                    77 if src.dtype == np.int8 else np.nan, src.dtype)
+        a[at] = src[:, :pos0].reshape(L, pos0, minor)
+        fields[name] = a
+    chunk = make_paged_prefill_fn(CFG, bs, C, None, with_readout)
+    logits, pool = chunk(
+        params, PoolState(**{n: jnp.asarray(a) for n, a in fields.items()}),
+        jnp.asarray(toks[None, pos0:]), jnp.int32(pos0), jnp.asarray(table))
+
+    want_logits, want = _dense_step(
+        params, jnp.asarray(toks[None, pos0:]), dense, cfg=CFG,
+        readout=with_readout)
+    if with_readout:
+        assert np.array_equal(_bits(logits), _bits(want_logits))
+    else:
+        assert logits is None and want_logits is None
+    new = pos0 + np.arange(C)
+    written = np.zeros((NB, bs), bool)
+    written[table[new // bs], new % bs] = True
+    for name, before in fields.items():
+        after = np.asarray(getattr(pool, name))
+        rows = np.asarray(getattr(want, name))[:, 0, pos0:pos0 + C]
+        assert np.array_equal(
+            _bits(after[:, table[new // bs], new % bs]),
+            _bits(rows.reshape(L, C, -1))), name
+        assert np.array_equal(_bits(after[:, ~written]),
+                              _bits(before[:, ~written])), name
 
 
 # ---- the CI acceptance smoke: continuous admission, bit-exact, no leaks -----
