@@ -8,9 +8,11 @@ D)`` updated in place with ``dynamic_update_slice`` inside a
 generation, no per-token retrace, MXU-friendly (the decode matmuls are
 (B·H, 1, D) × (D, S) batched GEMVs that XLA tiles together).
 
-Weights are exactly the training params (`gpt.py`) — layernorms, Megatron
-col/row-parallel projections (tp composes: q/k/v/cache shard over heads,
-``row_parallel_matmul`` psums the output), weight-tied fp32 readout.
+Weights are exactly the training params (`gpt.py`) and so is the block:
+``_block_step`` runs ``gpt.py``'s ``attn_half`` / ``ffn_half`` — norms,
+Megatron col/row-parallel projections (tp composes: q/k/v/cache shard over
+heads, the output projection psums), LoRA deltas, RoPE — and owns only
+:func:`cache_attend`, where the new keys go and what attends over them.
 Causality is positional masking against the cache fill level, so prefill
 and decode share one cached-attention implementation whose numerics are
 pinned to ``gpt_forward`` in ``tests/test_generate.py``.
@@ -26,16 +28,13 @@ import jax.numpy as jnp
 
 from byteps_tpu.models.gpt import (
     GPTConfig,
-    _bias,
     _layernorm,
-    _mlp,
     _readout,
+    attn_half,
+    ffn_half,
     resolve_norm,
     resolve_rope,
-    rope_rotate,
 )
-from byteps_tpu.parallel.tp import col_parallel_matmul, row_parallel_matmul
-
 
 
 class KVCache(NamedTuple):
@@ -105,7 +104,7 @@ def _quantize_block(x):
 def _cache_write(cache, new, pos0):
     """Append ``new`` (B, T, h, D) at position pos0. ``cache`` is either
     a dense (B, S, h, D) array or a :class:`_QuantSlot` — the quantized
-    form flows through _block_step/_attn_cached_half polymorphically so
+    form flows through _block_step/cache_attend polymorphically so
     the T5/MoE users of the same code path stay untouched."""
     if isinstance(cache, _QuantSlot):
         q, s = _quantize_block(new)
@@ -143,99 +142,70 @@ def _cached_attention(q, k_cache, v_cache, q_pos0):
     return o
 
 
-def _attn_cached_half(x, p, cache_k, cache_v, pos0, head_dim, tp_axis,
-                      rope_base: float = 0.0, norm_fn=_layernorm,
-                      norm_eps: float = 1e-5, use_bias: bool = True):
-    """The attention residual branch over T new tokens with cache append.
-
-    x: (B, T, d); cache_k/v: (B, S_max, h_loc, D) this layer's cache.
-    Returns (x_out, new_cache_k, new_cache_v). With ``rope_base > 0``
-    the new q/k rotate by their global positions before the cache write,
-    so cached keys are stored post-rotation (the standard decode
-    convention). Config-agnostic on purpose: the GPT/MoE block step AND
-    the T5 decoder (models/t5.py t5_decode_cached) share this one
-    cache-append path.
-    """
-    from byteps_tpu.models.lora import lora_delta
-
-    B, T = x.shape[:2]
-    h = norm_fn(x, p["ln1_g"], p.get("ln1_b"), norm_eps)
-    q = col_parallel_matmul(h, p["wq"].astype(x.dtype), _bias(p, "bq", x, use_bias))
-    k = col_parallel_matmul(h, p["wk"].astype(x.dtype), _bias(p, "bk", x, use_bias))
-    v = col_parallel_matmul(h, p["wv"].astype(x.dtype), _bias(p, "bv", x, use_bias))
-    if "lora" in p:
-        # keep grafted (unmerged) trees decode-exact with gpt_forward —
-        # without this the cached path silently ran the frozen base
-        q = q + lora_delta(h, p, "wq")
-        k = k + lora_delta(h, p, "wk")
-        v = v + lora_delta(h, p, "wv")
-    h_loc = q.shape[-1] // head_dim
-    kv_loc = k.shape[-1] // head_dim    # GQA: the cache stores kv heads only
-    q = q.reshape(B, T, h_loc, head_dim)
-    k = k.reshape(B, T, kv_loc, head_dim)
-    v = v.reshape(B, T, kv_loc, head_dim)
-    if rope_base > 0.0:
-        pos = pos0 + jnp.arange(T)
-        q = rope_rotate(q, pos, rope_base)
-        k = rope_rotate(k, pos, rope_base)
-    cache_k = _cache_write(cache_k, k, pos0)
-    cache_v = _cache_write(cache_v, v, pos0)
-    # GQA is native on every path — prefill and decode read the narrow
-    # cache directly, no repeat anywhere. The T=1 decode step takes the
-    # flash-decode kernel when available: one explicit VMEM online-
-    # softmax pass over the stored cache (int8 read directly, dequant
-    # per block in VMEM with _cache_read's rounding), dead blocks
-    # skipped past the fill level.
+def cache_attend(cache_k, cache_v, pos0):
+    """The static cache's ``attend`` for :func:`attn_half`: append the T
+    new keys and values (already rotated: cached keys are stored
+    post-RoPE, the standard decode convention) to this layer's cache at
+    ``pos0``, then attend over the cache. cache_k/v: (B, S_max, h_kv, D)
+    arrays or :class:`_QuantSlot`s; the carry is the updated pair.
+    Config-agnostic on purpose: the GPT/MoE block step, the serve tier's
+    prefill chunk AND the T5 decoder (models/t5.py t5_decode_cached) share
+    this one cache-append path."""
     from byteps_tpu.ops.backend import note_fallback
     from byteps_tpu.ops.flash_decode import (
         decode_supported, flash_decode, use_pallas)
 
-    S_max = (cache_k.q if isinstance(cache_k, _QuantSlot)
-             else cache_k).shape[1]
-    flash = T == 1 and use_pallas()
-    if flash and not decode_supported(S_max, head_dim):
-        note_fallback("flash_decode", (S_max, head_dim),
-                      "cache length must tile into 8..256 key blocks and "
-                      "head_dim be <= 256")
-        flash = False
-    if flash:
-        if isinstance(cache_k, _QuantSlot):
-            o = flash_decode(q, cache_k.q, cache_v.q, pos0,
-                             k_scale=cache_k.scale, v_scale=cache_v.scale)
+    def attend(q, k, v):
+        T, head_dim = q.shape[1], q.shape[-1]
+        ck = _cache_write(cache_k, k, pos0)
+        cv = _cache_write(cache_v, v, pos0)
+        # GQA is native on every path — prefill and decode read the narrow
+        # cache directly, no repeat anywhere. The T=1 decode step takes the
+        # flash-decode kernel when available: one explicit VMEM online-
+        # softmax pass over the stored cache (int8 read directly, dequant
+        # per block in VMEM with _cache_read's rounding), dead blocks
+        # skipped past the fill level.
+        S_max = (ck.q if isinstance(ck, _QuantSlot) else ck).shape[1]
+        flash = T == 1 and use_pallas()
+        if flash and not decode_supported(S_max, head_dim):
+            note_fallback("flash_decode", (S_max, head_dim),
+                          "cache length must tile into 8..256 key blocks "
+                          "and head_dim be <= 256")
+            flash = False
+        if not flash:
+            o = _cached_attention(q, _cache_read(ck, q.dtype),
+                                  _cache_read(cv, q.dtype), pos0)
+        elif isinstance(ck, _QuantSlot):
+            o = flash_decode(q, ck.q, cv.q, pos0,
+                             k_scale=ck.scale, v_scale=cv.scale)
         else:
-            o = flash_decode(q, cache_k, cache_v, pos0)
-    else:
-        o = _cached_attention(q, _cache_read(cache_k, x.dtype),
-                              _cache_read(cache_v, x.dtype), pos0)
-    o = o.reshape(B, T, h_loc * head_dim)
-    attn_out = row_parallel_matmul(o, p["wo"].astype(x.dtype), tp_axis,
-                                   _bias(p, "bo", x, use_bias))
-    if "lora" in p:
-        attn_out = attn_out + lora_delta(o, p, "wo", tp_axis)
-    return x + attn_out, cache_k, cache_v
+            o = flash_decode(q, ck, cv, pos0)
+        return o, (ck, cv)
+
+    return attend
 
 
 def _block_step(x, p, cache_k, cache_v, pos0, cfg, tp_axis, ep_axis,
                 norm_fn=_layernorm, norm_eps: float = 1e-5):
     """One transformer block (dense-MLP or MoE, by param structure) over
-    T new tokens with cache append."""
-    x, cache_k, cache_v = _attn_cached_half(
-        x, p, cache_k, cache_v, pos0, cfg.head_dim, tp_axis,
-        rope_base=(cfg.rope_base if cfg.pos_embedding == "rope" else 0.0),
-        norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
-    h = norm_fn(x, p["ln2_g"], p.get("ln2_b"), norm_eps)
+    T new tokens with cache append: the shared halves of ``models/gpt.py``
+    around :func:`cache_attend`."""
+    kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
+    x, (cache_k, cache_v) = attn_half(
+        x, p, cfg.head_dim, lambda: pos0 + jnp.arange(x.shape[1]),
+        cache_attend(cache_k, cache_v, pos0), tp_axis, resolve_rope(cfg),
+        **kw)
+    ffn = None
     if "moe" in p:
         from byteps_tpu.parallel.moe import moe_ffn
 
         # inference uses no-drop capacity: the training capacity_factor
         # is a throughput/static-shape lever, and a dropped token at
         # decode time silently corrupts the sample
-        m, _aux = moe_ffn(
-            h, p["moe"], ep_axis=ep_axis, router_topk=cfg.router_topk,
-            tp_axis=tp_axis, no_drop=True)
-        x = x + m
-    else:
-        x = x + _mlp(h, p, tp_axis, use_bias=cfg.use_bias)
+        ffn = functools.partial(
+            moe_ffn, params=p["moe"], ep_axis=ep_axis,
+            router_topk=cfg.router_topk, tp_axis=tp_axis, no_drop=True)
+    x, _ = ffn_half(x, p, tp_axis, ffn, **kw)
     return x, cache_k, cache_v
 
 

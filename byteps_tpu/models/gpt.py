@@ -253,101 +253,134 @@ def resolve_norm(cfg: GPTConfig):
     return _NORMS[cfg.norm], cfg.norm_eps
 
 
-def _bias(p, name, x, use_bias: bool):
-    """The projection bias to apply — None under use_bias=False (the
-    leaf stays in the tree, inert, zero-gradient)."""
-    return p[name].astype(x.dtype) if use_bias else None
+def _project(x, p, names, use_bias: bool, tp_axis=None, delta=None):
+    """``x`` through the block's frozen projections ``names``: the one place
+    a dense-family block weight is read. wq/wk/wv and w1/w3 are
+    column-parallel (the outputs stay tp-sharded), wo and w2 row-parallel
+    (psum over ``tp_axis``, the bias after it); each weight is cast to the
+    activation dtype and the bias is absent under ``use_bias=False``. Beside
+    each output go its LoRA deltas, at the same points for every caller: a
+    grafted tree's first (``"lora" in p``), then the caller's
+    ``delta(name, x)`` (the serve tier's per-row adapter slabs; None for a
+    name it does not target). Returns one output per name."""
+    from byteps_tpu.models.lora import _ROW_TARGETS, lora_delta
 
+    def matmul(name):
+        w = p[name].astype(x.dtype)
+        # wq's bias is bq, w1's b1, ...
+        b = p["b" + name[1:]].astype(x.dtype) if use_bias else None
+        if name in _ROW_TARGETS:
+            return row_parallel_matmul(x, w, tp_axis, b)
+        return col_parallel_matmul(x, w, b)
 
-def _attention(x, p, head_dim: int, tp_axis, sp_axis, causal: bool = True,
-               seq_layout: str = "contiguous", rope_base: float = 0.0,
-               use_bias: bool = True):
-    from byteps_tpu.models.lora import lora_delta
-
-    B, S = x.shape[:2]
-    q = col_parallel_matmul(x, p["wq"].astype(x.dtype), _bias(p, "bq", x, use_bias))
-    k = col_parallel_matmul(x, p["wk"].astype(x.dtype), _bias(p, "bk", x, use_bias))
-    v = col_parallel_matmul(x, p["wv"].astype(x.dtype), _bias(p, "bv", x, use_bias))
+    ys = [matmul(name) for name in names]
     if "lora" in p:
-        q = q + lora_delta(x, p, "wq")
-        k = k + lora_delta(x, p, "wk")
-        v = v + lora_delta(x, p, "wv")
-    h_loc = q.shape[-1] // head_dim     # query heads this tp shard owns
-    kv_loc = k.shape[-1] // head_dim    # kv heads (GQA: fewer)
-    if kv_loc == 0 or h_loc % kv_loc != 0:
-        raise ValueError(
-            f"per-shard head split is invalid: {h_loc} query heads vs "
-            f"{kv_loc} kv heads — with GQA under tensor parallelism, "
-            "n_kv_heads must be divisible by the tp axis size")
-    q = q.reshape(B, S, h_loc, head_dim)
-    k = k.reshape(B, S, kv_loc, head_dim)
-    v = v.reshape(B, S, kv_loc, head_dim)
-    if rope_base > 0.0:
-        pos = _positions(S, sp_axis, seq_layout)
-        q = rope_rotate(q, pos, rope_base)
-        k = rope_rotate(k, pos, rope_base)
-    # GQA: k/v stay NARROW (kv_loc heads) — the flash kernels associate
-    # query heads to kv heads by grid-index arithmetic, the jnp lse path
-    # by grouped einsum, and the rings rotate the narrow blocks (G× less
-    # ICI wire); only the legacy jnp contiguous-ring repeats internally
-    if seq_layout == "zigzag":
-        o = zigzag_ring_attention(q, k, v, sp_axis, causal=causal)
-    elif seq_layout == "contiguous":
-        o = ring_attention(q, k, v, sp_axis, causal=causal)
-    else:
-        raise ValueError(f"unknown seq_layout {seq_layout!r} — expected "
-                         "'contiguous' or 'zigzag'")
-    o = o.reshape(B, S, h_loc * head_dim)
-    out = row_parallel_matmul(o, p["wo"].astype(x.dtype), tp_axis,
-                              _bias(p, "bo", x, use_bias))
-    if "lora" in p:
-        out = out + lora_delta(o, p, "wo", tp_axis)
-    return out
+        ys = [y + lora_delta(x, p, name, tp_axis)
+              for y, name in zip(ys, names)]
+    if delta is not None:
+        for i, name in enumerate(names):
+            d = delta(name, x)
+            if d is not None:
+                ys[i] = ys[i] + d
+    return ys
 
 
-def _mlp(x, p, tp_axis, use_bias: bool = True):
-    from byteps_tpu.models.lora import lora_delta
-
-    h = col_parallel_matmul(x, p["w1"].astype(x.dtype),
-                            _bias(p, "b1", x, use_bias))
-    if "lora" in p:
-        h = h + lora_delta(x, p, "w1")
+def _mlp(x, p, tp_axis, use_bias: bool = True, delta=None):
+    (h,) = _project(x, p, ("w1",), use_bias, tp_axis, delta)
     if "w3" in p:
         # SwiGLU: silu-gated hidden (w1 value path ∘ w3 gate path); w1/w3
         # col-parallel over tp, w2 row-parallel as in the gelu MLP
-        g = col_parallel_matmul(x, p["w3"].astype(x.dtype),
-                                _bias(p, "b3", x, use_bias))
-        if "lora" in p:
-            g = g + lora_delta(x, p, "w3")
+        (g,) = _project(x, p, ("w3",), use_bias, tp_axis, delta)
         h = jax.nn.silu(h) * g
     else:
         h = jax.nn.gelu(h)
-    out = row_parallel_matmul(h, p["w2"].astype(x.dtype), tp_axis,
-                              _bias(p, "b2", x, use_bias))
-    if "lora" in p:
-        out = out + lora_delta(h, p, "w2", tp_axis)
-    return out
+    return _project(h, p, ("w2",), use_bias, tp_axis, delta)[0]
+
+
+def ring_attend(sp_axis, causal: bool = True,
+                seq_layout: str = "contiguous"):
+    """Training's ``attend``: ring attention over ``sp_axis`` in the given
+    sequence layout (plain flash / jnp attention when ``sp_axis`` is None);
+    no state to thread."""
+    if seq_layout not in ("contiguous", "zigzag"):
+        raise ValueError(f"unknown seq_layout {seq_layout!r} — expected "
+                         "'contiguous' or 'zigzag'")
+    ring = (zigzag_ring_attention if seq_layout == "zigzag"
+            else ring_attention)
+    # GQA: k/v stay NARROW (kv heads) — the flash kernels associate
+    # query heads to kv heads by grid-index arithmetic, the jnp lse path
+    # by grouped einsum, and the rings rotate the narrow blocks (G× less
+    # ICI wire); only the legacy jnp contiguous-ring repeats internally
+    return lambda q, k, v: (ring(q, k, v, sp_axis, causal=causal), None)
+
+
+def attn_half(x, p, head_dim: int, positions, attend, tp_axis=None,
+              rope_base: float = 0.0, norm_fn=_layernorm,
+              norm_eps: float = 1e-5, use_bias: bool = True, delta=None):
+    """First half of THE pre-norm block, ``x + attention(norm(x))``, for
+    every caller: training, the static cache, T5's decoder and the paged
+    serve step differ only in ``attend(q, k, v) -> (o, carry)``, which gets
+    q ``(B, T, h, D)`` and k/v ``(B, T, h_kv, D)`` after RoPE and owns
+    where the new keys go and what attends over them; ``carry`` is whatever
+    state it threads (None, a layer's cache pair, the KV pool).
+    ``positions()`` gives what RoPE rotates by (``(T,)``, or ``(B, T)``
+    where every row has its own); it is called under RoPE only, so a
+    learned-position program traces no position arithmetic.
+    ``delta`` as in :func:`_project`. Returns ``(x, carry)``."""
+    B, T = x.shape[:2]
+    # named scopes are for an operator's xprof op profile; they change no
+    # compiled program (docs/observability.md §spans)
+    with jax.named_scope("block/attn"):
+        h = norm_fn(x, p["ln1_g"], p.get("ln1_b"), norm_eps)
+        q, k, v = _project(h, p, ("wq", "wk", "wv"), use_bias, tp_axis, delta)
+        h_loc = q.shape[-1] // head_dim     # query heads this tp shard owns
+        kv_loc = k.shape[-1] // head_dim    # kv heads (GQA: fewer)
+        if kv_loc == 0 or h_loc % kv_loc != 0:
+            raise ValueError(
+                f"per-shard head split is invalid: {h_loc} query heads vs "
+                f"{kv_loc} kv heads — with GQA under tensor parallelism, "
+                "n_kv_heads must be divisible by the tp axis size")
+        q = q.reshape(B, T, h_loc, head_dim)
+        k = k.reshape(B, T, kv_loc, head_dim)
+        v = v.reshape(B, T, kv_loc, head_dim)
+        if rope_base > 0.0:
+            pos = positions()
+            q = rope_rotate(q, pos, rope_base)
+            k = rope_rotate(k, pos, rope_base)
+        o, carry = attend(q, k, v)
+        (out,) = _project(o.reshape(B, T, h_loc * head_dim), p, ("wo",),
+                          use_bias, tp_axis, delta)
+        return x + out, carry
+
+
+def ffn_half(x, p, tp_axis=None, ffn=None, norm_fn=_layernorm,
+             norm_eps: float = 1e-5, use_bias: bool = True, delta=None):
+    """Second half of the block, ``x + ffn(norm(x))``. ``ffn(h) -> (out,
+    aux)`` defaults to the dense :func:`_mlp` (aux None); the MoE families
+    pass ``moe_ffn`` bound to their capacity rule. Returns ``(x, aux)``."""
+    with jax.named_scope("block/mlp"):
+        h = norm_fn(x, p["ln2_g"], p.get("ln2_b"), norm_eps)
+        out, aux = ((_mlp(h, p, tp_axis, use_bias, delta), None)
+                    if ffn is None else ffn(h))
+        return x + out, aux
 
 
 def transformer_block(x, p, head_dim: int, tp_axis=None, sp_axis=None,
                       causal: bool = True, seq_layout: str = "contiguous",
                       rope_base: float = 0.0, norm_fn=_layernorm,
                       norm_eps: float = 1e-5, use_bias: bool = True):
-    """Pre-LN block shared by the GPT (causal) and BERT (bidirectional)
-    families: attention + MLP, tp col/row-parallel, optional sp ring
-    (contiguous or zigzag sequence layout), optional RoPE
+    """The block as training runs it, shared by the GPT (causal) and BERT /
+    ViT / T5-encoder (bidirectional) families: :func:`attn_half` around
+    ring attention (contiguous or zigzag sequence layout over ``sp_axis``)
+    then the dense :func:`ffn_half`; tp col/row-parallel, optional RoPE
     (``rope_base > 0``), layernorm or rmsnorm (``norm_fn``), optional
     llama-style bias-free projections (``use_bias=False``)."""
-    # named scopes are for an operator's xprof op profile; they change no
-    # compiled program (docs/observability.md §spans)
-    with jax.named_scope("block/attn"):
-        x = x + _attention(norm_fn(x, p["ln1_g"], p.get("ln1_b"), norm_eps),
-                           p, head_dim, tp_axis, sp_axis, causal=causal,
-                           seq_layout=seq_layout, rope_base=rope_base,
-                           use_bias=use_bias)
-    with jax.named_scope("block/mlp"):
-        return x + _mlp(norm_fn(x, p["ln2_g"], p.get("ln2_b"), norm_eps), p,
-                        tp_axis, use_bias=use_bias)
+    kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=use_bias)
+    x, _ = attn_half(x, p, head_dim,
+                     lambda: _positions(x.shape[1], sp_axis, seq_layout),
+                     ring_attend(sp_axis, causal, seq_layout), tp_axis,
+                     rope_base, **kw)
+    return ffn_half(x, p, tp_axis, **kw)[0]
 
 
 def block_init(rng, d: int, ff: int, hd: int, n_layers: int,

@@ -17,8 +17,8 @@ Design (TPU-first, functional like everything in ``models/``):
 * The forward grafts each block's adapters into the block dict under a
   ``"lora"`` key (with the ``alpha/rank`` scale pre-multiplied into
   ``b`` at graft time — optimizer state stays on the unscaled leaves);
-  ``_attention`` / ``_mlp`` add ``(x @ a) @ b`` beside the frozen
-  matmul. Two thin matmuls — the ``(d, d)`` delta is never
+  the block's ``_project`` (``models/gpt.py``: every caller's one read
+  of a block weight) adds ``(x @ a) @ b`` beside the frozen matmul. Two thin matmuls — the ``(d, d)`` delta is never
   materialized in training.
 * Tensor parallelism: for column-parallel targets (wq/wk/wv/w1/w3)
   ``a`` is replicated and ``b`` column-sharded, so the adapter path

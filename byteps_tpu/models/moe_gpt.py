@@ -12,6 +12,7 @@ averaged over layers and added with ``aux_coef``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -20,13 +21,16 @@ from jax.sharding import PartitionSpec as P
 
 from byteps_tpu.models.gpt import (
     GPTConfig,
-    _attention,
     _embed,
-    resolve_norm,
+    _positions,
     _readout_nll,
+    attn_half,
     block_init,
     block_specs,
+    ffn_half,
+    resolve_norm,
     resolve_rope,
+    ring_attend,
 )
 from byteps_tpu.parallel.moe import moe_ffn, moe_init, moe_specs
 from byteps_tpu.parallel.remat import maybe_remat
@@ -124,16 +128,19 @@ def moe_transformer_block(x, p, cfg: MoEGPTConfig,
                           tp_axis: Optional[str] = None,
                           sp_axis: Optional[str] = None,
                           seq_layout: str = "contiguous"):
-    """Pre-LN attention + MoE FFN; returns (x, aux_loss)."""
+    """Pre-LN attention + MoE FFN; returns (x, aux_loss): the dense
+    family's block (``models/gpt.py``) with ``moe_ffn`` at the training
+    capacity as its feed-forward."""
     norm_fn, norm_eps = resolve_norm(cfg)
-    x = x + _attention(norm_fn(x, p["ln1_g"], p.get("ln1_b"), norm_eps), p,
-                       cfg.head_dim, tp_axis, sp_axis, causal=True,
-                       seq_layout=seq_layout, rope_base=resolve_rope(cfg),
-                       use_bias=cfg.use_bias)
-    m, aux = moe_ffn(norm_fn(x, p["ln2_g"], p.get("ln2_b"), norm_eps), p["moe"],
-                     cfg.capacity_factor, ep_axis,
-                     router_topk=cfg.router_topk, tp_axis=tp_axis)
-    return x + m, aux
+    kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
+    x, _ = attn_half(x, p, cfg.head_dim,
+                     lambda: _positions(x.shape[1], sp_axis, seq_layout),
+                     ring_attend(sp_axis, True, seq_layout), tp_axis,
+                     resolve_rope(cfg), **kw)
+    ffn = functools.partial(
+        moe_ffn, params=p["moe"], capacity_factor=cfg.capacity_factor,
+        ep_axis=ep_axis, router_topk=cfg.router_topk, tp_axis=tp_axis)
+    return ffn_half(x, p, tp_axis, ffn, **kw)
 
 
 def moe_gpt_loss(params, tokens, targets, cfg: MoEGPTConfig,

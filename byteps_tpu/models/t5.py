@@ -33,14 +33,15 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from byteps_tpu.models.gpt import (
-    _attention,
     _layernorm,
-    _mlp,
     _nll,
     _positions as _gpt_positions,
     _readout,
+    attn_half,
     block_init,
     block_specs,
+    ffn_half,
+    ring_attend,
     transformer_block,
 )
 from byteps_tpu.parallel.remat import maybe_remat
@@ -140,14 +141,13 @@ def decoder_block(x, mem, p, head_dim: int, tp_axis=None, sp_axis=None):
     ``p`` is a GPT ``block_init`` dict (self-attn + MLP) merged with
     :func:`_cross_init`'s cross-attention fields.
     """
-    # self-attention + MLP halves reuse the shared block's pieces:
-    # transformer_block is attn-then-mlp; here cross-attn goes between,
-    # so apply the pieces explicitly with the same param names
-    x = x + _attention(_layernorm(x, p["ln1_g"], p["ln1_b"]), p, head_dim,
-                       tp_axis, sp_axis, causal=True)
+    # the shared block's two halves (models/gpt.py) under the same param
+    # names: transformer_block is attn-then-mlp, here cross-attn goes
+    # between them. T5 has no RoPE, hence no positions
+    x, _ = attn_half(x, p, head_dim, None, ring_attend(sp_axis), tp_axis)
     x = x + cross_attention(_layernorm(x, p["lnx_g"], p["lnx_b"]), mem, p,
                             head_dim, tp_axis, sp_axis)
-    return x + _mlp(_layernorm(x, p["ln2_g"], p["ln2_b"]), p, tp_axis)
+    return ffn_half(x, p, tp_axis)[0]
 
 
 def t5_init(rng: jnp.ndarray, cfg: T5Config) -> Dict[str, Any]:
@@ -357,7 +357,7 @@ def t5_decode_cached(params, tgt_tokens: jnp.ndarray, cache: T5DecCache,
     prompt length is the prefill, T = 1 one decode step — pinned to
     :func:`t5_decode` numerics either way. Returns (logits f32, cache).
     """
-    from byteps_tpu.models.generate import _attn_cached_half
+    from byteps_tpu.models.generate import cache_attend
 
     B, T = tgt_tokens.shape
     pos0 = cache.length
@@ -369,8 +369,9 @@ def t5_decode_cached(params, tgt_tokens: jnp.ndarray, cache: T5DecCache,
     for li, p in enumerate(params["dec_blocks"]):
         # causal self-attention over the cache — the one shared
         # cache-append path (models/generate.py)
-        x, ck, cv = _attn_cached_half(
-            x, p, cache.k[li], cache.v[li], pos0, head_dim, tp_axis)
+        x, (ck, cv) = attn_half(
+            x, p, head_dim, None,
+            cache_attend(cache.k[li], cache.v[li], pos0), tp_axis)
         h_loc = ck.shape[-2]    # T5 has no GQA: query heads == kv heads
         # cross-attention over the precomputed encoder k/v
         h = _layernorm(x, p["lnx_g"], p["lnx_b"])
@@ -382,7 +383,7 @@ def t5_decode_cached(params, tgt_tokens: jnp.ndarray, cache: T5DecCache,
         x = x + row_parallel_matmul(o.reshape(B, T, h_loc * head_dim),
                                     p["xwo"].astype(x.dtype), tp_axis,
                                     p["xbo"].astype(x.dtype))
-        x = x + _mlp(_layernorm(x, p["ln2_g"], p["ln2_b"]), p, tp_axis)
+        x, _ = ffn_half(x, p, tp_axis)
         new_k.append(ck)
         new_v.append(cv)
     logits = _readout(params, x)

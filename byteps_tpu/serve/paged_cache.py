@@ -38,7 +38,9 @@ but-idle prefix pages are evicted LRU under pool pressure before any
 allocation fails: the prefix cache can never cause
 :class:`PoolExhausted` for live traffic.
 
-Three layers:
+Three layers, none of which writes a transformer block: both jitted
+steps run the one in ``models/gpt.py`` (``attn_half`` / ``ffn_half``) and
+own only their ``attend`` — where the new keys go, what attends over them:
 
 * :class:`PagedKVCache` — the host-side allocator: pool arrays, block
   tables + per-block refcounts, the radix prefix index,
@@ -46,10 +48,11 @@ Three layers:
   scratch block: inactive decode rows scatter there and no table ever
   references it, so a padded batch slot can't corrupt live state.
 * :func:`make_paged_decode_fn` — ONE jitted packed decode step:
-  R requests at heterogeneous positions, per-row rope/masks, scatter
-  the new token's K/V into the pool, then attend over the pool IN
-  PLACE through the block tables (``ops/paged_attention.py``: each row
-  reads its live blocks and no dense copy of K or V is made). Off the
+  R requests at heterogeneous positions, per-row rope/masks; its
+  ``attend`` scatters the new token's K/V into the pool, then attends
+  over the pool IN PLACE through the block tables
+  (``ops/paged_attention.py``: each row reads its live blocks and no
+  dense copy of K or V is made). Off the
   Pallas backend, or for a pool the kernel does not take (int8, a
   block that is not whole tiles), the step keeps the kernel's jnp
   twin: gather zero-masked per-request views, ``attention_lse``
@@ -57,10 +60,11 @@ Three layers:
 * :func:`make_paged_prefill_fn` — chunked prefill/verify for one
   request, layer by layer: gather THIS request's blocks of one layer
   into a dense zero-masked view, run the stock ``_block_step`` of
-  ``models/generate.py`` on it (what ``gpt_apply_cached`` runs, so the
-  chunk is bit-identical to the single-request prefill by
-  construction), scatter the chunk's newly written rows of that layer
-  back in place. No other block of the pool is read, moved or written.
+  ``models/generate.py`` on it (the block around ``cache_attend``: what
+  ``gpt_apply_cached`` runs, so the chunk is bit-identical to the
+  single-request prefill by construction), scatter the chunk's newly
+  written rows of that layer back in place. No other block of the pool
+  is read, moved or written.
 """
 
 from __future__ import annotations
@@ -82,12 +86,11 @@ from byteps_tpu.models.generate import (
 )
 from byteps_tpu.models.gpt import (
     GPTConfig,
-    _bias,
-    _mlp,
     _readout,
+    attn_half,
+    ffn_half,
     resolve_norm,
     resolve_rope,
-    rope_rotate,
 )
 from byteps_tpu.ops.backend import note_fallback, use_pallas
 from byteps_tpu.ops.flash_attention import attention_lse
@@ -96,7 +99,6 @@ from byteps_tpu.ops.paged_attention import (
     unsupported_reason as paged_attn_unsupported,
 )
 from byteps_tpu.ops.segmented_lora import segmented_lora_delta
-from byteps_tpu.parallel.tp import col_parallel_matmul, row_parallel_matmul
 
 
 class PoolState(NamedTuple):
@@ -797,131 +799,72 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     replica in the process shares ONE jit wrapper, so a fresh replica
     (bench rep, failover respawn) reuses the compiled steps instead of
     paying a full retrace."""
-    resolve_rope(cfg)
+    rope_base = resolve_rope(cfg)
     norm_fn, norm_eps = resolve_norm(cfg)
-    rope_base = cfg.rope_base if cfg.pos_embedding == "rope" else 0.0
-    head_dim, use_bias = cfg.head_dim, cfg.use_bias
+    kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
     lora_targets = () if lora_sig is None else tuple(lora_sig[0])
 
-    def _seg(name, xin, slabs, slots, li, row_parallel=False):
-        # one layer's slab slice: (n_slots, d_in, rb) / (n_slots, rb, d_out)
-        sl = slabs[name]
-        return segmented_lora_delta(
-            xin, sl["a"][:, li], sl["b"][:, li], slots,
-            row_parallel=row_parallel, tp_axis=tp_axis)
+    def _slab_delta(slabs, slots, li):
+        # the block's per-projection delta hook: each row's OWN adapter,
+        # from one layer's slab slice (n_slots, d_in, rb) / (n_slots, rb,
+        # d_out), added where a grafted tree's lora_delta is (and after
+        # it), so a pooled tenant's arithmetic is the solo grafted one
+        def delta(name, xin):
+            if name not in lora_targets:
+                return None
+            sl = slabs[name]
+            return segmented_lora_delta(
+                xin, sl["a"][:, li], sl["b"][:, li], slots,
+                row_parallel=name in ("wo", "w2"), tp_axis=tp_axis)
+        return delta
 
-    def _mlp_seg(x, p, slabs, slots, li):
-        # gpt._mlp with per-row segmented deltas spliced in at the SAME
-        # points (value path, gate path, row projection) so a pooled
-        # tenant's MLP arithmetic is the solo grafted one exactly
-        h = col_parallel_matmul(x, p["w1"].astype(x.dtype),
-                                _bias(p, "b1", x, use_bias))
-        if "w1" in lora_targets:
-            h = h + _seg("w1", x, slabs, slots, li)
-        if "w3" in p:
-            g = col_parallel_matmul(x, p["w3"].astype(x.dtype),
-                                    _bias(p, "b3", x, use_bias))
-            if "w3" in lora_targets:
-                g = g + _seg("w3", x, slabs, slots, li)
-            h = jax.nn.silu(h) * g
-        else:
-            h = jax.nn.gelu(h)
-        out = row_parallel_matmul(h, p["w2"].astype(x.dtype), tp_axis,
-                                  _bias(p, "b2", x, use_bias))
-        if "w2" in lora_targets:
-            out = out + _seg("w2", h, slabs, slots, li, row_parallel=True)
-        return out
-
-    def _block(x, p, pool, li, blk, off, pos, tables,
-               slabs=None, slots=None):
-        from byteps_tpu.models.lora import lora_delta
-
-        R = x.shape[0]
-        h = norm_fn(x, p["ln1_g"], p.get("ln1_b"), norm_eps)
-        q = col_parallel_matmul(h, p["wq"].astype(x.dtype),
-                                _bias(p, "bq", x, use_bias))
-        k = col_parallel_matmul(h, p["wk"].astype(x.dtype),
-                                _bias(p, "bk", x, use_bias))
-        v = col_parallel_matmul(h, p["wv"].astype(x.dtype),
-                                _bias(p, "bv", x, use_bias))
-        if "lora" in p:
-            q = q + lora_delta(h, p, "wq")
-            k = k + lora_delta(h, p, "wk")
-            v = v + lora_delta(h, p, "wv")
-        if slabs is not None:
-            if "wq" in lora_targets:
-                q = q + _seg("wq", h, slabs, slots, li)
-            if "wk" in lora_targets:
-                k = k + _seg("wk", h, slabs, slots, li)
-            if "wv" in lora_targets:
-                v = v + _seg("wv", h, slabs, slots, li)
-        h_loc = q.shape[-1] // head_dim
-        kv_loc = k.shape[-1] // head_dim
-        q = q.reshape(R, 1, h_loc, head_dim)
-        k = k.reshape(R, 1, kv_loc, head_dim)
-        v = v.reshape(R, 1, kv_loc, head_dim)
-        if rope_base > 0.0:
-            q = rope_rotate(q, pos[:, None], rope_base)
-            k = rope_rotate(k, pos[:, None], rope_base)
-        # scatter the new token's K/V into each request's block slot
-        # (quantizing first in quant mode, so attention reads the same
-        # lossy values the dense _cache_write→_cache_read roundtrip
-        # produces)
-        with jax.named_scope("paged/scatter_kv"):
-            if pool.k_scale is not None:
-                kq, ks = _quantize_block(k)
-                vq, vs = _quantize_block(v)
-                pool = PoolState(
-                    k=pool.k.at[li, blk, off].set(kq.reshape(R, -1)),
-                    v=pool.v.at[li, blk, off].set(vq.reshape(R, -1)),
-                    k_scale=pool.k_scale.at[li, blk, off].set(ks[:, 0]),
-                    v_scale=pool.v_scale.at[li, blk, off].set(vs[:, 0]),
-                )
+    def _pool_attend(pool, li, blk, off, pos, tables):
+        """The paged pool's ``attend``: scatter each row's new K/V into its
+        block slot of layer ``li``, then attend over the pool through the
+        block tables; the carry is the pool."""
+        def attend(q, k, v):
+            R, kv_loc, head_dim = q.shape[0], k.shape[2], q.shape[-1]
+            quant = pool.k_scale is not None
+            # quantizing first in quant mode, so attention reads the same
+            # lossy values the dense _cache_write→_cache_read roundtrip
+            # produces
+            with jax.named_scope("paged/scatter_kv"):
+                if quant:
+                    kq, ks = _quantize_block(k)
+                    vq, vs = _quantize_block(v)
+                    new = PoolState(
+                        k=pool.k.at[li, blk, off].set(kq.reshape(R, -1)),
+                        v=pool.v.at[li, blk, off].set(vq.reshape(R, -1)),
+                        k_scale=pool.k_scale.at[li, blk, off].set(ks[:, 0]),
+                        v_scale=pool.v_scale.at[li, blk, off].set(vs[:, 0]),
+                    )
+                else:
+                    new = PoolState(
+                        k=pool.k.at[li, blk, off].set(
+                            k.reshape(R, -1).astype(pool.k.dtype)),
+                        v=pool.v.at[li, blk, off].set(
+                            v.reshape(R, -1).astype(pool.v.dtype)),
+                    )
+            length = pos + 1                       # new key included
+            if decode_uses_paged_attn(cfg, block_size, kv_loc, quant):
+                # the pool is read where it lies: the WHOLE pool is the
+                # kernel's operand (a pool.k[li] operand could become a
+                # pool-sized copy per layer), the layer picked in its DMAs
+                with jax.named_scope("paged/attention"):
+                    o = paged_attention_decode(q[:, 0], new.k, new.v,
+                                               tables, length, li)
             else:
-                pool = PoolState(
-                    k=pool.k.at[li, blk, off].set(
-                        k.reshape(R, -1).astype(pool.k.dtype)),
-                    v=pool.v.at[li, blk, off].set(
-                        v.reshape(R, -1).astype(pool.v.dtype)),
-                )
-        length = pos + 1                       # new key included
-        if decode_uses_paged_attn(cfg, block_size, kv_loc,
-                                  pool.k_scale is not None):
-            # the pool is read where it lies: the WHOLE pool is the
-            # kernel's operand (a pool.k[li] operand could become a
-            # pool-sized copy per layer), the layer picked in its DMAs
-            with jax.named_scope("paged/attention"):
-                o = paged_attention_decode(q[:, 0], pool.k, pool.v,
-                                           tables, length, li)
-        else:
-            with jax.named_scope("paged/gather_kv"):
-                kk = _gather_view(
-                    pool.k[li],
-                    None if pool.k_scale is None else pool.k_scale[li],
-                    tables, length, x.dtype, head_dim)
-                vv = _gather_view(
-                    pool.v[li],
-                    None if pool.v_scale is None else pool.v_scale[li],
-                    tables, length, x.dtype, head_dim)
-            with jax.named_scope("paged/attention"):
-                o, _ = attention_lse(q, kk, vv, pos, 0, causal=True)
-        o = o.reshape(R, 1, h_loc * head_dim)
-        attn_out = row_parallel_matmul(o, p["wo"].astype(x.dtype), tp_axis,
-                                       _bias(p, "bo", x, use_bias))
-        if "lora" in p:
-            attn_out = attn_out + lora_delta(o, p, "wo", tp_axis)
-        if slabs is not None and "wo" in lora_targets:
-            attn_out = attn_out + _seg("wo", o, slabs, slots, li,
-                                       row_parallel=True)
-        x = x + attn_out
-        h2 = norm_fn(x, p["ln2_g"], p.get("ln2_b"), norm_eps)
-        if "moe" in p:
-            raise NotImplementedError(
-                "the paged decode step serves dense-MLP GPT families "
-                "only — MoE routing hasn't been paged yet")
-        if slabs is not None:
-            return x + _mlp_seg(h2, p, slabs, slots, li), pool
-        return x + _mlp(h2, p, tp_axis, use_bias=use_bias), pool
+                with jax.named_scope("paged/gather_kv"):
+                    kk = _gather_view(
+                        new.k[li], new.k_scale[li] if quant else None,
+                        tables, length, q.dtype, head_dim)
+                    vv = _gather_view(
+                        new.v[li], new.v_scale[li] if quant else None,
+                        tables, length, q.dtype, head_dim)
+                with jax.named_scope("paged/attention"):
+                    o, _ = attention_lse(q, kk, vv, pos, 0, causal=True)
+            return o, new
+        return attend
 
     # the pool is DONATED: the caller always rebinds its state to the
     # returned pool, and without aliasing XLA would copy the entire
@@ -934,8 +877,16 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
             tables, (pos // block_size)[:, None], axis=1)[:, 0]
         off = pos % block_size
         for li, p in enumerate(params["blocks"]):
-            x, pool = _block(x, p, pool, li, blk, off, pos, tables,
-                             slabs, slots)
+            if "moe" in p:
+                raise NotImplementedError(
+                    "the paged decode step serves dense-MLP GPT families "
+                    "only — MoE routing hasn't been paged yet")
+            delta = None if slabs is None else _slab_delta(slabs, slots, li)
+            x, pool = attn_half(
+                x, p, cfg.head_dim, lambda: pos[:, None],
+                _pool_attend(pool, li, blk, off, pos, tables), tp_axis,
+                rope_base, delta=delta, **kw)
+            x, _ = ffn_half(x, p, tp_axis, delta=delta, **kw)
         logits = _readout(params, x, norm_fn, norm_eps)
         return logits[:, 0], pool
 

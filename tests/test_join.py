@@ -41,6 +41,11 @@ from byteps_tpu.server import (
 from byteps_tpu.server.native import NativeClient, load_lib
 
 BASE_PORT = 25300
+# the lease of the tests below that wait for no eviction: it only arms
+# admission, and with no heartbeat (health_interval_ms=0) a worker that
+# sits out longer than the lease while its peers work is evicted. Half a
+# second was a whole test's budget on a host running five other workers
+_LEASE_MS = 10_000
 
 
 @pytest.fixture(autouse=True)
@@ -67,7 +72,7 @@ def test_kjoin_admits_fresh_worker_and_grows_membership(monkeypatch):
     config_mod.reset_config()
     port = BASE_PORT + 1
     start_server(port=port, num_workers=2, engine_threads=2,
-                 async_mode=False, lease_ms=500)
+                 async_mode=False, lease_ms=_LEASE_MS)
     servers = [("127.0.0.1", port)]
     x = [np.full(16, float(i + 1), np.float32) for i in range(3)]
     w0 = PSWorker(servers=servers, worker_id=0, health_interval_ms=0)
@@ -116,7 +121,7 @@ def test_kjoin_closes_open_round_over_contributors(monkeypatch):
     config_mod.reset_config()
     port = BASE_PORT + 2
     start_server(port=port, num_workers=2, engine_threads=2,
-                 async_mode=False, lease_ms=500)
+                 async_mode=False, lease_ms=_LEASE_MS)
     servers = [("127.0.0.1", port)]
     x0 = np.linspace(0, 1, 16, dtype=np.float32)
     x1 = np.linspace(2, 3, 16, dtype=np.float32)
@@ -156,7 +161,7 @@ def test_join_bit_identical_post_join_rounds(monkeypatch):
         monkeypatch.setenv("DMLC_NUM_WORKER", str(n_workers))
         config_mod.reset_config()
         start_server(port=port, num_workers=n_workers, engine_threads=2,
-                     async_mode=False, lease_ms=500)
+                     async_mode=False, lease_ms=_LEASE_MS)
         servers = [("127.0.0.1", port)]
         ws = [PSWorker(servers=servers, worker_id=i,
                        health_interval_ms=0) for i in range(n_workers)]
@@ -201,7 +206,7 @@ def test_join_composes_with_staleness(monkeypatch):
     config_mod.reset_config()
     port = BASE_PORT + 5
     start_server(port=port, num_workers=2, engine_threads=2,
-                 async_mode=False, lease_ms=500, staleness=2)
+                 async_mode=False, lease_ms=_LEASE_MS, staleness=2)
     servers = [("127.0.0.1", port)]
     rng = np.random.default_rng(23)
     x0 = rng.standard_normal(16).astype(np.float32)
@@ -439,7 +444,7 @@ def test_fault_grammar_join_fires_once(monkeypatch):
     config_mod.reset_config()
     port = BASE_PORT + 10
     start_server(port=port, num_workers=1, engine_threads=2,
-                 async_mode=False, lease_ms=500)
+                 async_mode=False, lease_ms=_LEASE_MS)
     servers = [("127.0.0.1", port)]
     x = np.full(16, 2.0, np.float32)
     w0 = PSWorker(servers=servers, worker_id=0, health_interval_ms=0)

@@ -351,7 +351,10 @@ def test_async_push_validates_bounds_and_liveness(monkeypatch):
     monkeypatch.setenv("DMLC_NUM_WORKER", "2")
     config_mod.reset_config()
     port = BASE_PORT + 9
-    lease_ms = 300
+    # seconds, not 300 ms: after the eviction the ping, the push and the
+    # members() call below must all land inside ONE lease, on a host that
+    # runs five other test workers
+    lease_ms = 3000
     start_server(port=port, num_workers=2, engine_threads=2,
                  async_mode=True, lease_ms=lease_ms)
     x = np.arange(16, dtype=np.float32)
@@ -368,7 +371,7 @@ def test_async_push_validates_bounds_and_liveness(monkeypatch):
         np.testing.assert_array_equal(got[:n].view(np.float32), x)
 
         # both workers go silent past the lease: evicted, bitmap shrinks
-        deadline = time.time() + 10
+        deadline = time.time() + 30
         while time.time() < deadline:
             epoch, live, bits = c.members()
             if live == 0:

@@ -193,7 +193,9 @@ def test_proc_restart_fault_respawns():
         "    time.sleep(60)\n"  # first life: wait for the injected kill
         "sys.exit(0)\n"))
     try:
-        assert sup.wait_all(timeout_s=_T, poll_ms=20)
+        # a tick of a second: the respawn carries a fresh plan, so its
+        # python must start and exit inside two ticks or be killed again
+        assert sup.wait_all(timeout_s=_T, poll_ms=1000)
     finally:
         sup.shutdown()
     assert sup.exit_reasons[0] == ["signal:SIGKILL", "clean"]

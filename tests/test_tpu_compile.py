@@ -7,9 +7,9 @@ cannot partition — it refuses here, at no chip time. Interpret mode, which
 every other kernel test uses on CPU, shows none of that. Shapes are
 ``chip_smoke.py``'s: GPT-2-medium attention (16 heads of 64, S=1024, batch
 8, bf16), the serve tier's 32-token prefill chunk, solo generate's decode
-step, and 4 MB gradient partitions. One whole program is held too: the
-serve cells' prefill chunk at GPT-2-large, which must not touch the KV pool
-as a whole.
+step, and 4 MB gradient partitions. Two whole programs are held too: the
+serve cells' prefill chunk and packed decode step at GPT-2-large, which must
+not touch the KV pool as a whole.
 
 A compile is not a run: nothing here says a kernel is right or fast.
 """
@@ -231,7 +231,7 @@ def test_kernel_compiles_for_v5e(topo, as_on_tpu, case):
     assert _n_pallas(compiled) == n_calls, compiled.as_text()[:2000]
 
 
-# ---- one whole program: the serve cells' prefill chunk ----------------------
+# ---- whole programs: the serve cells' prefill chunk and decode step ---------
 _POOL = (36, 513, 16, 20 * 64)        # GPT-2-large's KV pool, 756 MB in bf16
 
 
@@ -248,20 +248,11 @@ def _ops_with_result(hlo: str, shape: str):
             yield op, '"aliasing_operands":{"lists":[{' in line
 
 
-@pytest.mark.parametrize("C,W,with_readout", [
-    (32, 8, False), (16, 64, True)],
-    ids=["c32_w8_no_readout", "c16_w64_readout"])
-def test_prefill_chunk_program_leaves_the_pool_in_place(
-        topo, as_on_tpu, C, W, with_readout):
-    """The whole chunk program of the serve cells: no instruction makes a
-    second pool (a ``copy`` re-laying it out, a scatter that is not in
-    place), and the program's temporaries are a layer's views, not pools —
-    the parent of PR 31 gathered and scattered all layers at once and
-    compiled to four pool-sized copies, two pool-sized scatters and 0.9 GB
-    of temporaries. The flash calls are the parent's count: one a layer,
-    less the last layer's where no readout asks for its output."""
+def _gpt2_large_on(topo):
+    """GPT-2-large's parameters and KV pool as shapes on the described
+    chip, and the maker of further arguments there."""
     from byteps_tpu.models import GPTConfig, gpt_init
-    from byteps_tpu.serve.paged_cache import PoolState, make_paged_prefill_fn
+    from byteps_tpu.serve.paged_cache import PoolState
 
     one = SingleDeviceSharding(topo.devices[0])
 
@@ -274,15 +265,52 @@ def test_prefill_chunk_program_leaves_the_pool_in_place(
         lambda a: on_chip(a.shape, a.dtype),
         jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg)))
     pool = PoolState(on_chip(_POOL, BF16), on_chip(_POOL, BF16))
+    return cfg, params, pool, on_chip
+
+
+def _assert_pool_in_place(compiled, n_pallas):
+    """No instruction makes a second pool (a ``copy`` re-laying it out, a
+    scatter that is not in place): K and V of every layer are scattered
+    once and in place, and the temporaries are far under a pool."""
+    shape = "bf16[%s]" % ",".join(map(str, _POOL))
+    made = [(op, aliased)
+            for op, aliased in _ops_with_result(compiled.as_text(), shape)
+            if op not in ("parameter", "tuple", "get-tuple-element",
+                          "bitcast")]
+    assert made == [("fusion", True)] * (2 * _POOL[0]), made
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9
+    assert _n_pallas(compiled) == n_pallas
+
+
+@pytest.mark.parametrize("C,W,with_readout", [
+    (32, 8, False), (16, 64, True)],
+    ids=["c32_w8_no_readout", "c16_w64_readout"])
+def test_prefill_chunk_program_leaves_the_pool_in_place(
+        topo, as_on_tpu, C, W, with_readout):
+    """The whole chunk program of the serve cells: no instruction makes a
+    second pool, and the program's temporaries are a layer's views, not
+    pools — the parent of PR 31 gathered and scattered all layers at once
+    and compiled to four pool-sized copies, two pool-sized scatters and
+    0.9 GB of temporaries. The flash calls are the parent's count: one a
+    layer, less the last layer's where no readout asks for its output."""
+    from byteps_tpu.serve.paged_cache import make_paged_prefill_fn
+
+    cfg, params, pool, on_chip = _gpt2_large_on(topo)
     chunk = make_paged_prefill_fn(cfg, _POOL[2], C, None, with_readout)
     compiled = chunk.lower(params, pool, on_chip((1, C), I32),
                            on_chip((), I32), on_chip((W,), I32)).compile()
-    hlo = compiled.as_text()
-    shape = "bf16[%s]" % ",".join(map(str, _POOL))
-    made = [(op, aliased) for op, aliased in _ops_with_result(hlo, shape)
-            if op not in ("parameter", "tuple", "get-tuple-element",
-                          "bitcast")]
-    # K and V of every layer, each scattered once and in place
-    assert made == [("fusion", True)] * (2 * _POOL[0]), made
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9
-    assert _n_pallas(compiled) == _POOL[0] - (not with_readout)
+    _assert_pool_in_place(compiled, _POOL[0] - (not with_readout))
+
+
+@pytest.mark.parametrize("W", [8, 64], ids=["w8", "w64"])
+def test_decode_step_program_leaves_the_pool_in_place(topo, as_on_tpu, W):
+    """The whole packed decode step of the serve cells (batch 8, table width
+    W): each layer scatters the new token's K and V in place and attends
+    through one ``paged_attn_decode`` call over the pool where it lies."""
+    from byteps_tpu.serve.paged_cache import make_paged_decode_fn
+
+    cfg, params, pool, on_chip = _gpt2_large_on(topo)
+    step = make_paged_decode_fn(cfg, _POOL[2])
+    compiled = step.lower(params, pool, on_chip((8,), I32),
+                          on_chip((8,), I32), on_chip((8, W), I32)).compile()
+    _assert_pool_in_place(compiled, _POOL[0])
