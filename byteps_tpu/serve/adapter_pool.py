@@ -174,25 +174,27 @@ class AdapterPool:
         chunks (this tree), packed decode (the device slabs), and the
         solo ``make_generate_fn`` exactness baseline all run identical
         arithmetic — the BIT-identical contract the tests enforce.
-        Cached per adapter (the tree shares every base leaf by
-        reference; only the thin adapter leaves are new)."""
-        p = self._graft_cache.get(adapter_id)
-        if p is None:
+        Cached per adapter and base (the tree shares every base leaf by
+        reference; only the thin adapter leaves are new, and a graft onto
+        another base — the scheduler's prepared operands, a test's own
+        tree — reuses them)."""
+        hit = self._graft_cache.get(adapter_id)
+        if hit is not None and hit[0] is base_params:
+            return hit[1]
+        if hit is not None:
+            loras = [blk["lora"] for blk in hit[1]["blocks"]]
+        else:
             host = self._registry[adapter_id]["slabs"]
-            blocks = []
-            for li, bp in enumerate(base_params["blocks"]):
-                blk = dict(bp)
-                # slabs already carry b * scale (lora_pool_slabs), so
-                # the graft folds scale=1 — graft_lora's output format
-                blk["lora"] = {
-                    t: {"a": jnp.asarray(host[t]["a"][li]),
-                        "b": jnp.asarray(host[t]["b"][li])}
-                    for t in self.targets
-                }
-                blocks.append(blk)
-            p = dict(base_params)
-            p["blocks"] = blocks
-            self._graft_cache[adapter_id] = p
+            # slabs already carry b * scale (lora_pool_slabs), so
+            # the graft folds scale=1 — graft_lora's output format
+            loras = [{t: {"a": jnp.asarray(host[t]["a"][li]),
+                          "b": jnp.asarray(host[t]["b"][li])}
+                      for t in self.targets}
+                     for li in range(len(base_params["blocks"]))]
+        p = dict(base_params)
+        p["blocks"] = [{**bp, "lora": lora}
+                       for bp, lora in zip(base_params["blocks"], loras)]
+        self._graft_cache[adapter_id] = (base_params, p)
         return p
 
     # -- accounting ----------------------------------------------------------

@@ -759,6 +759,50 @@ def decode_uses_paged_attn(cfg: GPTConfig, block_size: int,
     return True
 
 
+# every leaf models/gpt.py::_project reads of a block: the projections and
+# their biases (w3/b3 on a SwiGLU block; biases absent under use_bias=False)
+_PROJECTED = tuple(k + n for k in "wb" for n in "qkvo123")
+
+
+@functools.partial(jax.jit, static_argnames="dtype")
+def _cast_operands(blocks, head, dtype):
+    if "wte" in head:              # a tied readout: lm_head is wte.T, (d, V)
+        head = {"lm_head": head["wte"].T}
+    return jax.tree_util.tree_map(lambda w: w.astype(dtype), (blocks, head))
+
+
+def serve_operands(params, cfg: GPTConfig):
+    """The tree both serve programs are called with: the matmul operands
+    cast to ``cfg.dtype`` ONCE, where the shared block would cast them in
+    every decode step and prefill chunk (``_project``:
+    ``p[name].astype(x.dtype)``; the readout the same) — at bf16 compute on
+    an f32 tree each program otherwise streams the f32 weights from HBM to
+    round them to the operands it had a step ago (PERF.md §6, PR 33).
+
+    Cast, in one jitted call: every block leaf ``_project`` reads
+    (:data:`_PROJECTED`), and the readout as an ``lm_head`` leaf of shape
+    ``(d, V)`` (``wte.T`` of a tied tree), which ``_readout`` prefers.
+    ``astype`` to the dtype a leaf already has lowers to nothing, so the
+    model code adapts on what it can observe and every logit is
+    bit-identical to the caller's tree. Everything else is the caller's
+    leaf BY REFERENCE: ``wte``/``wpe`` (``_embed`` adds them in f32, then
+    rounds, and reads a few rows), norm gains and biases (f32 arithmetic),
+    a grafted ``"lora"`` subtree. A sharded leaf keeps its sharding (an
+    elementwise cast of a committed array), and one already in
+    ``cfg.dtype`` is the caller's too. Where none differs (f32 compute on
+    an f32 tree), returns ``params`` itself: no second tree."""
+    dtype = jnp.dtype(cfg.dtype)
+    blocks = [{k: p[k] for k in _PROJECTED if k in p and p[k].dtype != dtype}
+              for p in params["blocks"]]
+    src = "lm_head" if "lm_head" in params else "wte"
+    head = {} if params[src].dtype == dtype else {src: params[src]}
+    if not jax.tree_util.tree_leaves((blocks, head)):
+        return params
+    blocks, head = _cast_operands(blocks, head, dtype=dtype)
+    return {**params, **head,
+            "blocks": [{**p, **b} for p, b in zip(params["blocks"], blocks)]}
+
+
 @functools.lru_cache(maxsize=64)
 def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                          tp_axis: Optional[str] = None,

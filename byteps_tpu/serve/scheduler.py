@@ -115,6 +115,7 @@ from byteps_tpu.serve.paged_cache import (
     decode_uses_paged_attn,
     make_paged_decode_fn,
     make_paged_prefill_fn,
+    serve_operands,
 )
 
 log = get_logger("serve.scheduler")
@@ -423,6 +424,19 @@ class Scheduler:
             "queue_depth": _reg.gauge(
                 f"serve.r{next(_REPLICA_SEQ)}.queue_depth"),
         }
+        # every serve program is called with the PREPARED tree: its
+        # matmul operands cast to cfg.dtype once, here, not in each decode
+        # step and prefill chunk. self.params stays the caller's tree
+        # (shapes, adapters' registration); a draft model's tree
+        # (SpecPolicy.draft_params) is not prepared
+        with get_tracer().span("serve.prepare_operands", "SERVE"):
+            self._operands = jax.block_until_ready(
+                serve_operands(params, cfg))
+        theirs = {id(w) for w in jax.tree_util.tree_leaves(params)}
+        cast = [w for w in jax.tree_util.tree_leaves(self._operands)
+                if id(w) not in theirs]
+        _reg.gauge("serve.operand_leaves_cast").set(len(cast))
+        _reg.gauge("serve.operand_bytes").set(sum(w.nbytes for w in cast))
 
     # -- client surface -----------------------------------------------------
     def submit(self, req: Request,
@@ -765,8 +779,8 @@ class Scheduler:
         packed decode gathers is what keeps prefill logits, packed
         decode logits, and the solo baseline bit-identical."""
         if run.req.adapter is None:
-            return self.params
-        return self.adapter_pool.graft(self.params, run.req.adapter)
+            return self._operands
+        return self.adapter_pool.graft(self._operands, run.req.adapter)
 
     @property
     def kv_codec(self):
@@ -1453,7 +1467,7 @@ class Scheduler:
                 extra = (self.adapter_pool.slabs, jnp.asarray(slots))
         with tr.span("serve.decode_dispatch", "SERVE", (len(packed), W)):
             logits, self.cache.state = self._decode_step()(
-                self.params, self.cache.state, jnp.asarray(toks),
+                self._operands, self.cache.state, jnp.asarray(toks),
                 jnp.asarray(pos), jnp.asarray(tables), *extra)
             picked = self._pick(
                 logits, jnp.asarray(seeds), jnp.asarray(pos + 1),

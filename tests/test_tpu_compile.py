@@ -235,15 +235,22 @@ def test_kernel_compiles_for_v5e(topo, as_on_tpu, case):
 _POOL = (36, 513, 16, 20 * 64)        # GPT-2-large's KV pool, 756 MB in bf16
 
 
-def _ops_with_result(hlo: str, shape: str):
-    """``(op, aliased)`` of every instruction outside the fused computations
-    whose result has ``shape``. A fusion that updates an operand in place
-    says so in its ``aliasing_operands``."""
+def _top_level(hlo: str):
+    """The instructions outside the fused computations, one line each."""
     fused = False
     for line in hlo.splitlines():
         if line[:1] not in ("", " "):          # a computation's header
             fused = line.startswith("%fused_computation")
-        elif not fused and f" = {shape}{{" in line:
+        elif not fused:
+            yield line
+
+
+def _ops_with_result(hlo: str, shape: str):
+    """``(op, aliased)`` of every instruction outside the fused computations
+    whose result has ``shape``. A fusion that updates an operand in place
+    says so in its ``aliasing_operands``."""
+    for line in _top_level(hlo):
+        if f" = {shape}{{" in line:
             op = re.search(r"\} ([\w-]+)\(", line).group(1)
             yield op, '"aliasing_operands":{"lists":[{' in line
 
@@ -266,6 +273,22 @@ def _gpt2_large_on(topo):
         jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg)))
     pool = PoolState(on_chip(_POOL, BF16), on_chip(_POOL, BF16))
     return cfg, params, pool, on_chip
+
+
+def _compile_chunk(cfg, tree, pool, on_chip, C, W, with_readout):
+    from byteps_tpu.serve.paged_cache import make_paged_prefill_fn
+
+    chunk = make_paged_prefill_fn(cfg, _POOL[2], C, None, with_readout)
+    return chunk.lower(tree, pool, on_chip((1, C), I32), on_chip((), I32),
+                       on_chip((W,), I32)).compile()
+
+
+def _compile_decode(cfg, tree, pool, on_chip, W):
+    from byteps_tpu.serve.paged_cache import make_paged_decode_fn
+
+    step = make_paged_decode_fn(cfg, _POOL[2])
+    return step.lower(tree, pool, on_chip((8,), I32), on_chip((8,), I32),
+                      on_chip((8, W), I32)).compile()
 
 
 def _assert_pool_in_place(compiled, n_pallas):
@@ -293,12 +316,7 @@ def test_prefill_chunk_program_leaves_the_pool_in_place(
     and compiled to four pool-sized copies, two pool-sized scatters and
     0.9 GB of temporaries. The flash calls are the parent's count: one a
     layer, less the last layer's where no readout asks for its output."""
-    from byteps_tpu.serve.paged_cache import make_paged_prefill_fn
-
-    cfg, params, pool, on_chip = _gpt2_large_on(topo)
-    chunk = make_paged_prefill_fn(cfg, _POOL[2], C, None, with_readout)
-    compiled = chunk.lower(params, pool, on_chip((1, C), I32),
-                           on_chip((), I32), on_chip((W,), I32)).compile()
+    compiled = _compile_chunk(*_gpt2_large_on(topo), C, W, with_readout)
     _assert_pool_in_place(compiled, _POOL[0] - (not with_readout))
 
 
@@ -307,10 +325,40 @@ def test_decode_step_program_leaves_the_pool_in_place(topo, as_on_tpu, W):
     """The whole packed decode step of the serve cells (batch 8, table width
     W): each layer scatters the new token's K and V in place and attends
     through one ``paged_attn_decode`` call over the pool where it lies."""
-    from byteps_tpu.serve.paged_cache import make_paged_decode_fn
+    compiled = _compile_decode(*_gpt2_large_on(topo), W)
+    _assert_pool_in_place(compiled, _POOL[0])
+
+
+# a projection or bias of GPT-2-large's block: what serve_operands casts
+_WEIGHT = r"\[(1280|5120|1280,1280|1280,5120|5120,1280)\]"
+
+
+@pytest.mark.parametrize("program", ["decode_step", "chunk_c32"])
+def test_serve_program_reads_prepared_operands_as_they_are(
+        topo, as_on_tpu, program):
+    """Both serve programs on the tree ``serve_operands`` returns: nothing
+    is left of the per-call casts. On the caller's f32 tree the decode step
+    compiles to 216 top-level ``convert``s (a layer's six biases; the
+    weights' rounding sits inside the matmul fusions) and the chunk to 212,
+    each weight travels in f32 (slices and prefetches), and the cost
+    analysis reads 9.19 and 9.71 GB accessed; prepared, 6.16 and 6.82."""
+    from byteps_tpu.serve.paged_cache import _PROJECTED, serve_operands
 
     cfg, params, pool, on_chip = _gpt2_large_on(topo)
-    step = make_paged_decode_fn(cfg, _POOL[2])
-    compiled = step.lower(params, pool, on_chip((8,), I32),
-                          on_chip((8,), I32), on_chip((8, W), I32)).compile()
-    _assert_pool_in_place(compiled, _POOL[0])
+    operands = jax.tree.map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(lambda p: serve_operands(p, cfg), params))
+    assert operands["wte"].dtype == F32 and operands["lm_head"].dtype == BF16
+    if program == "decode_step":
+        compiled = _compile_decode(cfg, operands, pool, on_chip, 8)
+    else:
+        compiled = _compile_chunk(cfg, operands, pool, on_chip, 32, 8, False)
+    top = list(_top_level(compiled.as_text()))
+    casts = [ln for ln in top
+             if re.search(r" = bf16%s\S* convert\(" % _WEIGHT, ln)]
+    assert not casts, casts[:3]
+    # the async moves (prefetches, slices) of a projected leaf: none in f32
+    moves = [ln for ln in top if re.search(
+        r"-start\(%%params__blocks___\d+___(%s)__" % "|".join(_PROJECTED), ln)]
+    assert moves and not [ln for ln in moves if "f32[" in ln], moves[:3]
+    assert compiled.cost_analysis()["bytes accessed"] < 7e9
