@@ -239,27 +239,72 @@ def _swiglu(x, p):
     return _mlp(x, p, None, use_bias=False)
 
 
+def mla_latents(x, p, pos, *, n_heads: int, nope: int, rope: int,
+                kv_rank: int, theta: float, interleave: bool, eps: float,
+                q_scale: float = 1.0, kv_scale: float = 1.0,
+                v_dim: Optional[int] = None):
+    """The projections of latent attention, for ``x (B, S, d)`` at positions
+    ``pos`` (``(S,)`` or ``(B, S)``, or a callable giving them): ``c_q`` the
+    normed query latent, ``q
+    (B, S, H, nope + rope)`` with its rope part rotated, ``c_kv (B, S,
+    kv_rank)`` the normed key-value latent, ``k_rope (B, S, 1, rope)`` the
+    one rotated key part all heads share — ``(c_kv, k_rope)`` is all a
+    latent cache keeps of a token — and, with ``v_dim``, k and v
+    materialised from them: ``k (B, S, H, nope + rope)`` (every head's own
+    nope part beside the shared rope part), ``v (B, S, H, v_dim)``; None
+    both without it. ``q_scale`` / ``kv_scale`` multiply the normed latents
+    (``models/dots3.py``; 1.0 leaves the program as it was)."""
+    B, S, _ = x.shape
+    c_q = _rmsnorm(x @ p["wq_a"].astype(x.dtype), p["q_norm_g"], eps=eps)
+    if q_scale != 1.0:
+        c_q = c_q * q_scale
+    q = (c_q @ p["wq_b"].astype(x.dtype)).reshape(B, S, n_heads, nope + rope)
+    kv_a = x @ p["wkv_a"].astype(x.dtype)
+    c_kv = _rmsnorm(kv_a[..., :kv_rank], p["kv_norm_g"], eps=eps)
+    if kv_scale != 1.0:
+        c_kv = c_kv * kv_scale
+    kv = None if v_dim is None else (
+        c_kv @ p["wkv_b"].astype(x.dtype)).reshape(B, S, n_heads,
+                                                   nope + v_dim)
+    if callable(pos):              # made where they are used, as attn_half's
+        pos = pos()
+    q_rope = rope_rotate(q[..., nope:], pos, theta, interleaved=interleave)
+    k_rope = rope_rotate(kv_a[..., kv_rank:].reshape(B, S, 1, rope), pos,
+                         theta, interleaved=interleave)
+    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+    if kv is None:
+        return c_q, q, c_kv, k_rope, None, None
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, n_heads, rope))],
+        axis=-1)
+    return c_q, q, c_kv, k_rope, k, kv[..., nope:]
+
+
+def mla_expand(c_kv, k_rope, p, *, n_heads: int, nope: int, v_dim: int):
+    """k and v materialised from latents that were cached: ``k (B, S, H,
+    nope + rope)`` and ``v (B, S, H, v_dim)`` (what :func:`mla_latents`
+    gives with ``v_dim``, from ``c_kv`` and ``k_rope`` alone)."""
+    B, S, _ = c_kv.shape
+    kv = (c_kv @ p["wkv_b"].astype(c_kv.dtype)).reshape(
+        B, S, n_heads, nope + v_dim)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_rope, (B, S, n_heads, k_rope.shape[-1]))],
+        axis=-1)
+    return k, kv[..., nope:]
+
+
 def mla_attention(x, p, cfg: JoyAIConfig):
     """Latent attention over ``x (B, S, d)``, k and v materialised; the
     flash kernels take q/k of ``nope + rope`` and v of ``v_head_dim``."""
     B, S, _ = x.shape
-    H, nope, rope = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
-    eps = cfg.norm_eps
-    c_q = _rmsnorm(x @ p["wq_a"].astype(x.dtype), p["q_norm_g"], eps=eps)
-    q = (c_q @ p["wq_b"].astype(x.dtype)).reshape(B, S, H, nope + rope)
-    kv_a = x @ p["wkv_a"].astype(x.dtype)
-    c_kv = _rmsnorm(kv_a[..., :cfg.kv_lora_rank], p["kv_norm_g"], eps=eps)
-    kv = (c_kv @ p["wkv_b"].astype(x.dtype)).reshape(
-        B, S, H, nope + cfg.v_head_dim)
-    pos = _positions(S, None, "contiguous")
-    q_rope = rope_rotate(q[..., nope:], pos, cfg.rope_base,
-                         interleaved=cfg.rope_interleave)
-    k_rope = rope_rotate(kv_a[..., cfg.kv_lora_rank:].reshape(B, S, 1, rope),
-                         pos, cfg.rope_base, interleaved=cfg.rope_interleave)
-    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-    k = jnp.concatenate(
-        [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, H, rope))], axis=-1)
-    o = plain_attention(q, k, kv[..., nope:], causal=True)
+    H = cfg.n_heads
+    _, q, _, _, k, v = mla_latents(
+        x, p, lambda: _positions(S, None, "contiguous"), n_heads=H,
+        nope=cfg.qk_nope_dim, rope=cfg.qk_rope_dim, kv_rank=cfg.kv_lora_rank,
+        theta=cfg.rope_base, interleave=cfg.rope_interleave,
+        eps=cfg.norm_eps, v_dim=cfg.v_head_dim)
+    o = plain_attention(q, k, v, causal=True)
     return o.reshape(B, S, H * cfg.v_head_dim) @ p["wo"].astype(x.dtype)
 
 

@@ -219,17 +219,23 @@ def _read_offsets(qoff_ref, koff_ref):
             koff_ref[0, 0].astype(jnp.int32))
 
 
-def _mask_tile(s, q_off, k_off, q_start, k_start, bq, bk):
+def _mask_tile(s, q_off, k_off, q_start, k_start, bq, bk, window=None):
     rows = q_off + q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     cols = k_off + k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(rows >= cols, s, _NEG)
+    if window is None:
+        return jnp.where(rows >= cols, s, _NEG)
+    # a window beside the causal rule: the query's own position and the
+    # window - 1 before it; a key laid out before position 0 is padding
+    return jnp.where((rows >= cols) & (rows - cols < window) & (cols >= 0),
+                     s, _NEG)
 
 
 # --------------------------------------------------------------------------
 # forward kernel
 # --------------------------------------------------------------------------
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, bq, bk, nk):
+                m_scr, l_scr, acc_scr, *, scale, causal, bq, bk, nk,
+                window=None, mask_ref=None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -254,13 +260,17 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # (bq, bk)
-        if masked:
-            s = _mask_tile(s, q_off, k_off, q_start, k_start, bq, bk)
+        if mask_ref is not None:
+            # the caller's (bq, bk) tile of allowed pairs, the same for
+            # every head; it carries the causal rule
+            s = jnp.where(mask_ref[...].astype(jnp.float32) > 0.5, s, _NEG)
+        elif masked:
+            s = _mask_tile(s, q_off, k_off, q_start, k_start, bq, bk, window)
         m_prev = m_scr[:]                                    # (bq, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)                               # (bq, bk)
-        if masked:
+        if masked or mask_ref is not None:
             # exp(_NEG - m) underflows to 0 except when the whole row is
             # masked (m == _NEG) — zero those lanes explicitly
             p = jnp.where(s > _NEG / 2, p, 0.0)
@@ -280,6 +290,13 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # 1/nk fraction, so most tiles take the cheap path
         live = q_off + q_start + bq - 1 >= k_off + k_start
         interior = q_off + q_start >= k_off + k_start + bk - 1
+        if window is not None:
+            # tiles wholly behind the window are skipped like those wholly
+            # after the diagonal; an interior tile lies inside it for every
+            # pair and holds no padding key
+            live &= (q_off + q_start) - (k_off + k_start + bk - 1) < window
+            interior &= ((q_off + q_start + bq - 1) - (k_off + k_start)
+                         < window) & (k_off + k_start >= 0)
 
         @pl.when(live & interior)
         def _():
@@ -311,9 +328,11 @@ def _kv_index(heads: int, kv_heads: int):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret",
-                                             "heads", "kv_heads"))
+                                             "heads", "kv_heads", "window",
+                                             "name"))
 def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
-         heads: int, kv_heads: int):
+         heads: int, kv_heads: int, window: Optional[int] = None,
+         mask=None, name: str = "flash_fwd"):
     """q3: (B·H, S, D), k3: (B·Hkv, S, D), v3: (B·Hkv, S, Dv) →
     (o (B·H, Sq, Dv), lse (B·H, Sq, 1) f32). The softmax scale is
     ``D ** -0.5``, the q/k width."""
@@ -330,6 +349,18 @@ def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
     kv = _kv_index(heads, kv_heads)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              bq=bq, bk=bk, nk=nk)
+    if window is not None:         # the causal-only trace stays as it was
+        kern = functools.partial(kern, window=window)
+    extra_specs, extra = [], ()
+    if mask is not None:
+        # one (Sq, Sk) int8 plane of allowed pairs for every head: the
+        # block index leaves the head out
+        def kern(qoff_ref, koff_ref, q_ref, k_ref, v_ref, mask_ref, *rest,
+                 _inner=kern):
+            _inner(qoff_ref, koff_ref, q_ref, k_ref, v_ref, *rest,
+                   mask_ref=mask_ref)
+        extra_specs = [pl.BlockSpec((bq, bk), lambda b, qi, ki: (qi, ki))]
+        extra = (mask,)
     return pl.pallas_call(
         kern,
         grid=(BH, nq, nk),
@@ -339,7 +370,7 @@ def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
             pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bk, D), lambda b, qi, ki: (kv(b), ki, 0)),
             pl.BlockSpec((1, bk, Dv), lambda b, qi, ki: (kv(b), ki, 0)),
-        ],
+        ] + extra_specs,
         out_specs=[
             pl.BlockSpec((1, bq, Dv), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, qi, ki: (b, qi, 0)),
@@ -356,8 +387,8 @@ def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_fwd",
-    )(qoff, koff, q3, k3, v3)
+        name=name,
+    )(qoff, koff, q3, k3, v3, *extra)
 
 
 # --------------------------------------------------------------------------
@@ -764,6 +795,80 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         o, _ = attention_lse_jnp(q, k, v, 0, 0, causal=causal)
         return o
     return attention_jnp(q, k, v, causal=causal)
+
+
+def attention_window_jnp(q, k, v, q_offset, k_offset, window: int):
+    """jnp twin of :func:`flash_attention_window`: query ``i`` (global
+    position ``q_offset + i``) sees the keys at global positions ``p`` with
+    ``0 <= p <= its own`` and ``its own - p < window``; ``(B, S, H, D)``
+    layout, v of its own width, f32 softmax."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    rows = q_offset + jnp.arange(q.shape[1])[:, None]
+    cols = k_offset + jnp.arange(k.shape[1])[None, :]
+    ok = (rows >= cols) & (rows - cols < window) & (cols >= 0)
+    p = jax.nn.softmax(jnp.where(ok[None, None], s, _NEG), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def flash_attention_window(q, k, v, q_offset, k_offset, window: int):
+    """Causal attention inside a window of ``window`` keys (the query's own
+    position counted), forward only — serving's sliding layers. The flash
+    forward kernel with the window a trace-time constant of its mask: tiles
+    wholly behind the window are skipped as those after the diagonal are.
+    Offsets are the global positions of element 0 of q and of k (traced
+    scalars); keys laid out before position 0 are padding and never seen.
+    Every query has a live key (its own), so no row is empty. Falls back
+    to :func:`attention_window_jnp` off the Pallas backend and for shapes
+    the kernel does not tile."""
+    B, Sq, H, D = q.shape
+    if use_pallas():
+        if supported(Sq, k.shape[1], D) and k.shape[2] == H:
+            qoff = jnp.asarray(q_offset, jnp.float32).reshape(1, 1)
+            koff = jnp.asarray(k_offset, jnp.float32).reshape(1, 1)
+            o3, _ = _fwd(_to3(q), _to3(k), _to3(v), qoff, koff, True,
+                         _interpret(), H, H, window=int(window))
+            return _from3(o3, B, H)
+        _note_fallback("flash_attention_window", q.shape + k.shape,
+                       _UNSUPPORTED)
+    return attention_window_jnp(q, k, v, q_offset, k_offset, window)
+
+
+def attention_masked_jnp(q, k, v, mask):
+    """jnp twin of :func:`flash_attention_masked`: softmax over the keys
+    ``mask (Sq, Sk)`` (non-zero: allowed) lets each query see, the same for
+    every batch row and head; f32 softmax, v of its own width."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    p = jax.nn.softmax(jnp.where((mask != 0)[None, None], s, _NEG), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def flash_attention_masked(q, k, v, mask, q_offset, k_offset,
+                           name: str = "flash_fwd_masked"):
+    """Attention over an arbitrary set of keys a query, forward only:
+    ``mask (Sq, Sk)`` int8 says which pairs exist (it carries the causal
+    rule; every query must keep a key) and is the same for every head, so a
+    head's kernel step reads one ``(bq, bk)`` int8 tile beside its k and v
+    tiles. Tiles wholly after the diagonal of the global positions
+    (``q_offset``, ``k_offset``) are skipped. Serving's selected attention
+    over materialised keys (``serve/latent_step.py``: the mask is the
+    learned indexer's pick). ``name`` is the kernel's in a device trace.
+    Falls back to :func:`attention_masked_jnp` off the Pallas backend and
+    for shapes the kernel does not tile."""
+    B, Sq, H, D = q.shape
+    if use_pallas():
+        if supported(Sq, k.shape[1], D) and k.shape[2] == H and Sq % 32 == 0:
+            qoff = jnp.asarray(q_offset, jnp.float32).reshape(1, 1)
+            koff = jnp.asarray(k_offset, jnp.float32).reshape(1, 1)
+            o3, _ = _fwd(_to3(q), _to3(k), _to3(v), qoff, koff, True,
+                         _interpret(), H, H, mask=mask.astype(jnp.int8),
+                         name=name)
+            return _from3(o3, B, H)
+        _note_fallback(name, q.shape + k.shape, _UNSUPPORTED)
+    return attention_masked_jnp(q, k, v, mask)
 
 
 def merge_attention(o_a, lse_a, o_b, lse_b):

@@ -1,0 +1,405 @@
+"""The second model step of the paged serve tier: ``models/dots3.py`` over a
+*latent* paged cache of two layer kinds.
+
+Same contract as ``paged_cache.make_paged_decode_fn`` /
+``make_paged_prefill_fn`` — ``(params, pool, toks, pos, tables) -> (logits,
+pool)``, the pool donated — with a pool of another shape
+(:class:`LatentPool`) and table rows of two lines: line 0 the request's
+blocks of the *global* kind (full layers keep every block), line 1 those of
+the *window* kind (sliding layers keep the blocks that hold the last
+``window`` positions; the cache hands the others back while the request
+runs, and their entries read 0, the scratch block).
+
+A token leaves in the cache, per full layer, its latent row ``[c_kv; k_rope]``
+and one indexer key; per sliding layer its (wider) latent row. Nothing is
+ever materialised into per-head keys in the cache. The decode step attends in
+the absorbed form over rows gathered through the tables (the picked 2,048 on
+full layers, the last ``window`` on sliding ones). A prefill chunk
+materialises k and v from the request's latent rows, transiently: a full
+layer's for the live key buckets, a group of heads at a time, under the flash
+kernel with the indexer's pick as its mask; a sliding layer's for the chunk
+and the ``window - 1`` positions before it, under the flash kernel with the
+window in its mask. Every write is ``pool.at[layer, block, offset].set`` on the donated
+pool, per layer, as PR 31 made the rule.
+
+What the programs count reaches the host in ``pool.stats`` with the step's
+other outputs, a step late (:class:`LateStats`): no sync is added.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from byteps_tpu.common.metrics import get_registry
+from byteps_tpu.models import dots3
+from byteps_tpu.models.dots3 import FULL, SLIDING, Dots3Config
+from byteps_tpu.models.gpt import _rmsnorm
+from byteps_tpu.models.joyai import mla_expand
+from byteps_tpu.ops.dsa_index import index_scores
+from byteps_tpu.ops.flash_attention import (
+    flash_attention_masked,
+    flash_attention_window,
+)
+
+_NEG = -1e30
+#: a chunk's full layers work on the keys of the first ``ceil((pos0 + C) /
+#: _KEY_BUCKET)`` buckets alone, not on the table's whole width: the table is
+#: a power of two wide (a 16,385-token context reads 32,768 keys) and a
+#: request's chunks see half its final context on average. One statically
+#: shaped branch a bucket count: a branch is traced, lowered and compiled
+#: like a program of its own, so 8,192 and not less (4,096 read 46 ms of
+#: selected attention an iteration where this reads more, and twice the
+#: branches in every chunk program's set-up)
+_KEY_BUCKET = 8192
+#: heads whose materialised k and v exist at once in a chunk's full layer:
+#: 32 of 128 heads x 32,768 keys x (192 + 128) values is 0.67 GB in bf16
+_HEAD_GROUP = 32
+
+#: ``pool.stats``: what one program counted, f32
+STATS = ("moe.pairs_here", "moe.pairs_total", "moe.load_max_over_mean",
+         "dsa.scored_pairs", "dsa.selected_keys", "dsa.queries",
+         "dsa.prefill_scored_pairs", "dsa.prefill_selected_keys")
+
+
+class LatentPool(NamedTuple):
+    """The device half of the latent cache.
+
+    kv: ``(full layers, blocks, block_size, page_row)`` latent rows of the
+    full layers (``[c_kv; k_rope]`` and zeros to whole lane tiles); ki: ``(full layers, blocks, block_size,
+    index_head_dim)`` their indexer keys (same blocks, same tables); wkv:
+    ``(sliding layers, window blocks, block_size, page_row)`` the sliding
+    layers' rows, in a pool of its own, far smaller; stats:
+    :data:`STATS` of the program that last wrote the pool."""
+
+    kv: jnp.ndarray
+    ki: jnp.ndarray
+    wkv: jnp.ndarray
+    stats: jnp.ndarray
+
+
+def init_pool(cfg: Dots3Config, block_size: int, pool_blocks: int,
+              window_blocks: int) -> LatentPool:
+    nf, nw = len(cfg.layers_of(FULL)), len(cfg.layers_of(SLIDING))
+    return LatentPool(
+        kv=jnp.zeros((nf, pool_blocks, block_size, cfg.dims(FULL).page_row),
+                     cfg.dtype),
+        ki=jnp.zeros((nf, pool_blocks, block_size, cfg.index_head_dim),
+                     cfg.dtype),
+        wkv=jnp.zeros((max(nw, 1), window_blocks, block_size,
+                       cfg.dims(SLIDING).page_row), cfg.dtype),
+        stats=jnp.zeros((len(STATS),), jnp.float32))
+
+
+class LateStats:
+    """``pool.stats`` of each dispatched program, observed into the registry
+    once the device has it — a step later, when it costs no wait."""
+
+    def __init__(self):
+        reg = get_registry()
+        self._pending = []
+        self._pairs_here = reg.histogram("moe.pairs_here")
+        self._load = reg.histogram("moe.load_max_over_mean")
+        self._per_query = reg.histogram("serve.dsa.selected_per_query")
+        self._scored = reg.counter("serve.dsa.scored_pairs")
+        self._selected = reg.counter("serve.dsa.selected_keys")
+        self._prefill_scored = reg.counter("serve.dsa.prefill_scored_pairs")
+        self._prefill_selected = reg.counter(
+            "serve.dsa.prefill_selected_keys")
+
+    def note(self, pool: LatentPool) -> None:
+        # a buffer of its own: the pool, stats leaf included, is donated to
+        # the next program
+        self._pending.append(pool.stats + 0.0)
+        self.drain(block=False)
+
+    def drain(self, block: bool) -> None:
+        while self._pending and (block or self._pending[0].is_ready()):
+            s = dict(zip(STATS, np.asarray(self._pending.pop(0)).tolist()))
+            self._pairs_here.observe(s["moe.pairs_here"])
+            self._load.observe(s["moe.load_max_over_mean"])
+            self._scored.inc(int(s["dsa.scored_pairs"]))
+            self._selected.inc(int(s["dsa.selected_keys"]))
+            self._prefill_scored.inc(int(s["dsa.prefill_scored_pairs"]))
+            self._prefill_selected.inc(int(s["dsa.prefill_selected_keys"]))
+            if s["dsa.queries"] > 0:
+                self._per_query.observe(
+                    s["dsa.selected_keys"] / s["dsa.queries"])
+
+
+def _pick_rows(scores, topk: int):
+    """``(sel (N, K), valid (N, K))``: the ``topk`` keys of largest score a
+    query (every key while there are no more than ``topk``); ``valid`` is
+    False where a query has fewer live keys than K."""
+    L = scores.shape[-1]
+    if topk >= L:
+        return (jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32),
+                                 scores.shape), scores > _NEG / 2)
+    top, sel = jax.lax.top_k(scores, topk)
+    return sel.astype(jnp.int32), top > _NEG / 2
+
+
+def select_mask(scores, topk: int):
+    """``(N, L)`` bool: for each query the ``topk`` keys of largest score
+    among its live ones (score above -1e30), every live key while there are
+    no more than ``topk`` — exactly the set ``jax.lax.top_k`` picks (of equal
+    scores the lower position first), as a mask and with no sort: the
+    ``topk``-th largest score of a row is found bit by bit on the scores'
+    order-preserving integer image (32 counting passes), then the position
+    up to which its ties are in (one pass a bit of ``L``)."""
+    L = scores.shape[-1]
+    live = scores > _NEG / 2
+    if topk >= L:
+        return live
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    # float order as unsigned order; a dead key sorts below everything
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7fffffff), bits)
+    u = jnp.where(live, jax.lax.bitcast_convert_type(key, jnp.uint32)
+                  ^ jnp.uint32(0x80000000), jnp.uint32(0))
+
+    def count(m):
+        return jnp.sum(m, axis=-1, dtype=jnp.int32)
+
+    def value_bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(count(u >= cand[:, None]) >= topk, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, value_bit,
+                            jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = (u > thr[:, None]) & live
+    tied = (u == thr[:, None]) & live
+    need = topk - count(above)               # ties that are in: the first
+    pos = jnp.arange(L, dtype=jnp.int32)
+    nbits = max(1, (L - 1).bit_length())
+
+    def pos_bit(i, last):
+        cand = last | (jnp.int32(1) << (nbits - 1 - i))
+        return jnp.where(count(tied & (pos < cand[:, None])) < need, cand,
+                         last)
+
+    last = jax.lax.fori_loop(0, nbits, pos_bit,
+                             jnp.zeros(scores.shape[:-1], jnp.int32))
+    return above | (tied & (pos <= last[:, None]))
+
+
+def _gather_rows(pool_a, layer, table, sel, block_size: int):
+    """Rows at logical positions ``sel (..., K)`` of one request (``table
+    (W,)``) or of one request a row (``table (N, W)``)."""
+    blk = (jnp.take_along_axis(table, sel // block_size, axis=-1)
+           if table.ndim == 2 else jnp.take(table, sel // block_size))
+    return pool_a[layer, blk, sel % block_size]
+
+
+def _full_decode_scores(qi, w, keys, pos):
+    """``(R, L)`` indexer scores of one query a row against its own keys
+    ``(R, L, Di)``; a key after the row's position reads -1e30."""
+    s = jnp.einsum("rhd,rld->rhl", qi, keys,
+                   preferred_element_type=jnp.float32)
+    sc = jnp.einsum("rh,rhl->rl", w, jax.nn.relu(s))
+    live = jnp.arange(keys.shape[1])[None, :] <= pos[:, None]
+    return jnp.where(live, sc, _NEG)
+
+
+def _window_rows(pool, wi, table, pos, P: int, block_size: int):
+    """``(rows (N, P + 1, row), valid)`` of the positions ``pos - P .. pos``
+    of each of N requests (``table (N, W)``)."""
+    at = pos[:, None] - P + jnp.arange(P + 1)[None, :]
+    rows = _gather_rows(pool.wkv, wi, table, jnp.maximum(at, 0), block_size)
+    return rows, at >= 0
+
+
+def _stats(moe, scored, selected, queries, prefill_scored, prefill_selected):
+    return jnp.concatenate([moe, jnp.stack([
+        jnp.asarray(v, jnp.float32) for v in (
+            scored, selected, queries, prefill_scored, prefill_selected)])])
+
+
+@functools.lru_cache(maxsize=64)
+def make_latent_decode_fn(cfg: Dots3Config, block_size: int):
+    """The jitted packed decode step: R requests feed one token each at
+    their own positions. ``tables (R, 2, W)``."""
+    bs = block_size
+    full_of = {li: i for i, li in enumerate(cfg.layers_of(FULL))}
+    win_of = {li: i for i, li in enumerate(cfg.layers_of(SLIDING))}
+    P = cfg.window - 1
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def step(params, pool, toks, pos, tables):
+        g_tab, w_tab = tables[:, 0], tables[:, 1]
+        R, W = g_tab.shape
+        off = pos % bs
+        blk_g = jnp.take_along_axis(g_tab, (pos // bs)[:, None], 1)[:, 0]
+        blk_w = jnp.take_along_axis(w_tab, (pos // bs)[:, None], 1)[:, 0]
+        x = params["wte"][toks][:, None].astype(cfg.dtype)      # (R, 1, d)
+        moe = jnp.zeros((3,), jnp.float32)
+        selected = jnp.zeros((), jnp.float32)
+        for li, p in enumerate(params["blocks"]):
+            kind = cfg.layer_types[li]
+            a = cfg.dims(kind)
+            h = _rmsnorm(x, p["ln1_g"], eps=cfg.norm_eps)
+            c_q, q, c_kv, k_rope = dots3.latents(h, p, pos[:, None], cfg,
+                                                 kind)
+            row = dots3.cache_row(c_kv, k_rope[:, :, 0], a)[:, 0]
+            q_abs = dots3.absorb_q(q[:, 0], p, a)               # (R, H, row)
+            if kind == FULL:
+                fi = full_of[li]
+                ki = dots3.index_keys(h, p["idx"], pos[:, None], cfg)[:, 0]
+                with jax.named_scope("latent/scatter"):
+                    pool = pool._replace(
+                        kv=pool.kv.at[fi, blk_g, off].set(row),
+                        ki=pool.ki.at[fi, blk_g, off].set(ki))
+                qi, w = dots3.index_queries(c_q, h, p["idx"], pos[:, None],
+                                            cfg)
+                with jax.named_scope("latent/index_scores"):
+                    keys = pool.ki[fi, g_tab].reshape(R, W * bs, -1)
+                    sel, valid = _pick_rows(_full_decode_scores(
+                        qi[:, 0], w[:, 0], keys, pos), cfg.index_topk)
+                with jax.named_scope("latent/gather"):
+                    rows = _gather_rows(pool.kv, fi, g_tab, sel, bs)
+                selected = selected + jnp.sum(valid)
+            else:
+                wi = win_of[li]
+                with jax.named_scope("latent/scatter"):
+                    pool = pool._replace(
+                        wkv=pool.wkv.at[wi, blk_w, off].set(row))
+                with jax.named_scope("latent/gather"):
+                    rows, valid = _window_rows(pool, wi, w_tab, pos, P, bs)
+            with jax.named_scope("latent/attention"):
+                o = dots3.unabsorb_v(
+                    dots3.latent_attend(q_abs, rows, valid, a), p, a)
+            x = x + dots3.headwise_gate(o[:, None], h, p)
+            x, layer = dots3.ffn(x, p, cfg)
+            moe = dots3.fold_moe_stats(moe, layer)
+        nf = len(full_of)
+        pool = pool._replace(stats=_stats(
+            moe, jnp.sum(pos + 1) * nf, selected, R * nf, 0.0, 0.0))
+        return dots3.readout(params, x, cfg)[:, 0], pool
+
+    return step
+
+
+@functools.lru_cache(maxsize=256)
+def make_latent_prefill_fn(cfg: Dots3Config, block_size: int, chunk_len: int,
+                           with_readout: bool = True):
+    """The jitted prefill chunk of one request: ``C`` tokens at ``pos0``,
+    ``table (2, W)``. ``with_readout=False`` returns ``(None, pool)``."""
+    bs, C = block_size, chunk_len
+    full_of = {li: i for i, li in enumerate(cfg.layers_of(FULL))}
+    win_of = {li: i for i, li in enumerate(cfg.layers_of(SLIDING))}
+    P = cfg.window - 1
+    a_full = cfg.dims(FULL)
+
+    def full_attend(p, q, qi, w, pool, fi, g_tab, pos0):
+        """A full layer's attention for the chunk: ``(o (1, C, H, v), keys
+        picked)``. Indexer scores against the cached keys
+        (``ops/dsa_index.py``), the picked set as a mask
+        (:func:`select_mask`), k and v materialised from the request's
+        latent rows a group of heads at a time, and the flash forward kernel
+        over exactly the picked pairs (``mla_sparse_attn``). No row is
+        gathered one by one: XLA's gather of 2,048 rows a query ran at a
+        tenth of the memory's rate and, with the sort behind ``top_k``, was
+        three fifths of an iteration (PERF.md section 6, PR 35)."""
+        a = a_full
+        Lw, G = g_tab.shape[0] * bs, _KEY_BUCKET
+        H, Hg = a.heads, min(_HEAD_GROUP, a.heads)
+        if H % Hg:
+            Hg = H
+        wkv = p["wkv_b"].reshape(a.kv_rank, H // Hg, Hg, a.nope + a.v)
+        qg = q.reshape(1, C, H // Hg, Hg, a.nope + a.rope)
+
+        def over(n_keys):
+            def attend(kv_pool, ki_pool):
+                tab = g_tab[:n_keys // bs]
+                keys = ki_pool[fi, tab].reshape(n_keys, -1)
+                mask = select_mask(index_scores(qi, keys, w, pos0),
+                                   cfg.index_topk)
+                rows = kv_pool[fi, tab].reshape(1, n_keys, -1)
+                c_kv, k_rope = rows[..., :a.kv_rank], rows[..., a.kv_rank:a.row]
+
+                def group(args):
+                    wg, qq = args                 # (r, Hg, e), (1, C, Hg, d)
+                    kv = jnp.einsum(
+                        "blr,rhe->blhe", c_kv, wg.astype(c_kv.dtype),
+                        preferred_element_type=jnp.float32).astype(c_kv.dtype)
+                    k = jnp.concatenate([kv[..., :a.nope], jnp.broadcast_to(
+                        k_rope[:, :, None, :], (1, n_keys, Hg, a.rope))], -1)
+                    return flash_attention_masked(
+                        qq, k, kv[..., a.nope:], mask.astype(jnp.int8), pos0,
+                        0, name="mla_sparse_attn")
+
+                o = jax.lax.map(group, (jnp.moveaxis(wkv, 1, 0),
+                                        jnp.moveaxis(qg, 2, 0)))
+                # (groups, 1, C, Hg, v) -> (1, C, H, v)
+                return (jnp.moveaxis(o, 0, 2).reshape(1, C, H, a.v),
+                        jnp.sum(mask).astype(jnp.float32))
+            return attend
+
+        if Lw <= G or Lw % G or G % bs:
+            return over(Lw)(pool.kv, pool.ki)
+        n = Lw // G
+        return jax.lax.switch(jnp.minimum((pos0 + C - 1) // G, n - 1),
+                              [over((i + 1) * G) for i in range(n)],
+                              pool.kv, pool.ki)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def chunk(params, pool, tokens, pos0, table):
+        g_tab, w_tab = table[0], table[1]
+        positions = pos0 + jnp.arange(C)
+        off = positions % bs
+        blk_g = jnp.take(g_tab, positions // bs)
+        blk_w = jnp.take(w_tab, positions // bs)
+        x = params["wte"][tokens].astype(cfg.dtype)             # (1, C, d)
+        moe = jnp.zeros((3,), jnp.float32)
+        selected = jnp.zeros((), jnp.float32)
+        for li, p in enumerate(params["blocks"]):
+            kind = cfg.layer_types[li]
+            a = cfg.dims(kind)
+            h = _rmsnorm(x, p["ln1_g"], eps=cfg.norm_eps)
+            c_q, q, c_kv, k_rope = dots3.latents(h, p, positions, cfg, kind)
+            row = dots3.cache_row(c_kv, k_rope[:, :, 0], a)[0]
+            if kind == FULL:
+                fi = full_of[li]
+                ki = dots3.index_keys(h, p["idx"], positions, cfg)[0]
+                with jax.named_scope("latent/scatter"):
+                    pool = pool._replace(
+                        kv=pool.kv.at[fi, blk_g, off].set(row),
+                        ki=pool.ki.at[fi, blk_g, off].set(ki))
+                qi, w = dots3.index_queries(c_q, h, p["idx"], positions, cfg)
+                with jax.named_scope("latent/sparse_attention"):
+                    o, picked = full_attend(p, q, qi[0], w[0], pool, fi,
+                                            g_tab, pos0)
+                selected = selected + picked
+            else:
+                wi = win_of[li]
+                with jax.named_scope("latent/scatter"):
+                    pool = pool._replace(
+                        wkv=pool.wkv.at[wi, blk_w, off].set(row))
+                with jax.named_scope("latent/window_attention"):
+                    # the window - 1 rows before the chunk (those before
+                    # position 0 are padding the mask never lets through)
+                    # and the chunk's own, k and v materialised
+                    before = pos0 - P + jnp.arange(P)
+                    prev = _gather_rows(pool.wkv, wi, w_tab,
+                                        jnp.maximum(before, 0), bs)
+                    lat = jnp.concatenate([prev, row])[None]
+                    k, v = mla_expand(
+                        lat[..., :a.kv_rank],
+                        lat[:, :, None, a.kv_rank:a.row], p,
+                        n_heads=a.heads, nope=a.nope, v_dim=a.v)
+                    o = flash_attention_window(q, k, v, pos0, pos0 - P,
+                                               cfg.window)
+            x = x + dots3.headwise_gate(o, h, p)
+            x, layer = dots3.ffn(x, p, cfg)
+            moe = dots3.fold_moe_stats(moe, layer)
+        nf = len(full_of)
+        scored = (C * pos0 + C * (C + 1) // 2) * nf
+        pool = pool._replace(stats=_stats(
+            moe, scored, selected, C * nf, scored, selected))
+        logits = dots3.readout(params, x, cfg) if with_readout else None
+        return logits, pool
+
+    return chunk

@@ -124,6 +124,21 @@ class PoolState(NamedTuple):
     v_scale: Optional[jnp.ndarray] = None
 
 
+def kv_pool_state(cfg: GPTConfig, block_size: int, pool_blocks: int,
+                  kv_heads: int, quant: bool) -> PoolState:
+    """The zeroed k/v pool of the GPT family."""
+    shape = (cfg.n_layers, pool_blocks, block_size, kv_heads * cfg.head_dim)
+    if quant:
+        return PoolState(
+            k=jnp.zeros(shape, jnp.int8),
+            v=jnp.zeros(shape, jnp.int8),
+            k_scale=jnp.zeros(shape[:-1] + (kv_heads,), jnp.float32),
+            v_scale=jnp.zeros(shape[:-1] + (kv_heads,), jnp.float32),
+        )
+    return PoolState(k=jnp.zeros(shape, cfg.dtype),
+                     v=jnp.zeros(shape, cfg.dtype))
+
+
 class PoolExhausted(RuntimeError):
     """A block allocation could not be satisfied — the scheduler's cue
     to preempt (it should never escape to callers)."""
@@ -168,9 +183,14 @@ class PagedKVCache:
     keeps every width bit-comparable to the solo dense run.
     """
 
-    def __init__(self, cfg: GPTConfig, *, block_size: int,
+    def __init__(self, cfg, *, block_size: int,
                  pool_blocks: int, max_batch: int,
-                 h_loc: Optional[int] = None, quant: bool = False):
+                 h_loc: Optional[int] = None, quant: bool = False,
+                 layout=None):
+        """``layout``: ``(block_size, pool_blocks) -> families.PoolLayout``,
+        the model family's answer to what the pools hold (``Scheduler``
+        passes its family's); None is the GPT family's k/v pool of ``h_loc``
+        heads."""
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1; got {block_size}")
         self.cfg = cfg
@@ -185,21 +205,26 @@ class PagedKVCache:
                 "(per-request fit is validated at Scheduler.submit)")
         self.pool_blocks = pool_blocks
         self.quant = quant
-        h = h_loc if h_loc is not None else cfg.kv_heads
-        self.kv_heads = h
-        shape = (cfg.n_layers, pool_blocks, block_size, h * cfg.head_dim)
-        if quant:
-            self.state = PoolState(
-                k=jnp.zeros(shape, jnp.int8),
-                v=jnp.zeros(shape, jnp.int8),
-                k_scale=jnp.zeros(shape[:-1] + (h,), jnp.float32),
-                v_scale=jnp.zeros(shape[:-1] + (h,), jnp.float32),
-            )
-        else:
-            self.state = PoolState(
-                k=jnp.zeros(shape, cfg.dtype),
-                v=jnp.zeros(shape, cfg.dtype),
-            )
+        if layout is None:
+            # a direct caller's k/v pool of ``h_loc`` heads: the layout the
+            # GPT family answers, made here, so that one path builds a pool
+            from byteps_tpu.serve.families import PoolLayout
+            h = h_loc if h_loc is not None else cfg.kv_heads
+            layout = lambda bs_, nb_: PoolLayout(       # noqa: E731
+                state=kv_pool_state(cfg, bs_, nb_, h, quant), kv_heads=h)
+        lay = layout(block_size, pool_blocks)
+        self.kv_heads, self.state = lay.kv_heads, lay.state
+        self.window, window_blocks = lay.window, lay.window_blocks
+
+        # the window kind: layers that keep the blocks holding a request's
+        # last ``window`` positions and hand the others back while it runs.
+        # Blocks of a pool of their own (another row width), one allocator:
+        # a free list, a table per request (logical block -> physical, the
+        # released ones gone) and the same leak account
+        self.window_blocks = window_blocks
+        self._wfree: List[int] = list(range(window_blocks - 1, 0, -1))
+        self._wtables: Dict[object, Dict[int, int]] = {}
+        self._wnext: Dict[object, int] = {}   # first block never allocated
         # LIFO free list over blocks 1..NB-1 (0 = scratch, reserved)
         self._free: List[int] = list(range(pool_blocks - 1, 0, -1))
         self._tables: Dict[object, List[int]] = {}
@@ -227,6 +252,13 @@ class PagedKVCache:
         seq = next(_POOL_SEQ)
         self._g_in_use = _reg.gauge(f"serve.pool{seq}.kv_blocks_in_use")
         self._g_prefix = _reg.gauge(f"serve.pool{seq}.prefix_blocks")
+        if self.window is not None:
+            self._g_latent = _reg.gauge(
+                f"serve.pool{seq}.latent_blocks_in_use")
+            self._g_window = _reg.gauge(
+                f"serve.pool{seq}.window_blocks_in_use")
+            self._c_released = _reg.counter(
+                "serve.cache.window_blocks_released")
         self._c_alloc_fail = _reg.counter("serve.kv_alloc_failures")
         self._c_prefix_evict = _reg.counter("serve.prefix_evictions")
 
@@ -262,8 +294,17 @@ class PagedKVCache:
     def leaked_blocks(self) -> int:
         """Blocks neither free nor referenced by a live table or the
         prefix index — must be 0 at drain (the CI smoke's leak pin)."""
-        return (self.pool_blocks - 1) - len(self._free) \
+        leaked = (self.pool_blocks - 1) - len(self._free) \
             - len(self._live_blocks())
+        if self.window is not None:
+            leaked += (self.window_blocks - 1) - len(self._wfree) \
+                - self.window_blocks_in_use
+        return leaked
+
+    @property
+    def window_blocks_in_use(self) -> int:
+        """Blocks of the window kind that live tables hold."""
+        return sum(len(t) for t in self._wtables.values())
 
     def reclaimable_blocks(self, exclude=()) -> int:
         """Blocks LRU eviction could actually return to the free list:
@@ -304,6 +345,9 @@ class PagedKVCache:
         if rid in self._tables:
             raise ValueError(f"request {rid!r} already registered")
         self._tables[rid] = []
+        if self.window is not None:
+            self._wtables[rid] = {}
+            self._wnext[rid] = 0
 
     def _alloc_block(self) -> int:
         b = self._free.pop()
@@ -369,6 +413,64 @@ class PagedKVCache:
         for b in reversed(table):
             self._decref(b)
         self._g_in_use.set(self.blocks_in_use)
+        if self.window is not None:
+            self._wfree.extend(self._wtables.pop(rid).values())
+            del self._wnext[rid]
+            self._set_kind_gauges()
+
+    def _kv_only(self, what: str) -> None:
+        if self.kv_heads == 0:
+            raise NotImplementedError(
+                f"PagedKVCache.{what}: latent pages have no k/v payload "
+                "(the GPT family's pool only)")
+
+    # -- the window kind ----------------------------------------------------
+    def _set_kind_gauges(self) -> None:
+        self._g_latent.set(self.blocks_in_use)
+        self._g_window.set(self.window_blocks_in_use)
+
+    def kind_widths(self, rid) -> tuple:
+        """Live blocks ``rid`` holds of each layer kind beyond the one every
+        family has — ``(window blocks,)``, or ``()`` without a window kind
+        (what ``serve.prefill_dispatch`` adds to its args)."""
+        return () if self.window is None else (len(self._wtables[rid]),)
+
+    def ensure_window(self, rid, n_tokens: int) -> None:
+        """Grow ``rid``'s window table to cover positions below
+        ``n_tokens`` (every logical block not allocated yet; those released
+        behind the window stay released). All or nothing, like
+        :meth:`ensure`; a cache with no window kind has nothing to do."""
+        if self.window is None:
+            return
+        lo, hi = self._wnext[rid], self.blocks_for(n_tokens)
+        if hi - lo > len(self._wfree):
+            self._c_alloc_fail.inc()
+            raise PoolExhausted(
+                f"request {rid!r} needs {hi - lo} more window block(s), the "
+                f"window pool has {len(self._wfree)} free of "
+                f"{self.window_blocks - 1}")
+        table = self._wtables[rid]
+        for b in range(lo, hi):
+            table[b] = self._wfree.pop()
+        self._wnext[rid] = max(lo, hi)
+        self._set_kind_gauges()
+
+    def release_behind(self, rid, fill: int) -> int:
+        """Hand back the window blocks no later query of ``rid`` can see:
+        the next query sits at ``fill`` and sees ``window - 1`` positions
+        before it. Returns how many were freed (0 without a window
+        kind)."""
+        if self.window is None:
+            return 0
+        table = self._wtables[rid]
+        dead = [b for b in table
+                if (b + 1) * self.block_size <= fill - (self.window - 1)]
+        for b in dead:
+            self._wfree.append(table.pop(b))
+        if dead:
+            self._c_released.inc(len(dead))
+            self._set_kind_gauges()
+        return len(dead)
 
     def adopt_prefix(self, rid, blocks: List[int]) -> None:
         """Seed ``rid``'s (empty) table with shared prefix pages from a
@@ -601,6 +703,14 @@ class PagedKVCache:
         t = self._tables[rid]
         if w < len(t):
             raise ValueError(f"width {w} < live table {len(t)}")
+        if self.window is not None:
+            # line 0 the global kind, line 1 the window kind (0, the
+            # scratch block, where a block was released or never held)
+            rows = np.zeros((2, w), np.int32)
+            rows[0, :len(t)] = t
+            wt = self._wtables[rid]
+            rows[1, list(wt)] = list(wt.values())
+            return rows
         row = np.zeros(w, np.int32)
         row[:len(t)] = t
         return row
@@ -614,6 +724,7 @@ class PagedKVCache:
         carry whatever the recycled block held — the receiving gather's
         zero-mask keeps them out of the math, exactly as it does
         locally)."""
+        self._kv_only("snapshot_blocks")
         from byteps_tpu.serve.kv_wire import BlockPayload
 
         if hi <= lo:
@@ -644,6 +755,7 @@ class PagedKVCache:
         regardless of block count. Payload dtypes are the pool's own
         (the wire codec round-trips bytes, never values), so this write
         is bit-exact by construction."""
+        self._kv_only("write_payloads")
         if not block_ids:
             return
         idx = jnp.asarray(list(block_ids), jnp.int32)
@@ -681,6 +793,7 @@ class PagedKVCache:
         invisible — but a long-lived replica's pool walks toward high
         ids and compaction restores allocation locality for the gather.
         Returns the number of blocks moved."""
+        self._kv_only("defrag")
         live = sorted(self._live_blocks())
         perm = np.arange(self.pool_blocks)
         moved = 0
@@ -823,9 +936,8 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     block (``ensure_writable``) before this step scatters into
     ``tables[r][pos // bs]``, so the scatter below only ever lands in a
     private block (or scratch).
-    Dense-MLP GPT families only (the MoE block's no-drop capacity
-    logic hasn't been paged yet — detected from the params and
-    rejected loudly).
+    Dense-MLP GPT families only (``families.GPTFamily`` refuses a tree
+    with a Switch-routed layer when the scheduler is built).
 
     Multi-tenant variant: ``lora_sig=(targets, rank_bucket,
     n_adapter_slots)`` makes the step accept two trailing arguments —
@@ -921,10 +1033,6 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
             tables, (pos // block_size)[:, None], axis=1)[:, 0]
         off = pos % block_size
         for li, p in enumerate(params["blocks"]):
-            if "moe" in p:
-                raise NotImplementedError(
-                    "the paged decode step serves dense-MLP GPT families "
-                    "only — MoE routing hasn't been paged yet")
             delta = None if slabs is None else _slab_delta(slabs, slots, li)
             x, pool = attn_half(
                 x, p, cfg.head_dim, lambda: pos[:, None],

@@ -109,14 +109,8 @@ from byteps_tpu.common.tracing import get_tracer
 from byteps_tpu.models.generate import gpt_apply_cached, init_cache
 from byteps_tpu.models.gpt import GPTConfig
 from byteps_tpu.models.speculative import _verify_commit
-from byteps_tpu.serve.paged_cache import (
-    PagedKVCache,
-    PoolExhausted,
-    decode_uses_paged_attn,
-    make_paged_decode_fn,
-    make_paged_prefill_fn,
-    serve_operands,
-)
+from byteps_tpu.serve.families import serve_family
+from byteps_tpu.serve.paged_cache import PagedKVCache, PoolExhausted
 
 log = get_logger("serve.scheduler")
 
@@ -345,10 +339,26 @@ class Scheduler:
                 "serve: block_size %d does not divide max_seq %d — the "
                 "gathered views carry a zero tail past max_seq (correct, "
                 "slightly wasteful)", bs, cfg.max_seq)
-        kv_loc = params["blocks"][0]["wk"].shape[-1] // cfg.head_dim
-        self.cache = PagedKVCache(cfg, block_size=bs, pool_blocks=nb,
-                                  max_batch=self.max_batch, h_loc=kv_loc,
-                                  quant=quant)
+        # the one seam to the model: pools by layer kind, the two programs
+        # and the operand tree come from the family the configuration's
+        # type names, and what its cache layout cannot carry is refused
+        # here, by name
+        self._family = serve_family(cfg)
+        self._family.validate(params, cfg, dict(
+            prefix_cache=prefix_cache, adapter_pool=adapter_pool is not None,
+            quant_cache=quant, role=role != "both",
+            tp_axis=tp_axis is not None))
+        if self._prefix_on and not self._family.shares_prefixes:
+            log.info("serve: the configured prefix cache is not applied: "
+                     "the %s family's pages are not shared", self._family.name)
+        self._prefix_on = self._prefix_on and self._family.shares_prefixes
+        self.cache = PagedKVCache(
+            cfg, block_size=bs, pool_blocks=nb, max_batch=self.max_batch,
+            quant=quant, layout=lambda bs_, nb_: self._family.layout(
+                params, cfg, block_size=bs_, pool_blocks=nb_,
+                max_batch=self.max_batch, prefill_chunk=self.prefill_chunk,
+                quant=quant))
+        self._late = self._family.late_stats()
         # the packed decode step is built LAZILY (first decode touch):
         # a prefill-only replica must never trace/compile it — that is
         # the dedicated replica's cold-start and HBM win, asserted in
@@ -431,7 +441,7 @@ class Scheduler:
         # (SpecPolicy.draft_params) is not prepared
         with get_tracer().span("serve.prepare_operands", "SERVE"):
             self._operands = jax.block_until_ready(
-                serve_operands(params, cfg))
+                self._family.operands(params, cfg))
         theirs = {id(w) for w in jax.tree_util.tree_leaves(params)}
         cast = [w for w in jax.tree_util.tree_leaves(self._operands)
                 if id(w) not in theirs]
@@ -452,6 +462,7 @@ class Scheduler:
             raise ValueError(f"max_new must be >= 1; got {req.max_new}")
         spec_k = 0
         if req.spec is not None:
+            self._family.validate_request(req, self.cfg)
             if req.temperature != 0.0:
                 raise ValueError(
                     "speculative policies are greedy-only "
@@ -737,8 +748,8 @@ class Scheduler:
         # the factory is lru-cached process-wide — every replica shares
         # one jit wrapper per (cfg, block_size, C, readout)
         self._prefill_built = True
-        return make_paged_prefill_fn(self.cfg, self.cache.block_size, C,
-                                     self.tp_axis, with_readout)
+        return self._family.prefill_fn(self.cfg, self.cache.block_size, C,
+                                       self.tp_axis, with_readout)
 
     def _decode_step(self):
         """The packed decode step, built on first decode touch. A
@@ -760,14 +771,13 @@ class Scheduler:
                 ap = self.adapter_pool
                 lora_sig = (tuple(ap.targets), ap.rank_bucket,
                             ap.n_slots)
-            self._decode_fn = make_paged_decode_fn(
+            self._decode_fn = self._family.decode_fn(
                 self.cfg, self.cache.block_size, self.tp_axis, lora_sig)
             # the same question the step asks when it is traced: does
             # its attention read the pool in place (the Pallas kernel)
             # or gather a dense view (serve.decode_steps_paged_attn)
-            self._decode_paged_attn = decode_uses_paged_attn(
-                self.cfg, self.cache.block_size, self.cache.kv_heads,
-                self.cache.quant)
+            self._decode_paged_attn = \
+                self._family.decode_reads_pool_in_place(self.cfg, self.cache)
         return self._decode_fn
 
     def _params_for(self, run: _Run):
@@ -1043,6 +1053,7 @@ class Scheduler:
         while True:
             try:
                 self.cache.ensure(run.req.rid, n_tokens)
+                self.cache.ensure_window(run.req.rid, n_tokens)
                 if write_lo is not None:
                     self.cache.ensure_writable(run.req.rid, write_lo,
                                                write_hi)
@@ -1347,9 +1358,15 @@ class Scheduler:
                     len(run.full_input) - run.prefill_done)
             toks = run.full_input[run.prefill_done:run.prefill_done + C]
             final = run.prefill_done + C == len(run.full_input)
+            if self.cache.window is not None:
+                # the window kind grows chunk by chunk (and shrinks behind
+                # it): its blocks for this chunk's rows, or a preemption
+                if not self._ensure_or_preempt(run, run.prefill_done + C):
+                    break
             W = self._width(run.req.rid)
             with tr.span("serve.prefill_dispatch", "SERVE",
-                         (run.req.rid, C, W, final)):
+                         (run.req.rid, C, W, final)
+                         + self.cache.kind_widths(run.req.rid)):
                 # the chunk scatters C rows — CoW any shared page in its
                 # span (a no-op by construction: admission already CoW'd
                 # the divergence block; enforced, not assumed)
@@ -1364,6 +1381,9 @@ class Scheduler:
                     jnp.asarray(self.cache.table_row(run.req.rid, W)))
             run.prefill_done += C
             run.cache_len = run.prefill_done
+            self.cache.release_behind(run.req.rid, run.cache_len)
+            if self._late is not None:
+                self._late.note(self.cache.state)
             self._m["prefill_tokens"].inc(C)
             if self._prefix_on:
                 # publish the newly fully-written leading blocks so the
@@ -1444,13 +1464,14 @@ class Scheduler:
             W = max(self._width(r.req.rid) for r in packed)
             toks = np.zeros(R, np.int32)
             pos = np.zeros(R, np.int32)
-            tables = np.zeros((R, W), np.int32)
+            rows = [self.cache.table_row(r.req.rid, W) for r in packed]
+            tables = np.zeros((R,) + rows[0].shape, np.int32)
             seeds = np.zeros(R, np.int32)
             temps = np.zeros(R, np.float32)
             for i, run in enumerate(packed):
                 toks[i] = run.pending
                 pos[i] = run.cache_len
-                tables[i] = self.cache.table_row(run.req.rid, W)
+                tables[i] = rows[i]
                 seeds[i] = run.req.seed
                 temps[i] = run.req.temperature
             extra = ()
@@ -1472,12 +1493,15 @@ class Scheduler:
             picked = self._pick(
                 logits, jnp.asarray(seeds), jnp.asarray(pos + 1),
                 jnp.asarray(temps))
+            if self._late is not None:
+                self._late.note(self.cache.state)
         with tr.span("serve.decode_sync", "SERVE"):
             picked = np.asarray(picked)    # the host blocks on the device
         with tr.span("serve.commit", "SERVE"):
             now = self._clock()
             for i, run in enumerate(packed):
                 run.cache_len += 1
+                self.cache.release_behind(run.req.rid, run.cache_len)
                 self._commit_token(run, int(picked[i]), now)
         self._m["decode_tokens"].inc(len(packed))
         if self._decode_paged_attn:
@@ -1499,6 +1523,14 @@ class Scheduler:
             jnp.asarray(run.full_input if tokens is None
                         else tokens)[None], dc)
         run.draft_cache = dc
+
+    def flush_stats(self) -> None:
+        """Observe what the dispatched programs counted and the registry has
+        not seen yet (a latent family's ``moe.*`` / ``serve.dsa.*``, a step
+        late otherwise), waiting for the device: for a reading at a fixed
+        point, outside the hot loop."""
+        if self._late is not None:
+            self._late.drain(block=True)
 
     def serve(self, requests: List[Request], max_idle_iters: int = 10000):
         """Submit + drain convenience for tests/bench: runs ``step()``

@@ -362,3 +362,91 @@ def test_serve_program_reads_prepared_operands_as_they_are(
         r"-start\(%%params__blocks___\d+___(%s)__" % "|".join(_PROJECTED), ln)]
     assert moves and not [ln for ln in moves if "f32[" in ln], moves[:3]
     assert compiled.cost_analysis()["bytes accessed"] < 7e9
+
+
+# --- the dots3 serving cell's two programs at the cut configuration ---------
+_DOTS_BS, _DOTS_BATCH, _DOTS_CHUNK = 128, 16, 2048
+
+
+def _dots3_on(topo):
+    """The cut dots3-note-prev (``benchmark/configs/dots3-note-ep8.json``:
+    5 layers, 32 of 256 experts, 19,072 vocabulary rows, bf16), its
+    parameters and latent pool as shapes on the described chip."""
+    from byteps_tpu.models.dots3 import Dots3Config, dots3_init
+    from byteps_tpu.serve.families import serve_family
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = Dots3Config(vocab_size=19072, max_seq=32768, n_layers=5,
+                      experts_held=32)
+    shapes = jax.eval_shape(lambda: dots3_init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), shapes)
+    pool = jax.eval_shape(lambda: serve_family(cfg).layout(
+        shapes, cfg, block_size=_DOTS_BS, pool_blocks=1 + 20 * 256,
+        max_batch=_DOTS_BATCH, prefill_chunk=_DOTS_CHUNK,
+        quant=False).state)
+    pool = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), pool)
+    return cfg, params, pool, on_chip
+
+
+def _bytes(tree) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("program", ["chunk_c2048_w256", "decode_r16_w256"])
+def test_dots3_serve_program_fits_and_leaves_the_pool_in_place(
+        topo, as_on_tpu, program):
+    """The proof of Mosaic lowering that needs no chip: the 2,048-token
+    chunk program and the 16-row decode step at the table width of 32,768
+    positions compile for the described v5e. The weights are 8.17 GB in bf16
+    (within 5%), arguments are the weights, the pool and a few vectors and
+    nothing else, the pool is updated in place (no instruction makes a second
+    one), no ``(64, C, L)`` indexer intermediate exists, and weights + pool +
+    temporaries fit the chip's 16 GB."""
+    from byteps_tpu.serve.latent_step import (
+        make_latent_decode_fn,
+        make_latent_prefill_fn,
+    )
+
+    cfg, params, pool, on_chip = _dots3_on(topo)
+    W = 32768 // _DOTS_BS
+    if program.startswith("chunk"):
+        compiled = make_latent_prefill_fn(cfg, _DOTS_BS, _DOTS_CHUNK, False)\
+            .lower(params, pool, on_chip((1, _DOTS_CHUNK), I32),
+                   on_chip((), I32), on_chip((2, W), I32)).compile()
+    else:
+        compiled = make_latent_decode_fn(cfg, _DOTS_BS).lower(
+            params, pool, on_chip((_DOTS_BATCH,), I32),
+            on_chip((_DOTS_BATCH,), I32),
+            on_chip((_DOTS_BATCH, 2, W), I32)).compile()
+    weights, pages = _bytes(params), _bytes(pool)
+    assert abs(weights / 8.17e9 - 1) < 0.05, weights
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pages - 64            # donated, in place
+    assert mem.argument_size_in_bytes <= weights + pages + (1 << 20)
+    assert weights + pages + mem.temp_size_in_bytes < 15.5e9
+    hlo = compiled.as_text()
+    for name, a in zip(pool._fields, pool):
+        shape = "bf16[%s]" % ",".join(map(str, a.shape))
+        made = [op for op, aliased in _ops_with_result(hlo, shape)
+                if op not in ("parameter", "tuple", "get-tuple-element",
+                              "bitcast") and not aliased]
+        assert not made, (name, made)
+    # the indexer's scores never exist a head at a time: no (Hi, C, L)
+    assert not re.search(r"\[64,2048,32768\]|\[2048,64,32768\]", hlo)
+    n = _n_pallas(compiled)
+    if program.startswith("chunk"):
+        # an indexer-score call and a selected-attention call for each of
+        # the 4 key-bucket branches of each full layer, 3 window-flash
+        # calls, 3 grouped products for each expert layer but the last (no
+        # readout asks for its output, so its second half is not in the
+        # program)
+        assert n == 2 * 4 * 2 + 3 + 9, n
+        for name in ("dsa_index_scores", "mla_sparse_attn", "flash_fwd",
+                     "moe_gmm_fwd"):
+            assert name in hlo, name
+    else:
+        assert n == 12, n                   # the grouped products alone
