@@ -40,6 +40,23 @@ four-phase iteration (``step()``):
    rows scatter into the reserved scratch block): one token per
    request per iteration at heterogeneous positions.
 
+**The order of an iteration** — a step's tokens are read one step late.
+The packed step is issued first, its input tokens taken on the device
+from the picks of the step before it (``_take``), and only then does the
+host read that earlier step's tokens and commit them; the first token of
+a final chunk is read in its own iteration, after the decode step that
+already feeds on it was issued. So the device always has the next program
+queued while the host reads, commits, admits and packs. What the host
+needs before a token's value it knows without it: positions and table
+growth from ``cache_len`` (advanced at issue), whether a request goes on
+from the COUNT of its picked tokens. Only ``eos_id`` needs the value: a
+request that ends at eos has one row of the step already issued dropped.
+At most one decode step is ever unread; an iteration with no row to
+decode reads it before it returns, and everything that reads or rewrites
+a request from outside the lanes (preemption, migration either way,
+``drain_incomplete``, a kill) calls ``_drain_in_flight`` first. Tokens,
+their order and the cache contents are what a read-at-once loop gives.
+
 **Preemption** — when a block allocation fails, the youngest admitted
 request is evicted: its blocks free immediately, its committed tokens
 are kept, and it re-queues with ``prompt + emitted`` as the recompute
@@ -114,6 +131,12 @@ from byteps_tpu.serve.paged_cache import PagedKVCache, PoolExhausted
 
 log = get_logger("serve.scheduler")
 
+# why an unread decode step was read with no step queued behind it: the
+# iteration had no row to decode; a preemption needed every run's tokens on
+# the host; a run was leaving for, or arriving from, another replica; the
+# fault plan killed the replica
+_DRAIN_CAUSES = ("idle", "preempt", "migrate", "kill")
+
 # global replica instance sequence for per-replica gauge series (the
 # PR 6 scheduler.s<N> pattern — replica_id is caller-chosen and two
 # fresh replicas may both say 0)
@@ -128,7 +151,9 @@ def _make_pick_fn(vocab_size: int):
     (fold_in by absolute position, invariant to batch packing), so the
     bit-exact greedy contract can never drift from make_generate_fn's.
     lru-cached like the paged-step factories: fresh replicas (bench
-    reps, failover respawns) must reuse the compiled programs."""
+    reps, failover respawns) must reuse the compiled programs. Returns
+    ``(pick, pick_last)``: over a decode step's rows, and over the last
+    position of a final chunk's ``(1, C, vocab)`` logits."""
     from byteps_tpu.models.generate import make_pick, make_truncate
 
     pick1 = make_pick(make_truncate(None, None, vocab_size))
@@ -140,7 +165,51 @@ def _make_pick_fn(vocab_size: int):
         return jax.vmap(lambda l, k, t: pick1(l[None], k, t)[0])(
             logits, keys, temps)
 
-    return jax.jit(pick)
+    # the same pick on a chunk's last position, sliced on the device inside
+    # the one call: only vocab floats would cross to host, never the whole
+    # (1, C, vocab) chunk, and no eager slice is dispatched for it
+    return jax.jit(pick), jax.jit(
+        lambda logits, seeds, pos, temps: pick(logits[:, -1], seeds, pos,
+                                               temps))
+
+
+# columns of the one host array a packed decode step is issued with (a
+# transfer costs the host ~0.25 ms on a v5e machine whatever its size,
+# PERF.md §6, PR 36: seven of them were most of an iteration); the row's
+# block table follows them, flattened
+_TOK, _SRC, _POS, _SEED, _TEMP, _SLOT, _N_COLS = range(7)
+
+
+@functools.partial(jax.jit, static_argnames="table_shape")
+def _take(picked, first, host, table_shape):
+    """The packed step's operands from ONE host array, and its input tokens
+    taken on the device: row ``i``'s token is element ``src[i]`` of the
+    unread decode step's picks, the unread first token of the request whose
+    final chunk was just issued, and the tokens the host already holds,
+    laid end to end. So a step can be issued before the tokens of the one
+    before it are read. Returns ``(toks, pos, tables, seeds, pos + 1,
+    temps, slots)``; temperatures cross as their bits."""
+    toks = jnp.concatenate([picked, first, host[:, _TOK]])[host[:, _SRC]]
+    pos = host[:, _POS]
+    return (toks, pos,
+            host[:, _N_COLS:].reshape((host.shape[0],) + table_shape),
+            host[:, _SEED], pos + 1,
+            jax.lax.bitcast_convert_type(host[:, _TEMP], jnp.float32),
+            host[:, _SLOT])
+
+
+class _InFlight:
+    """The packed decode step whose tokens the host has not read: the
+    device array ``_pick`` returned, the runs of its rows, the position
+    each row wrote, and the row of each request in it."""
+
+    __slots__ = ("picked", "runs", "pos", "rows")
+
+    def __init__(self, picked, runs: List["_Run"], pos: np.ndarray):
+        self.picked = picked
+        self.runs = runs
+        self.pos = pos
+        self.rows = {run.req.rid: i for i, run in enumerate(runs)}
 
 
 @dataclasses.dataclass
@@ -365,7 +434,7 @@ class Scheduler:
         # tests/test_serve_disagg.py
         self._decode_fn = None
         self._decode_paged_attn = False   # set with _decode_fn
-        self._pick = _make_pick_fn(cfg.vocab_size)
+        self._pick, self._pick_last = _make_pick_fn(cfg.vocab_size)
         self._draft_steps: Dict[int, Any] = {}
         self._plan = fault_plan if fault_plan is not None \
             else plan_from_env(worker_id=replica_id)
@@ -388,6 +457,14 @@ class Scheduler:
         self._waiting: deque = deque()
         self._running: List[_Run] = []
         self._runs: Dict[Any, _Run] = {}
+        # what the device has picked and the host has not read: the packed
+        # decode step issued last, and the first token of the request whose
+        # final chunk this iteration issued. A step is issued before the
+        # one before it is read, so the device never waits for the host to
+        # commit, admit and pack (module docstring, "The order of an
+        # iteration")
+        self._flight: Optional[_InFlight] = None
+        self._first: Optional[tuple] = None      # (run, (1,) device array)
         self.results: Dict[Any, Dict[str, Any]] = {}
         # admit a little past the decode-slot count so a finished
         # request's slot refills from a PREFILLED standby instead of
@@ -405,6 +482,17 @@ class Scheduler:
             "decode_tokens": _reg.counter("serve.decode_tokens"),
             "decode_steps_paged_attn": _reg.counter(
                 "serve.decode_steps_paged_attn"),
+            # the pipeline of one (docs/observability.md): decode steps
+            # issued while the step before them was unread; unread steps
+            # read with no decode step queued behind them, in all and by
+            # cause; rows of an issued step whose run had ended at eos by
+            # the time they were read
+            "decode_steps_overlapped": _reg.counter(
+                "serve.decode_steps_overlapped"),
+            "pipeline_drains": _reg.counter("serve.pipeline_drains"),
+            **{f"pipeline_drains.{c}": _reg.counter(
+                f"serve.pipeline_drains.{c}") for c in _DRAIN_CAUSES},
+            "decode_rows_dropped": _reg.counter("serve.decode_rows_dropped"),
             "spec_rounds": _reg.counter("serve.spec_rounds"),
             "spec_tokens": _reg.counter("serve.spec_tokens"),
             "prefix_hits": _reg.counter("serve.prefix_hits"),
@@ -523,7 +611,11 @@ class Scheduler:
 
     @property
     def finished(self) -> bool:
-        return not self._waiting and not self._running
+        """Nothing queued, nothing running and nothing unread: a request
+        whose last token the device has picked is not finished until a
+        ``step()`` has read and committed it."""
+        return (not self._waiting and not self._running
+                and self._flight is None and self._first is None)
 
     @property
     def dead(self) -> bool:
@@ -536,7 +628,9 @@ class Scheduler:
         """Pop every unfinished request (queued AND running), freeing
         their blocks; returns ``[(Request, emitted_tokens), ...]`` for
         the router to re-queue on a survivor. Completed results stay
-        readable — they were already delivered."""
+        readable — they were already delivered. An unread step is read
+        first: ``emitted`` is every token the device picked."""
+        self._drain_in_flight("migrate")
         out = []
         for run in list(self._running):
             self.cache.release(run.req.rid)
@@ -622,7 +716,11 @@ class Scheduler:
         replica — snapshot ALL its committed blocks, free them, and
         return the ticket the router ships to a sibling. Unlike
         :meth:`_preempt` nothing is recomputed: the tokens move, the
-        pool pressure drops NOW."""
+        pool pressure drops NOW. An unread step is read first (the ticket
+        carries every token the device picked); a request that read
+        finishes is no longer here to extract (``KeyError``, its result
+        is in ``results``)."""
+        self._drain_in_flight("migrate")
         run = self._runs.pop(rid)
         self._running.remove(run)
         nb = self.cache.blocks_for(run.cache_len)
@@ -684,6 +782,8 @@ class Scheduler:
                 self.cache.ensure(rid, ticket.cache_len + 1)
                 break
             except PoolExhausted:
+                if self._drain_in_flight("migrate"):
+                    continue       # what it finished gave its blocks back
                 victim = None
                 for cand in reversed(self._running):
                     if cand.state in ("prefill", "decode"):
@@ -778,6 +878,9 @@ class Scheduler:
             # or gather a dense view (serve.decode_steps_paged_attn)
             self._decode_paged_attn = \
                 self._family.decode_reads_pool_in_place(self.cfg, self.cache)
+            # what _take reads where nothing is unread
+            self._no_picked = jnp.zeros(self.max_batch, jnp.int32)
+            self._no_first = jnp.zeros(1, jnp.int32)
         return self._decode_fn
 
     def _params_for(self, run: _Run):
@@ -985,7 +1088,9 @@ class Scheduler:
     def _preempt(self, run: _Run) -> None:
         """Evict ``run`` under pool pressure: free its blocks, keep its
         committed tokens, re-queue at the FRONT for recompute-on-resume
-        (its next prefill input is prompt + emitted)."""
+        (its next prefill input is prompt + emitted). Nothing is unread
+        here: whoever picks a victim has called ``_drain_in_flight``, so
+        ``emitted`` is whole."""
         # the recompute bill: every committed KV row thrown away here
         # must be re-prefilled on resume (the request's own prefix
         # commits may refund part of it if they survive the pressure
@@ -1038,6 +1143,10 @@ class Scheduler:
                 if (need <= 0 or self._tenant_usage(run.tenant) + need
                         <= self._quota):
                     break
+                if self._drain_in_flight("preempt"):
+                    if run.state not in ("prefill", "decode"):
+                        return False         # the read tokens ended it
+                    continue
                 self._tenant_m(run.tenant)["quota_hits"].inc()
                 victim = None
                 for cand in reversed(self._running):
@@ -1059,6 +1168,13 @@ class Scheduler:
                                                write_hi)
                 return True
             except PoolExhausted:
+                # a victim's recompute input is prompt + emitted, and a
+                # run whose last token is unread still holds its blocks:
+                # read what is unread, then look again
+                if self._drain_in_flight("preempt"):
+                    if run.state not in ("prefill", "decode"):
+                        return False         # the read tokens ended it
+                    continue
                 victim = None
                 for cand in reversed(self._running):
                     if cand.state in ("prefill", "decode"):
@@ -1179,8 +1295,8 @@ class Scheduler:
     # -- the iteration ------------------------------------------------------
     def step(self) -> bool:
         """One scheduler iteration; returns True when any request made
-        progress (admission, a prefill chunk, a spec round, or at least
-        one decoded token)."""
+        progress (admission, a prefill chunk, a spec round, a decode step
+        issued, or a token read and committed)."""
         if self._dead:
             raise WorkerKilledError(
                 f"serve replica {self.replica_id} is dead")
@@ -1188,6 +1304,9 @@ class Scheduler:
             inj = self._plan.intercept("serve", -1)
             if inj is not None:
                 if inj.kind == "kill":
+                    # what the device has picked was served: the router's
+                    # drain_incomplete hands it to the survivor
+                    self._drain_in_flight("kill")
                     self._dead = True
                     get_flight_recorder().record_event(
                         "serve.replica_killed",
@@ -1319,11 +1438,24 @@ class Scheduler:
         return progress
 
     def _lanes(self, tr) -> bool:
-        """§2–4 of an iteration: one prefill chunk, the speculative
-        rounds, the packed decode step. True when any advanced."""
-        progress = False
+        """§2–4 of an iteration, in the order the device is kept busy by:
+        one prefill chunk issued, the speculative rounds, the packed decode
+        step issued, and only then the tokens of the step BEFORE it read
+        and committed (and the chunk's first token after them). True when
+        anything was issued or committed."""
+        progress = self._prefill_lane(tr)
+        progress = self._spec_lane(tr) or progress
+        unread, issued = self._issue_decode(tr)
+        if unread is not None and not issued:
+            self._note_drain("idle")
+        progress = self._read_decode(tr, unread) or progress
+        return self._read_first(tr) or issued or progress
 
-        # 2. prefill lane: ONE chunk for the oldest prefilling request
+    def _prefill_lane(self, tr) -> bool:
+        """§2: ONE chunk for the oldest prefilling request. A final chunk
+        leaves the request's first token picked and unread in
+        ``self._first``. True when a chunk was issued."""
+        progress = False
         for run in list(self._running):
             if run.state != "prefill":
                 continue
@@ -1373,12 +1505,13 @@ class Scheduler:
                 self.cache.ensure_writable(run.req.rid, run.prefill_done,
                                            run.prefill_done + C)
                 # intermediate chunks skip the vocab readout — only the
-                # final chunk's last-position logits are ever read
+                # final chunk's last-position logits are ever read. Host
+                # arrays go in as they are: the call transfers them, at
+                # less than half of what a jnp.asarray each costs
                 logits, self.cache.state = self._prefill_fn(C, final)(
                     self._params_for(run), self.cache.state,
-                    jnp.asarray(toks)[None],
-                    jnp.int32(run.prefill_done),
-                    jnp.asarray(self.cache.table_row(run.req.rid, W)))
+                    toks[None], np.int32(run.prefill_done),
+                    self.cache.table_row(run.req.rid, W))
             run.prefill_done += C
             run.cache_len = run.prefill_done
             self.cache.release_behind(run.req.rid, run.cache_len)
@@ -1412,39 +1545,48 @@ class Scheduler:
                         and run.req.spec.kind == "draft"
                         and self.role != "prefill"):
                     self._build_draft_cache(run)
-                with tr.span("serve.prefill_sync", "SERVE"):
-                    # device-side last-position slice: only vocab floats
-                    # cross to host, not the whole (1, C, vocab) chunk;
-                    # the host blocks on the device here
-                    tok = int(np.asarray(self._pick(
-                        logits[:, -1],
-                        jnp.asarray([run.req.seed], jnp.int32),
-                        jnp.asarray([run.cache_len], jnp.int32),
-                        jnp.asarray([run.req.temperature],
-                                    jnp.float32)))[0])
-                now = self._clock()
-                self._m["prefill_ms"].observe(
-                    self._phase(run, "prefill", now))
-                self._commit_token(run, tok, now)
-                if run.state == "decode" and self.role == "prefill":
-                    # prefill is this replica's whole job: the request
-                    # parks (blocks pinned) until the router migrates
-                    # it — its first token is already committed, so
-                    # TTFT was stamped here, untouched by wire time
-                    run.state = "handoff"
+                # the pick is issued here and read in _read_first
+                self._first = (run, self._pick_last(
+                    logits, np.asarray([run.req.seed], np.int32),
+                    np.asarray([run.cache_len], np.int32),
+                    np.asarray([run.req.temperature], np.float32)))
+                self._first[1].copy_to_host_async()
+                if run.req.spec is not None or self.role == "prefill":
+                    # its round of this iteration proposes from the token
+                    # on the host; a prefill replica issues nothing more
+                    self._read_first(tr)
             break                                 # one chunk per iteration
+        return progress
 
-        # 3. speculative lane: one round per spec request — they never
-        # take plain decode steps (a token committed outside the round
-        # would desync the per-request draft cache)
+    def _spec_lane(self, tr) -> bool:
+        """§3: one round per spec request — they never take plain decode
+        steps (a token committed outside the round would desync the
+        per-request draft cache). True when a round ran."""
+        progress = False
         for run in [r for r in self._running
                     if r.state == "decode" and r.req.spec is not None]:
             if run.state == "decode":   # an earlier round may preempt
                 with tr.span("serve.spec_round", "SERVE", (run.req.rid,)):
                     self._spec_round(run, self._clock())
                 progress = True
+        return progress
 
-        # 4. packed decode for the non-speculative decoders
+    def _tokens_picked(self, run: _Run) -> int:
+        """Tokens the device has picked for ``run``, read or not: whether
+        it goes on is known from this count before the last one's value
+        is."""
+        return (len(run.emitted)
+                + (self._flight is not None
+                   and run.req.rid in self._flight.rows)
+                + (self._first is not None and self._first[0] is run))
+
+    def _issue_decode(self, tr):
+        """§4: the packed decode step for the non-speculative decoders,
+        issued with its input tokens taken on the device where the host has
+        not read them yet. Returns ``(unread, issued)``: the step that was
+        in flight before (None when there was none, or packing had to
+        drain it), now the caller's to read, and whether a step was
+        issued behind it."""
         with tr.span("serve.decode_pack", "SERVE"):
             packed: List[_Run] = []
             for run in list(self._running):
@@ -1452,62 +1594,130 @@ class Scheduler:
                     continue
                 if len(packed) >= self.max_batch:
                     break
+                if self._tokens_picked(run) >= run.req.max_new:
+                    continue               # its unread token is its last
                 if self._ensure_or_preempt(run, run.cache_len + 1,
                                            run.cache_len,
                                            run.cache_len + 1):
                     if run.state == "decode":  # survived any preemptions
                         packed.append(run)
             packed = [r for r in packed if r.state == "decode"]
+            unread, self._flight = self._flight, None
             if not packed:
-                return progress
+                return unread, False
             R = self.max_batch
             W = max(self._width(r.req.rid) for r in packed)
-            toks = np.zeros(R, np.int32)
-            pos = np.zeros(R, np.int32)
             rows = [self.cache.table_row(r.req.rid, W) for r in packed]
-            tables = np.zeros((R,) + rows[0].shape, np.int32)
-            seeds = np.zeros(R, np.int32)
+            host = np.zeros((R, _N_COLS + rows[0].size), np.int32)
+            # where row i's input token is: a row of the unread step, the
+            # unread first token (R), or the host's own (R + 1 + i)
+            host[:, _SRC] = np.arange(R + 1, 2 * R + 1)
             temps = np.zeros(R, np.float32)
             for i, run in enumerate(packed):
-                toks[i] = run.pending
-                pos[i] = run.cache_len
-                tables[i] = rows[i]
-                seeds[i] = run.req.seed
+                if unread is not None and run.req.rid in unread.rows:
+                    host[i, _SRC] = unread.rows[run.req.rid]
+                elif self._first is not None and self._first[0] is run:
+                    host[i, _SRC] = R
+                else:
+                    host[i, _TOK] = run.pending
+                host[i, _POS] = run.cache_len
+                host[i, _SEED] = run.req.seed
                 temps[i] = run.req.temperature
-            extra = ()
-            if self.adapter_pool is not None:
                 # heterogeneous-adapter decode: each row gathers its
-                # adapter's A/B slabs by pool slot inside the ONE
-                # jitted step (ops/segmented_lora.py); padded rows and
-                # base-model runs ride slot 0, the reserved all-zero
-                # slot, so batch composition never branches the program
-                slots = np.zeros(R, np.int32)
-                for i, run in enumerate(packed):
-                    if run.slot is not None:
-                        slots[i] = run.slot
-                extra = (self.adapter_pool.slabs, jnp.asarray(slots))
+                # adapter's A/B slabs by pool slot inside the ONE jitted
+                # step (ops/segmented_lora.py); padded rows and base-model
+                # runs ride slot 0, the reserved all-zero slot, so batch
+                # composition never branches the program
+                host[i, _SLOT] = run.slot or 0
+                host[i, _N_COLS:] = rows[i].reshape(-1)
+            host[:, _TEMP] = temps.view(np.int32)
         with tr.span("serve.decode_dispatch", "SERVE", (len(packed), W)):
-            logits, self.cache.state = self._decode_step()(
-                self._operands, self.cache.state, jnp.asarray(toks),
-                jnp.asarray(pos), jnp.asarray(tables), *extra)
-            picked = self._pick(
-                logits, jnp.asarray(seeds), jnp.asarray(pos + 1),
-                jnp.asarray(temps))
+            step = self._decode_step()
+            toks, pos, tables, seeds, pos1, temps, slots = _take(
+                unread.picked if unread is not None else self._no_picked,
+                self._first[1] if self._first is not None
+                else self._no_first, host, table_shape=rows[0].shape)
+            extra = () if self.adapter_pool is None \
+                else (self.adapter_pool.slabs, slots)
+            logits, self.cache.state = step(
+                self._operands, self.cache.state, toks, pos, tables, *extra)
+            picked = self._pick(logits, seeds, pos1, temps)
+            picked.copy_to_host_async()
             if self._late is not None:
                 self._late.note(self.cache.state)
-        with tr.span("serve.decode_sync", "SERVE"):
-            picked = np.asarray(picked)    # the host blocks on the device
-        with tr.span("serve.commit", "SERVE"):
-            now = self._clock()
-            for i, run in enumerate(packed):
-                run.cache_len += 1
-                self.cache.release_behind(run.req.rid, run.cache_len)
-                self._commit_token(run, int(picked[i]), now)
-        self._m["decode_tokens"].inc(len(packed))
+        # what the host knows without the tokens' values: each row wrote
+        # its position
+        for run in packed:
+            run.cache_len += 1
+        self._flight = _InFlight(picked, packed, host[:len(packed), _POS])
+        if unread is not None:
+            self._m["decode_steps_overlapped"].inc()
         if self._decode_paged_attn:
             self._m["decode_steps_paged_attn"].inc()
         self._m["batch_occupancy"].observe(len(packed))
+        return unread, True
+
+    def _read_decode(self, tr, flight: Optional[_InFlight]) -> bool:
+        """Read a decode step's tokens and commit them: the host waits in
+        ``serve.decode_sync`` (with a step queued behind this one, the
+        device does not), ``serve.commit`` is host work alone. A row
+        whose run ended at eos after the step was issued is dropped: its
+        write went to a block that run released, and whoever owns the
+        block next writes after it, in issue order."""
+        if flight is None:
+            return False
+        with tr.span("serve.decode_sync", "SERVE"):
+            picked = np.asarray(flight.picked)  # the host blocks on the device
+        with tr.span("serve.commit", "SERVE"):
+            now = self._clock()
+            live = 0
+            for i, run in enumerate(flight.runs):
+                if run.state != "decode":
+                    continue
+                live += 1
+                self.cache.release_behind(run.req.rid, int(flight.pos[i]) + 1)
+                self._commit_token(run, int(picked[i]), now)
+        self._m["decode_tokens"].inc(live)
+        self._m["decode_rows_dropped"].inc(len(flight.runs) - live)
         return True
+
+    def _read_first(self, tr) -> bool:
+        """Read and commit the first token of the request whose final
+        chunk this iteration issued (TTFT is stamped here): after the
+        iteration's decode step was issued, so that step is queued behind
+        the chunk while the host waits in ``serve.prefill_sync``."""
+        if self._first is None:
+            return False
+        (run, picked), self._first = self._first, None
+        with tr.span("serve.prefill_sync", "SERVE"):
+            tok = int(np.asarray(picked)[0])    # the host blocks on the device
+        now = self._clock()
+        self._m["prefill_ms"].observe(self._phase(run, "prefill", now))
+        self._commit_token(run, tok, now)
+        if run.state == "decode" and self.role == "prefill":
+            # prefill is this replica's whole job: the request parks
+            # (blocks pinned) until the router migrates it — its first
+            # token is already committed, so TTFT was stamped here,
+            # untouched by wire time
+            run.state = "handoff"
+        return True
+
+    def _note_drain(self, cause: str) -> None:
+        self._m["pipeline_drains"].inc()
+        self._m[f"pipeline_drains.{cause}"].inc()
+
+    def _drain_in_flight(self, cause: str) -> bool:
+        """Read and commit whatever the device has picked and the host has
+        not read, with nothing issued behind it: for everything that reads
+        or rewrites a run from outside the lanes (a preemption, a
+        migration either way, ``drain_incomplete``, the fault plan's
+        kill). True when there was anything to read."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self._note_drain(cause)
+        tr = get_tracer()
+        read = self._read_decode(tr, flight)
+        return self._read_first(tr) or read
 
     def _build_draft_cache(self, run: _Run,
                            tokens: Optional[np.ndarray] = None) -> None:

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from benchmark.configs import dots3_reference as ref
+from byteps_tpu.common.metrics import get_registry
 from byteps_tpu.models import dots3
 from byteps_tpu.models.dots3 import (
     FULL,
@@ -222,6 +223,39 @@ def test_window_layers_release_their_blocks(served):
     assert snap["histograms"]["moe.pairs_here"]["count"] > 0
 
 
+def test_window_blocks_go_back_when_their_step_is_read(params, served):
+    """With a step's tokens read one step late, the window blocks behind
+    it go back one step late too: ``release_behind`` is called at the
+    commit, with the fill of the step being committed, while the run's
+    ``cache_len`` already counts the step issued after it. Same tokens as
+    the fixture's (the reference's), nothing leaked."""
+    reqs, want, _, _, _ = served
+    sched = Scheduler(params, CFG, max_batch=3, block_size=4, pool_blocks=64,
+                      prefill_chunk=8)
+    calls = []
+    release = sched.cache.release_behind
+
+    def watched(rid, fill):
+        calls.append((fill, sched._runs[rid].cache_len,
+                      sched._runs[rid].state))
+        return release(rid, fill)
+
+    sched.cache.release_behind = watched
+    got = sched.serve(_requests())
+    for r in reqs:
+        np.testing.assert_array_equal(got[r.rid]["emitted"],
+                                      want[r.rid]["emitted"])
+    decode = [(fill, n) for fill, n, state in calls if state == "decode"]
+    # a step read behind the next one: the host's count is one ahead
+    assert sum(1 for fill, n in decode if n == fill + 1) > len(decode) // 2
+    assert all(n in (fill, fill + 1) for fill, n in decode)
+    assert sched.cache.leaked_blocks() == 0
+    assert sched.cache.window_blocks_in_use == 0
+    snap = get_registry().snapshot()["counters"]
+    assert snap["serve.decode_steps_overlapped"] > 0
+    assert snap["serve.cache.window_blocks_released"] > 0
+
+
 def test_preemption_and_resume_give_both_kinds_back(params):
     """Two requests of 12 + 12 tokens in a pool of 9 blocks: both are
     admitted on 4 blocks, both need a fifth at token 17 and one is free, so
@@ -250,7 +284,10 @@ def taken(params):
     """The benchmark's read-back (``drivers/serve_dots3.py::take_running``):
     SHAPES served until the 40-token request has decoded three tokens, then
     its pages as the programs left them, beside the reference's forward over
-    what it was fed."""
+    what it was fed. A decode step is unread when the loop stops, as when
+    the benchmark's window closes: it has been fed the newest committed
+    token, so the pool holds (once the read-back has waited for it) a row
+    for every token of prompt + emitted."""
     from benchmark.drivers.serve_dots3 import take_running
 
     sched = Scheduler(params, CFG, max_batch=3, block_size=4, pool_blocks=64,
@@ -261,7 +298,8 @@ def taken(params):
                   and len(r.emitted) >= 3 for r in sched._running):
         sched.step()
     got = take_running(sched, CFG, 40, np.random.default_rng(0))
-    assert got["cached"] == 40 + len(got["emitted"]) - 1
+    assert got["rid"] in sched._flight.rows
+    assert got["cached"] == 40 + len(got["emitted"])
     # one program's worth of window blocks, whatever the 40 tokens before
     assert got["wkv"].shape[1] == CFG.window - 1 and got["w_lo"] > 32
     return got, np.concatenate([got["prompt"], got["emitted"]])

@@ -447,3 +447,231 @@ def test_bench_serve_quick_sweep():
         assert race[side]["ttft_ms_p99_short"] > 0, side
     assert res["results"]["migrate_preempt"]["off"]["recompute_tokens"] > 0
     assert res["results"]["migrate_preempt"]["on"]["migrated_requests"] >= 1
+
+
+# ---- a step's tokens are read one step late (docs/serving.md §the iteration) -
+# long enough for prompts of 16-200 with 40 new tokens; the matrices x8 so
+# that greedy decoding does not settle on one token
+BIG = GPTConfig(vocab_size=256, max_seq=256, d_model=64, n_heads=4,
+                n_layers=2, d_ff=128)
+MIXED = dict(lens=[16, 200, 47, 120, 33, 64, 150, 21, 90, 180],
+             news=[1, 2, 8, 40, 40, 8, 2, 1, 40, 8],
+             temps=[0.0, 0.8, 0.0, 0.8, 0.0, 0.8, 0.0, 0.8, 0.0, 0.0])
+# what the parent of PR 36 (commit 6312baa: every step's tokens read before
+# the next is issued) served for _mixed() on the same scheduler arguments
+PARENT_EMITTED = {
+    0: [76], 1: [200, 137], 2: [143, 58, 149, 139, 140, 123, 222, 2],
+    3: [203, 39, 173, 72, 49, 203, 203, 179, 36, 121, 75, 75, 177, 159, 213,
+        91, 132, 203, 234, 134, 123, 175, 218, 203, 21, 103, 167, 102, 6, 100,
+        163, 137, 116, 191, 97, 67, 60, 86, 235, 237],
+    4: [203, 116, 31, 27, 31, 31, 31, 31, 31, 31, 31, 31, 31, 31, 31, 31, 31,
+        31, 31, 31, 31, 31, 31, 222, 116, 116, 203, 203, 218, 218, 218, 218,
+        218, 203, 203, 134, 95, 95, 122, 218],
+    5: [6, 18, 128, 203, 99, 0, 192, 53], 6: [122, 29], 7: [102],
+    8: [155, 95, 58, 200, 95, 120, 58, 58, 58, 58, 31, 27, 102, 167, 134,
+        194, 72, 191, 64, 172, 203, 114, 123, 222, 222, 28, 222, 116, 116,
+        179, 222, 141, 222, 31, 36, 50, 200, 95, 159, 123],
+    9: [207, 187, 86, 86, 86, 86, 137, 203]}
+
+
+@pytest.fixture(scope="module")
+def big_params():
+    return jax.tree_util.tree_map(
+        lambda w: w * 8 if w.ndim >= 2 else w,
+        gpt_init(jax.random.PRNGKey(0), BIG))
+
+
+def _mixed(only=None, over=None):
+    rng = np.random.default_rng(36)
+    reqs = [Request(rid=i, max_new=m, temperature=t, seed=100 + i,
+                    prompt=rng.integers(0, BIG.vocab_size, n)
+                    .astype(np.int32))
+            for i, (n, m, t) in enumerate(zip(
+                MIXED["lens"], MIXED["news"], MIXED["temps"]))]
+    reqs = [r for r in reqs if only is None or r.rid in only]
+    for r in reqs:
+        for k, v in (over or {}).get(r.rid, {}).items():
+            setattr(r, k, v)
+    return reqs
+
+
+def _big_solo(big_params, req):
+    gen = make_generate_fn(BIG, req.max_new)
+    return np.asarray(gen(big_params, jnp.asarray(req.prompt)[None],
+                          jax.random.PRNGKey(0), 0.0))[0, len(req.prompt):]
+
+
+def _counters():
+    return get_registry().snapshot()["counters"]
+
+
+@pytest.fixture(scope="module")
+def mixed_served(big_params):
+    sched = Scheduler(big_params, BIG, max_batch=3, prefill_chunk=32,
+                      block_size=8)
+    res = sched.serve(_mixed())
+    return res, sched.cache.leaked_blocks()
+
+
+@pytest.mark.parametrize("rid", range(len(MIXED["lens"])))
+def test_mixed_batch_serves_the_parents_tokens(big_params, mixed_served, rid):
+    """Prompts of 16-200, max_new 1, 2, 8 and 40, greedy and sampled at 0.8
+    in one batch of three rows: each request's tokens are those the parent
+    commit served, and a greedy one's are its solo static-cache run's."""
+    res, leaked = mixed_served
+    (req,) = _mixed(only={rid})
+    assert res[rid]["emitted"].tolist() == PARENT_EMITTED[rid]
+    if req.temperature == 0.0:
+        np.testing.assert_array_equal(res[rid]["emitted"],
+                                      _big_solo(big_params, req))
+    assert len(res[rid]["token_s"]) == req.max_new
+    assert leaked == 0
+
+
+@pytest.mark.parametrize("max_new,steps,drains", [(1, 0, 0), (2, 1, 1)])
+def test_a_run_that_never_decodes_and_one_whose_only_step_is_its_last(
+        big_params, max_new, steps, drains):
+    """max_new 1 ends at its prefill's token: no decode step. max_new 2
+    takes one, known to be its last when it is issued: it is read by the
+    next step(), which issues nothing."""
+    (req,) = _mixed(only={2}, over={2: {"max_new": max_new}})
+    sched = Scheduler(big_params, BIG, max_batch=3, prefill_chunk=32,
+                      block_size=8)
+    res = sched.serve([req])
+    assert res[2]["emitted"].tolist() == PARENT_EMITTED[2][:max_new]
+    snap = get_registry().snapshot()
+    assert snap["histograms"]["serve.batch_occupancy"]["count"] == steps
+    assert snap["counters"]["serve.pipeline_drains.idle"] == drains
+    assert snap["counters"]["serve.decode_steps_overlapped"] == 0
+    assert sched.cache.leaked_blocks() == 0
+
+
+def test_eos_in_the_middle_drops_one_row_of_the_step_already_issued(
+        big_params):
+    """Request 2's fourth greedy token as its eos_id: it finishes there,
+    the row it had in the step issued before that token was read is
+    dropped, its blocks come back and its neighbours' tokens do not
+    move."""
+    reqs = _mixed(only={2, 4, 8},
+                  over={2: {"eos_id": PARENT_EMITTED[2][3]}})
+    sched = Scheduler(big_params, BIG, max_batch=3, prefill_chunk=32,
+                      block_size=8)
+    res = sched.serve(reqs)
+    assert res[2]["emitted"].tolist() == PARENT_EMITTED[2][:4]
+    for rid in (4, 8):
+        assert res[rid]["emitted"].tolist() == PARENT_EMITTED[rid]
+    snap = _counters()
+    assert snap["serve.decode_rows_dropped"] == 1
+    assert snap["serve.decode_tokens"] == 3 + 39 + 39
+    assert sched.cache.leaked_blocks() == 0
+    assert sched.finished
+
+
+def test_preemption_reads_the_unread_step_first(big_params):
+    """A pool too small for two growing requests: when a block cannot be
+    had with a step unread, the step is read before a victim is picked, so
+    the victim's recompute input is every token the device picked for it;
+    both requests still end on the parent's tokens."""
+    reqs = _mixed(only={4, 8})
+    sched = Scheduler(big_params, BIG, max_batch=2, prefill_chunk=32,
+                      block_size=8, pool_blocks=1 + 24)
+    seen = []
+    preempt = sched._preempt
+
+    def watched(run):
+        seen.append((sched._flight, sched._first, len(run.emitted)))
+        preempt(run)
+        assert run.full_input.tolist() == (
+            run.req.prompt.tolist() + PARENT_EMITTED[run.req.rid][:seen[-1][2]])
+
+    sched._preempt = watched
+    res = sched.serve(reqs)
+    assert seen and all(f is None and p is None for f, p, _ in seen)
+    assert sum(res[r]["preemptions"] for r in (4, 8)) == len(seen)
+    for rid in (4, 8):
+        assert res[rid]["emitted"].tolist() == PARENT_EMITTED[rid]
+    assert _counters()["serve.pipeline_drains.preempt"] >= 1
+    assert sched.cache.leaked_blocks() == 0
+
+
+def test_unread_tokens_keep_the_scheduler_unfinished(big_params):
+    """A lone request: while its last token is picked and unread,
+    ``finished`` is False; the next step() issues nothing, reads and
+    commits it, and says it made progress."""
+    from byteps_tpu.common.tracing import get_tracer
+
+    (req,) = _mixed(only={9})
+    sched = Scheduler(big_params, BIG, max_batch=3, prefill_chunk=32,
+                      block_size=8)
+    sched.submit(req)
+    run = sched._runs[9]
+    while sched._tokens_picked(run) < req.max_new:
+        assert sched.step()
+    assert sched._flight is not None and len(run.emitted) == req.max_new - 1
+    assert not sched.finished and 9 not in sched.results
+    issued = sum(1 for e in get_tracer().spans()
+                 if e[0] in ("serve.decode_dispatch",
+                             "serve.prefill_dispatch"))
+    assert sched.step()
+    assert sched.finished and sched._flight is None
+    assert sched.results[9]["emitted"].tolist() == PARENT_EMITTED[9]
+    assert issued == sum(1 for e in get_tracer().spans()
+                         if e[0] in ("serve.decode_dispatch",
+                                     "serve.prefill_dispatch"))
+    assert _counters()["serve.pipeline_drains.idle"] == 1
+    assert not sched.step()                 # and nothing is left to do
+
+
+def test_every_decode_step_is_overlapped_or_drained(big_params):
+    """A backlog over three rows: each decode step either has the next one
+    issued before it is read, or is read with none behind it."""
+    sched = Scheduler(big_params, BIG, max_batch=3, prefill_chunk=32,
+                      block_size=8)
+    sched.serve(_mixed())
+    snap = get_registry().snapshot()
+    steps = snap["histograms"]["serve.batch_occupancy"]["count"]
+    c = snap["counters"]
+    assert c["serve.decode_steps_overlapped"] \
+        == steps - c["serve.pipeline_drains"]
+    assert c["serve.pipeline_drains"] == c["serve.pipeline_drains.idle"]
+    assert c["serve.decode_steps_overlapped"] > 0.9 * steps
+    assert c["serve.decode_tokens"] == sum(MIXED["news"]) - len(MIXED["news"])
+    assert c["serve.decode_rows_dropped"] == 0
+
+
+def test_adapter_rows_and_a_speculative_request_beside_plain_decoders(
+        params):
+    """Rows that gather adapter slabs, a base-model row and a prompt-lookup
+    speculative request (which reads its tokens at once, every round) in one
+    scheduler: each greedy output is its solo run's, as on the parent."""
+    from byteps_tpu.models.lora import lora_init
+    from byteps_tpu.serve.adapter_pool import AdapterPool
+
+    pool = AdapterPool(CFG, n_slots=3, rank_bucket=4, targets=("wq", "wv"))
+    for i, rank in enumerate((2, 4)):
+        ad = lora_init(jax.random.PRNGKey(10 + i), CFG, rank, ("wq", "wv"))
+        for bi, blk in enumerate(ad["blocks"]):
+            for t in blk:
+                blk[t]["b"] = 0.02 * jax.random.normal(
+                    jax.random.fold_in(jax.random.PRNGKey(10 + i), bi),
+                    blk[t]["b"].shape)
+        pool.register(f"a{i}", ad)
+    rng = np.random.default_rng(5)
+    motif = rng.integers(0, CFG.vocab_size, 5).astype(np.int32)
+    reqs = [Request(rid="a0", prompt=rng.integers(0, 256, 9).astype(np.int32),
+                    max_new=10, adapter="a0", tenant="t0"),
+            Request(rid="a1", prompt=rng.integers(0, 256, 13).astype(np.int32),
+                    max_new=7, adapter="a1", tenant="t1"),
+            Request(rid="base", prompt=rng.integers(0, 256, 6)
+                    .astype(np.int32), max_new=12),
+            Request(rid="spec", prompt=np.tile(motif, 3), max_new=9,
+                    spec=SpecPolicy("lookup", spec_len=3))]
+    sched = Scheduler(params, CFG, max_batch=3, block_size=8, pool_blocks=40,
+                      prefill_chunk=4, adapter_pool=pool)
+    res = sched.serve(list(reqs))
+    for r in reqs:
+        golden = params if r.adapter is None else pool.graft(params, r.adapter)
+        np.testing.assert_array_equal(res[r.rid]["tokens"], _solo(golden, r))
+    assert res["spec"]["spec_rounds"] > 0
+    assert _counters()["serve.decode_steps_overlapped"] > 0
+    assert sched.cache.leaked_blocks() == 0 and pool.leaked_slots() == 0

@@ -246,6 +246,76 @@ def test_migrate_dont_evict_zero_recompute(params):
     assert a.cache.leaked_blocks() == 0 and b.cache.leaked_blocks() == 0
 
 
+# ---- leaving a replica with a decode step unread ----------------------------
+def _stepped_to_an_unread_step(params, reqs, picked, **kw):
+    """A scheduler stepped until the device has picked ``picked`` tokens for
+    the first request, the last of them unread in the step in flight, which
+    carries a row of every request."""
+    sched = Scheduler(params, CFG, max_batch=len(reqs), prefill_chunk=8,
+                      block_size=4, **kw)
+    for r in reqs:
+        sched.submit(r)
+    while not (sched._flight is not None
+               and all(r.rid in sched._flight.rows for r in reqs)
+               and sched._tokens_picked(sched._runs[reqs[0].rid]) == picked):
+        assert sched.step()
+    return sched
+
+
+def test_extract_for_migration_reads_the_unread_step_first(params):
+    """A ticket cut while a step is unread carries that step's token and
+    the row it wrote; adopted elsewhere, the request ends on its solo
+    tokens, and so does the neighbour that stayed."""
+    reqs = _mk_requests(2, np.random.default_rng(3), max_news=(10, 9))
+    a = _stepped_to_an_unread_step(params, reqs, 5, replica_id=0)
+    victim, stays = reqs
+    run = a._runs[victim.rid]
+    picked = a._tokens_picked(run)
+    assert picked == len(run.emitted) + 1
+    ticket = a.extract_for_migration(victim.rid)
+    assert a._flight is None
+    assert len(ticket.emitted) == picked and ticket.pending == ticket.emitted[-1]
+    assert ticket.cache_len == len(victim.prompt) + picked - 1
+    assert _counters()["serve.pipeline_drains.migrate"] == 1
+    b = Scheduler(params, CFG, max_batch=2, prefill_chunk=8, block_size=4,
+                  replica_id=1)
+    assert b.submit_migrated(ticket, dict(ticket.payloads))
+    for sched in (a, b):
+        while not sched.finished:
+            sched.step()
+    np.testing.assert_array_equal(b.results[victim.rid]["tokens"],
+                                  _solo(params, victim))
+    np.testing.assert_array_equal(a.results[stays.rid]["tokens"],
+                                  _solo(params, stays))
+    assert _counters()["serve.migration.recompute_tokens"] == 0
+    assert a.cache.leaked_blocks() == 0 and b.cache.leaked_blocks() == 0
+
+
+def test_drain_incomplete_hands_over_every_token_the_device_picked(params):
+    """``drain_incomplete`` with a step unread: what it returns for each
+    request includes that step's token, a request that token completes is a
+    result and not a leftover, and a survivor resumes the rest exactly."""
+    reqs = _mk_requests(2, np.random.default_rng(4), max_news=(6, 9))
+    # the first request's last token is the unread one
+    a = _stepped_to_an_unread_step(params, reqs, 6, replica_id=0)
+    assert not a.finished
+    picked = {r.rid: a._tokens_picked(a._runs[r.rid]) for r in reqs}
+    left = a.drain_incomplete()
+    assert a.finished and a.cache.leaked_blocks() == 0
+    np.testing.assert_array_equal(a.results[reqs[0].rid]["tokens"],
+                                  _solo(params, reqs[0]))
+    ((req, emitted),) = left
+    assert req.rid == reqs[1].rid and len(emitted) == picked[req.rid]
+    b = Scheduler(params, CFG, max_batch=2, prefill_chunk=8, block_size=4,
+                  replica_id=1)
+    b.submit(req, resume_tokens=emitted)
+    while not b.finished:
+        b.step()
+    np.testing.assert_array_equal(b.results[req.rid]["tokens"],
+                                  _solo(params, req))
+    assert _counters()["serve.pipeline_drains.migrate"] == 1
+
+
 def test_migrate_preempt_off_recomputes(params):
     """The escape hatch: with migration off the same pressure takes the
     classic evict path — recompute tokens charged, outputs unchanged."""

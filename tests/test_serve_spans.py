@@ -78,6 +78,11 @@ def test_queued_plus_prefill_is_ttft_to_the_last_bit(served):
 
 
 def test_children_lie_inside_their_iteration_and_do_not_overlap(served):
+    """Every lane span is a child of its iteration and none overlaps
+    another, in the new order too: ``serve.decode_sync`` and
+    ``serve.commit`` of the step before sit after ``serve.decode_dispatch``
+    in the same iteration. (A read forced from inside ``serve.decode_pack``
+    by a preemption nests there instead; this traffic has none.)"""
     _, _, ring = served
     iterations = {e[3]: e for e in ring if e[0] == "serve.iteration"}
     assert [e[5][0] for e in iterations.values()] == \
@@ -98,6 +103,41 @@ def test_children_lie_inside_their_iteration_and_do_not_overlap(served):
             assert a[1] + a[2] <= b[1], (a, b)
 
 
+def test_a_step_is_committed_after_the_next_one_was_issued(served):
+    """The order of an iteration: the packed step is issued, then the step
+    BEFORE it is read (``serve.decode_sync``) and committed
+    (``serve.commit``). So step n's commit starts after step n+1's dispatch
+    has ended, unless nothing was left to decode and n was read with no
+    step behind it; a final chunk's first token is read last of all."""
+    _, _, ring = served
+    spans = _by_name(ring)
+    issues = sorted(spans["serve.decode_dispatch"], key=lambda e: e[1])
+    commits = sorted(spans["serve.commit"], key=lambda e: e[1])
+    syncs = sorted(spans["serve.decode_sync"], key=lambda e: e[1])
+    assert len(issues) == len(commits) == len(syncs) > 0
+    c = get_registry().snapshot()["counters"]
+    overlapped = 0
+    for n, (commit, sync) in enumerate(zip(commits, syncs)):
+        assert sync[1] + sync[2] <= commit[1]
+        assert issues[n][1] + issues[n][2] <= sync[1]     # its own step
+        if n + 1 < len(issues) and issues[n + 1][4] == commit[4]:
+            # read in the iteration that issued the step after it
+            assert issues[n + 1][1] + issues[n + 1][2] <= sync[1]
+            overlapped += 1
+        else:
+            # read with no step behind it: that iteration issued none
+            assert not any(e[4] == commit[4] for e in issues)
+    assert overlapped == c["serve.decode_steps_overlapped"]
+    assert len(issues) - overlapped == c["serve.pipeline_drains"] > 0
+    by_iteration = collections.defaultdict(list)
+    for e in ring:
+        if e[0] in ITERATION_CHILDREN:
+            by_iteration[e[4]].append(e)
+    for first in spans["serve.prefill_sync"]:
+        last = max(by_iteration[first[4]], key=lambda e: e[1])
+        assert last is first
+
+
 def test_one_prefill_dispatch_per_chunk(served):
     reqs, _, ring = served
     chunks = collections.Counter(
@@ -109,11 +149,13 @@ def test_one_prefill_dispatch_per_chunk(served):
         assert chunks[r.rid] == math.ceil(len(r.prompt) / CHUNK)
         assert finals[r.rid] == 1
     assert sum(1 for e in ring if e[0] == "serve.prefill_sync") == len(reqs)
-    # every decode step: pack, dispatch, sync, commit
+    # every decode step: pack, dispatch, and one sync and commit (a step
+    # later); an iteration packs whether or not it finds a row to decode
     n = sum(1 for e in ring if e[0] == "serve.decode_dispatch")
     assert n > 0
-    for name in ("serve.decode_pack", "serve.decode_sync", "serve.commit"):
-        assert sum(1 for e in ring if e[0] == name) >= n
+    assert sum(1 for e in ring if e[0] == "serve.decode_pack") >= n
+    for name in ("serve.decode_sync", "serve.commit"):
+        assert sum(1 for e in ring if e[0] == name) == n
 
 
 def test_preempted_request_gets_a_second_queued_span(params):
