@@ -186,16 +186,22 @@ def cache_attend(cache_k, cache_v, pos0):
 
 
 def _block_step(x, p, cache_k, cache_v, pos0, cfg, tp_axis, ep_axis,
-                norm_fn=_layernorm, norm_eps: float = 1e-5):
+                norm_fn=_layernorm, norm_eps: float = 1e-5, rope=None,
+                ffn=None):
     """One transformer block (dense-MLP or MoE, by param structure) over
     T new tokens with cache append: the shared halves of ``models/gpt.py``
-    around :func:`cache_attend`."""
+    around :func:`cache_attend`. ``rope`` (default: the config's one base)
+    and ``ffn`` (``h -> (out, aux)``; default: by param structure) are a
+    caller's whose layers differ in either. Returns ``(x, cache_k, cache_v)``,
+    and ``ffn``'s ``aux`` after them where one was given."""
     kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
     x, (cache_k, cache_v) = attn_half(
         x, p, cfg.head_dim, lambda: pos0 + jnp.arange(x.shape[1]),
-        cache_attend(cache_k, cache_v, pos0), tp_axis, resolve_rope(cfg),
-        **kw)
-    ffn = None
+        cache_attend(cache_k, cache_v, pos0), tp_axis,
+        resolve_rope(cfg) if rope is None else rope, **kw)
+    if ffn is not None:
+        x, aux = ffn_half(x, p, tp_axis, ffn, **kw)
+        return x, cache_k, cache_v, aux
     if "moe" in p:
         from byteps_tpu.parallel.moe import moe_ffn
 

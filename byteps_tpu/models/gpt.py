@@ -15,7 +15,7 @@ softmax and the loss accumulate in float32.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -183,8 +183,20 @@ def _positions(S_loc: int, sp_axis, seq_layout: str) -> jnp.ndarray:
     return off + jnp.arange(S_loc)
 
 
+class RopeFreqs(NamedTuple):
+    """A rotation given as data: the ``D/2`` inverse frequencies of a head's
+    pairs and one factor on cos and sin — what a frequency-scaled scheme
+    (YaRN: interpolated low frequencies, an attention factor) needs where
+    plain RoPE needs a base. Tuples of Python floats, built once a layer
+    kind (``models/mellum2.py::rope_freqs``): hashable, so it can sit in a
+    jitted program's static plan."""
+
+    inv_freq: Tuple[float, ...]
+    factor: float = 1.0
+
+
 def rope_rotate(x: jnp.ndarray, pos: jnp.ndarray,
-                base: float = 10000.0,
+                base: Union[float, RopeFreqs] = 10000.0,
                 interleaved: bool = False) -> jnp.ndarray:
     """Rotary position embedding, (B, S, H, D): the half-split convention
     (dims ``i`` and ``i + D/2`` turn together), or with ``interleaved`` the
@@ -193,12 +205,20 @@ def rope_rotate(x: jnp.ndarray, pos: jnp.ndarray,
     with global positions ``pos`` — either ``(S,)`` shared across the
     batch (training / single-request decode) or ``(B, S)`` per-row (the
     serve tier's packed decode, where one batch holds requests at
-    heterogeneous positions). Pure elementwise rotation — composes with
+    heterogeneous positions). ``base`` is plain RoPE's base, or a
+    :class:`RopeFreqs` whose frequencies are used as they are and whose
+    factor multiplies cos and sin. Pure elementwise rotation — composes with
     the flash kernel, ring/zigzag schedules (positions are
     layout-aware), and the KV cache (keys cached post-rotation)."""
     D = x.shape[-1]
     half = D // 2
-    inv_freq = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32) / half))
+    factor = 1.0
+    if isinstance(base, RopeFreqs):
+        inv_freq = jnp.asarray(base.inv_freq, jnp.float32)
+        factor = base.factor
+    else:
+        inv_freq = 1.0 / (base ** (jnp.arange(half, dtype=jnp.float32)
+                                   / half))
     if jnp.ndim(pos) == 2:
         ang = pos.astype(jnp.float32)[..., None] * inv_freq  # (B, S, half)
         cos = jnp.cos(ang)[:, :, None, :]
@@ -207,6 +227,8 @@ def rope_rotate(x: jnp.ndarray, pos: jnp.ndarray,
         ang = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]
         cos = jnp.cos(ang)[None, :, None, :]
         sin = jnp.sin(ang)[None, :, None, :]
+    if factor != 1.0:              # a plain base traces no multiply
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     if interleaved:
         pairs = xf.reshape(*xf.shape[:-1], half, 2)
@@ -325,7 +347,8 @@ def attn_half(x, p, head_dim: int, positions, attend, tp_axis=None,
     state it threads (None, a layer's cache pair, the KV pool).
     ``positions()`` gives what RoPE rotates by (``(T,)``, or ``(B, T)``
     where every row has its own); it is called under RoPE only, so a
-    learned-position program traces no position arithmetic.
+    learned-position program traces no position arithmetic. ``rope_base``: 0
+    for none, a base, or a layer kind's :class:`RopeFreqs`.
     ``delta`` as in :func:`_project`. Returns ``(x, carry)``."""
     B, T = x.shape[:2]
     # named scopes are for an operator's xprof op profile; they change no
@@ -343,7 +366,7 @@ def attn_half(x, p, head_dim: int, positions, attend, tp_axis=None,
         q = q.reshape(B, T, h_loc, head_dim)
         k = k.reshape(B, T, kv_loc, head_dim)
         v = v.reshape(B, T, kv_loc, head_dim)
-        if rope_base > 0.0:
+        if isinstance(rope_base, RopeFreqs) or rope_base > 0.0:
             pos = positions()
             q = rope_rotate(q, pos, rope_base)
             k = rope_rotate(k, pos, rope_base)

@@ -802,14 +802,19 @@ def attention_window_jnp(q, k, v, q_offset, k_offset, window: int):
     position ``q_offset + i``) sees the keys at global positions ``p`` with
     ``0 <= p <= its own`` and ``its own - p < window``; ``(B, S, H, D)``
     layout, v of its own width, f32 softmax."""
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
-    rows = q_offset + jnp.arange(q.shape[1])[:, None]
+    B, Sq, H, D = q.shape
+    Hkv = k.shape[2]
+    # GQA: query head j reads kv head j // (H / Hkv), by a grouped product
+    qg = q.reshape(B, Sq, Hkv, H // Hkv, D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                   preferred_element_type=jnp.float32) * D ** -0.5
+    rows = q_offset + jnp.arange(Sq)[:, None]
     cols = k_offset + jnp.arange(k.shape[1])[None, :]
     ok = (rows >= cols) & (rows - cols < window) & (cols >= 0)
-    p = jax.nn.softmax(jnp.where(ok[None, None], s, _NEG), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+    p = jax.nn.softmax(jnp.where(ok[None, None, None], s, _NEG), axis=-1)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def flash_attention_window(q, k, v, q_offset, k_offset, window: int):
@@ -819,16 +824,22 @@ def flash_attention_window(q, k, v, q_offset, k_offset, window: int):
     wholly behind the window are skipped as those after the diagonal are.
     Offsets are the global positions of element 0 of q and of k (traced
     scalars); keys laid out before position 0 are padding and never seen.
-    Every query has a live key (its own), so no row is empty. Falls back
-    to :func:`attention_window_jnp` off the Pallas backend and for shapes
-    the kernel does not tile."""
+    Every query has a live key (its own), so no row is empty. k and v may
+    carry fewer heads than q (GQA: the kernel maps a query head to its kv
+    head by index, as the causal forward does). Falls back to
+    :func:`attention_window_jnp` off the Pallas backend and for shapes the
+    kernel does not tile."""
     B, Sq, H, D = q.shape
+    Hkv = k.shape[2]
+    if H % Hkv != 0 or v.shape[2] != Hkv:
+        raise ValueError(f"q heads ({H}) not a multiple of the k/v heads "
+                         f"({Hkv}, {v.shape[2]})")
     if use_pallas():
-        if supported(Sq, k.shape[1], D) and k.shape[2] == H:
+        if supported(Sq, k.shape[1], D):
             qoff = jnp.asarray(q_offset, jnp.float32).reshape(1, 1)
             koff = jnp.asarray(k_offset, jnp.float32).reshape(1, 1)
             o3, _ = _fwd(_to3(q), _to3(k), _to3(v), qoff, koff, True,
-                         _interpret(), H, H, window=int(window))
+                         _interpret(), H, Hkv, window=int(window))
             return _from3(o3, B, H)
         _note_fallback("flash_attention_window", q.shape + k.shape,
                        _UNSUPPORTED)

@@ -20,6 +20,13 @@ lies:
   the fill level. A padded row (length 1, scratch table) reads one
   page. Chunks are double buffered across rows: while chunk ``c`` is
   computed, chunk ``c+1`` — or the next row's first — is in flight.
+* **A window layer's row starts where its window does.** With ``first``
+  (one scalar-prefetched position a row) a row walks the chunks from the
+  one holding ``first[r]`` on: pages wholly below it are not fetched (the
+  cache has handed their blocks back; the table still has an entry a
+  logical block, 0 where one was released), keys below it are masked like
+  keys past the fill level. Without ``first`` the trace is the kernel as
+  it was: the branch is taken in Python.
 * **Garbage never reaches the output.** The gathered view zeroed every
   position at or past the fill level; here scores there take ``_NEG``
   and their ``p`` is forced to 0, and V's rows there are selected to 0
@@ -93,12 +100,21 @@ def unsupported_reason(block_size: int, kv_heads: int, head_dim: int,
     return None
 
 
-def _kernel(len_ref, tab_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, m_scr, l_scr, acc_scr, *,
-            scale: float, R: int, W: int, bs: int, ppb: int):
+def _kernel(len_ref, *refs, scale: float, R: int, W: int, bs: int, ppb: int,
+            windowed: bool = False):
+    # a windowed trace prefetches one scalar array more: each row's first
+    # live key. Without it nothing below differs from the kernel as it was
+    first_ref = refs[0] if windowed else None
+    (tab_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
+     m_scr, l_scr, acc_scr) = refs[1:] if windowed else refs
     H, HD = acc_scr.shape
     T = bs * ppb
     layer = layer_ref[0]
+
+    def first_chunk(r):
+        # the chunk that holds the row's first live key: the ones before it
+        # are neither fetched nor scored
+        return first_ref[r] // T if windowed else 0
 
     def page_copies(r, c, slot, i):
         blk = tab_ref[r * W + jnp.minimum(c * ppb + i, W - 1)]
@@ -113,12 +129,16 @@ def _kernel(len_ref, tab_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         # start and wait walk the SAME predicate, so every DMA issued
         # is awaited and no dead page is ever fetched
         for i in range(ppb):
-            @pl.when((c * ppb + i) * bs < len_ref[r])
+            live = (c * ppb + i) * bs < len_ref[r]
+            if windowed:           # and it ends above the first live key
+                live &= (c * ppb + i + 1) * bs > first_ref[r]
+
+            @pl.when(live)
             def _(i=i):
                 for cp in page_copies(r, c, slot, i):
                     cp.wait() if wait else cp.start()
 
-    def chunk(r, c, slot, length):
+    def chunk(r, c, slot, length, first):
         k = kbuf[slot]                                    # (T, HD)
         v = vbuf[slot]
         q = q_ref[r]                                      # (H, HD)
@@ -126,8 +146,10 @@ def _kernel(len_ref, tab_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         s = jax.lax.dot_general(
             q.astype(ct), k.astype(ct), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (H, T)
-        live = c * T + jax.lax.broadcasted_iota(
-            jnp.int32, (H, T), 1) < length
+        at = c * T + jax.lax.broadcasted_iota(jnp.int32, (H, T), 1)
+        live = at < length
+        if windowed:
+            live &= at >= first
         s = jnp.where(live, s, _NEG)
         m_prev = m_scr[...]                               # (H, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -136,19 +158,22 @@ def _kernel(len_ref, tab_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
         # rows at or past the fill level: a partly filled block's tail,
         # or a page this chunk never fetched — either may hold NaN/inf
-        rows_live = c * T + jax.lax.broadcasted_iota(
-            jnp.int32, (T, HD), 0) < length
+        row_at = c * T + jax.lax.broadcasted_iota(jnp.int32, (T, HD), 0)
+        rows_live = row_at < length
+        if windowed:               # a page the window only partly covers,
+            rows_live &= row_at >= first    # or one behind it never fetched
         v = jnp.where(rows_live, v, jnp.zeros((), v.dtype))
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (H, HD)
         m_scr[...] = m_new
 
-    dma(0, 0, 0, wait=False)
+    dma(0, first_chunk(0), 0, wait=False)
 
     def row(r, n):
         length = len_ref[r]
         nchunks = jnp.maximum(pl.cdiv(length, T), 1)
+        first = first_ref[r] if windowed else 0
         m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
@@ -163,13 +188,14 @@ def _kernel(len_ref, tab_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
 
             @pl.when(jnp.logical_and(last, r + 1 < R))
             def _():
-                dma(r + 1, 0, 1 - slot, wait=False)
+                nxt = r + 1
+                dma(nxt, first_chunk(nxt), 1 - slot, wait=False)
 
             dma(r, c, slot, wait=True)
-            chunk(r, c, slot, length)
+            chunk(r, c, slot, length, first)
             return n + 1
 
-        n = jax.lax.fori_loop(0, nchunks, step, n)
+        n = jax.lax.fori_loop(first_chunk(r), nchunks, step, n)
         l = l_scr[...]
         o_ref[r] = (acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
                     ).astype(o_ref.dtype)
@@ -180,21 +206,30 @@ def _kernel(len_ref, tab_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _paged(qbd, k_pool, v_pool, tables, lengths, layer, scale: float,
-           interpret: bool):
+           interpret: bool, first=None):
     """qbd: (R, H, Hkv·D) block-diagonal queries; pools (L, NB, bs,
-    Hkv·D) → (R, H, Hkv·D), row ``h``'s output in its kv head's block."""
+    Hkv·D) → (R, H, Hkv·D), row ``h``'s output in its kv head's block.
+    ``first (R,)``: each row's first live key (None: key 0, and the trace
+    of the kernel as it was without a window)."""
     R, H, HD = qbd.shape
     bs = k_pool.shape[2]
     W = tables.shape[1]
     ppb = _pages_per_chunk(W, bs, HD * k_pool.dtype.itemsize)
+    windowed = first is not None
+    kern = functools.partial(_kernel, scale=scale, R=R, W=W, bs=bs, ppb=ppb)
+    if windowed:
+        kern = functools.partial(kern, windowed=True)
     operands = _unify_vma(
-        lengths.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
+        lengths.astype(jnp.int32),
+        *((first.astype(jnp.int32),) if windowed else ()),
+        tables.reshape(-1).astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1), qbd, k_pool, v_pool)
     whole = pl.BlockSpec((R, H, HD), lambda i, *_: (0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, R=R, W=W, bs=bs, ppb=ppb),
+        kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,       # lengths, tables, layer
+            # lengths, [first,] tables, layer
+            num_scalar_prefetch=4 if windowed else 3,
             grid=(1,),
             in_specs=[whole,
                       pl.BlockSpec(memory_space=pl.ANY),
@@ -216,7 +251,8 @@ def _paged(qbd, k_pool, v_pool, tables, lengths, layer, scale: float,
     )(*operands)
 
 
-def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer):
+def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer,
+                           first=None):
     """One layer's packed decode attention over the paged pool.
 
     ``q (R, H, D)``: one query per row; ``k_pool``/``v_pool`` the WHOLE
@@ -227,7 +263,12 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer):
     lengths[r])``, position ``p`` living at ``(tables[r, p // bs], p %
     bs)``. Returns ``o (R, H, D)`` in ``q.dtype``. Table entries past a
     row's last live block are never read, and nothing stored at or past
-    a fill level can reach the output. Callers gate on
+    a fill level can reach the output. ``first (R,)`` int32, a window
+    layer's: row ``r`` attends to ``[first[r], lengths[r])`` alone; a chunk
+    wholly below ``first[r]`` is not visited, a page wholly below it not
+    fetched (its table entry may be a released block's, whatever it reads),
+    a key below it scores ``_NEG`` with ``p`` forced to 0. ``None`` traces
+    the kernel without any of that. Callers gate on
     :func:`unsupported_reason` / ``backend.use_pallas``."""
     R, H, D = q.shape
     bs, HD = k_pool.shape[2:]
@@ -244,7 +285,7 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer):
            == jnp.arange(Hkv)[None, :])[None, :, :, None]   # (1, H, Hkv, 1)
     qbd = jnp.where(own, q[:, :, None, :], jnp.zeros((), q.dtype))
     o = _paged(qbd.reshape(R, H, HD), k_pool, v_pool, tables, lengths,
-               layer, 1.0 / (D ** 0.5), _interpret())
+               layer, 1.0 / (D ** 0.5), _interpret(), first=first)
     # the diagonal blocks; a select, so an off-diagonal product (some
     # other head's V) never meets arithmetic
     o = jnp.where(own, o.reshape(R, H, Hkv, D), jnp.zeros((), o.dtype))

@@ -252,6 +252,32 @@ def sigmoid_topk_route(xt: jnp.ndarray, wg: jnp.ndarray, bias: jnp.ndarray,
     return idx.astype(jnp.int32), weight
 
 
+def softmax_topk_route(xt: jnp.ndarray, wg: jnp.ndarray, k: int,
+                       scale: float = 1.0):
+    """``(idx (T, k) int32, weight (T, k) f32)`` of softmax routing with
+    renormalised top-k weights (``norm_topk_prob``): ``p = softmax(x·wg)``
+    over all experts in f32 (the product at full precision, as
+    :func:`sigmoid_topk_route` and for its reason), the ``k`` largest, and
+    ``scale · p_i / Σ_picked p``. No bias steers the picks."""
+    p = jax.nn.softmax(jax.lax.dot_general(
+        xt.astype(jnp.float32), wg.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32), axis=-1)
+    picked, idx = jax.lax.top_k(p, k)
+    weight = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), weight
+
+
+#: the routing rules of :func:`moe_ffn_dropless`: ``name -> (xt, params, k,
+#: scale) -> (idx, weight)``
+ROUTES = {
+    "sigmoid_bias": lambda xt, p, k, scale: sigmoid_topk_route(
+        xt, p["wg"], p["router_bias"], k, scale),
+    "softmax": lambda xt, p, k, scale: softmax_topk_route(
+        xt, p["wg"], k, scale),
+}
+
+
 def _rows_of(x, index):
     """``x[index]`` with zero rows where ``index`` is out of range."""
     return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
@@ -311,12 +337,17 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
-                     first_expert: int = 0, row_tile: Optional[int] = None):
-    """Sigmoid top-k MoE feed-forward in which no token is ever dropped,
-    over the experts THIS device holds.
+                     first_expert: int = 0, row_tile: Optional[int] = None,
+                     route: str = "sigmoid_bias"):
+    """Top-k MoE feed-forward in which no token is ever dropped, over the
+    experts THIS device holds; ``route`` names the rule that picks and
+    weighs (:data:`ROUTES`: ``sigmoid_bias``, DeepSeek-V3's ``noaux_tc``,
+    or ``softmax`` with renormalised top-k weights), and everything after
+    the picks is one path.
 
     ``params``: ``wg (d, E)`` the router over all ``E`` routed experts,
-    ``router_bias (E,)`` the correction bias (a buffer), and the gated
+    ``router_bias (E,)`` the correction bias (a buffer; ``sigmoid_bias``
+    alone reads it, a ``softmax`` tree has no such leaf), and the gated
     expert stacks ``w1``/``w3 (held, d, ff)``, ``w2 (held, ff, d)`` of the
     ``held`` experts ``first_expert .. first_expert + held - 1``. Every
     token is routed over all ``E``; the ``T·k`` (token, expert) pairs are
@@ -343,8 +374,7 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     lead, d = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, d)
     T = xt.shape[0]
-    idx, weight = sigmoid_topk_route(xt, params["wg"], params["router_bias"],
-                                     top_k, scale)
+    idx, weight = ROUTES[route](xt, params, top_k, scale)
     P_ = T * top_k
     local = idx.reshape(P_) - first_expert
     is_held = (local >= 0) & (local < held)

@@ -12,11 +12,17 @@ same for every family.
   ``paged_cache.py``, as they were.
 * :class:`LatentFamily` (``Dots3Config``): latent pages of two layer kinds
   and the programs of ``latent_step.py``.
+* :class:`WindowedKVFamily` (``Mellum2Config``): k/v pages of two layer
+  kinds under ``paged_cache.py``'s own two programs, each layer told its
+  kind, with a dropless expert FFN in every block.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple, Optional
+
+import numpy as np
 
 from byteps_tpu.models.gpt import GPTConfig
 
@@ -35,6 +41,66 @@ class PoolLayout(NamedTuple):
     window_blocks: int = 0
 
 
+class LateStats:
+    """What a family's programs count on the device (``pool.stats``, f32, one
+    value a name of ``names``), observed into the registry once the device
+    has it — a step later, when it costs no wait. A family's own subclass
+    says in :meth:`observe` which series each value feeds."""
+
+    names: tuple = ()
+
+    def __init__(self):
+        self._pending = []
+
+    def note(self, pool) -> None:
+        # a buffer of its own: the pool, stats leaf included, is donated to
+        # the next program
+        self._pending.append(pool.stats + 0.0)
+        self.drain(block=False)
+
+    def drain(self, block: bool) -> None:
+        while self._pending and (block or self._pending[0].is_ready()):
+            self.observe(dict(zip(
+                self.names, np.asarray(self._pending.pop(0)).tolist())))
+
+    def observe(self, s: dict) -> None:
+        raise NotImplementedError
+
+
+def window_pool_blocks(window: int, block_size: int, max_batch: int,
+                       prefill_chunk: int) -> int:
+    """Blocks of a window kind's pool: a request keeps the blocks of its
+    last ``window - 1`` positions (and the one being filled); the one request
+    a chunk runs for holds the chunk's beside them. Every admitted request
+    at once, and scratch."""
+    per_req = -(-(window - 1) // block_size) + 2
+    admitted = max_batch + max(1, max_batch // 4)
+    return 1 + admitted * per_req + -(-prefill_chunk // block_size) + 1
+
+
+class _Refusing:
+    """A family whose layout does not carry everything: ``REFUSED`` maps a
+    feature to the message's subject, each refused at construction by
+    name, a speculative request at ``submit``."""
+
+    REFUSED: dict = {}
+
+    def _refuse(self, name, cfg):
+        raise NotImplementedError(
+            f"Scheduler: {self.REFUSED[name]} is not served with the "
+            f"{self.name} cache layout of {type(cfg).__name__} (asked "
+            f"through {name})")
+
+    def validate(self, params, cfg, features) -> None:
+        for name, on in features.items():
+            if on:
+                self._refuse(name, cfg)
+
+    def validate_request(self, req, cfg) -> None:
+        if req.spec is not None:
+            self._refuse("speculation", cfg)
+
+
 class GPTFamily:
     """The dense GPT family over a k/v pool (``paged_cache.py``)."""
 
@@ -45,8 +111,10 @@ class GPTFamily:
         if any("moe" in p for p in params["blocks"]):
             raise NotImplementedError(
                 "Scheduler: a Switch-routed expert layer (models/moe_gpt.py) "
-                "is not served — the GPT serve step runs dense-MLP blocks "
-                "only (no-drop capacity routing has not been paged)")
+                "is not served — a GPTConfig's serve step runs dense-MLP "
+                "blocks only (capacity routing has not been paged; dropless "
+                "expert layers are served under a Mellum2Config over k/v "
+                "pages and under a Dots3Config over latent pages)")
 
     def validate_request(self, req, cfg) -> None:
         pass
@@ -86,7 +154,7 @@ class GPTFamily:
         return None
 
 
-class LatentFamily:
+class LatentFamily(_Refusing):
     """dots3 over latent pages of two layer kinds (``latent_step.py``)."""
 
     name = "latent"
@@ -106,30 +174,12 @@ class LatentFamily:
         "tp_axis": "tensor parallelism",
     }
 
-    def validate(self, params, cfg, features) -> None:
-        for name, on in features.items():
-            if on:
-                self._refuse(name, cfg)
-
-    def _refuse(self, name, cfg):
-        raise NotImplementedError(
-            f"Scheduler: {self.REFUSED[name]} is not served with the latent "
-            f"cache layout of {type(cfg).__name__} (asked through {name})")
-
-    def validate_request(self, req, cfg) -> None:
-        if req.spec is not None:
-            self._refuse("speculation", cfg)
-
     def layout(self, params, cfg, *, block_size, pool_blocks, max_batch,
                prefill_chunk, quant) -> PoolLayout:
         from byteps_tpu.serve.latent_step import init_pool
 
-        # a request keeps the blocks of its last window - 1 positions (and
-        # the one being filled); the one request a chunk runs for holds the
-        # chunk's beside them. Every admitted request at once, and scratch
-        per_req = -(-(cfg.window - 1) // block_size) + 2
-        admitted = max_batch + max(1, max_batch // 4)
-        wb = 1 + admitted * per_req + -(-prefill_chunk // block_size) + 1
+        wb = window_pool_blocks(cfg.window, block_size, max_batch,
+                                prefill_chunk)
         return PoolLayout(
             state=init_pool(cfg, block_size, pool_blocks, wb),
             window=cfg.window, window_blocks=wb)
@@ -157,14 +207,93 @@ class LatentFamily:
         return LateStats()
 
 
+@functools.lru_cache(maxsize=16)
+def _windowed_plan(cfg):
+    """The ``StepPlan`` of a ``Mellum2Config``: each layer's row in its
+    kind's pool, its window, its rotation; the expert FFN. Built once a
+    configuration (a chunk is dispatched through it)."""
+    from byteps_tpu.models.mellum2 import (
+        FULL, SLIDING, expert_ffn, rope_freqs)
+    from byteps_tpu.serve.paged_cache import LayerKind, StepPlan
+
+    place = {li: i for kind in (FULL, SLIDING)
+             for i, li in enumerate(cfg.layers_of(kind))}
+    return StepPlan(tuple(
+        LayerKind(place[li], cfg.window if kind == SLIDING else None,
+                  rope_freqs(cfg, kind))
+        for li, kind in enumerate(cfg.layer_types)), expert_ffn)
+
+
+class WindowedKVFamily(_Refusing, GPTFamily):
+    """Mellum2 over k/v pages of two layer kinds: the programs of
+    ``paged_cache.py`` under a :class:`~paged_cache.StepPlan` that gives
+    each layer its kind — a global pool for the full (YaRN) layers, a window
+    pool whose blocks come back for the sliding ones — and the dropless
+    expert FFN as the block's second half."""
+
+    name = "windowed k/v"
+    shares_prefixes = False
+
+    #: what two kinds of k/v page do not carry yet, each refused at
+    #: construction: ``feature -> the message's subject``
+    REFUSED = {
+        "prefix_cache": "the prefix cache (a window layer's released blocks "
+                        "cannot be shared)",
+        "speculation": "speculative decoding (a rejected draft would rewind "
+                       "past blocks the window layers have given back)",
+        "adapter_pool": "LoRA adapter slabs",
+        "quant_cache": "the int8 pool",
+        "role": "role='prefill'|'decode' and migration over kv_wire (a "
+                "payload carries one kind of page)",
+        "tp_axis": "tensor parallelism",
+    }
+
+    def layout(self, params, cfg, *, block_size, pool_blocks, max_batch,
+               prefill_chunk, quant) -> PoolLayout:
+        from byteps_tpu.models.mellum2 import FULL, SLIDING
+        from byteps_tpu.serve.paged_cache import kv_pool_state
+
+        wb = window_pool_blocks(cfg.window, block_size, max_batch,
+                                prefill_chunk)
+        return PoolLayout(
+            state=kv_pool_state(
+                cfg, block_size, pool_blocks, cfg.kv_heads, False,
+                layers=len(cfg.layers_of(FULL)),
+                window_layers=len(cfg.layers_of(SLIDING)), window_blocks=wb),
+            kv_heads=cfg.kv_heads, window=cfg.window, window_blocks=wb)
+
+    def operands(self, params, cfg):
+        return params              # published in bf16: every leaf as it is
+
+    def decode_fn(self, cfg, block_size, tp_axis, lora_sig):
+        from byteps_tpu.serve.paged_cache import make_paged_decode_fn
+
+        return make_paged_decode_fn(cfg, block_size, plan=_windowed_plan(cfg))
+
+    def prefill_fn(self, cfg, block_size, chunk_len, tp_axis, with_readout):
+        from byteps_tpu.serve.paged_cache import make_paged_prefill_fn
+
+        return make_paged_prefill_fn(cfg, block_size, chunk_len,
+                                     with_readout=with_readout,
+                                     plan=_windowed_plan(cfg))
+
+    def late_stats(self):
+        from byteps_tpu.serve.paged_cache import StepStats
+
+        return StepStats()
+
+
 def serve_family(cfg):
     """The family that serves ``cfg``, by its type."""
     from byteps_tpu.models.dots3 import Dots3Config
+    from byteps_tpu.models.mellum2 import Mellum2Config
 
     if isinstance(cfg, Dots3Config):
         return LatentFamily()
+    if isinstance(cfg, Mellum2Config):
+        return WindowedKVFamily()
     if isinstance(cfg, GPTConfig):
         return GPTFamily()
     raise TypeError(
         f"Scheduler: no serve family for a {type(cfg).__name__} "
-        "(GPTConfig and Dots3Config are served)")
+        "(GPTConfig, Dots3Config and Mellum2Config are served)")

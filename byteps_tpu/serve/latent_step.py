@@ -33,7 +33,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from byteps_tpu.common.metrics import get_registry
 from byteps_tpu.models import dots3
@@ -45,6 +44,8 @@ from byteps_tpu.ops.flash_attention import (
     flash_attention_masked,
     flash_attention_window,
 )
+from byteps_tpu.serve import families
+from byteps_tpu.serve.paged_cache import gather_rows as _gather_rows
 
 _NEG = -1e30
 #: a chunk's full layers work on the keys of the first ``ceil((pos0 + C) /
@@ -95,13 +96,15 @@ def init_pool(cfg: Dots3Config, block_size: int, pool_blocks: int,
         stats=jnp.zeros((len(STATS),), jnp.float32))
 
 
-class LateStats:
-    """``pool.stats`` of each dispatched program, observed into the registry
-    once the device has it — a step later, when it costs no wait."""
+class LateStats(families.LateStats):
+    """:data:`STATS` of each dispatched program into the ``moe.*`` and
+    ``serve.dsa.*`` series."""
+
+    names = STATS
 
     def __init__(self):
+        super().__init__()
         reg = get_registry()
-        self._pending = []
         self._pairs_here = reg.histogram("moe.pairs_here")
         self._load = reg.histogram("moe.load_max_over_mean")
         self._per_query = reg.histogram("serve.dsa.selected_per_query")
@@ -111,24 +114,16 @@ class LateStats:
         self._prefill_selected = reg.counter(
             "serve.dsa.prefill_selected_keys")
 
-    def note(self, pool: LatentPool) -> None:
-        # a buffer of its own: the pool, stats leaf included, is donated to
-        # the next program
-        self._pending.append(pool.stats + 0.0)
-        self.drain(block=False)
-
-    def drain(self, block: bool) -> None:
-        while self._pending and (block or self._pending[0].is_ready()):
-            s = dict(zip(STATS, np.asarray(self._pending.pop(0)).tolist()))
-            self._pairs_here.observe(s["moe.pairs_here"])
-            self._load.observe(s["moe.load_max_over_mean"])
-            self._scored.inc(int(s["dsa.scored_pairs"]))
-            self._selected.inc(int(s["dsa.selected_keys"]))
-            self._prefill_scored.inc(int(s["dsa.prefill_scored_pairs"]))
-            self._prefill_selected.inc(int(s["dsa.prefill_selected_keys"]))
-            if s["dsa.queries"] > 0:
-                self._per_query.observe(
-                    s["dsa.selected_keys"] / s["dsa.queries"])
+    def observe(self, s: dict) -> None:
+        self._pairs_here.observe(s["moe.pairs_here"])
+        self._load.observe(s["moe.load_max_over_mean"])
+        self._scored.inc(int(s["dsa.scored_pairs"]))
+        self._selected.inc(int(s["dsa.selected_keys"]))
+        self._prefill_scored.inc(int(s["dsa.prefill_scored_pairs"]))
+        self._prefill_selected.inc(int(s["dsa.prefill_selected_keys"]))
+        if s["dsa.queries"] > 0:
+            self._per_query.observe(
+                s["dsa.selected_keys"] / s["dsa.queries"])
 
 
 def _pick_rows(scores, topk: int):
@@ -184,14 +179,6 @@ def select_mask(scores, topk: int):
     last = jax.lax.fori_loop(0, nbits, pos_bit,
                              jnp.zeros(scores.shape[:-1], jnp.int32))
     return above | (tied & (pos <= last[:, None]))
-
-
-def _gather_rows(pool_a, layer, table, sel, block_size: int):
-    """Rows at logical positions ``sel (..., K)`` of one request (``table
-    (W,)``) or of one request a row (``table (N, W)``)."""
-    blk = (jnp.take_along_axis(table, sel // block_size, axis=-1)
-           if table.ndim == 2 else jnp.take(table, sel // block_size))
-    return pool_a[layer, blk, sel % block_size]
 
 
 def _full_decode_scores(qi, w, keys, pos):

@@ -65,13 +65,23 @@ own only their ``attend`` — where the new keys go, what attends over them:
   single-request prefill by construction), scatter the chunk's newly
   written rows of that layer back in place. No other block of the pool
   is read, moved or written.
+
+Both programs take a :class:`StepPlan`: a :class:`LayerKind` a layer (its
+row in its pool, its window if it has one, what it rotates by) and the
+block's second half. The GPT family is the plan "one kind"
+(:func:`one_kind_plan`); a family whose sliding layers give their blocks
+back (``families.WindowedKVFamily``) keeps those layers' k/v in a second,
+small pool (``PoolState.wk``/``wv``) under the cache's window kind, and its
+programs read two table lines, start a window layer's attention at ``fill −
+window`` and count what they did into ``PoolState.stats``
+(:class:`StepStats`).
 """
 
 from __future__ import annotations
 
 import functools
 import itertools
-from typing import Dict, List, NamedTuple, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +96,7 @@ from byteps_tpu.models.generate import (
 )
 from byteps_tpu.models.gpt import (
     GPTConfig,
+    RopeFreqs,
     _readout,
     attn_half,
     ffn_half,
@@ -93,12 +104,16 @@ from byteps_tpu.models.gpt import (
     resolve_rope,
 )
 from byteps_tpu.ops.backend import note_fallback, use_pallas
-from byteps_tpu.ops.flash_attention import attention_lse
+from byteps_tpu.ops.flash_attention import (
+    attention_lse,
+    flash_attention_window,
+)
 from byteps_tpu.ops.paged_attention import (
     paged_attention_decode,
     unsupported_reason as paged_attn_unsupported,
 )
 from byteps_tpu.ops.segmented_lora import segmented_lora_delta
+from byteps_tpu.serve.families import LateStats
 
 
 class PoolState(NamedTuple):
@@ -116,18 +131,85 @@ class PoolState(NamedTuple):
     the pool then converts all of it on the way in and out (PERF.md §6,
     PR 28). The layout is private to this module: views, payloads and
     the wire keep ``(..., h_kv, head_dim)``.
+
+    A family with window layers (:class:`LayerKind`) keeps two kinds of
+    page: ``k``/``v`` hold its global layers alone and ``wk``/``wv``
+    ``(window layers, window blocks, block_size, h_kv * head_dim)`` the
+    layers that give blocks back, in a pool of their own with its own block
+    ids; ``stats`` f32 ``(len(STATS),)`` is what the program that last
+    wrote the pool counted (:class:`StepStats` reads it a step late). All
+    three are None for one kind of layer: no leaf, so the GPT family's
+    programs are traced over the tree they always had.
     """
 
     k: jnp.ndarray
     v: jnp.ndarray
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    wk: Optional[jnp.ndarray] = None
+    wv: Optional[jnp.ndarray] = None
+    stats: Optional[jnp.ndarray] = None
+
+
+class LayerKind(NamedTuple):
+    """How one layer uses the cache. ``index``: its row in its pool.
+    ``window``: None for a global layer (``pool.k``/``v``, table line 0,
+    every key live); else the keys a query sees, its own included — a
+    window layer (``pool.wk``/``wv``, table line 1, keys below ``fill -
+    window`` neither held nor read). ``rope``: what it rotates by (0: not at
+    all)."""
+
+    index: int
+    window: Optional[int] = None
+    rope: Union[float, RopeFreqs] = 0.0
+
+
+class StepPlan(NamedTuple):
+    """What the two programs need of a model beyond a ``GPTConfig``'s
+    fields: a :class:`LayerKind` a layer, and the block's second half —
+    ``ffn(cfg, p, h) -> (out, aux f32 (3,))`` with ``aux`` = (pairs
+    computed, experts with a row, heaviest expert over the mean); None is
+    the dense MLP. Hashable: it keys the programs' factories."""
+
+    kinds: Tuple[LayerKind, ...]
+    ffn: Optional[Callable] = None
+
+
+def _window_of(plan: "StepPlan") -> Optional[int]:
+    """The one window of a plan's window layers (None: it has none)."""
+    windows = {k.window for k in plan.kinds} - {None}
+    if len(windows) > 1:
+        raise ValueError(f"one window a plan; got {sorted(windows)}")
+    return windows.pop() if windows else None
+
+
+def one_kind_plan(cfg) -> StepPlan:
+    """The GPT family: every layer global, one base, the dense MLP."""
+    rope = resolve_rope(cfg)
+    return StepPlan(tuple(LayerKind(li, None, rope)
+                          for li in range(cfg.n_layers)))
+
+
+#: ``pool.stats``: what one program counted, f32. ``moe.experts_hit`` and
+#: ``moe.pairs_here`` add over the ``moe.layers`` expert layers of the
+#: program; keys a decode step's attention must read (a row and layer: its
+#: length, or the window where that is shorter) and visible (query, key)
+#: pairs of a chunk, by layer kind
+STATS = ("moe.pairs_here", "moe.experts_hit", "moe.layers",
+         "moe.load_max_over_mean",
+         "serve.kv.decode_keys_read.full", "serve.kv.decode_keys_read.window",
+         "serve.attn.prefill_pairs.full", "serve.attn.prefill_pairs.window")
 
 
 def kv_pool_state(cfg: GPTConfig, block_size: int, pool_blocks: int,
-                  kv_heads: int, quant: bool) -> PoolState:
-    """The zeroed k/v pool of the GPT family."""
-    shape = (cfg.n_layers, pool_blocks, block_size, kv_heads * cfg.head_dim)
+                  kv_heads: int, quant: bool, layers: Optional[int] = None,
+                  window_layers: int = 0, window_blocks: int = 0
+                  ) -> PoolState:
+    """The zeroed k/v pool: ``layers`` (default every layer) global ones
+    and, with ``window_layers``, a window pool of ``window_blocks`` blocks
+    beside it and the ``stats`` leaf."""
+    n = cfg.n_layers if layers is None else layers
+    shape = (n, pool_blocks, block_size, kv_heads * cfg.head_dim)
     if quant:
         return PoolState(
             k=jnp.zeros(shape, jnp.int8),
@@ -135,8 +217,14 @@ def kv_pool_state(cfg: GPTConfig, block_size: int, pool_blocks: int,
             k_scale=jnp.zeros(shape[:-1] + (kv_heads,), jnp.float32),
             v_scale=jnp.zeros(shape[:-1] + (kv_heads,), jnp.float32),
         )
-    return PoolState(k=jnp.zeros(shape, cfg.dtype),
+    pool = PoolState(k=jnp.zeros(shape, cfg.dtype),
                      v=jnp.zeros(shape, cfg.dtype))
+    if window_layers:
+        wshape = (window_layers, window_blocks) + shape[2:]
+        pool = pool._replace(wk=jnp.zeros(wshape, cfg.dtype),
+                             wv=jnp.zeros(wshape, cfg.dtype),
+                             stats=jnp.zeros((len(STATS),), jnp.float32))
+    return pool
 
 
 class PoolExhausted(RuntimeError):
@@ -538,7 +626,7 @@ class PagedKVCache:
                 raise PoolExhausted(self._exhausted_msg(rid, 1))
             nb = self._alloc_block()
             st = self.state
-            self.state = PoolState(
+            self.state = st._replace(
                 k=st.k.at[:, nb].set(st.k[:, b]),
                 v=st.v.at[:, nb].set(st.v[:, b]),
                 k_scale=(None if st.k_scale is None
@@ -773,12 +861,12 @@ class PagedKVCache:
                 [np.asarray(p.k_scale) for p in payloads], axis=1))
             vs = jnp.asarray(np.stack(
                 [np.asarray(p.v_scale) for p in payloads], axis=1))
-            self.state = PoolState(
+            self.state = st._replace(
                 k=st.k.at[:, idx].set(k), v=st.v.at[:, idx].set(v),
                 k_scale=st.k_scale.at[:, idx].set(ks),
                 v_scale=st.v_scale.at[:, idx].set(vs))
         else:
-            self.state = PoolState(
+            self.state = st._replace(
                 k=st.k.at[:, idx].set(k.astype(st.k.dtype)),
                 v=st.v.at[:, idx].set(v.astype(st.v.dtype)))
         self.migrated_in_blocks += len(block_ids)
@@ -807,7 +895,7 @@ class PagedKVCache:
             return 0
         remap = {old: new for new, old in enumerate(live, start=1)}
         src = jnp.asarray(perm)
-        self.state = PoolState(
+        self.state = self.state._replace(
             k=self.state.k[:, src],
             v=self.state.v[:, src],
             k_scale=(None if self.state.k_scale is None
@@ -848,6 +936,67 @@ def _gather_view(pool_l, scale_l, table, length, dtype, head_dim):
     g = g.astype(dtype)
     keep = jnp.arange(S) < jnp.asarray(length)[..., None]
     return jnp.where(keep[..., None, None], g, jnp.zeros((), dtype))
+
+
+class StepStats(LateStats):
+    """:data:`STATS` of each dispatched program of a family with window
+    layers into the ``moe.*`` histograms and the ``serve.kv.*`` /
+    ``serve.attn.*`` counters (docs/observability.md)."""
+
+    names = STATS
+
+    def __init__(self):
+        super().__init__()
+        reg = get_registry()
+        self._pairs_here = reg.histogram("moe.pairs_here")
+        self._experts_hit = reg.histogram("moe.experts_hit")
+        self._load = reg.histogram("moe.load_max_over_mean")
+        self._counters = {n: reg.counter(n) for n in STATS
+                          if n.startswith("serve.")}
+
+    def observe(self, s: dict) -> None:
+        if s["moe.layers"] > 0:
+            self._pairs_here.observe(s["moe.pairs_here"])
+            # of a layer: the mean over the program's expert layers
+            self._experts_hit.observe(s["moe.experts_hit"] / s["moe.layers"])
+            self._load.observe(s["moe.load_max_over_mean"])
+        for n, c in self._counters.items():
+            c.inc(int(s[n]))
+
+
+def _fold_moe(total, aux):
+    """A layer's ``ffn`` aux into the program's: pairs, experts hit and
+    layers add; the load ratio keeps its worst layer."""
+    return jnp.stack([total[0] + aux[0], total[1] + aux[1], total[2] + 1.0,
+                      jnp.maximum(total[3], aux[2])])
+
+
+def gather_rows(pool_a, layer, table, sel, block_size: int):
+    """Rows of one layer of a pool at logical positions ``sel (..., K)`` of
+    one request (``table (W,)``) or of one request a row (``table (N,
+    W)``)."""
+    blk = (jnp.take_along_axis(table, sel // block_size, axis=-1)
+           if table.ndim == 2 else jnp.take(table, sel // block_size))
+    return pool_a[layer, blk, sel % block_size]
+
+
+def _window_attend_twin(q, k_pool, v_pool, wi, table, pos, window: int,
+                        block_size: int):
+    """The jnp twin of the windowed kernel call: each row's last ``window``
+    keys gathered one by one through its table (no wider view is made),
+    softmax in f32 over those at or after position 0. ``q (R, 1, H, D)``."""
+    R, _, H, D = q.shape
+    at = pos[:, None] - (window - 1) + jnp.arange(window)[None, :]
+    kk = gather_rows(k_pool, wi, table, jnp.maximum(at, 0), block_size)
+    vv = gather_rows(v_pool, wi, table, jnp.maximum(at, 0), block_size)
+    kk = kk.reshape(R, window, -1, D)
+    vv = vv.reshape(R, window, -1, D)
+    qg = q[:, 0].reshape(R, kk.shape[2], -1, D)
+    s = jnp.einsum("rhgd,rkhd->rhgk", qg.astype(jnp.float32),
+                   kk.astype(jnp.float32)) * D ** -0.5
+    pr = jax.nn.softmax(jnp.where((at >= 0)[:, None, None], s, -1e30), -1)
+    o = jnp.einsum("rhgk,rkhd->rhgd", pr, vv.astype(jnp.float32))
+    return o.reshape(R, 1, H, D).astype(q.dtype)
 
 
 def decode_uses_paged_attn(cfg: GPTConfig, block_size: int,
@@ -919,7 +1068,8 @@ def serve_operands(params, cfg: GPTConfig):
 @functools.lru_cache(maxsize=64)
 def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                          tp_axis: Optional[str] = None,
-                         lora_sig: Optional[tuple] = None):
+                         lora_sig: Optional[tuple] = None,
+                         plan: Optional[StepPlan] = None):
     """Build the jitted packed decode step.
 
     ``step(params, pool, toks, pos, tables) -> (logits (R, vocab) f32,
@@ -936,8 +1086,14 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     block (``ensure_writable``) before this step scatters into
     ``tables[r][pos // bs]``, so the scatter below only ever lands in a
     private block (or scratch).
-    Dense-MLP GPT families only (``families.GPTFamily`` refuses a tree
-    with a Switch-routed layer when the scheduler is built).
+    ``plan`` (default :func:`one_kind_plan`: every layer global, the dense
+    MLP; ``families.GPTFamily`` refuses a tree with a Switch-routed layer
+    when the scheduler is built) tells each layer its kind. With window
+    layers ``tables`` is ``(R, 2, W)`` — line 0 the global kind's blocks,
+    line 1 the window kind's, indexed by logical block, 0 where one was
+    released — and a window layer scatters into ``pool.wk``/``wv`` and
+    attends over ``[pos + 1 - window, pos]`` alone: the kernel with each
+    row's first key, or :func:`_window_attend_twin`.
 
     Multi-tenant variant: ``lora_sig=(targets, rank_bucket,
     n_adapter_slots)`` makes the step accept two trailing arguments —
@@ -955,7 +1111,7 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     replica in the process shares ONE jit wrapper, so a fresh replica
     (bench rep, failover respawn) reuses the compiled steps instead of
     paying a full retrace."""
-    rope_base = resolve_rope(cfg)
+    plan = one_kind_plan(cfg) if plan is None else plan
     norm_fn, norm_eps = resolve_norm(cfg)
     kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
     lora_targets = () if lora_sig is None else tuple(lora_sig[0])
@@ -974,10 +1130,14 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                 row_parallel=name in ("wo", "w2"), tp_axis=tp_axis)
         return delta
 
-    def _pool_attend(pool, li, blk, off, pos, tables):
+    def _pool_attend(pool, kind, blk, off, pos, tables):
         """The paged pool's ``attend``: scatter each row's new K/V into its
-        block slot of layer ``li``, then attend over the pool through the
-        block tables; the carry is the pool."""
+        block slot of the layer's row in its kind's pool, then attend over
+        that pool through the kind's block tables; the carry is the
+        pool."""
+        li = kind.index
+        kn, vn = ("k", "v") if kind.window is None else ("wk", "wv")
+
         def attend(q, k, v):
             R, kv_loc, head_dim = q.shape[0], k.shape[2], q.shape[-1]
             quant = pool.k_scale is not None
@@ -988,27 +1148,34 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                 if quant:
                     kq, ks = _quantize_block(k)
                     vq, vs = _quantize_block(v)
-                    new = PoolState(
+                    new = pool._replace(
                         k=pool.k.at[li, blk, off].set(kq.reshape(R, -1)),
                         v=pool.v.at[li, blk, off].set(vq.reshape(R, -1)),
                         k_scale=pool.k_scale.at[li, blk, off].set(ks[:, 0]),
                         v_scale=pool.v_scale.at[li, blk, off].set(vs[:, 0]),
                     )
                 else:
-                    new = PoolState(
-                        k=pool.k.at[li, blk, off].set(
-                            k.reshape(R, -1).astype(pool.k.dtype)),
-                        v=pool.v.at[li, blk, off].set(
-                            v.reshape(R, -1).astype(pool.v.dtype)),
-                    )
+                    kp, vp = getattr(pool, kn), getattr(pool, vn)
+                    new = pool._replace(**{
+                        kn: kp.at[li, blk, off].set(
+                            k.reshape(R, -1).astype(kp.dtype)),
+                        vn: vp.at[li, blk, off].set(
+                            v.reshape(R, -1).astype(vp.dtype))})
             length = pos + 1                       # new key included
+            kp, vp = getattr(new, kn), getattr(new, vn)
             if decode_uses_paged_attn(cfg, block_size, kv_loc, quant):
                 # the pool is read where it lies: the WHOLE pool is the
                 # kernel's operand (a pool.k[li] operand could become a
                 # pool-sized copy per layer), the layer picked in its DMAs
                 with jax.named_scope("paged/attention"):
-                    o = paged_attention_decode(q[:, 0], new.k, new.v,
-                                               tables, length, li)
+                    o = paged_attention_decode(
+                        q[:, 0], kp, vp, tables, length, li,
+                        first=None if kind.window is None
+                        else jnp.maximum(length - kind.window, 0))
+            elif kind.window is not None:
+                with jax.named_scope("paged/attention"):
+                    o = _window_attend_twin(q, kp, vp, li, tables, pos,
+                                            kind.window, block_size)
             else:
                 with jax.named_scope("paged/gather_kv"):
                     kk = _gather_view(
@@ -1029,16 +1196,37 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, pool, toks, pos, tables, slabs=None, slots=None):
         x = _embed(params, toks[:, None], pos[:, None], cfg)  # (R, 1, d)
-        blk = jnp.take_along_axis(
-            tables, (pos // block_size)[:, None], axis=1)[:, 0]
+        # one table a kind: (R, W), or (R, 2, W) with a window kind
+        kind_tables = (tables,) if tables.ndim == 2 \
+            else (tables[:, 0], tables[:, 1])
+        blks = [jnp.take_along_axis(
+            t, (pos // block_size)[:, None], axis=1)[:, 0]
+            for t in kind_tables]
         off = pos % block_size
-        for li, p in enumerate(params["blocks"]):
+        moe = None if plan.ffn is None else jnp.zeros((4,), jnp.float32)
+        for li, (p, kind) in enumerate(zip(params["blocks"], plan.kinds)):
             delta = None if slabs is None else _slab_delta(slabs, slots, li)
+            line = 0 if kind.window is None else 1
             x, pool = attn_half(
                 x, p, cfg.head_dim, lambda: pos[:, None],
-                _pool_attend(pool, li, blk, off, pos, tables), tp_axis,
-                rope_base, delta=delta, **kw)
-            x, _ = ffn_half(x, p, tp_axis, delta=delta, **kw)
+                _pool_attend(pool, kind, blks[line], off, pos,
+                             kind_tables[line]), tp_axis,
+                kind.rope, delta=delta, **kw)
+            x, aux = ffn_half(
+                x, p, tp_axis, None if plan.ffn is None
+                else functools.partial(plan.ffn, cfg, p), delta=delta, **kw)
+            if aux is not None:
+                moe = _fold_moe(moe, aux)
+        if pool.stats is not None:
+            # keys each live row's attention must read (a padded row sits at
+            # position 0, where no request decodes), by layer kind
+            live = pos > 0
+            keys = [jnp.sum(jnp.where(live, pos + 1 if w is None else
+                                      jnp.minimum(pos + 1, w), 0))
+                    * sum(k.window == w for k in plan.kinds)
+                    for w in (None, _window_of(plan))]
+            pool = pool._replace(stats=jnp.concatenate([moe, jnp.stack(
+                [jnp.asarray(v, jnp.float32) for v in (*keys, 0.0, 0.0)])]))
         logits = _readout(params, x, norm_fn, norm_eps)
         return logits[:, 0], pool
 
@@ -1048,7 +1236,8 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
 @functools.lru_cache(maxsize=256)
 def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
                           tp_axis: Optional[str] = None,
-                          with_readout: bool = True):
+                          with_readout: bool = True,
+                          plan: Optional[StepPlan] = None):
     """Build the jitted per-request prefill/verify chunk.
 
     ``chunk(params, pool, tokens (1, C), pos0, table (W,)) ->
@@ -1071,11 +1260,21 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     tokens in, per-position logits out, and only the committed prefix
     of the written rows is ever counted live (the fill level rewinds
     exactly like ``speculative.py``'s cache contract).
+    ``plan`` as :func:`make_paged_decode_fn` takes it. With window layers
+    ``table`` is ``(2, W)``; a global layer runs the path above over line 0,
+    under the flash forward kernel's causal rule (GQA by index: the narrow
+    k/v is read as it is); a window layer writes the chunk's rows into
+    ``pool.wk``/``wv`` through line 1 and attends under
+    ``flash_attention_window`` over the ``window - 1`` rows before the chunk
+    (gathered through the table; those before position 0 are padding the
+    mask never lets through) and the chunk's own: no view of the request's
+    whole context is made for it.
     ``with_readout=False`` skips the vocab projection (an intermediate
     prefill chunk's logits are never read — at real vocab sizes that
     projection is the biggest weight stream in the chunk) and returns
     ``(None, pool)``. lru-cached like :func:`make_paged_decode_fn`."""
     C = chunk_len
+    plan = one_kind_plan(cfg) if plan is None else plan
     norm_fn, norm_eps = resolve_norm(cfg)
 
     def _view(pool_a, li, table, keep, *tail):
@@ -1092,12 +1291,55 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
         rows = jax.lax.dynamic_slice_in_dim(cache_a, pos0, C, axis=1)
         return pool_a.at[at].set(rows[0].reshape(C, -1))
 
+    def _ffn(p):
+        return None if plan.ffn is None \
+            else functools.partial(plan.ffn, cfg, p)
+
+    def _window_layer(x, p, pool, wi, pos0, table, blk, off, kind):
+        """A window layer of the chunk: ``(x, pool, aux)``."""
+        # keys laid out before the chunk: the window - 1 it can see, and as
+        # many more (masked by the window) as make the key count whole tiles
+        before = kind.window - 1
+        if before >= 128:
+            before = -(-before // 128) * 128
+
+        def attend(q, k, v):
+            with jax.named_scope("paged/scatter_kv"):
+                new = pool._replace(
+                    wk=pool.wk.at[wi, blk, off].set(
+                        k[0].reshape(C, -1).astype(pool.wk.dtype)),
+                    wv=pool.wv.at[wi, blk, off].set(
+                        v[0].reshape(C, -1).astype(pool.wv.dtype)))
+            with jax.named_scope("paged/gather_kv"):
+                at = jnp.maximum(pos0 - before + jnp.arange(before), 0)
+                pk = gather_rows(new.wk, wi, table, at, block_size)
+                pv = gather_rows(new.wv, wi, table, at, block_size)
+                kk = jnp.concatenate(
+                    [pk.reshape((1, before) + k.shape[2:]).astype(k.dtype),
+                     k], axis=1)
+                vv = jnp.concatenate(
+                    [pv.reshape((1, before) + v.shape[2:]).astype(v.dtype),
+                     v], axis=1)
+            with jax.named_scope("paged/window_attention"):
+                return flash_attention_window(
+                    q, kk, vv, pos0, pos0 - before, kind.window), new
+
+        kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
+        x, pool = attn_half(x, p, cfg.head_dim,
+                            lambda: pos0 + jnp.arange(C), attend, tp_axis,
+                            kind.rope, **kw)
+        x, aux = ffn_half(x, p, tp_axis, _ffn(p), **kw)
+        return x, pool, aux
+
     # jitted, the layer index DATA: layers of one shape share one trace.
     # A replica traces and lowers a chunk program for every tail chunk x
     # table width x readout before it serves, compile cache or not, and
-    # 36 traces of the block are most of a second of host time in each
-    @jax.jit
-    def _layer(x, p, pool, li, pos0, table, keep, blk, off):
+    # 36 traces of the block are most of a second of host time in each.
+    # ``kind``, static, carries no index: a trace a kind
+    @functools.partial(jax.jit, static_argnames="kind")
+    def _layer(x, p, pool, li, pos0, table, keep, blk, off, kind):
+        if kind.window is not None:
+            return _window_layer(x, p, pool, li, pos0, table, blk, off, kind)
         quant = pool.k_scale is not None
         with jax.named_scope("paged/gather_kv"):
             ck = _view(pool.k, li, table, keep, cfg.head_dim)
@@ -1105,32 +1347,49 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
             if quant:
                 ck = _QuantSlot(ck, _view(pool.k_scale, li, table, keep))
                 cv = _QuantSlot(cv, _view(pool.v_scale, li, table, keep))
-        x, ck, cv = _block_step(x, p, ck, cv, pos0, cfg, tp_axis, None,
-                                norm_fn=norm_fn, norm_eps=norm_eps)
+        x, ck, cv, *aux = _block_step(
+            x, p, ck, cv, pos0, cfg, tp_axis, None, norm_fn=norm_fn,
+            norm_eps=norm_eps, rope=kind.rope, ffn=_ffn(p))
         with jax.named_scope("paged/scatter_kv"):
             at = (li, blk, off)
             if quant:
-                pool = PoolState(
+                pool = pool._replace(
                     k=_put(pool.k, at, ck.q, pos0),
                     v=_put(pool.v, at, cv.q, pos0),
                     k_scale=_put(pool.k_scale, at, ck.scale, pos0),
                     v_scale=_put(pool.v_scale, at, cv.scale, pos0))
             else:
-                pool = PoolState(k=_put(pool.k, at, ck, pos0),
-                                 v=_put(pool.v, at, cv, pos0))
-        return x, pool
+                pool = pool._replace(k=_put(pool.k, at, ck, pos0),
+                                     v=_put(pool.v, at, cv, pos0))
+        return x, pool, (aux[0] if aux else None)
 
     # pool donated for the same reason as the decode step
     @functools.partial(jax.jit, donate_argnums=(1,))
     def chunk(params, pool, tokens, pos0, table):
         positions = pos0 + jnp.arange(C)
-        blk = jnp.take(table, positions // block_size)
+        # one table a kind: (W,), or (2, W) with a window kind
+        kind_tables = (table,) if table.ndim == 1 else (table[0], table[1])
+        blks = [jnp.take(t, positions // block_size) for t in kind_tables]
         off = positions % block_size
-        keep = jnp.arange(table.shape[0] * block_size) < pos0
+        keep = jnp.arange(table.shape[-1] * block_size) < pos0
         x = _embed(params, tokens, positions, cfg)
-        for li, p in enumerate(params["blocks"]):
-            x, pool = _layer(x, p, pool, jnp.int32(li), pos0, table, keep,
-                             blk, off)
+        moe = None if plan.ffn is None else jnp.zeros((4,), jnp.float32)
+        for p, kind in zip(params["blocks"], plan.kinds):
+            line = 0 if kind.window is None else 1
+            x, pool, aux = _layer(
+                x, p, pool, jnp.int32(kind.index), pos0, kind_tables[line],
+                keep, blks[line], off, kind=kind._replace(index=0))
+            if aux is not None:
+                moe = _fold_moe(moe, aux)
+        if pool.stats is not None:
+            # visible (query, key) pairs of the chunk, by layer kind: query
+            # t sees t + 1 keys, or the window where that is fewer
+            pairs = [jnp.sum(positions + 1 if w is None
+                             else jnp.minimum(positions + 1, w))
+                     * sum(k.window == w for k in plan.kinds)
+                     for w in (None, _window_of(plan))]
+            pool = pool._replace(stats=jnp.concatenate([moe, jnp.stack(
+                [jnp.asarray(v, jnp.float32) for v in (0.0, 0.0, *pairs)])]))
         logits = (_readout(params, x, norm_fn, norm_eps) if with_readout
                   else None)
         return logits, pool

@@ -450,3 +450,82 @@ def test_dots3_serve_program_fits_and_leaves_the_pool_in_place(
             assert name in hlo, name
     else:
         assert n == 12, n                   # the grouped products alone
+
+
+# --- the Mellum2 serving cell's two programs at the cut configuration -------
+_MEL_BS, _MEL_BATCH, _MEL_CHUNK = 128, 24, 2048
+
+
+def _mellum2_on(topo):
+    """The cut Mellum2 (``benchmark/configs/mellum2-12b-a2.5b-l8.json``: 8
+    of 28 layers, every layer whole, bf16), its parameters and pools of two
+    kinds as shapes on the described chip."""
+    from byteps_tpu.models.mellum2 import Mellum2Config, mellum2_init
+    from byteps_tpu.serve.families import serve_family
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = Mellum2Config(max_seq=32768, n_layers=8)
+    shapes = jax.eval_shape(lambda: mellum2_init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), shapes)
+    family = serve_family(cfg)
+    pool = jax.eval_shape(lambda: family.layout(
+        shapes, cfg, block_size=_MEL_BS, pool_blocks=1 + _MEL_BATCH * 257,
+        max_batch=_MEL_BATCH, prefill_chunk=_MEL_CHUNK, quant=False).state)
+    pool = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), pool)
+    return cfg, family, params, pool, on_chip
+
+
+@pytest.mark.parametrize("program", ["chunk_c2048_w256", "decode_r24_w256"])
+def test_mellum2_serve_program_fits_and_leaves_the_pools_in_place(
+        topo, as_on_tpu, program):
+    """The 2,048-token chunk program and the 24-row decode step at the table
+    width of 32,768 positions compile for the described v5e at the published
+    widths: 3,794,966,784 parameters (7.59 GB in bf16), a global pool of
+    6,169 blocks for the 2 full layers and a window pool of 318 for the 6
+    sliding ones, both donated and updated in place; weights + pools +
+    temporaries fit the chip's 16 GB. A chunk: 2 causal + 6 window flash
+    calls and 3 grouped products a layer (the last layer's second half is
+    not in a program without readout); a decode step: 8 paged-attention
+    calls — 2 as they were, 6 with a first key — and 24 grouped products."""
+    cfg, family, params, pool, on_chip = _mellum2_on(topo)
+    W = 32768 // _MEL_BS
+    if program.startswith("chunk"):
+        compiled = family.prefill_fn(cfg, _MEL_BS, _MEL_CHUNK, None, False)\
+            .lower(params, pool, on_chip((1, _MEL_CHUNK), I32),
+                   on_chip((), I32), on_chip((2, W), I32)).compile()
+    else:
+        assert family.decode_reads_pool_in_place(
+            cfg, type("C", (), dict(block_size=_MEL_BS, kv_heads=4,
+                                    quant=False)))
+        compiled = family.decode_fn(cfg, _MEL_BS, None, None).lower(
+            params, pool, on_chip((_MEL_BATCH,), I32),
+            on_chip((_MEL_BATCH,), I32),
+            on_chip((_MEL_BATCH, 2, W), I32)).compile()
+    weights, pages = _bytes(params), _bytes(pool)
+    assert weights == 2 * 3794966784, weights
+    assert pool.k.shape == (2, 6169, 128, 512)
+    assert pool.wk.shape == (6, 318, 128, 512)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pages - 64            # donated, in place
+    assert mem.argument_size_in_bytes <= weights + pages + (1 << 20)
+    assert weights + pages + mem.temp_size_in_bytes < 15.5e9, \
+        mem.temp_size_in_bytes
+    hlo = compiled.as_text()
+    for name in ("k", "v", "wk", "wv"):
+        shape = "bf16[%s]" % ",".join(map(str, getattr(pool, name).shape))
+        made = [op for op, aliased in _ops_with_result(hlo, shape)
+                if op not in ("parameter", "tuple", "get-tuple-element",
+                              "bitcast") and not aliased]
+        assert not made, (name, made)
+    n = _n_pallas(compiled)
+    if program.startswith("chunk"):
+        assert n == 8 + 3 * 7, n
+        for name in ("flash_fwd", "moe_gmm_fwd"):
+            assert name in hlo, name
+    else:
+        assert n == 8 + 3 * 8, n
+        assert "paged_attn_decode" in hlo
