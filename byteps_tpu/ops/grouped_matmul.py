@@ -24,12 +24,24 @@ rhs[g]ᵀ``, the same kernel contracting the other axis) and ``moe_gmm_dw``
 (``drhs[g] = Σ_tiles lhs_tileᵀ · dout_tile``: the tile axis innermost, one
 f32 accumulator carried while the group stays the same). All accumulate
 in f32 and round once, to the operand type, at the store.
+
+**Blocks are chosen from the shapes alone** (``_rows_blocks``,
+``_dw_blocks``: pure functions of the row tile, the two widths and the
+operand item size; no argument, environment variable or config field).
+A block of a width is the whole axis or any multiple of 128 that divides
+it, and a call takes the set with the fewest grid steps a row tile whose
+pipeline — two buffers of each operand block, two of the output block, the
+f32 accumulator — fits ``_VMEM_BUDGET``. An expert matrix that fits is
+taken whole (Mellum2's 2304 x 896 and JoyAI's 2048 x 768 in bf16: one step
+a row tile, the matrix not fetched again for the group's next tile); one
+that does not (dots3's 5120 x 1536) splits its contraction axis first and
+keeps the output axis as wide as the steps allow.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,19 +54,83 @@ from byteps_tpu.ops.flash_attention import _out_struct, _unify_vma
 
 __all__ = ["grouped_matmul", "grouped_matmul_jnp", "ROW_TILE"]
 
-ROW_TILE = 256       # rows of a tile: two MXU passes, 4 tiles of padding/MB
+# Rows of a tile: two MXU passes, 4 tiles of padding/MB. The one block the
+# kernels do not choose: the caller lays its groups out by it.
+ROW_TILE = 256
+
+# What one call's pipeline may hold of Mosaic's 16 MiB of scoped VMEM on a
+# v5e (its default): the rest is the compiler's own (the f32 product before
+# it is stored, spills).
+_VMEM_BUDGET = 14 * 1024 * 1024
+_SCOPED_VMEM = 16 * 1024 * 1024
 
 
-def _tile(n: int, cap: int) -> int:
-    """The widest of ``cap, cap/2, ... 128`` that divides ``n``; ``n``
-    itself where none does (a block as wide as the array is always
-    legal)."""
-    t = cap
-    while t >= 128:
-        if n % t == 0:
-            return t
-        t //= 2
-    return n
+def _widths(n: int) -> List[int]:
+    """The legal blocks of a last dimension of ``n``, widest first: the
+    whole axis, then every multiple of 128 that divides it."""
+    return [n] + [w for w in range(n - n % 128, 0, -128)
+                  if w != n and n % w == 0]
+
+
+def _set_bytes(a: int, b: int, out: int, itemsize: int) -> int:
+    """VMEM the pipeline holds for operand blocks of ``a`` and ``b``
+    elements and an output block of ``out``: two buffers of each, and the
+    f32 accumulator."""
+    return 2 * (a + b + out) * itemsize + 4 * out
+
+
+def _rows_bytes(tm: int, tc: int, to: int, itemsize: int) -> int:
+    return _set_bytes(tm * tc, tc * to, tm * to, itemsize)
+
+
+def _dw_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    return _set_bytes(tm * tk, tm * tn, tk * tn, itemsize)
+
+
+def _fewest_steps(cands) -> Tuple[int, int]:
+    """Of candidates ``(grid steps a row tile, elements read again, blocks,
+    bytes of the set)``: among the sets that fit ``_VMEM_BUDGET`` the one
+    of the fewest steps, then of the fewest elements read again; where none
+    fits, the smallest (the call then asks for the VMEM it needs)."""
+    cands = list(cands)
+    fit = [c[:3] for c in cands if c[3] <= _VMEM_BUDGET]
+    return min(fit)[2] if fit else min(cands, key=lambda c: c[3])[2]
+
+
+def _rows_blocks(tm: int, C: int, out_dim: int, itemsize: int
+                 ) -> Tuple[int, int]:
+    """``(tc, to)`` of ``_rows_call`` from the shapes alone. Whole axes
+    where they fit: with ``tc == C`` an expert's block of ``rhs`` depends on
+    the tile's group only, so the next row tile of the same group does not
+    fetch it again and nothing is summed across grid steps; with ``to ==
+    out_dim`` the row block of ``lhs`` is read once. Where both do not fit:
+    the fewest grid steps, and of two such sets the wider output block (the
+    contraction axis is split first: ``lhs`` is read ``out_dim // to``
+    times)."""
+    return _fewest_steps(
+        ((C // tc) * (out_dim // to), out_dim // to, (tc, to),
+         _rows_bytes(tm, tc, to, itemsize))
+        for tc in _widths(C) for to in _widths(out_dim))
+
+
+def _dw_blocks(tm: int, K: int, N: int, itemsize: int) -> Tuple[int, int]:
+    """``(tk, tn)`` of ``_dw_call``: the fewest ``(k, n)`` walks over the
+    live tiles, then the fewest elements read again (``lhs`` is read ``N //
+    tn`` times and ``dout`` ``K // tk`` times)."""
+    return _fewest_steps(
+        ((K // tk) * (N // tn), K * (N // tn) + N * (K // tk), (tk, tn),
+         _dw_bytes(tm, tk, tn, itemsize))
+        for tk in _widths(K) for tn in _widths(N))
+
+
+def _compiler_params(semantics: Tuple[str, ...], need: int):
+    """A set of ``need`` bytes within the budget runs under Mosaic's
+    default; a larger one (no set fit) asks for its bytes and the headroom
+    the budget leaves under the default."""
+    limit = None if need <= _VMEM_BUDGET else \
+        need + _SCOPED_VMEM - _VMEM_BUDGET
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
 
 
 def _tile_groups(group_sizes: jnp.ndarray, tm: int, n_tiles: int
@@ -96,7 +172,7 @@ def _rows_call(lhs, rhs, tile_group, n_live, tm: int, transposed: bool,
     ``transposed``, ``rhs[g] (O, C)`` contracted on its second axis."""
     M, C = lhs.shape
     out_dim = rhs.shape[1] if transposed else rhs.shape[2]
-    tc, to = _tile(C, 1024), _tile(out_dim, 1024)
+    tc, to = _rows_blocks(tm, C, out_dim, lhs.dtype.itemsize)
     n_k = C // tc
     if transposed:
         rhs_spec = pl.BlockSpec((None, to, tc),
@@ -118,8 +194,9 @@ def _rows_call(lhs, rhs, tile_group, n_live, tm: int, transposed: bool,
             grid=(out_dim // to, n_live[0], n_k),
             scratch_shapes=[pltpu.VMEM((tm, to), jnp.float32)],
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=_compiler_params(
+            ("parallel", "arbitrary", "arbitrary"),
+            _rows_bytes(tm, tc, to, lhs.dtype.itemsize)),
         interpret=_interpret(),
         name=name,
     )(tile_group, n_live, lhs, rhs)
@@ -152,7 +229,7 @@ def _dw_kernel(tile_group, n_live, lhs_ref, dout_ref, out_ref, acc):
 def _dw_call(lhs, dout, tile_group, n_live, n_groups: int, tm: int):
     M, K = lhs.shape
     N = dout.shape[1]
-    tk, tn = _tile(K, 512), _tile(N, 1024)
+    tk, tn = _dw_blocks(tm, K, N, lhs.dtype.itemsize)
     lhs, dout, tile_group, n_live = _unify_vma(lhs, dout, tile_group, n_live)
     return pl.pallas_call(
         _dw_kernel,
@@ -168,8 +245,9 @@ def _dw_call(lhs, dout, tile_group, n_live, n_groups: int, tm: int):
             grid=(K // tk, N // tn, n_live[0]),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"),
+            _dw_bytes(tm, tk, tn, lhs.dtype.itemsize)),
         interpret=_interpret(),
         name="moe_gmm_dw",
     )(tile_group, n_live, lhs, dout)

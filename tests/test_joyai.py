@@ -228,35 +228,120 @@ def test_flash_kernels_with_a_value_width_of_their_own(pallas):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
 
-@pytest.mark.parametrize("sizes", [[16, 0, 8, 24, 0], [0, 0, 0, 0, 0],
-                                   [8, 8, 8, 8, 8], [0, 56, 0, 0, 8]])
-def test_grouped_product_against_its_twin(pallas, sizes):
-    from byteps_tpu.ops.grouped_matmul import (
-        grouped_matmul, grouped_matmul_jnp)
+def _gmm_module():
+    # (the package re-exports a function of the module's name)
+    import importlib
+    return importlib.import_module("byteps_tpu.ops.grouped_matmul")
 
-    k = jax.random.split(jax.random.PRNGKey(1), 3)
-    tm, G, K, N, M = 8, 5, 16, 24, 64
+
+def _check_against_twin(sizes, K, N, key, rhs_scale=1.0, atol=1e-5):
+    """Forward, ``dlhs`` and ``drhs`` of the interpreted kernels against the
+    twin for five groups of ``sizes`` rows (row tile 8) in a buffer of 64;
+    rows past the last group exactly zero."""
+    gm = _gmm_module()
+    k = jax.random.split(jax.random.PRNGKey(key), 3)
+    tm, G, M = 8, 5, 64
     gs = jnp.asarray(sizes, jnp.int32)
     lhs = jax.random.normal(k[0], (M, K))
-    rhs = jax.random.normal(k[1], (G, K, N))
+    rhs = jax.random.normal(k[1], (G, K, N)) * rhs_scale
     w = jax.random.normal(k[2], (M, N))
 
     def scalar(fn):
         return lambda a, b: (fn(a, b) * w).sum()
 
     def kern(a, b):
-        return grouped_matmul(a, b, gs, tm)
+        return gm.grouped_matmul(a, b, gs, tm)
 
     def twin(a, b):
-        return grouped_matmul_jnp(a, b, gs)
+        return gm.grouped_matmul_jnp(a, b, gs)
 
     out = jax.jit(kern)(lhs, rhs)
-    np.testing.assert_allclose(out, twin(lhs, rhs), atol=1e-5)
-    assert float(jnp.abs(out[sum(sizes):]).max(initial=0.0)) == 0.0
+    np.testing.assert_allclose(out, twin(lhs, rhs), atol=atol)
     got = jax.jit(jax.grad(scalar(kern), (0, 1)))(lhs, rhs)
     want = jax.grad(scalar(twin), (0, 1))(lhs, rhs)
+    for past in (out, got[0]):
+        assert float(jnp.abs(past[sum(sizes):]).max(initial=0.0)) == 0.0
     for a, b in zip(got, want):
-        np.testing.assert_allclose(a, b, atol=1e-5)
+        np.testing.assert_allclose(a, b, atol=atol)
+
+
+@pytest.mark.parametrize("sizes", [[16, 0, 8, 24, 0], [0, 0, 0, 0, 0],
+                                   [8, 8, 8, 8, 8], [0, 56, 0, 0, 8]])
+def test_grouped_product_against_its_twin(pallas, sizes):
+    _check_against_twin(sizes, 16, 24, key=1)
+
+
+@pytest.mark.parametrize("budget", [None, 800 << 10], ids=["whole", "split"])
+@pytest.mark.parametrize("K,N", [(384, 640), (640, 384)])
+def test_grouped_product_at_widths_off_a_power_of_two(
+        pallas, monkeypatch, K, N, budget):
+    """Widths that are multiples of 128 and no power of two, groups with
+    empty and multi-tile members: whole-axis blocks under the module's own
+    budget, and under one of 800 KB a split contraction axis (384 x 640:
+    blocks of 128 x 640) or a split output axis (640 x 384: 640 x 128) and
+    ``moe_gmm_dw`` in five walks."""
+    gm = _gmm_module()
+    if budget is not None:
+        monkeypatch.setattr(gm, "_VMEM_BUDGET", budget)
+    rows, dw = gm._rows_blocks(8, K, N, 4), gm._dw_blocks(8, K, N, 4)
+    if budget is None:
+        assert rows == dw == (K, N)
+    else:
+        assert rows == ((128, 640) if K == 384 else (640, 128)), rows
+        assert dw == ((384, 128) if K == 384 else (128, 384)), dw
+    _check_against_twin([16, 0, 8, 24, 0], K, N, key=2,
+                        rhs_scale=K ** -0.5, atol=2e-5)
+
+
+# every cell's expert matrices (Mellum2, JoyAI, dots3: gate/up and down) at
+# the cells' row tile, and the tests' own tiny one
+_CELL_SHAPES = [(256, 2304, 896), (256, 896, 2304), (256, 2048, 768),
+                (256, 768, 2048), (256, 5120, 1536), (256, 1536, 5120),
+                (8, 16, 24)]
+
+
+@pytest.mark.parametrize("itemsize", [2, 4], ids=["bf16", "f32"])
+@pytest.mark.parametrize("tm,C,O", _CELL_SHAPES,
+                         ids=["x".join(map(str, s[1:])) for s in _CELL_SHAPES])
+def test_grouped_product_blocks_from_shapes_alone(tm, C, O, itemsize):
+    """The chooser is a function of ``(tm, C, out_dim, itemsize)``: each
+    block divides its axis and is a multiple of 128 or the whole axis, the
+    set the pipeline holds is within the stated budget (itself under
+    Mosaic's scoped default, so no call of a cell asks for more), and an
+    expert matrix that fits is taken whole: Mellum2's 2304 x 896 in bf16 is
+    one grid step a row tile, JoyAI's 2048 x 768 too; dots3's 5120 x 1536
+    (15.7 MB) splits its contraction axis and keeps the output axis."""
+    gm = _gmm_module()
+    assert gm._VMEM_BUDGET < gm._SCOPED_VMEM
+    for blocks, nbytes in ((gm._rows_blocks, gm._rows_bytes),
+                           (gm._dw_blocks, gm._dw_bytes)):
+        a, b = blocks(tm, C, O, itemsize)
+        for blk, axis in ((a, C), (b, O)):
+            assert axis % blk == 0 and (blk % 128 == 0 or blk == axis), (
+                blk, axis)
+        assert nbytes(tm, a, b, itemsize) <= gm._VMEM_BUDGET
+    rows = gm._rows_blocks(tm, C, O, itemsize)
+    if itemsize == 2 and C * O <= 2304 * 896:
+        assert rows == (C, O)
+    if (itemsize, C, O) == (2, 5120, 1536):
+        assert rows[1] == O and 1 < C // rows[0] <= 5
+    assert gm._compiler_params(("arbitrary",), gm._rows_bytes(
+        tm, *rows, itemsize)).vmem_limit_bytes is None
+    # never more grid steps a row tile than the halving walk gave
+    old = [next((t for t in (1024, 512, 256, 128) if n % t == 0), n)
+           for n in (C, O)]
+    assert (C // rows[0]) * (O // rows[1]) <= (C // old[0]) * (O // old[1])
+
+
+def test_grouped_product_over_the_budget_asks_for_its_vmem():
+    """Widths off the lanes have one legal block, the whole axis: a set over
+    the budget asks Mosaic for its bytes and the headroom the budget
+    leaves, a number derived from the blocks."""
+    gm = _gmm_module()
+    assert gm._rows_blocks(256, 3000, 1000, 2) == (3000, 1000)
+    need = gm._rows_bytes(256, 3000, 1000, 2)
+    assert gm._compiler_params(("arbitrary",), need).vmem_limit_bytes \
+        == need + gm._SCOPED_VMEM - gm._VMEM_BUDGET > gm._SCOPED_VMEM
 
 
 def test_dropless_layer_through_the_kernels(pallas):

@@ -132,10 +132,21 @@ def _gmm_fwd_bwd(lhs, w_up, w_down, sizes):
     return jax.grad(loss, argnums=(0, 1, 2))(lhs, w_up, w_down)
 
 
+def _gmm_up_down(lhs, w_up, w_down, sizes):
+    # a serve program's expert layer: forward alone, up then down
+    return grouped_matmul(grouped_matmul(lhs, w_up, sizes), w_down, sizes)
+
+
+def _gmm_shapes(rows, held, d, ff):
+    """A cell's worst-case row buffer (pairs / 256 + a tile an expert held)
+    and its expert stacks up and down, bf16."""
+    return [_sds((rows, d), BF16), _sds((held, d, ff), BF16),
+            _sds((held, ff, d), BF16), _sds((held,), I32)]
+
+
 # the JoyAI-LLM-Flash cell's routed experts: 16 held, 2048 -> 768 -> 2048,
 # the worst-case row buffer of 4 x 4096 tokens x top-8 (+ a tile a group)
-_GMM = [_sds((135168, 2048), BF16), _sds((16, 2048, 768), BF16),
-        _sds((16, 768, 2048), BF16), _sds((16,), I32)]
+_GMM = _gmm_shapes(135168, 16, 2048, 768)
 
 # (id, function, argument shapes, Pallas calls expected in the program)
 ONE_CHIP = [
@@ -153,6 +164,21 @@ ONE_CHIP = [
     # the first product forward (the second's output is not needed for
     # its gradient), then dx and dw of each
     ("moe_gmm_fwd_bwd_joyai", _gmm_fwd_bwd, _GMM, 5),
+    # blocks as wide as an expert's matrix must fit v5e's scoped VMEM:
+    # Mellum2's 64 experts of 2304 x 896 under a 24-row decode step (192
+    # pairs: 65 tiles) and a 2,048-token chunk (128 tiles), whole matrices;
+    # dots3's 32 held experts of 5120 x 1536 under its chunk (96 tiles),
+    # the contraction axis split
+    ("moe_gmm_fwd_mellum2_decode", _gmm_up_down,
+     _gmm_shapes(65 * 256, 64, 2304, 896), 2),
+    ("moe_gmm_fwd_mellum2_chunk", _gmm_up_down,
+     _gmm_shapes(128 * 256, 64, 2304, 896), 2),
+    ("moe_gmm_fwd_dots3_chunk", _gmm_up_down,
+     _gmm_shapes(96 * 256, 32, 5120, 1536), 2),
+    # widths off the lanes have one legal block, the whole axis: 17.6 MB,
+    # over the budget, so the call asks for the VMEM it needs
+    ("moe_gmm_fwd_whole_axes_over_budget", _gmm_up_down,
+     _gmm_shapes(4 * 256, 4, 3000, 1000), 2),
     # the paged prefill chunk: 32 new tokens against the widest and the
     # narrowest gathered view
     ("flash_fwd_prefill_chunk_wide", _flash_fwd(16, 16),
