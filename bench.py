@@ -726,69 +726,6 @@ def bench_model_singlechip(model: str, compressor: str,
     }
 
 
-def bench_model_profile(model: str, compressor: str,
-                        chunked_ce: bool = True) -> dict:
-    """Device-trace attribution for a single-chip workload: run the
-    framework step under ``jax.profiler`` and aggregate the DEVICE lane
-    per kernel (byteps_tpu.common.xprof_analysis). The device event
-    timestamps are hardware timing, so ``step_ms_device`` is an absolute
-    step time independent of the host clock, and the bucket table names
-    where every microsecond goes."""
-    import shutil
-    import tempfile
-
-    from byteps_tpu.common.xprof_analysis import profile_fn
-
-    on_cpu = jax.devices()[0].platform == "cpu"
-    kind, peak = _detect_peak()
-    name, built = _model_setup(model, compressor, on_cpu, chunked_ce)
-    step, state, dev_batch = built["ours"]
-    flops = built["flops"]
-
-    def one_step():
-        out = step(*state.values(), *dev_batch)
-        for k, v in zip(state, out[1:]):
-            state[k] = v
-        return _fence(out[1])
-
-    trace_dir = os.environ.get("BYTEPS_TRACE_DIR") or tempfile.mkdtemp(
-        prefix="byteps_profile_")
-    prof = profile_fn(one_step, trace_dir, steps=4 if on_cpu else 10,
-                      warmup=2)
-    _log(f"trace: {trace_dir}")
-    _log(prof.table())
-    if "BYTEPS_TRACE_DIR" not in os.environ:
-        shutil.rmtree(trace_dir, ignore_errors=True)
-
-    step_s = prof.step_us / 1e6
-    mfu_dev = (flops / step_s / 1e12 / peak
-               if (flops and peak and step_s > 0) else None)
-    ups = built["unit_per_step"]
-    comp = f"+{compressor}" if compressor != "none" else ""
-    return {
-        "metric": f"{name}{comp} device-trace step time (xprof attribution)",
-        "value": round(prof.step_us / 1e3, 3),
-        "unit": "ms/step (device timeline)",
-        "vs_baseline": round(mfu_dev, 4) if mfu_dev is not None else None,
-        "mfu_device": round(mfu_dev, 4) if mfu_dev is not None else None,
-        "throughput_device": round(ups / step_s, 1),
-        "throughput_unit": f"{built['unit']}/s",
-        "device_kind": kind,
-        "peak_tflops_bf16": peak,
-        "flops_per_step": flops,
-        "n_steps_profiled": prof.n_steps,
-        "category_ms": {c: round(us / 1e3, 3)
-                        for c, us in sorted(prof.category_us.items(),
-                                            key=lambda kv: -kv[1])},
-        "gap_in_step_ms": round(prof.gap_us / 1e3, 3),
-        "top_kernels": [
-            {"name": k.name[:80], "category": k.category, "count": k.count,
-             "ms_per_step": round(k.total_us / prof.n_steps / 1e3, 3)}
-            for k in prof.kernels[:12]
-        ],
-    }
-
-
 def bench_generate() -> dict:
     """Cached-decode throughput (the KV-cache generation subsystem) vs
     the naive full-recompute sampler a user would write without it. Both
@@ -3678,8 +3615,8 @@ def main() -> None:
     ap.add_argument("--mode",
                     choices=["auto", "dcn", "dcn-profile", "throttled",
                              "tune", "chaos", "hybrid", "generate",
-                             "serve", "ici", "multislice", "profile",
-                             "trend", "whatif"],
+                             "serve", "ici", "multislice", "trend",
+                             "whatif"],
                     default="auto")
     ap.add_argument("--refresh", action="store_true",
                     help="trend mode: rebuild BENCH_trend.json's "
@@ -3818,11 +3755,6 @@ def main() -> None:
                      "--mode trend --refresh")
                 print(json.dumps(result), flush=True)
                 sys.exit(5)
-    elif args.mode == "profile":
-        n = len(jax.devices())
-        _log(f"bench: {n} device(s): {jax.devices()[0].device_kind}")
-        result = bench_model_profile(args.model, args.compressor,
-                                     chunked_ce=args.ce == "chunked")
     elif args.mode == "generate":
         if flags_set:
             _log("bench: WARNING --model/--compressor ignored in "

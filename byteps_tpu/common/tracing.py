@@ -46,9 +46,11 @@ from byteps_tpu.common.metrics import json_safe
 
 log = get_logger("tracing")
 
-# the saturated serving cell makes ~830 iterations x ~8 spans in a 50 s
+# the saturated serving cell makes ~214 iterations a second x ~12 entries
+# (8 structural spans, the issue's parts, the device steps) + three phases
+# for each of 27 requests a second: ~2,700 entries a second, 135k in a 50 s
 # window; an entry is one small tuple
-RING_SPANS = 65536
+RING_SPANS = 262144
 
 
 class TraceRecorder:
@@ -183,12 +185,13 @@ class TraceRecorder:
 
     # -- event emission -----------------------------------------------------
     def emit(self, name: str, stage: str, start_s: float, dur_s: float,
-             args: Any = None) -> int:
+             args: Any = None, parent: int = 0) -> int:
         """One finished span whose ends the caller stamped itself on
         ``clock`` (the serve scheduler's per-request phases, from the
-        stamps its results are computed from). Returns the span's id."""
+        stamps its results are computed from), under the span whose id
+        ``parent`` is. Returns the span's id."""
         sid = next(self._ids)
-        self._record(name, stage, start_s, dur_s, sid, 0, args)
+        self._record(name, stage, start_s, dur_s, sid, parent, args)
         return sid
 
     def _record(self, name, stage, start_s, dur_s, sid, parent, args):

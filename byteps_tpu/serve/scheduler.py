@@ -56,6 +56,10 @@ decode reads it before it returns, and everything that reads or rewrites
 a request from outside the lanes (preemption, migration either way,
 ``drain_incomplete``, a kill) calls ``_drain_in_flight`` first. Tokens,
 their order and the cache contents are what a read-at-once loop gives.
+The two reads are also where the host learns that a program is done: the
+time between two of them it waited in is the device's time for what was
+issued between the programs read, a ``serve.device_step.*`` span on the
+ring (``_wait``; docs/serving.md §Scheduler iteration).
 
 **Preemption** — when a block allocation fails, the youngest admitted
 request is evicted: its blocks free immediately, its committed tokens
@@ -137,6 +141,13 @@ log = get_logger("serve.scheduler")
 # fault plan killed the replica
 _DRAIN_CAUSES = ("idle", "preempt", "migrate", "kill")
 
+# a device step by what the device ran between two reads the host waited in
+# (``Scheduler._wait``): a decode step alone, a final chunk alone, a
+# non-final chunk and the decode step behind it; ``unseen`` is any of them
+# one of whose ends the host did not wait for
+_DEVICE_STEP = {k: "serve.device_step." + k
+                for k in ("decode", "chunk", "chunk_decode", "unseen")}
+
 # global replica instance sequence for per-replica gauge series (the
 # PR 6 scheduler.s<N> pattern — replica_id is caller-chosen and two
 # fresh replicas may both say 0)
@@ -201,15 +212,17 @@ def _take(picked, first, host, table_shape):
 class _InFlight:
     """The packed decode step whose tokens the host has not read: the
     device array ``_pick`` returned, the runs of its rows, the position
-    each row wrote, and the row of each request in it."""
+    each row wrote, the row of each request in it, and the device step
+    that reading it ends (``Scheduler._wait``)."""
 
-    __slots__ = ("picked", "runs", "pos", "rows")
+    __slots__ = ("picked", "runs", "pos", "rows", "step")
 
-    def __init__(self, picked, runs: List["_Run"], pos: np.ndarray):
+    def __init__(self, picked, runs: List["_Run"], pos: np.ndarray, step):
         self.picked = picked
         self.runs = runs
         self.pos = pos
         self.rows = {run.req.rid: i for i, run in enumerate(runs)}
+        self.step = step
 
 
 @dataclasses.dataclass
@@ -464,7 +477,16 @@ class Scheduler:
         # commit, admit and pack (module docstring, "The order of an
         # iteration")
         self._flight: Optional[_InFlight] = None
-        self._first: Optional[tuple] = None      # (run, (1,) device array)
+        # (run, (1,) device array, the device step its read ends)
+        self._first: Optional[tuple] = None
+        # device steps (_wait): the non-final chunk issued and not yet
+        # followed by a program whose result the host reads, as (clock at
+        # its launch, tokens, table width); the last read's (clock when it
+        # returned, whether the host waited in it); the open
+        # serve.iteration's span id
+        self._ahead: Optional[tuple] = None
+        self._done = (0.0, False)
+        self._iter = 0
         self.results: Dict[Any, Dict[str, Any]] = {}
         # admit a little past the decode-slot count so a finished
         # request's slot refills from a PREFILLED standby instead of
@@ -1319,10 +1341,13 @@ class Scheduler:
                     time.sleep(inj.rule.latency_ms / 1e3)
         tr = get_tracer()
         self._iteration += 1
-        with tr.span("serve.iteration", "SERVE", (self._iteration,)):
+        with tr.span("serve.iteration", "SERVE", (self._iteration,)) as it:
+            self._iter = it.sid
             with tr.span("serve.admit", "SERVE"):
                 progress = self._admit(self._clock())
-            return self._lanes(tr) or progress
+            progress = self._lanes(tr) or progress
+        self._iter = 0
+        return progress
 
     def _admit(self, now: float) -> bool:
         """§1 of an iteration: admission. True when a request was
@@ -1498,7 +1523,7 @@ class Scheduler:
             W = self._width(run.req.rid)
             with tr.span("serve.prefill_dispatch", "SERVE",
                          (run.req.rid, C, W, final)
-                         + self.cache.kind_widths(run.req.rid)):
+                         + self.cache.kind_widths(run.req.rid)) as sp:
                 # the chunk scatters C rows — CoW any shared page in its
                 # span (a no-op by construction: admission already CoW'd
                 # the divergence block; enforced, not assumed)
@@ -1508,10 +1533,19 @@ class Scheduler:
                 # final chunk's last-position logits are ever read. Host
                 # arrays go in as they are: the call transfers them, at
                 # less than half of what a jnp.asarray each costs
+                launch = tr.clock()
                 logits, self.cache.state = self._prefill_fn(C, final)(
                     self._params_for(run), self.cache.state,
                     toks[None], np.int32(run.prefill_done),
                     self.cache.table_row(run.req.rid, W))
+                tr.emit("serve.issue.chunk_call", "SERVE", launch,
+                        tr.clock() - launch, parent=sp.sid)
+            if self._ahead is not None:
+                # no row decoded since the last chunk: two chunks in one
+                # device step, whose time is then no one kind's
+                launch = self._ahead[0]
+                self._done = (self._done[0], False)
+            self._ahead = None if final else (launch, C, W)
             run.prefill_done += C
             run.cache_len = run.prefill_done
             self.cache.release_behind(run.req.rid, run.cache_len)
@@ -1549,7 +1583,8 @@ class Scheduler:
                 self._first = (run, self._pick_last(
                     logits, np.asarray([run.req.seed], np.int32),
                     np.asarray([run.cache_len], np.int32),
-                    np.asarray([run.req.temperature], np.float32)))
+                    np.asarray([run.req.temperature], np.float32)),
+                    ("chunk", launch, (C, W)))
                 self._first[1].copy_to_host_async()
                 if run.req.spec is not None or self.role == "prefill":
                     # its round of this iteration proposes from the token
@@ -1631,25 +1666,43 @@ class Scheduler:
                 host[i, _SLOT] = run.slot or 0
                 host[i, _N_COLS:] = rows[i].reshape(-1)
             host[:, _TEMP] = temps.view(np.int32)
-        with tr.span("serve.decode_dispatch", "SERVE", (len(packed), W)):
+        with tr.span("serve.decode_dispatch", "SERVE",
+                     (len(packed), W)) as sp:
             step = self._decode_step()
+            t0 = tr.clock()
             toks, pos, tables, seeds, pos1, temps, slots = _take(
                 unread.picked if unread is not None else self._no_picked,
                 self._first[1] if self._first is not None
                 else self._no_first, host, table_shape=rows[0].shape)
+            t1 = tr.clock()
             extra = () if self.adapter_pool is None \
                 else (self.adapter_pool.slabs, slots)
             logits, self.cache.state = step(
                 self._operands, self.cache.state, toks, pos, tables, *extra)
+            t2 = tr.clock()
             picked = self._pick(logits, seeds, pos1, temps)
             picked.copy_to_host_async()
+            t3 = tr.clock()
             if self._late is not None:
                 self._late.note(self.cache.state)
+            # the host's issue by its parts: a clock read and a ring
+            # append each, no profiler annotation
+            tr.emit("serve.issue.take", "SERVE", t0, t1 - t0, parent=sp.sid)
+            tr.emit("serve.issue.decode_call", "SERVE", t1, t2 - t1,
+                    parent=sp.sid)
+            tr.emit("serve.issue.pick", "SERVE", t2, t3 - t2, parent=sp.sid)
         # what the host knows without the tokens' values: each row wrote
         # its position
         for run in packed:
             run.cache_len += 1
-        self._flight = _InFlight(picked, packed, host[:len(packed), _POS])
+        if self._ahead is None:
+            dstep = ("decode", t0, (len(packed), W))
+        else:
+            dstep = ("chunk_decode", self._ahead[0],
+                     self._ahead[1:] + (len(packed), W))
+            self._ahead = None
+        self._flight = _InFlight(picked, packed, host[:len(packed), _POS],
+                                 dstep)
         if unread is not None:
             self._m["decode_steps_overlapped"].inc()
         if self._decode_paged_attn:
@@ -1667,7 +1720,7 @@ class Scheduler:
         if flight is None:
             return False
         with tr.span("serve.decode_sync", "SERVE"):
-            picked = np.asarray(flight.picked)  # the host blocks on the device
+            picked = self._wait(tr, flight.picked, flight.step)
         with tr.span("serve.commit", "SERVE"):
             now = self._clock()
             live = 0
@@ -1688,9 +1741,9 @@ class Scheduler:
         the chunk while the host waits in ``serve.prefill_sync``."""
         if self._first is None:
             return False
-        (run, picked), self._first = self._first, None
+        (run, picked, step), self._first = self._first, None
         with tr.span("serve.prefill_sync", "SERVE"):
-            tok = int(np.asarray(picked)[0])    # the host blocks on the device
+            tok = int(self._wait(tr, picked, step)[0])
         now = self._clock()
         self._m["prefill_ms"].observe(self._phase(run, "prefill", now))
         self._commit_token(run, tok, now)
@@ -1701,6 +1754,32 @@ class Scheduler:
             # untouched by wire time
             run.state = "handoff"
         return True
+
+    def _wait(self, tr, picked, step) -> np.ndarray:
+        """``picked`` on the host (the host blocks on the device here), and
+        the device step its arrival ends as a span on the ring. The device
+        runs what it is handed in issue order, so between two reads the host
+        WAITED in it ran exactly what was issued between the two programs
+        read: ``step`` = (kind, clock at the first launch of those, args).
+        The span runs from the previous read's return to this one's. It
+        carries its kind's name when the host waited in both reads and the
+        step was queued before the previous read returned; else (the result
+        was ready already, nothing was queued behind the previous step: a
+        drain, an idle wait, the first step) it is ``unseen``, from the
+        launch if that is later, its kind the first of its args: counted,
+        never averaged."""
+        waited = not picked.is_ready()
+        out = np.asarray(picked)
+        now = tr.clock()
+        kind, launch, args = step
+        prev, seen = self._done
+        start = max(prev, launch)
+        if not (seen and waited and launch <= prev):
+            kind, args = "unseen", (kind,) + args
+        tr.emit(_DEVICE_STEP[kind], "SERVE", start, now - start, args,
+                self._iter)
+        self._done = (now, waited)
+        return out
 
     def _note_drain(self, cause: str) -> None:
         self._m["pipeline_drains"].inc()
