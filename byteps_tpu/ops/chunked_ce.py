@@ -56,7 +56,7 @@ still round-trips HBM per vocab tile, saving ~1 pass). The scan form
 keeps the path portable (CPU tier-1 pins it bit-exactly), VJP-exact
 under remat/pipeline, and free of Mosaic compile risk on backends this
 repo can't test against; if a future attribution shows the residual
-passes matter, the flash kernels' (fwd, dq, dkv)-style split is the
+passes matter, the flash kernels' (forward, backward)-style split is the
 shape a kernel port would take.
 """
 

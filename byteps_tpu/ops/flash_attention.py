@@ -19,12 +19,12 @@ Design (flash-attention-2 schedule, TPU-shaped):
   VMEM scratch — scores for one ``(bq, bk)`` tile only ever exist in
   VMEM. Emits the per-row logsumexp for the backward and for cross-shard
   combination.
-* Backward: two kernels — ``dq`` (grid ``(BH, nq, nk)``) and ``dkv``
-  (grid ``(BH, nk, nq)``) — each recomputing ``P = exp(S − lse)`` per
-  tile, so the backward reads O(S·D) and never stores P.
-  ``delta = rowsum(dO ∘ O)`` is one fused jnp pass. The lse output's own
-  cotangent folds in exactly (``dS = P ∘ (dP − Δ + dlse)``), which is
-  what lets ring attention differentiate through the cross-shard merge.
+* Backward: ONE kernel (grid ``(BH, nk, nq)``) recomputes ``P = exp(S −
+  lse)`` once a tile and feeds dq, dk and dv from it, dq resident in VMEM
+  for its head; grouped-query shapes and a dq too long to stay keep the
+  two-kernel form (``_bwd_plan``). ``delta = rowsum(dO ∘ O)`` is one jnp
+  pass; the lse output's own cotangent folds in exactly (``dS = P ∘ (dP −
+  Δ + dlse)``): ring attention differentiates through its merge with it.
 * Causal masking compares *global* positions: the q/k sequence offsets
   are runtime scalars (SMEM), so the same compiled kernel serves the
   single-device case (offsets 0), and every step of ring attention —
@@ -75,14 +75,14 @@ def _pick_block(S: int, prefer: Tuple[int, ...] = ()) -> Optional[int]:
     return None
 
 
-# Block size is the dominant throughput knob on this kernel family —
-# per-tile pipeline overhead (the sequential online-softmax revisit
-# chain through VMEM scratch) swamps the VPU/MXU work at 256² tiles.
-# Measured on v5e, gpt2m shapes (BH=32, S=1024, D=64, bf16), fwd+bwd
-# via the train-loss path: 256-tiles 24.0 ms, 512 14.6 ms, 1024
-# 14.9 ms (fwd alone: 8.2 / 4.6 / 3.7 ms) — the forward prefers
-# whole-sequence k-tiles, the backward 512. BYTEPS_FLASH_BLOCK=N,...
-# prepends experiment overrides (train kernels only).
+# Block size is the dominant throughput knob on this kernel family.
+# Measured on v5e at PR 42, bf16, the one-pass backward of one layer's
+# call, ms by (bq, bk): GPT-2-medium (BH=128, S=1024, D=64) 512² 1.39,
+# 512×1024 1.50, 1024×512 1.51, 256×512 1.63, 256² 2.07 (the two kernels
+# at 512²: 2.61); JoyAI (BH=128, S=4096, D 192/128) 512² 20.7, 512×1024
+# 19.9, 256×512 22.7, 256² 27.8 (two kernels: 36.3). The forward keeps
+# whole-sequence k-tiles at S=1024 (3.7 against 4.6 ms at BH=32, before
+# PR 1). BYTEPS_FLASH_BLOCK=N,... prepends experiment tiles (train kernels).
 _FWD_PREFER = (1024, 512)
 _BWD_PREFER = (512,)
 _VMEM_BUDGET = 12 * 1024 * 1024   # leave headroom under the ~16MB VMEM
@@ -91,6 +91,18 @@ _VMEM_BUDGET = 12 * 1024 * 1024   # leave headroom under the ~16MB VMEM
 def _env_prefer() -> Tuple[int, ...]:
     force = os.environ.get("BYTEPS_FLASH_BLOCK")
     return tuple(int(x) for x in force.split(",")) if force else ()
+
+
+def _live_bytes(bq: int, bk: int, D: int, Dv: int, itemsize: int,
+                n_inter: int) -> int:
+    """A train kernel's live set: ``n_inter`` (bq, bk) f32 intermediates —
+    2 for the forward (s, p), 4 for a backward (s, p, dp, ds) — the q, (k,
+    v)(, do) blocks double-buffered by the pallas pipeline (q and k blocks
+    D wide; v, o and do blocks Dv wide), and the f32 accumulators."""
+    inter = n_inter * bq * bk * 4
+    io = 2 * 2 * (bq + bk) * (D + Dv) * itemsize
+    scratch = (bq * Dv + bk * (D + Dv)) * 4
+    return inter + io + scratch
 
 
 def _train_blocks(Sq: int, Sk: int, D: int, itemsize: int,
@@ -102,27 +114,15 @@ def _train_blocks(Sq: int, Sk: int, D: int, itemsize: int,
     ``_pick_block``/``supported()`` establish; callers not pre-gated by
     ``supported()`` must get the same None, not a TypeError). Otherwise:
     the preferred large tiles, walked back down the candidate list until
-    the tile set fits VMEM — the big-tile retune was measured at
-    bf16/D=64; f32 or D→256 shapes must degrade gracefully instead of
-    blowing the Mosaic budget.
-
-    ``n_inter`` models the kernel's live (bq, bk) f32 intermediates:
-    2 for the forward (s, p), 4 for the backwards (s, p, dp, ds) — the
-    backward call sites pass 4, which is what steers them to 512 tiles
-    while the forward keeps whole-sequence k-tiles.
-
-    ``D`` is the q/k width, ``Dv`` the value width where it differs
-    (latent attention: 192 / 128); q and k blocks are D wide, v, o and do
-    blocks Dv wide. With ``Dv == D`` the sums below are the ones the
-    GPT-2 retune was measured at."""
+    ``_live_bytes`` fits ``_VMEM_BUDGET`` — f32 or D→256 shapes degrade
+    gracefully instead of blowing the Mosaic budget. The backward passes
+    ``n_inter=4``, which steers it to 512 tiles while the forward keeps
+    whole-sequence k-tiles. ``Dv`` is the value width where it differs
+    from ``D`` (latent attention: 192 / 128)."""
     Dv = D if Dv is None else Dv
 
     def fits(bq: int, bk: int) -> bool:
-        inter = n_inter * bq * bk * 4
-        # q,(k,v)(,do) blocks double-buffered by the pallas pipeline
-        io = 2 * 2 * (bq + bk) * (D + Dv) * itemsize
-        scratch = (bq * Dv + bk * (D + Dv)) * 4     # f32 accumulators
-        return inter + io + scratch <= _VMEM_BUDGET
+        return _live_bytes(bq, bk, D, Dv, itemsize, n_inter) <= _VMEM_BUDGET
 
     prefer = _env_prefer() + prefer
     bq = _pick_block(Sq, prefer)
@@ -516,26 +516,189 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+from byteps_tpu.common.metrics import get_registry  # noqa: E402
+
+_FUSED_VMEM_CAP = 32 * 1024 * 1024   # half the smallest VMEM a TPU core has
+
+
+def _bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                dd_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                *, scale, causal, bq, bk, nq, nk):
+    """One pass over a head's tiles, kv block outer, q block inner: the
+    tile's scores, probabilities, dp and ds are computed once and feed all
+    three gradients. dk/dv accumulate over the inner axis as in
+    ``_dkv_kernel``; dq for the WHOLE head accumulates in ``dq_scr`` at
+    rows ``qi·bq`` and leaves through an output block whose index does not
+    change inside the head. The tile is held TRANSPOSED, ``(bk, bq)``:
+    ``pᵀ do`` and ``dsᵀ q`` are then plain products (the two-kernel form
+    transposes both) and only ``ds k`` contracts over rows; the per-query
+    statistics arrive as lane-dense ``(1, bq)`` rows."""
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)
+
+    @pl.when(ki == 0)
+    def _init_dq():
+        dq_scr[rows, :] = jnp.zeros((bq, dq_scr.shape[1]), jnp.float32)
+
+    @pl.when(qi == 0)
+    def _init_dkv():
+        dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    q_start, k_start = qi * bq, ki * bk
+    q_off, k_off = _read_offsets(qoff_ref, koff_ref)
+
+    def _tile(masked: bool):
+        # input-dtype operands on every MXU dot (see _fwd_kernel note);
+        # p and ds round to the input dtype only at a product's boundary
+        q = q_ref[0]                                         # (bq, D)
+        k = k_ref[0]                                         # (bk, D)
+        v = v_ref[0]                                         # (bk, Dv)
+        do = do_ref[0]                                       # (bq, Dv)
+        lse = lse_ref[0]                                     # (1, bq)
+        dd = dd_ref[0]                                       # (1, bq)
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # (bk, bq)
+        if masked:
+            kpos = k_off + k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (bk, bq), 0)
+            qpos = q_off + q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (bk, bq), 1)
+            st = jnp.where(qpos >= kpos, st, _NEG)
+        pt = jnp.exp(st - lse)                                # (bk, bq)
+        if masked:
+            pt = jnp.where(st > _NEG / 2, pt, 0.0)
+        dv_scr[:] += jax.lax.dot_general(
+            pt.astype(do_ref.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (bk, Dv)
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (bk, bq)
+        dst = (pt * (dpt - dd)).astype(q_ref.dtype)
+        dk_scr[:] += scale * jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (bk, D)
+        dq_scr[rows, :] += scale * jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (bq, D)
+
+    if causal:
+        live = q_off + q_start + bq - 1 >= k_off + k_start
+        interior = q_off + q_start >= k_off + k_start + bk - 1
+
+        @pl.when(live & interior)
+        def _():
+            _tile(False)
+
+        @pl.when(live & jnp.logical_not(interior))
+        def _():
+            _tile(True)
+    else:
+        _tile(False)
+
+    @pl.when(qi == nq - 1)
+    def _finish_dkv():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when(ki == nk - 1)
+    def _finish_dq():
+        dq_ref[0, rows, :] = dq_scr[rows, :].astype(dq_ref.dtype)
+
+
+def _bwd_plan(Sq: int, Sk: int, D: int, Dv: int, itemsize: int,
+              group: int) -> Optional[Tuple[int, int, Optional[int]]]:
+    """``(bq, bk, need)`` of the backward from the shapes alone, None where
+    no tile divides a sequence. ``need`` is the fused kernel's live set in
+    bytes — ``_live_bytes`` of a backward tile plus the head's resident dq
+    (f32) and its output block (twice: the pipeline's two buffers) — or None
+    where the two-kernel form runs: a grouped-query shape (one kv row's
+    tiles meet ``group`` q rows, whose dq would all have to stay), a q tile
+    that is neither whole lanes nor the whole sequence (the statistics' row
+    blocks), and a set over ``_FUSED_VMEM_CAP``."""
+    blocks = _train_blocks(Sq, Sk, D, itemsize, _BWD_PREFER, n_inter=4,
+                           Dv=Dv)
+    if blocks is None:
+        return None
+    bq, bk = blocks
+    lanes = -(-D // 128) * 128      # a VMEM row holds whole 128-lane vregs
+    need = (_live_bytes(bq, bk, D, Dv, itemsize, n_inter=4)
+            + Sq * lanes * (4 + 2 * itemsize))
+    fused = (group == 1 and (bq % 128 == 0 or bq == Sq)
+             and need <= _FUSED_VMEM_CAP)
+    return bq, bk, need if fused else None
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "interpret",
                                              "heads", "kv_heads"))
 def _bwd(q3, k3, v3, o3, lse, qoff, koff, do3, dlse,
          causal: bool, interpret: bool, heads: int, kv_heads: int):
     BH, Sq, D = q3.shape
     BHkv, Sk, Dv = k3.shape[0], k3.shape[1], v3.shape[2]
-    blocks = _train_blocks(Sq, Sk, D, q3.dtype.itemsize, _BWD_PREFER,
-                           n_inter=4, Dv=Dv)
-    if blocks is None:
+    group = heads // kv_heads
+    plan = _bwd_plan(Sq, Sk, D, Dv, q3.dtype.itemsize, group)
+    if plan is None:
         raise ValueError(
             f"flash backward kernel has no dividing tile for Sq={Sq}, "
             f"Sk={Sk} — gate call sites with supported() (jnp fallback)")
-    bq, bk = blocks
+    bq, bk, need = plan
     nq, nk = Sq // bq, Sk // bk
-    group = heads // kv_heads
     kv = _kv_index(heads, kv_heads)
     scale = 1.0 / (D ** 0.5)
+    # which form this program took, counted where it is chosen: at trace time
+    get_registry().counter(
+        "flash.bwd_split" if need is None else "flash.bwd_fused").inc()
     # delta_i = Σ_d dO_id · O_id  (one fused elementwise pass, f32)
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1, keepdims=True)                   # (BH, Sq, 1)
+
+    if need is not None:
+        # dS = P ∘ (dP − (Δ − dlse)): the two statistics of a query ride
+        # that one pass, as rows along the lanes
+        like = _unify_vma(qoff, koff, q3, k3, v3, do3,
+                          lse.reshape(BH, 1, Sq),
+                          (delta - dlse).reshape(BH, 1, Sq))
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                              bq=bq, bk=bk, nq=nq, nk=nk),
+            grid=(BH, nk, nq),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, bq, D), lambda b, ki, qi: (b, qi, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, ki, qi: (b, ki, 0)),
+                pl.BlockSpec((1, bk, Dv), lambda b, ki, qi: (b, ki, 0)),
+                pl.BlockSpec((1, bq, Dv), lambda b, ki, qi: (b, qi, 0)),
+                pl.BlockSpec((1, 1, bq), lambda b, ki, qi: (b, 0, qi)),
+                pl.BlockSpec((1, 1, bq), lambda b, ki, qi: (b, 0, qi)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, Sq, D), lambda b, ki, qi: (b, 0, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, ki, qi: (b, ki, 0)),
+                pl.BlockSpec((1, bk, Dv), lambda b, ki, qi: (b, ki, 0)),
+            ],
+            out_shape=[
+                _out_struct((BH, Sq, D), q3.dtype, *like),
+                _out_struct((BH, Sk, D), k3.dtype, *like),
+                _out_struct((BH, Sk, Dv), v3.dtype, *like),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((Sq, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, Dv), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=(None if need <= _VMEM_BUDGET
+                                  else need + 2 * 1024 * 1024)),
+            interpret=interpret,
+            # the name the two-kernel form's larger half has: what reads a
+            # device trace by kernel name sums the backward either way
+            name="flash_bwd_dkv",
+        )(*like)
+
     q3, k3, v3, do3, lse, delta, dlse, qoff, koff = _unify_vma(
         q3, k3, v3, do3, lse, delta, dlse, qoff, koff)
 

@@ -381,11 +381,11 @@ def phase_train(args) -> None:
     # --- raw leg: the default fused DistributedOptimizer + chunked CE ----
     losses, text, _, _, _ = train_leg("train", "raw", cfg, mesh, params0,
                                       batches)
-    flash_calls = 3 * cfg.n_layers      # fwd + dq + dkv, every layer
+    flash_calls = 2 * cfg.n_layers      # fwd + the one-pass bwd, every layer
     if on_tpu:
         check(n_pallas_calls(text) == flash_calls,
               f"raw step holds {n_pallas_calls(text)} Pallas calls, "
-              f"expected {flash_calls} (flash fwd + 2 bwd per layer)")
+              f"expected {flash_calls} (flash fwd + 1 bwd per layer)")
     ln_v = math.log(cfg.vocab_size)
     check(abs(losses[0] - ln_v) <= 0.05 * ln_v,
           f"step-0 loss {losses[0]} within 5% of ln(vocab) {ln_v:.4f}")

@@ -6,6 +6,8 @@ mirroring the reference's compressor-vs-golden test style
 (SURVEY §4: every kernel has a dense-math twin asserted bit-close).
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +27,9 @@ from byteps_tpu.parallel import (
     make_mesh,
     ring_attention,
 )
+
+# (the package re-exports a function named like the module)
+fa = importlib.import_module("byteps_tpu.ops.flash_attention")
 
 
 @pytest.fixture(autouse=True)
@@ -330,6 +335,13 @@ def test_train_blocks_retuned_gpt2m_tiles():
         (512, 512)
     assert _train_blocks(512, 512, 64, 2, _BWD_PREFER, n_inter=4) == \
         (512, 512)
+    # what the backward runs is _bwd_plan's: the same tiles, and at these
+    # shapes the one-pass kernel under Mosaic's default limit
+    from byteps_tpu.ops.flash_attention import _VMEM_BUDGET, _bwd_plan
+
+    for S in (1024, 512):
+        bq, bk, need = _bwd_plan(S, S, 64, 64, 2, 1)
+        assert (bq, bk) == (512, 512) and need <= _VMEM_BUDGET
 
 
 @pytest.mark.parametrize("itemsize,D,n_inter", [
@@ -370,3 +382,166 @@ def test_train_blocks_env_override(monkeypatch):
     monkeypatch.setenv("BYTEPS_FLASH_BLOCK", "256")
     assert _train_blocks(1024, 1024, 64, 2, _FWD_PREFER, n_inter=2) == \
         (256, 256)
+
+
+# ---------------------------------------------------------------------------
+# the one-pass backward (PR 42): no cell's `correct` reads a gradient, so
+# these hold dq, dk and dv to the f32 jnp golden, case by case
+# ---------------------------------------------------------------------------
+def _form_counts():
+    from byteps_tpu.common.metrics import get_registry
+
+    reg = get_registry()
+    return (reg.counter("flash.bwd_fused").value(),
+            reg.counter("flash.bwd_split").value())
+
+
+@pytest.fixture
+def fresh_traces(monkeypatch):
+    """The tile preference and the form are read while ``_fwd`` / ``_bwd``
+    trace: a case that sets either must not meet another case's trace."""
+    def fresh(block=None, cap=None):
+        if block is not None:
+            monkeypatch.setenv("BYTEPS_FLASH_BLOCK", str(block))
+        if cap is not None:
+            monkeypatch.setattr(fa, "_FUSED_VMEM_CAP", cap)
+        fa._fwd.clear_cache()
+        fa._bwd.clear_cache()
+    fresh()
+    yield fresh
+    fa._fwd.clear_cache()
+    fa._bwd.clear_cache()
+
+
+def _vjp_inputs(B, Sq, Sk, H, Hkv, D, Dv, seed=40):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32),
+            jax.random.normal(ks[1], (B, Sk, Hkv, D), jnp.float32),
+            jax.random.normal(ks[2], (B, Sk, Hkv, Dv), jnp.float32),
+            # cotangents of o and of lse, the second on every row
+            jax.random.normal(ks[3], (B, Sq, H, Dv), jnp.float32),
+            jax.random.normal(ks[4], (B, Sq, H), jnp.float32))
+
+
+def _grads(fn, q, k, v, do, dlse, qoff, koff, causal):
+    _, vjp = jax.vjp(
+        lambda q, k, v: fn(q, k, v, qoff, koff, causal=causal), q, k, v)
+    return vjp((do, dlse))
+
+
+# id: (B, Sq, Sk, H, Hkv, D, Dv, causal, qoff, koff, tile, fused)
+_BWD_CASES = {
+    "causal": (2, 64, 64, 2, 2, 16, 16, True, 0, 0, None, True),
+    "not_causal": (2, 64, 64, 2, 2, 16, 16, False, 0, 0, None, True),
+    # v narrower than q/k (24/16 standing for 192/128), two blocks each way:
+    # the resident dq is added to at two row offsets, dk/dv over two q blocks
+    "dv_narrower_2x2_blocks": (1, 256, 256, 2, 2, 24, 16, True, 0, 0, 128,
+                               True),
+    "not_causal_3_q_2_kv_blocks": (1, 384, 256, 2, 2, 16, 16, False, 0, 0,
+                                   128, True),
+    # ring attention's offsets: q ahead of k (an all-live block beside the
+    # diagonal), k ahead of q (rows 0..63 have no live key at all), and k
+    # wholly in the future (every tile skipped: all three gradients zero)
+    "qoff_ahead": (1, 256, 256, 2, 2, 16, 16, True, 128, 0, 128, True),
+    "koff_ahead_masked_rows": (1, 256, 256, 2, 2, 16, 16, True, 0, 64, 128,
+                               True),
+    "koff_all_future": (1, 256, 256, 1, 1, 16, 16, True, 0, 1000, 128, True),
+    # what must keep the two kernels: a grouped-query shape, and a q tile
+    # that is neither whole lanes nor the whole sequence (192 = 3 x 64)
+    "gqa_keeps_two_kernels": (2, 64, 64, 4, 2, 16, 16, True, 0, 0, None,
+                              False),
+    "narrow_q_tile_keeps_two_kernels": (1, 192, 192, 2, 2, 16, 16, True, 0,
+                                        0, None, False),
+}
+
+
+@pytest.mark.parametrize("case", _BWD_CASES.values(), ids=_BWD_CASES.keys())
+def test_backward_matches_f32_golden(fresh_traces, case):
+    from byteps_tpu.ops.flash_attention import attention_lse_jnp
+
+    B, Sq, Sk, H, Hkv, D, Dv, causal, qoff, koff, tile, fused = case
+    fresh_traces(block=tile)
+    args = _vjp_inputs(B, Sq, Sk, H, Hkv, D, Dv)
+    before = _form_counts()
+    got = _grads(flash_attention_lse, *args, qoff, koff, causal)
+    after = _form_counts()
+    assert (after[0] - before[0], after[1] - before[1]) == \
+        ((1, 0) if fused else (0, 1))
+    want = _grads(attention_lse_jnp, *args, qoff, koff, causal)
+    for name, g, w in zip(("dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+    if koff >= Sq + qoff:
+        assert all(not np.asarray(g).any() for g in got)
+
+
+@pytest.mark.parametrize("name", ["causal", "not_causal",
+                                  "dv_narrower_2x2_blocks",
+                                  "not_causal_3_q_2_kv_blocks",
+                                  "koff_ahead_masked_rows"])
+def test_one_pass_equals_two_kernels(fresh_traces, name):
+    """One input through both forms: the same products over the same tiles,
+    so equal to f32 rounding (the sum over k blocks is taken in another
+    order for dq, and Δ − dlse is taken before the tile, not inside it)."""
+    B, Sq, Sk, H, Hkv, D, Dv, causal, qoff, koff, tile, _ = _BWD_CASES[name]
+    args = _vjp_inputs(B, Sq, Sk, H, Hkv, D, Dv, seed=41)
+    fresh_traces(block=tile)
+    before = _form_counts()
+    one = _grads(flash_attention_lse, *args, qoff, koff, causal)
+    fresh_traces(cap=0)                 # no live set fits: the two kernels
+    two = _grads(flash_attention_lse, *args, qoff, koff, causal)
+    after = _form_counts()
+    assert (after[0] - before[0], after[1] - before[1]) == (1, 1)
+    for g, w in zip(one, two):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
+                                   atol=1e-5)
+
+
+def test_one_pass_bf16_close_to_f32_golden(fresh_traces):
+    q, k, v, do, dlse = _vjp_inputs(1, 256, 256, 2, 2, 24, 16)
+    fresh_traces(block=128)
+    lo = [x.astype(jnp.bfloat16) for x in (q, k, v, do)]
+    got = _grads(flash_attention_lse, *lo, dlse, 0, 0, True)
+    from byteps_tpu.ops.flash_attention import attention_lse_jnp
+
+    want = _grads(attention_lse_jnp,
+                  *[x.astype(jnp.float32) for x in lo], dlse, 0, 0, True)
+    for g, w in zip(got, want):
+        assert g.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(g, dtype=np.float32),
+                                   np.asarray(w), rtol=5e-2, atol=5e-2)
+
+
+# (Sq, Sk, D, Dv, itemsize, group) -> the form, and where the live set lies
+@pytest.mark.parametrize("shape,form", [
+    ((1024, 1024, 64, 64, 2, 1), "fused_default_limit"),     # gpt2m-train
+    ((4096, 4096, 192, 128, 2, 1), "fused_stated_limit"),    # JoyAI
+    ((65536, 65536, 128, 128, 2, 1), "split"),   # dq cannot stay: 67 MB
+    ((1024, 1024, 64, 64, 2, 4), "split"),       # grouped-query
+    ((1023, 1024, 64, 64, 2, 1), "none"),
+])
+def test_bwd_plan_from_shapes_alone(shape, form):
+    from byteps_tpu.ops.flash_attention import (
+        _FUSED_VMEM_CAP, _VMEM_BUDGET, _bwd_plan)
+
+    plan = _bwd_plan(*shape)
+    if form == "none":
+        assert plan is None
+        return
+    bq, bk, need = plan
+    assert (bq, bk) == (512, 512)
+    if form == "split":
+        assert need is None
+    elif form == "fused_default_limit":
+        assert need <= _VMEM_BUDGET
+    else:
+        assert _VMEM_BUDGET < need <= _FUSED_VMEM_CAP
+
+
+def test_backward_form_is_counted_once_a_trace(fresh_traces):
+    args = _vjp_inputs(1, 64, 64, 2, 2, 16, 16)
+    before = _form_counts()
+    for _ in range(3):                  # one trace, three calls
+        _grads(flash_attention_lse, *args, 0, 0, True)
+    assert _form_counts() == (before[0] + 1, before[1])

@@ -103,6 +103,11 @@ def _attention_bshd(q, k, v):
     return flash_attention(q, k, v, causal=True)
 
 
+def _attention_grad(q, k, v):
+    return jax.grad(lambda *a: _attention_bshd(*a).astype(F32).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
 def _decode(quant):
     def f(q, k, v, *scales):
         return flash_decode(q, k, v, jnp.int32(100), *scales)
@@ -151,14 +156,24 @@ _GMM = _gmm_shapes(135168, 16, 2048, 768)
 # (id, function, argument shapes, Pallas calls expected in the program)
 ONE_CHIP = [
     ("flash_fwd_gpt2m", _flash_fwd(16, 16), _qkv(128, 1024, 1024, 64), 1),
+    # the backward is ONE kernel beside the forward where a head's dq can
+    # stay in VMEM; a grouped-query shape keeps dq and dkv apart
     ("flash_fwd_bwd_gpt2m", _flash_fwd_bwd(16, 16),
-     _qkv(128, 1024, 1024, 64), 3),
+     _qkv(128, 1024, 1024, 64), 2),
     ("flash_fwd_bwd_gqa32_4_d128_s2048", _flash_fwd_bwd(32, 4),
      _qkv(32, 2048, 2048, 128, kv_bh=4), 3),
     # latent attention with k and v materialised: q/k 192 wide, v 128
     # (JoyAI-LLM-Flash: 4 sequences x 32 heads, S 4096)
     ("flash_fwd_bwd_mla_qk192_v128_s4096", _flash_fwd_bwd(32, 32),
-     [_sds((128, 4096, 192), BF16)] * 2 + [_sds((128, 4096, 128), BF16)], 3),
+     [_sds((128, 4096, 192), BF16)] * 2 + [_sds((128, 4096, 128), BF16)], 2),
+    # the training cells' two shapes as the models call them, (B, S, H, D)
+    # through the dispatcher under jax.grad: forward + the fused backward
+    # (JoyAI's live set is over the scoped default and states its limit)
+    ("flash_grad_fused_gpt2m_b8_h16", _attention_grad,
+     [_sds((8, 1024, 16, 64), BF16)] * 3, 2),
+    ("flash_grad_fused_joyai_b4_h32", _attention_grad,
+     [_sds((4, 4096, 32, 192), BF16)] * 2 + [_sds((4, 4096, 32, 128), BF16)],
+     2),
     ("moe_gmm_fwd_joyai", lambda lhs, w, _, sizes:
      grouped_matmul(lhs, w, sizes), _GMM, 1),
     # the first product forward (the second's output is not needed for
