@@ -187,15 +187,16 @@ def cache_attend(cache_k, cache_v, pos0):
 
 def _block_step(x, p, cache_k, cache_v, pos0, cfg, tp_axis, ep_axis,
                 norm_fn=_layernorm, norm_eps: float = 1e-5, rope=None,
-                ffn=None):
+                ffn=None, attn=None):
     """One transformer block (dense-MLP or MoE, by param structure) over
     T new tokens with cache append: the shared halves of ``models/gpt.py``
     around :func:`cache_attend`. ``rope`` (default: the config's one base)
     and ``ffn`` (``h -> (out, aux)``; default: by param structure) are a
-    caller's whose layers differ in either. Returns ``(x, cache_k, cache_v)``,
+    caller's whose layers differ in either, ``attn`` one whose first half is
+    not :func:`attn_half` (same signature and ``attend`` contract). Returns ``(x, cache_k, cache_v)``,
     and ``ffn``'s ``aux`` after them where one was given."""
     kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
-    x, (cache_k, cache_v) = attn_half(
+    x, (cache_k, cache_v) = (attn_half if attn is None else attn)(
         x, p, cfg.head_dim, lambda: pos0 + jnp.arange(x.shape[1]),
         cache_attend(cache_k, cache_v, pos0), tp_axis,
         resolve_rope(cfg) if rope is None else rope, **kw)

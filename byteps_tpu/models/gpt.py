@@ -261,7 +261,19 @@ def _rmsnorm(x: jnp.ndarray, g: jnp.ndarray, b=None,
     return (xf * jax.lax.rsqrt(ms + eps) * g).astype(x.dtype)
 
 
-_NORMS = {"layernorm": _layernorm, "rmsnorm": _rmsnorm}
+def _rmsnorm_zc(x: jnp.ndarray, g: jnp.ndarray, b=None,
+                eps: float = 1e-6) -> jnp.ndarray:
+    """The zero-centred RMS norm, ``x̂ · (1 + g)`` in f32 (``g`` is drawn
+    and decayed around 0; Qwen3-Next's every norm but one)."""
+    assert b is None, "rmsnorm trees carry no norm-bias leaf"
+    xf = x.astype(jnp.float32)
+    ms = (xf * xf).mean(-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(ms + eps)
+            * (1.0 + g.astype(jnp.float32))).astype(x.dtype)
+
+
+_NORMS = {"layernorm": _layernorm, "rmsnorm": _rmsnorm,
+          "rmsnorm_zero_centred": _rmsnorm_zc}
 
 
 def resolve_norm(cfg: GPTConfig):

@@ -15,6 +15,9 @@ same for every family.
 * :class:`WindowedKVFamily` (``Mellum2Config``): k/v pages of two layer
   kinds under ``paged_cache.py``'s own two programs, each layer told its
   kind, with a dropless expert FFN in every block.
+* :class:`RecurrentKVFamily` (``Qwen3NextConfig``): k/v pages for the full-
+  attention layers and one slot of a state pool a request for the recurrent
+  (Gated-DeltaNet) layers, under the same two programs.
 """
 
 from __future__ import annotations
@@ -33,12 +36,15 @@ class PoolLayout(NamedTuple):
     layout has no such payload, so nothing of it travels a wire).
     ``window``: keys a window layer keeps, the query's own included (None:
     no window kind); ``window_blocks``: blocks of the window kind's own
-    pool, its scratch block included."""
+    pool, its scratch block included. ``state_slots``: slots of the
+    recurrent kind's state pool, its scratch slot included (0: no such
+    kind)."""
 
     state: Any
     kv_heads: int = 0
     window: Optional[int] = None
     window_blocks: int = 0
+    state_slots: int = 0
 
 
 class LateStats:
@@ -67,6 +73,12 @@ class LateStats:
         raise NotImplementedError
 
 
+def admitted_at_once(max_batch: int) -> int:
+    """Requests the scheduler holds admitted: the decode rows and the
+    prefilled standbys beside them (``Scheduler._admit_cap``)."""
+    return max_batch + max(1, max_batch // 4)
+
+
 def window_pool_blocks(window: int, block_size: int, max_batch: int,
                        prefill_chunk: int) -> int:
     """Blocks of a window kind's pool: a request keeps the blocks of its
@@ -74,8 +86,8 @@ def window_pool_blocks(window: int, block_size: int, max_batch: int,
     a chunk runs for holds the chunk's beside them. Every admitted request
     at once, and scratch."""
     per_req = -(-(window - 1) // block_size) + 2
-    admitted = max_batch + max(1, max_batch // 4)
-    return 1 + admitted * per_req + -(-prefill_chunk // block_size) + 1
+    return 1 + admitted_at_once(max_batch) * per_req \
+        + -(-prefill_chunk // block_size) + 1
 
 
 class _Refusing:
@@ -265,17 +277,20 @@ class WindowedKVFamily(_Refusing, GPTFamily):
     def operands(self, params, cfg):
         return params              # published in bf16: every leaf as it is
 
+    #: the configuration's ``StepPlan`` (a subclass names its own)
+    plan = staticmethod(_windowed_plan)
+
     def decode_fn(self, cfg, block_size, tp_axis, lora_sig):
         from byteps_tpu.serve.paged_cache import make_paged_decode_fn
 
-        return make_paged_decode_fn(cfg, block_size, plan=_windowed_plan(cfg))
+        return make_paged_decode_fn(cfg, block_size, plan=self.plan(cfg))
 
     def prefill_fn(self, cfg, block_size, chunk_len, tp_axis, with_readout):
         from byteps_tpu.serve.paged_cache import make_paged_prefill_fn
 
         return make_paged_prefill_fn(cfg, block_size, chunk_len,
                                      with_readout=with_readout,
-                                     plan=_windowed_plan(cfg))
+                                     plan=self.plan(cfg))
 
     def late_stats(self):
         from byteps_tpu.serve.paged_cache import StepStats
@@ -283,17 +298,87 @@ class WindowedKVFamily(_Refusing, GPTFamily):
         return StepStats()
 
 
+@functools.lru_cache(maxsize=16)
+def _recurrent_plan(cfg):
+    """The ``StepPlan`` of a ``Qwen3NextConfig``: each full layer's row in
+    the k/v pool, each DeltaNet layer's row in the state pool, the model's
+    own two first halves and its expert FFN."""
+    from byteps_tpu.models.qwen3_next import (
+        FULL, LINEAR, expert_ffn, full_attn_half, gdn_half)
+    from byteps_tpu.serve.paged_cache import LayerKind, StepPlan
+
+    place = {li: i for kind in (FULL, LINEAR)
+             for i, li in enumerate(cfg.layers_of(kind))}
+    return StepPlan(tuple(
+        LayerKind(place[li], None, cfg.rope_base, state=kind == LINEAR)
+        for li, kind in enumerate(cfg.layer_types)),
+        expert_ffn, full_attn_half, gdn_half)
+
+
+class RecurrentKVFamily(WindowedKVFamily):
+    """Qwen3-Next: k/v pages for the gated full-attention layers and, for
+    the Gated-DeltaNet layers, a slot of a state pool a request (the f32
+    state and the convolution's tail), under ``paged_cache.py``'s two
+    programs and a :class:`~paged_cache.StepPlan` that carries the model's
+    own first halves (the plan is the one thing the programs' factories
+    are given differently)."""
+
+    name = "recurrent state + k/v"
+    plan = staticmethod(_recurrent_plan)
+
+    #: what a slot cannot do yet, each refused at construction:
+    #: ``feature -> the message's subject``
+    REFUSED = {
+        "prefix_cache": "the prefix cache (a shared prefix needs the "
+                        "recurrent state as it was at the sharing point: "
+                        "no snapshot is kept)",
+        "speculation": "speculative decoding (a rejected draft cannot "
+                       "rewind a recurrent state)",
+        "adapter_pool": "LoRA adapter slabs",
+        "quant_cache": "the int8 pool",
+        "role": "role='prefill'|'decode' and migration over kv_wire (no "
+                "payload for a state slot)",
+        "tp_axis": "tensor parallelism",
+    }
+
+    def layout(self, params, cfg, *, block_size, pool_blocks, max_batch,
+               prefill_chunk, quant) -> PoolLayout:
+        from byteps_tpu.models.qwen3_next import FULL, LINEAR
+        from byteps_tpu.serve.paged_cache import (
+            kv_pool_state, with_state_pool)
+
+        slots = 1 + admitted_at_once(max_batch)
+        pool = kv_pool_state(cfg, block_size, pool_blocks, cfg.kv_heads,
+                             False, layers=len(cfg.layers_of(FULL)))
+        return PoolLayout(
+            state=with_state_pool(
+                pool, len(cfg.layers_of(LINEAR)), slots,
+                (cfg.linear_value_heads, cfg.linear_key_dim,
+                 cfg.linear_value_dim),
+                ((cfg.conv_kernel - 1) * cfg.conv_channels,), cfg.dtype),
+            kv_heads=cfg.kv_heads, state_slots=slots)
+
+    def late_stats(self):
+        from byteps_tpu.serve.paged_cache import STATS_STATE, StepStats
+
+        return StepStats(STATS_STATE)
+
+
 def serve_family(cfg):
     """The family that serves ``cfg``, by its type."""
     from byteps_tpu.models.dots3 import Dots3Config
     from byteps_tpu.models.mellum2 import Mellum2Config
+    from byteps_tpu.models.qwen3_next import Qwen3NextConfig
 
     if isinstance(cfg, Dots3Config):
         return LatentFamily()
     if isinstance(cfg, Mellum2Config):
         return WindowedKVFamily()
+    if isinstance(cfg, Qwen3NextConfig):
+        return RecurrentKVFamily()
     if isinstance(cfg, GPTConfig):
         return GPTFamily()
     raise TypeError(
         f"Scheduler: no serve family for a {type(cfg).__name__} "
-        "(GPTConfig, Dots3Config and Mellum2Config are served)")
+        "(GPTConfig, Dots3Config, Mellum2Config and Qwen3NextConfig are "
+        "served)")

@@ -74,7 +74,11 @@ back (``families.WindowedKVFamily``) keeps those layers' k/v in a second,
 small pool (``PoolState.wk``/``wv``) under the cache's window kind, and its
 programs read two table lines, start a window layer's attention at ``fill −
 window`` and count what they did into ``PoolState.stats``
-(:class:`StepStats`).
+(:class:`StepStats`). A third kind keeps no keys at all: a *recurrent* layer
+(``LayerKind.state``; ``families.RecurrentKVFamily``) owns no table line —
+a request owns one SLOT of a state pool (``PoolState.s``/``conv``) from
+admission to release, its index rides at the head of the request's table
+row, and the plan's ``recur`` updates the slot in place.
 """
 
 from __future__ import annotations
@@ -140,6 +144,12 @@ class PoolState(NamedTuple):
     wrote the pool counted (:class:`StepStats` reads it a step late). All
     three are None for one kind of layer: no leaf, so the GPT family's
     programs are traced over the tree they always had.
+
+    A family with recurrent layers keeps what a request carries between
+    tokens in a pool of slots: ``s (recurrent layers, slots, ...)`` the
+    state itself (f32) and ``conv (recurrent layers, slots, ...)`` the
+    layer's short convolution tail; slot 0 is scratch. None, no leaf, for
+    every other family.
     """
 
     k: jnp.ndarray
@@ -149,6 +159,8 @@ class PoolState(NamedTuple):
     wk: Optional[jnp.ndarray] = None
     wv: Optional[jnp.ndarray] = None
     stats: Optional[jnp.ndarray] = None
+    s: Optional[jnp.ndarray] = None
+    conv: Optional[jnp.ndarray] = None
 
 
 class LayerKind(NamedTuple):
@@ -157,11 +169,13 @@ class LayerKind(NamedTuple):
     every key live); else the keys a query sees, its own included — a
     window layer (``pool.wk``/``wv``, table line 1, keys below ``fill -
     window`` neither held nor read). ``rope``: what it rotates by (0: not at
-    all)."""
+    all). ``state``: a recurrent layer — no keys, no table line; ``index`` is
+    its row in the state pool (``pool.s``/``conv``)."""
 
     index: int
     window: Optional[int] = None
     rope: Union[float, RopeFreqs] = 0.0
+    state: bool = False
 
 
 class StepPlan(NamedTuple):
@@ -169,10 +183,18 @@ class StepPlan(NamedTuple):
     fields: a :class:`LayerKind` a layer, and the block's second half —
     ``ffn(cfg, p, h) -> (out, aux f32 (3,))`` with ``aux`` = (pairs
     computed, experts with a row, heaviest expert over the mean); None is
-    the dense MLP. Hashable: it keys the programs' factories."""
+    the dense MLP. ``attn``: the first half of a block over k/v, called as
+    ``models/gpt.py::attn_half`` is with ``cfg`` before its arguments (None:
+    ``attn_half`` itself). ``recur``: the first half of a recurrent layer,
+    ``recur(cfg, x, p, pool.s, pool.conv, index, slots, fresh, norm_fn=,
+    norm_eps=) -> (x, s, conv)`` — ``slots (R,)`` for the packed decode
+    step's rows, ``()`` for a chunk of one request, which starts from a zero
+    state where ``fresh``. Hashable: it keys the programs' factories."""
 
     kinds: Tuple[LayerKind, ...]
     ffn: Optional[Callable] = None
+    attn: Optional[Callable] = None
+    recur: Optional[Callable] = None
 
 
 def _window_of(plan: "StepPlan") -> Optional[int]:
@@ -181,6 +203,13 @@ def _window_of(plan: "StepPlan") -> Optional[int]:
     if len(windows) > 1:
         raise ValueError(f"one window a plan; got {sorted(windows)}")
     return windows.pop() if windows else None
+
+
+def _layers_by_kind(plan: "StepPlan") -> Tuple[int, int, int]:
+    """``(global, window, recurrent)`` layers of a plan."""
+    n_state = sum(k.state for k in plan.kinds)
+    n_window = sum(k.window is not None for k in plan.kinds)
+    return len(plan.kinds) - n_state - n_window, n_window, n_state
 
 
 def one_kind_plan(cfg) -> StepPlan:
@@ -199,6 +228,9 @@ STATS = ("moe.pairs_here", "moe.experts_hit", "moe.layers",
          "moe.load_max_over_mean",
          "serve.kv.decode_keys_read.full", "serve.kv.decode_keys_read.window",
          "serve.attn.prefill_pairs.full", "serve.attn.prefill_pairs.window")
+#: a pool with recurrent layers counts these too: live rows of a decode
+#: step and tokens of a chunk, each times the recurrent layers
+STATS_STATE = STATS + ("serve.gdn.decode_rows", "serve.gdn.prefill_tokens")
 
 
 def kv_pool_state(cfg: GPTConfig, block_size: int, pool_blocks: int,
@@ -225,6 +257,19 @@ def kv_pool_state(cfg: GPTConfig, block_size: int, pool_blocks: int,
                              wv=jnp.zeros(wshape, cfg.dtype),
                              stats=jnp.zeros((len(STATS),), jnp.float32))
     return pool
+
+
+def with_state_pool(pool: PoolState, layers: int, slots: int,
+                    state_shape: tuple, tail_shape: tuple, tail_dtype
+                    ) -> PoolState:
+    """``pool`` with a zeroed state pool of ``slots`` slots (slot 0 scratch)
+    for ``layers`` recurrent layers beside it — a slot of a layer holds
+    ``state_shape`` f32 and ``tail_shape`` of ``tail_dtype`` — and the
+    ``stats`` leaf of :data:`STATS_STATE`."""
+    return pool._replace(
+        s=jnp.zeros((layers, slots) + tuple(state_shape), jnp.float32),
+        conv=jnp.zeros((layers, slots) + tuple(tail_shape), tail_dtype),
+        stats=jnp.zeros((len(STATS_STATE),), jnp.float32))
 
 
 class PoolExhausted(RuntimeError):
@@ -303,6 +348,14 @@ class PagedKVCache:
         lay = layout(block_size, pool_blocks)
         self.kv_heads, self.state = lay.kv_heads, lay.state
         self.window, window_blocks = lay.window, lay.window_blocks
+        # the recurrent kind: a request owns one slot of the state pool from
+        # register() to release(); slot 0 is scratch (rows of a packed step
+        # that hold no request write there). Nothing zeroes a slot: the
+        # chunk at position 0 starts from a zero state whatever it holds,
+        # which is the reset (counted here, by cause)
+        self.state_slots = lay.state_slots
+        self._sfree: List[int] = list(range(lay.state_slots - 1, 0, -1))
+        self._slots: Dict[object, int] = {}
 
         # the window kind: layers that keep the blocks holding a request's
         # last ``window`` positions and hand the others back while it runs.
@@ -347,6 +400,14 @@ class PagedKVCache:
                 f"serve.pool{seq}.window_blocks_in_use")
             self._c_released = _reg.counter(
                 "serve.cache.window_blocks_released")
+        if self.state_slots:
+            self._slot_bytes = sum(
+                a.nbytes // a.shape[1] for a in (self.state.s,
+                                                 self.state.conv))
+            self._g_slots = _reg.gauge("serve.state.slots_in_use")
+            self._g_state_bytes = _reg.gauge("serve.state.bytes")
+            self._c_resets = {c: _reg.counter(f"serve.state.resets.{c}")
+                              for c in ("admit", "preempt")}
         self._c_alloc_fail = _reg.counter("serve.kv_alloc_failures")
         self._c_prefix_evict = _reg.counter("serve.prefix_evictions")
 
@@ -387,7 +448,26 @@ class PagedKVCache:
         if self.window is not None:
             leaked += (self.window_blocks - 1) - len(self._wfree) \
                 - self.window_blocks_in_use
-        return leaked
+        return leaked + self.leaked_slots()
+
+    @property
+    def slots_in_use(self) -> int:
+        """Slots of the state pool that registered requests hold."""
+        return len(self._slots)
+
+    def leaked_slots(self) -> int:
+        """Slots neither free nor a registered request's (0 without a
+        recurrent kind); counted among :meth:`leaked_blocks`."""
+        if not self.state_slots:
+            return 0
+        return (self.state_slots - 1) - len(self._sfree) - len(self._slots)
+
+    def slot_of(self, rid) -> int:
+        return self._slots[rid]
+
+    def _set_state_gauges(self) -> None:
+        self._g_slots.set(len(self._slots))
+        self._g_state_bytes.set(len(self._slots) * self._slot_bytes)
 
     @property
     def window_blocks_in_use(self) -> int:
@@ -429,9 +509,22 @@ class PagedKVCache:
         return len(self._tables[rid])
 
     # -- allocation ---------------------------------------------------------
-    def register(self, rid) -> None:
+    def register(self, rid, resumed: bool = False) -> None:
+        """Open ``rid``'s account: an empty table a kind and, with a
+        recurrent kind, a slot of the state pool — granted here, reset by
+        the request's first chunk (``resumed``: after a preemption, which
+        the reset is then counted under)."""
         if rid in self._tables:
             raise ValueError(f"request {rid!r} already registered")
+        if self.state_slots:
+            if not self._sfree:
+                self._c_alloc_fail.inc()
+                raise PoolExhausted(
+                    f"request {rid!r} needs a state slot, all "
+                    f"{self.state_slots - 1} are held")
+            self._slots[rid] = self._sfree.pop()
+            self._c_resets["preempt" if resumed else "admit"].inc()
+            self._set_state_gauges()
         self._tables[rid] = []
         if self.window is not None:
             self._wtables[rid] = {}
@@ -505,6 +598,9 @@ class PagedKVCache:
             self._wfree.extend(self._wtables.pop(rid).values())
             del self._wnext[rid]
             self._set_kind_gauges()
+        if self.state_slots:
+            self._sfree.append(self._slots.pop(rid))
+            self._set_state_gauges()
 
     def _kv_only(self, what: str) -> None:
         if self.kv_heads == 0:
@@ -799,6 +895,13 @@ class PagedKVCache:
             wt = self._wtables[rid]
             rows[1, list(wt)] = list(wt.values())
             return rows
+        if self.state_slots:
+            # the request's slot of the state pool rides at the head of its
+            # row: one host array a step, as it was
+            row = np.zeros(1 + w, np.int32)
+            row[0] = self._slots[rid]
+            row[1:1 + len(t)] = t
+            return row
         row = np.zeros(w, np.int32)
         row[:len(t)] = t
         return row
@@ -943,15 +1046,14 @@ class StepStats(LateStats):
     layers into the ``moe.*`` histograms and the ``serve.kv.*`` /
     ``serve.attn.*`` counters (docs/observability.md)."""
 
-    names = STATS
-
-    def __init__(self):
+    def __init__(self, names: tuple = STATS):
         super().__init__()
+        self.names = names
         reg = get_registry()
         self._pairs_here = reg.histogram("moe.pairs_here")
         self._experts_hit = reg.histogram("moe.experts_hit")
         self._load = reg.histogram("moe.load_max_over_mean")
-        self._counters = {n: reg.counter(n) for n in STATS
+        self._counters = {n: reg.counter(n) for n in names
                           if n.startswith("serve.")}
 
     def observe(self, s: dict) -> None:
@@ -1093,7 +1195,10 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     line 1 the window kind's, indexed by logical block, 0 where one was
     released — and a window layer scatters into ``pool.wk``/``wv`` and
     attends over ``[pos + 1 - window, pos]`` alone: the kernel with each
-    row's first key, or :func:`_window_attend_twin`.
+    row's first key, or :func:`_window_attend_twin`. With recurrent layers
+    ``tables`` is ``(R, 1 + W)``: column 0 each row's slot of the state pool
+    (0, scratch, for a row that holds no request), which ``plan.recur``
+    updates in place; such a layer reads no table and no key.
 
     Multi-tenant variant: ``lora_sig=(targets, rank_bucket,
     n_adapter_slots)`` makes the step accept two trailing arguments —
@@ -1115,6 +1220,9 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     norm_fn, norm_eps = resolve_norm(cfg)
     kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
     lora_targets = () if lora_sig is None else tuple(lora_sig[0])
+    half = attn_half if plan.attn is None \
+        else functools.partial(plan.attn, cfg)
+    n_full, n_window, n_state = _layers_by_kind(plan)
 
     def _slab_delta(slabs, slots, li):
         # the block's per-projection delta hook: each row's OWN adapter,
@@ -1196,6 +1304,8 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, pool, toks, pos, tables, slabs=None, slots=None):
         x = _embed(params, toks[:, None], pos[:, None], cfg)  # (R, 1, d)
+        if n_state:
+            state_slots, tables = tables[:, 0], tables[:, 1:]
         # one table a kind: (R, W), or (R, 2, W) with a window kind
         kind_tables = (tables,) if tables.ndim == 2 \
             else (tables[:, 0], tables[:, 1])
@@ -1207,11 +1317,17 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
         for li, (p, kind) in enumerate(zip(params["blocks"], plan.kinds)):
             delta = None if slabs is None else _slab_delta(slabs, slots, li)
             line = 0 if kind.window is None else 1
-            x, pool = attn_half(
-                x, p, cfg.head_dim, lambda: pos[:, None],
-                _pool_attend(pool, kind, blks[line], off, pos,
-                             kind_tables[line]), tp_axis,
-                kind.rope, delta=delta, **kw)
+            if kind.state:
+                x, s, conv = plan.recur(
+                    cfg, x, p, pool.s, pool.conv, kind.index, state_slots,
+                    None, norm_fn=norm_fn, norm_eps=norm_eps)
+                pool = pool._replace(s=s, conv=conv)
+            else:
+                x, pool = half(
+                    x, p, cfg.head_dim, lambda: pos[:, None],
+                    _pool_attend(pool, kind, blks[line], off, pos,
+                                 kind_tables[line]), tp_axis,
+                    kind.rope, delta=delta, **kw)
             x, aux = ffn_half(
                 x, p, tp_axis, None if plan.ffn is None
                 else functools.partial(plan.ffn, cfg, p), delta=delta, **kw)
@@ -1222,11 +1338,14 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
             # position 0, where no request decodes), by layer kind
             live = pos > 0
             keys = [jnp.sum(jnp.where(live, pos + 1 if w is None else
-                                      jnp.minimum(pos + 1, w), 0))
-                    * sum(k.window == w for k in plan.kinds)
-                    for w in (None, _window_of(plan))]
+                                      jnp.minimum(pos + 1, w), 0)) * n
+                    if n else 0.0
+                    for w, n in ((None, n_full),
+                                 (_window_of(plan), n_window))]
+            rows = (jnp.sum(live) * n_state, 0.0) if n_state else ()
             pool = pool._replace(stats=jnp.concatenate([moe, jnp.stack(
-                [jnp.asarray(v, jnp.float32) for v in (*keys, 0.0, 0.0)])]))
+                [jnp.asarray(v, jnp.float32)
+                 for v in (*keys, 0.0, 0.0, *rows)])]))
         logits = _readout(params, x, norm_fn, norm_eps)
         return logits[:, 0], pool
 
@@ -1268,7 +1387,11 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     ``flash_attention_window`` over the ``window - 1`` rows before the chunk
     (gathered through the table; those before position 0 are padding the
     mask never lets through) and the chunk's own: no view of the request's
-    whole context is made for it.
+    whole context is made for it. With recurrent layers ``table`` is ``(1 +
+    W,)``, the request's slot of the state pool first: ``plan.recur`` runs
+    the chunk from the slot's state — from zero at ``pos0 == 0``, whatever
+    the slot's last owner left — and leaves there what the next chunk or
+    the first decode step continues from.
     ``with_readout=False`` skips the vocab projection (an intermediate
     prefill chunk's logits are never read — at real vocab sizes that
     projection is the biggest weight stream in the chunk) and returns
@@ -1276,6 +1399,8 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     C = chunk_len
     plan = one_kind_plan(cfg) if plan is None else plan
     norm_fn, norm_eps = resolve_norm(cfg)
+    half = None if plan.attn is None else functools.partial(plan.attn, cfg)
+    n_full, n_window, n_state = _layers_by_kind(plan)
 
     def _view(pool_a, li, table, keep, *tail):
         # this request's (1, W * bs, h[, D]) view of one layer, zero past
@@ -1331,6 +1456,15 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
         x, aux = ffn_half(x, p, tp_axis, _ffn(p), **kw)
         return x, pool, aux
 
+    @jax.jit
+    def _state_layer(x, p, pool, li, pos0, slot):
+        """A recurrent layer of the chunk: ``(x, pool, aux)``."""
+        x, s, conv = plan.recur(cfg, x, p, pool.s, pool.conv, li, slot,
+                                pos0 == 0, norm_fn=norm_fn, norm_eps=norm_eps)
+        x, aux = ffn_half(x, p, tp_axis, _ffn(p), norm_fn=norm_fn,
+                          norm_eps=norm_eps, use_bias=cfg.use_bias)
+        return x, pool._replace(s=s, conv=conv), aux
+
     # jitted, the layer index DATA: layers of one shape share one trace.
     # A replica traces and lowers a chunk program for every tail chunk x
     # table width x readout before it serves, compile cache or not, and
@@ -1349,7 +1483,7 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
                 cv = _QuantSlot(cv, _view(pool.v_scale, li, table, keep))
         x, ck, cv, *aux = _block_step(
             x, p, ck, cv, pos0, cfg, tp_axis, None, norm_fn=norm_fn,
-            norm_eps=norm_eps, rope=kind.rope, ffn=_ffn(p))
+            norm_eps=norm_eps, rope=kind.rope, ffn=_ffn(p), attn=half)
         with jax.named_scope("paged/scatter_kv"):
             at = (li, blk, off)
             if quant:
@@ -1367,6 +1501,8 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     @functools.partial(jax.jit, donate_argnums=(1,))
     def chunk(params, pool, tokens, pos0, table):
         positions = pos0 + jnp.arange(C)
+        if n_state:
+            slot, table = table[0], table[1:]
         # one table a kind: (W,), or (2, W) with a window kind
         kind_tables = (table,) if table.ndim == 1 else (table[0], table[1])
         blks = [jnp.take(t, positions // block_size) for t in kind_tables]
@@ -1376,20 +1512,28 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
         moe = None if plan.ffn is None else jnp.zeros((4,), jnp.float32)
         for p, kind in zip(params["blocks"], plan.kinds):
             line = 0 if kind.window is None else 1
-            x, pool, aux = _layer(
-                x, p, pool, jnp.int32(kind.index), pos0, kind_tables[line],
-                keep, blks[line], off, kind=kind._replace(index=0))
+            if kind.state:
+                x, pool, aux = _state_layer(
+                    x, p, pool, jnp.int32(kind.index), pos0, slot)
+            else:
+                x, pool, aux = _layer(
+                    x, p, pool, jnp.int32(kind.index), pos0,
+                    kind_tables[line], keep, blks[line], off,
+                    kind=kind._replace(index=0))
             if aux is not None:
                 moe = _fold_moe(moe, aux)
         if pool.stats is not None:
             # visible (query, key) pairs of the chunk, by layer kind: query
             # t sees t + 1 keys, or the window where that is fewer
             pairs = [jnp.sum(positions + 1 if w is None
-                             else jnp.minimum(positions + 1, w))
-                     * sum(k.window == w for k in plan.kinds)
-                     for w in (None, _window_of(plan))]
+                             else jnp.minimum(positions + 1, w)) * n
+                     if n else 0.0
+                     for w, n in ((None, n_full),
+                                  (_window_of(plan), n_window))]
+            toks = (0.0, float(C * n_state)) if n_state else ()
             pool = pool._replace(stats=jnp.concatenate([moe, jnp.stack(
-                [jnp.asarray(v, jnp.float32) for v in (0.0, 0.0, *pairs)])]))
+                [jnp.asarray(v, jnp.float32)
+                 for v in (0.0, 0.0, *pairs, *toks)])]))
         logits = (_readout(params, x, norm_fn, norm_eps) if with_readout
                   else None)
         return logits, pool

@@ -130,7 +130,7 @@ from byteps_tpu.common.tracing import get_tracer
 from byteps_tpu.models.generate import gpt_apply_cached, init_cache
 from byteps_tpu.models.gpt import GPTConfig
 from byteps_tpu.models.speculative import _verify_commit
-from byteps_tpu.serve.families import serve_family
+from byteps_tpu.serve.families import admitted_at_once, serve_family
 from byteps_tpu.serve.paged_cache import PagedKVCache, PoolExhausted
 
 log = get_logger("serve.scheduler")
@@ -492,7 +492,7 @@ class Scheduler:
         # request's slot refills from a PREFILLED standby instead of
         # waiting a prompt's worth of prefill chunks with the batch
         # underfull (the pool pressure valve is preemption either way)
-        self._admit_cap = self.max_batch + max(1, self.max_batch // 4)
+        self._admit_cap = admitted_at_once(self.max_batch)
         self._iteration = 0            # the serve.iteration span's number
         _reg = get_registry()
         self._m = {
@@ -1414,7 +1414,7 @@ class Scheduler:
                            exclude=hit_blocks)):
                 break
             self._waiting.remove(run)
-            self.cache.register(run.req.rid)
+            self.cache.register(run.req.rid, resumed=run.preemptions > 0)
             try:
                 if hit_blocks:
                     self.cache.adopt_prefix(run.req.rid, hit_blocks)

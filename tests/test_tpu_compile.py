@@ -570,3 +570,142 @@ def test_mellum2_serve_program_fits_and_leaves_the_pools_in_place(
     else:
         assert n == 8 + 3 * 8, n
         assert "paged_attn_decode" in hlo
+
+
+# --- the Qwen3-Next serving cell's two programs at the cut configuration ----
+_QN_BS, _QN_BATCH, _QN_CHUNK, _QN_BLOCKS = 128, 128, 2048, 8193
+
+
+def _qwen3next_on(topo):
+    """The cut Qwen3-Next (``benchmark/configs/qwen3-next-80b-a3b-ep8-l8
+    .json``: 8 of 48 layers — two whole periods —, 64 of 512 experts held,
+    an eighth of the vocabulary, bf16), its parameters, its k/v pool and its
+    state pool as shapes on the described chip."""
+    from byteps_tpu.models.qwen3_next import Qwen3NextConfig, qwen3_next_init
+    from byteps_tpu.serve.families import serve_family
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = Qwen3NextConfig(max_seq=32768, n_layers=8, experts_held=64,
+                          vocab_size=19072)
+    shapes = jax.eval_shape(
+        lambda: qwen3_next_init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), shapes)
+    family = serve_family(cfg)
+    pool = jax.eval_shape(lambda: family.layout(
+        shapes, cfg, block_size=_QN_BS, pool_blocks=_QN_BLOCKS,
+        max_batch=_QN_BATCH, prefill_chunk=_QN_CHUNK, quant=False).state)
+    pool = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), pool)
+    return cfg, family, params, pool, on_chip
+
+
+def _gdn_decode_case():
+    from byteps_tpu.ops.gated_delta import gdn_decode
+
+    def f(q, k, v, g, beta, pool, slots):
+        return gdn_decode(q, k, v, g, beta, pool, 5, slots)
+    R, H, D = _QN_BATCH, 32, 128
+    row = _sds((R, H, D), F32)
+    return f, [row, row, row, _sds((R, H), F32), _sds((R, H), F32),
+               _sds((6, 161, H, D, D), F32), _sds((R,), I32)]
+
+
+def _qn_paged_decode(q, k, v, tables, lengths):
+    return paged_attention_decode(q, k, v, tables, lengths, 1)
+
+
+_QN_KERNELS = [
+    # the decode step's state update in place in the slot pool
+    ("gdn_decode_r128_h32_d128", *_gdn_decode_case(), 1),
+    # head size 256 with 8 query heads a k/v head: the packed decode step's
+    # attention over the 2-layer pool, and a 2,048-token chunk's forward
+    # against a 16k-key view
+    ("paged_attn_decode_qwen3next_d256_w256", _qn_paged_decode,
+     [_sds((_QN_BATCH, 16, 256), BF16)]
+     + [_sds((2, _QN_BLOCKS, _QN_BS, 512), BF16)] * 2
+     + [_sds((_QN_BATCH, 256), I32), _sds((_QN_BATCH,), I32)], 1),
+    ("flash_fwd_qwen3next_d256_gqa16_2", _flash_fwd(16, 2),
+     _qkv(16, 2048, 16384, 256, kv_bh=2), 1),
+]
+
+
+@pytest.mark.parametrize("case", _QN_KERNELS, ids=_case_id)
+def test_qwen3next_kernel_compiles_for_v5e(topo, as_on_tpu, case):
+    test_kernel_compiles_for_v5e(topo, as_on_tpu, case)
+
+
+def test_qwen3next_chunked_rule_compiles_for_v5e(topo, as_on_tpu):
+    """A prefill chunk's chunked gated delta rule (plain XLA, f32 at the
+    highest precision) compiles for the chip with temporaries far under a
+    state pool."""
+    from byteps_tpu.ops.gated_delta import gdn_chunk_fwd
+
+    one = SingleDeviceSharding(topo.devices[0])
+    T, H, D = _QN_CHUNK, 32, 128
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one) for s in
+            [(T, H, D)] * 3 + [(T, H)] * 2 + [(H, D, D)]]
+    compiled = jax.jit(gdn_chunk_fwd).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.parametrize("program", ["chunk_c2048_w128", "decode_r128_w256",
+                                     "params"])
+def test_qwen3next_serve_program_fits_and_leaves_the_pools_in_place(
+        topo, as_on_tpu, program):
+    """The cut configuration counts 1,979,175,040 parameters (3.96 GB in
+    bf16); its 2,048-token chunk program and its 128-row decode step compile
+    for the described v5e with the k/v pool (2 layers x 8,193 blocks) and
+    the state pool (6 layers x 161 slots x 2 MB, f32) donated and updated in
+    place, and weights + pools + temporaries fit the chip's 16 GB. A decode
+    step: 6 state updates, 2 paged-attention calls, 24 grouped products."""
+    cfg, family, params, pool, on_chip = _qwen3next_on(topo)
+    weights, pages = _bytes(params), _bytes(pool)
+    # bf16 but A_log and dt_bias (32 + 32 f32 a DeltaNet layer)
+    assert sum(a.size for a in jax.tree.leaves(params)) == 1979175040
+    assert weights == 2 * 1979175040 + 6 * 64 * 2, weights
+    assert pool.k.shape == (2, _QN_BLOCKS, 128, 512)
+    assert pool.s.shape == (6, 161, 32, 128, 128) and pool.s.dtype == F32
+    assert pool.conv.shape == (6, 161, 3 * 8192)
+    if program == "params":
+        return
+    if program.startswith("chunk"):
+        W = 128
+        compiled = family.prefill_fn(cfg, _QN_BS, _QN_CHUNK, None, False)\
+            .lower(params, pool, on_chip((1, _QN_CHUNK), I32),
+                   on_chip((), I32), on_chip((1 + W,), I32)).compile()
+    else:
+        W = 256
+        assert family.decode_reads_pool_in_place(
+            cfg, type("C", (), dict(block_size=_QN_BS, kv_heads=2,
+                                    quant=False)))
+        compiled = family.decode_fn(cfg, _QN_BS, None, None).lower(
+            params, pool, on_chip((_QN_BATCH,), I32),
+            on_chip((_QN_BATCH,), I32),
+            on_chip((_QN_BATCH, 1 + W), I32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pages - 64            # donated, in place
+    # (narrow leaves — in_ba, shared_gate — are padded to the lanes)
+    assert mem.argument_size_in_bytes <= weights + pages + (8 << 20)
+    assert weights + pages + mem.temp_size_in_bytes < 15.5e9, \
+        mem.temp_size_in_bytes
+    hlo = compiled.as_text()
+    for name in ("k", "v", "s", "conv"):
+        a = getattr(pool, name)
+        shape = "%s[%s]" % ("f32" if a.dtype == F32 else "bf16",
+                            ",".join(map(str, a.shape)))
+        made = [op for op, aliased in _ops_with_result(hlo, shape)
+                if op not in ("parameter", "tuple", "get-tuple-element",
+                              "bitcast") and not aliased]
+        assert not made, (name, made)
+    n = _n_pallas(compiled)
+    if program.startswith("chunk"):
+        assert n == 2 + 3 * 7, n
+        for name in ("flash_fwd", "moe_gmm_fwd"):
+            assert name in hlo, name
+    else:
+        assert n == 6 + 2 + 3 * 8, n
+        for name in ("gdn_decode", "paged_attn_decode"):
+            assert name in hlo, name
