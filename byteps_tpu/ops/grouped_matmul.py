@@ -12,7 +12,11 @@ contract that makes the kernels plain:
   a row tile belongs to exactly one group and no kernel masks rows. The
   caller pads each group with zero rows (``parallel/moe.py`` lays its
   sorted token-expert pairs out so); zero rows give zero products and add
-  nothing to a weight gradient.
+  nothing to a weight gradient. **The caller chooses ``tm``**, since the
+  layout is its own: ``parallel/moe.py::dropless_row_tile`` derives it
+  from the pairs its program holds, between :func:`min_row_tile` (a
+  decode step's few rows an expert) and ``ROW_TILE`` (a chunk, a training
+  step), and the kernels take the tile they are given.
 * **Only tiles that hold rows are visited.** ``M`` is the caller's static
   worst case; ``sum(group_sizes) / tm`` tiles are live, and that number is
   the (dynamic) extent of the kernels' tile axis. Rows past the last
@@ -52,11 +56,23 @@ from byteps_tpu.ops.backend import interpret as _interpret
 from byteps_tpu.ops.backend import use_pallas
 from byteps_tpu.ops.flash_attention import _out_struct, _unify_vma
 
-__all__ = ["grouped_matmul", "grouped_matmul_jnp", "ROW_TILE"]
+__all__ = ["grouped_matmul", "grouped_matmul_jnp", "ROW_TILE",
+           "min_row_tile"]
 
-# Rows of a tile: two MXU passes, 4 tiles of padding/MB. The one block the
-# kernels do not choose: the caller lays its groups out by it.
+# Rows of a tile. The one block the kernels do not choose: the caller lays
+# its groups out by it, so the caller picks it — ``parallel/moe.py::
+# dropless_row_tile`` from the pairs its program holds, between the two
+# numbers below. ``ROW_TILE`` (two MXU passes, 4 tiles of padding/MB) is the
+# cap and the default of :func:`grouped_matmul`.
 ROW_TILE = 256
+
+
+def min_row_tile(itemsize: int) -> int:
+    """The smallest row block Mosaic tiles for operands of ``itemsize``
+    bytes: one packed tile of sublanes (8 rows of 32 bits — 8 for f32, 16
+    for bf16)."""
+    return 32 // itemsize
+
 
 # What one call's pipeline may hold of Mosaic's 16 MiB of scoped VMEM on a
 # v5e (its default): the rest is the compiler's own (the f32 product before

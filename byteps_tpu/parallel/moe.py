@@ -34,6 +34,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from byteps_tpu.common.metrics import get_registry
+
 
 def topk_dispatch(gate_logits: jnp.ndarray, capacity: int, k: int = 1):
     """Top-k routing tensors from ``(T, E)`` gate logits (k=1: Switch;
@@ -336,6 +338,24 @@ def _combine_bwd(res, dout):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def dropless_row_tile(pairs: int, held: int, itemsize: int) -> int:
+    """The row tile :func:`moe_ffn_dropless` lays its groups out by, from
+    the program's static shapes alone: the rows a held expert would get if
+    all ``pairs`` landed here evenly, ``ceil(pairs / held)``, rounded up to
+    a power of two, no smaller than the least row block Mosaic tiles for
+    operands of ``itemsize`` bytes and no larger than ``ROW_TILE``. The
+    buffer pads every group to a tile, so a decode step's few pairs an
+    expert ride in 16-row tiles (Mellum2: 192 pairs over 64 experts in
+    1,216 rows, where 256-row tiles took 16,640), and a chunk or a training
+    step, 256 rows an expert or more, keeps ``ROW_TILE`` and the program it
+    had."""
+    from byteps_tpu.ops.grouped_matmul import ROW_TILE, min_row_tile
+
+    even = -(-pairs // held)
+    return min(max(1 << (even - 1).bit_length(), min_row_tile(itemsize)),
+               ROW_TILE)
+
+
 def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
                      first_expert: int = 0, row_tile: Optional[int] = None,
                      route: str = "sigmoid_bias"):
@@ -357,9 +377,12 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     grouped products; the rows are then gathered back and added with
     their weights. The row buffer is sized for the worst case, all ``T·k``
     pairs held here, so its shape is static and nothing can overflow; the
-    kernels visit only the tiles that hold pairs. What the experts held
-    elsewhere would add is left out (under expert parallelism their
-    owners compute it; this layer runs no exchange).
+    kernels visit only the tiles that hold pairs. The tile follows from
+    the shapes (:func:`dropless_row_tile`; ``row_tile`` is the tests'
+    override), and the trace counts it: ``moe.row_tile.<tm>`` once a trace
+    of the layer, the gauge ``moe.row_buffer_rows`` the buffer's rows.
+    What the experts held elsewhere would add is left out (under expert
+    parallelism their owners compute it; this layer runs no exchange).
 
     Returns ``(y, stats, load)``: ``y`` shaped like ``x``, ``stats`` f32
     ``(3,)`` = pairs computed here (the rows of the buffer that hold a
@@ -367,15 +390,16 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     unless a held pair lost its row), heaviest held expert over the mean
     held expert; ``load`` f32 ``(E,)`` the tokens each of ALL routed
     experts was picked by (what :func:`noaux_bias_step` balances)."""
-    from byteps_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
+    from byteps_tpu.ops.grouped_matmul import grouped_matmul
 
-    tm = ROW_TILE if row_tile is None else row_tile
     held = params["w1"].shape[0]
     lead, d = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, d)
     T = xt.shape[0]
     idx, weight = ROUTES[route](xt, params, top_k, scale)
     P_ = T * top_k
+    tm = (dropless_row_tile(P_, held, x.dtype.itemsize)
+          if row_tile is None else row_tile)
     local = idx.reshape(P_) - first_expert
     is_held = (local >= 0) & (local < held)
     local = jnp.where(is_held, local, held)          # the rest sort last
@@ -386,6 +410,10 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     padded = -(-counts // tm) * tm
     starts = jnp.cumsum(padded) - padded
     n_rows = (-(-P_ // tm) + held) * tm            # static worst case
+    # the tile is chosen while tracing, so it is counted there
+    reg = get_registry()
+    reg.counter(f"moe.row_tile.{tm}").inc()
+    reg.gauge("moe.row_buffer_rows").set(n_rows)
     pair_row = jnp.where(is_held, jnp.take(starts, local, mode="clip") + rank,
                          n_rows).reshape(T, top_k)
     # row -> pair, by gathers alone: the stable sort's position j of group

@@ -119,12 +119,16 @@ def test_bias_changes_the_picks_and_weights_still_come_from_scores():
     assert bool(jnp.all(jnp.any(idx == 3, -1) & jnp.any(idx == 11, -1)))
 
 
+def _towering(x, p):
+    # the router's column 5 towers over the rest: every token's first pick
+    # is expert 5
+    return jnp.abs(x), dict(p, wg=p["wg"].at[:, 5].set(3.0))
+
+
 def test_every_token_to_one_held_expert_drops_nothing():
-    # the router's column 5 towers over the rest: all T tokens pick expert
-    # 5 first, T pairs in one group — a capacity would overflow
-    x, p = _layer(T=40)
-    x = jnp.abs(x)
-    p = dict(p, wg=p["wg"].at[:, 5].set(3.0))
+    # all T tokens pick expert 5 first, T pairs in one group — a capacity
+    # would overflow
+    x, p = _towering(*_layer(T=40))
     y, stats, load = moe_ffn_dropless(x, p, 4, 2.5, row_tile=8)
     want = ref.moe_layer(x, p, top_k=4, scale=2.5)
     np.testing.assert_allclose(y, want, atol=2e-5, rtol=2e-5)
@@ -360,6 +364,110 @@ def test_dropless_layer_through_the_kernels(pallas):
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(a, b, atol=2e-4 * float(
             jnp.abs(b).max()) + 1e-7)
+
+
+# -- the row tile follows from the pairs a program holds ----------------------
+# (program, tokens, top-k, experts held, operand bytes, tile): the four cells'
+# decode steps, 2,048-token chunks and 1,024-token tail chunks, JoyAI's
+# training step, and a decode step in f32
+_TILE_CASES = [
+    ("mellum2_decode", 24, 8, 64, 2, 16),
+    ("qwen3next_decode", 128, 10, 64, 2, 32),
+    ("dots3_decode", 16, 8, 32, 2, 16),
+    ("mellum2_chunk", 2048, 8, 64, 2, 256),
+    ("qwen3next_chunk", 2048, 10, 64, 2, 256),
+    ("dots3_chunk", 2048, 8, 32, 2, 256),
+    ("mellum2_tail", 1024, 8, 64, 2, 128),
+    ("qwen3next_tail", 1024, 10, 64, 2, 256),
+    ("dots3_tail", 1024, 8, 32, 2, 256),
+    ("joyai_step", 4 * 4096, 8, 16, 2, 256),
+    ("decode_f32", 24, 8, 64, 4, 8),
+    ("one_row_f32", 1, 2, 64, 4, 8),
+]
+
+
+@pytest.mark.parametrize("T,k,held,itemsize,want",
+                         [c[1:] for c in _TILE_CASES],
+                         ids=[c[0] for c in _TILE_CASES])
+def test_row_tile_from_the_program_shapes(T, k, held, itemsize, want):
+    """A pure function of what the layer sees while tracing: an expert's
+    even share of the pairs, up to a power of two, between Mosaic's least
+    row block for the operand type and ``ROW_TILE`` — so 256, the program
+    as it was, wherever that share is 256 rows or more."""
+    from byteps_tpu.ops.grouped_matmul import ROW_TILE, min_row_tile
+    from byteps_tpu.parallel.moe import dropless_row_tile
+
+    tm = dropless_row_tile(T * k, held, itemsize)
+    assert tm == want
+    assert tm & (tm - 1) == 0 and min_row_tile(itemsize) <= tm <= ROW_TILE
+    even = -(-T * k // held)
+    assert tm == ROW_TILE if even >= ROW_TILE else even <= tm
+
+
+@pytest.mark.parametrize("backend", ["pallas", "jnp"])
+@pytest.mark.parametrize("top_k,tower,tile",
+                         [(4, False, 16), (4, True, 16), (1, True, 8)],
+                         ids=["spread", "first_pick_on_one", "all_on_one"])
+def test_dropless_layer_at_the_derived_tile(monkeypatch, backend, top_k,
+                                            tower, tile):
+    """No ``row_tile=``: the layer derives its tile (f32, 40 tokens over 16
+    experts: 16 rows at top-4, 8 at top-1) and gives what 256-row tiles and
+    the reference give, forward and gradients, through the interpreted
+    kernels and through the twin — also when every pair lands on ONE held
+    expert (top-1, a towering router column: 40 rows, five tiles, where the
+    even share is three rows), and the buffer still holds them all: nothing
+    lost a row."""
+    from byteps_tpu.parallel.moe import dropless_row_tile
+
+    monkeypatch.setenv("BYTEPS_KERNEL_BACKEND", backend)
+    x, p = _layer(T=40, bias_std=0.01)
+    if tower:
+        x, p = _towering(x, p)
+    assert dropless_row_tile(40 * top_k, 16, 4) == tile
+
+    def run(row_tile):
+        def f(x, p):
+            y, stats, _ = moe_ffn_dropless(x, p, top_k, 2.5,
+                                           row_tile=row_tile)
+            return (y * y).sum(), (y, stats)
+        return jax.value_and_grad(f, (0, 1), has_aux=True)(x, p)
+
+    ((_, (y1, st1)), g1), ((_, (y2, st2)), g2) = run(None), run(256)
+    want = ref.moe_layer(x, p, top_k=top_k, scale=2.5)
+    np.testing.assert_allclose(y1, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(y1, y2, atol=2e-6, rtol=2e-6)
+    np.testing.assert_array_equal(st1, st2)
+    assert float(st1[0]) == float(st1[1]) == 40 * top_k
+    if tower and top_k == 1:
+        assert float(st1[2]) == 16.0             # one expert has them all
+    g_ref = jax.grad(lambda x, p: (ref.moe_layer(
+        x, p, top_k=top_k, scale=2.5) ** 2).sum(), (0, 1))(x, p)
+    for a, b, c in zip(*map(jax.tree.leaves, (g1, g2, g_ref))):
+        tol = 2e-4 * float(jnp.abs(c).max()) + 1e-7
+        np.testing.assert_allclose(a, c, atol=tol)
+        np.testing.assert_allclose(a, b, atol=tol)
+
+
+def test_a_trace_counts_its_row_tile_once():
+    """``moe.row_tile.<tm>`` is incremented where the tile is chosen, while
+    tracing: once a trace of the layer, not once a call; the gauge holds
+    the last traced buffer's rows."""
+    from byteps_tpu.common.metrics import get_registry
+
+    x, p = _layer(T=40)
+    reg = get_registry()
+    before = {tm: reg.counter(f"moe.row_tile.{tm}").value()
+              for tm in (16, 256)}
+    f = jax.jit(lambda x, p: moe_ffn_dropless(x, p, 4, 2.5)[0])
+    f(x, p)
+    f(x + 1.0, p)                                # the compiled program again
+    assert reg.counter("moe.row_tile.16").value() == before[16] + 1
+    assert reg.counter("moe.row_tile.256").value() == before[256]
+    assert reg.gauge("moe.row_buffer_rows").value() == (10 + 16) * 16
+    jax.jit(lambda x, p: moe_ffn_dropless(x, p, 4, 2.5, row_tile=256)[0])(
+        x, p)
+    assert reg.counter("moe.row_tile.256").value() == before[256] + 1
+    assert reg.gauge("moe.row_buffer_rows").value() == (1 + 16) * 256
 
 
 # -- the normal train step --------------------------------------------------

@@ -137,14 +137,16 @@ def _gmm_fwd_bwd(lhs, w_up, w_down, sizes):
     return jax.grad(loss, argnums=(0, 1, 2))(lhs, w_up, w_down)
 
 
-def _gmm_up_down(lhs, w_up, w_down, sizes):
+def _gmm_up_down(lhs, w_up, w_down, sizes, tm=256):
     # a serve program's expert layer: forward alone, up then down
-    return grouped_matmul(grouped_matmul(lhs, w_up, sizes), w_down, sizes)
+    return grouped_matmul(grouped_matmul(lhs, w_up, sizes, tm), w_down,
+                          sizes, tm)
 
 
 def _gmm_shapes(rows, held, d, ff):
-    """A cell's worst-case row buffer (pairs / 256 + a tile an expert held)
-    and its expert stacks up and down, bf16."""
+    """A cell's worst-case row buffer (pairs / tile + a tile an expert held,
+    the tile ``parallel/moe.py::dropless_row_tile`` gives the program) and
+    its expert stacks up and down, bf16."""
     return [_sds((rows, d), BF16), _sds((held, d, ff), BF16),
             _sds((held, ff, d), BF16), _sds((held,), I32)]
 
@@ -186,6 +188,19 @@ ONE_CHIP = [
     # the contraction axis split
     ("moe_gmm_fwd_mellum2_decode", _gmm_up_down,
      _gmm_shapes(65 * 256, 64, 2304, 896), 2),
+    # the decode steps at the tiles their pairs give (an expert's even share
+    # rounded up to a power of two, 16 rows at the least in bf16): Mellum2's
+    # 192 pairs over 64 experts in 12 + 64 tiles of 16, Qwen3-Next's 1,280
+    # over 64 held (2048 x 512) in 40 + 64 of 32, dots3's 128 over 32 held
+    # in 8 + 32 of 16; the 1,024-token tail chunk of Mellum2 at 128
+    ("moe_gmm_fwd_mellum2_decode_tm16", functools.partial(
+        _gmm_up_down, tm=16), _gmm_shapes(76 * 16, 64, 2304, 896), 2),
+    ("moe_gmm_fwd_qwen3next_decode_tm32", functools.partial(
+        _gmm_up_down, tm=32), _gmm_shapes(104 * 32, 64, 2048, 512), 2),
+    ("moe_gmm_fwd_dots3_decode_tm16", functools.partial(
+        _gmm_up_down, tm=16), _gmm_shapes(40 * 16, 32, 5120, 1536), 2),
+    ("moe_gmm_fwd_mellum2_tail_tm128", functools.partial(
+        _gmm_up_down, tm=128), _gmm_shapes(128 * 128, 64, 2304, 896), 2),
     ("moe_gmm_fwd_mellum2_chunk", _gmm_up_down,
      _gmm_shapes(128 * 256, 64, 2304, 896), 2),
     ("moe_gmm_fwd_dots3_chunk", _gmm_up_down,
