@@ -56,3 +56,23 @@ def test_benchmark_json_lists_what_the_rule_picks():
         assert listed[name].get("workloads", cells) == ws
     assert sum(w["chips"] == 4 for w in bm["workloads"]) <= max(
         1, len(cells) // 4)
+
+
+def test_the_list_fits_and_device_time_is_cut_along_steps_of_one_kind():
+    """The contract's cap, and the rule for a serve cell whose iterations
+    are of more than one kind: a device time is cut along the device steps
+    of ONE kind (``trace_ms_in_device_steps``), never divided by the
+    ``serve.step`` spans of the traced slice, whose mix of kinds it would
+    follow. A cell is under the rule once one of its metrics is cut so
+    (the rooflines carry no step count)."""
+    bm = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    assert len(bm["per_layer"]) <= 128
+    cut = set()
+    for w in bm["workloads"]:
+        t = harness.load_json(harness.HERE, "traffic", w["traffic"] + ".json")
+        ms = harness.layer_metrics_for(t["driver"], t["reports"], w["chips"])
+        if any(m["reader"] == "trace_ms_in_device_steps" for m in ms):
+            cut.add(t["driver"])
+            assert [m["name"] for m in ms if m.get("params", {}).get(
+                "step_span") == "serve.step"] == []
+    assert {"serve_dots3", "serve_mellum2", "serve_qwen3next"} <= cut

@@ -179,8 +179,8 @@ def test_a_traced_run_of_an_idle_device_still_prints_its_line(monkeypatch,
     assert line["device"]["busy_s"] == 0
     assert line["device"]["window_s"] == pytest.approx(4000e-9)
     assert line["breakdown"]["device_ops"] == []
-    assert line["metrics"]["sat_queued_min"] == {"value": 130.0,
-                                                 "unit": "seqs"}
+    assert line["metrics"]["sat_requests_per_s"] == {"value": 0.06,
+                                                     "unit": "req/s"}
     assert line["metrics"]["compiles_in_window"]["value"] == 0
-    assert "sat_copy_share_pct" not in line["metrics"]
+    assert "sat_paged_attn_ms_per_iteration" not in line["metrics"]
     json.dumps(line)

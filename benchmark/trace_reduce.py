@@ -4,9 +4,9 @@
 ``jax.profiler.ProfileData`` (nothing but JAX) into a plain dict; everything
 after that is pure Python on that dict, which is also the form the small
 recorded trace under ``benchmark/tests/`` is kept in. Category names are
-those of XLA's ``hlo_category`` as ``byteps_tpu/common/xprof_analysis.py``
-reads them ("convolution fusion" is MXU work, "custom-call" a Pallas
-kernel, "data formatting" a copy ...). On the installed JAX a device event
+those of XLA's ``hlo_category`` statistic ("convolution fusion" is MXU work,
+"custom-call" a Pallas kernel, "data formatting" a copy ...), kept here and
+nowhere in the program since PR 41. On the installed JAX a device event
 carries its whole HLO instruction as its name and no category, so
 ``categorise`` works the category out from the instruction's opcode and
 fusion kind, and the event keeps the instruction's short name.
