@@ -129,20 +129,27 @@ def _cache_read(cache, dtype):
     return cache
 
 
-def _cached_attention(q, k_cache, v_cache, q_pos0):
+def _cached_attention(q, k_cache, v_cache, q_pos0, block=None):
     """q: (B, T, H, D) new queries at positions q_pos0..q_pos0+T-1;
     k/v_cache: (B, S_max, H, D) with the new keys already written.
     Causal-masks against global positions, so entries past the fill level
     (zeros) are masked out by construction. Long prefills (tileable T)
     ride the flash kernel — same global-offset masking; single-token
-    decode (T=1) stays on the fused-GEMV jnp path automatically."""
-    from byteps_tpu.ops.flash_attention import attention_lse
+    decode (T=1) stays on the fused-GEMV jnp path automatically.
+    ``block``: the mask is block-causal instead (a query sees all of its
+    own block of that many positions; the T new queries end on a block's
+    boundary, so nothing past the fill level is seen either)."""
+    from byteps_tpu.ops.flash_attention import (
+        attention_lse, flash_attention_block_causal)
 
+    if block is not None:
+        return flash_attention_block_causal(q, k_cache, v_cache, q_pos0, 0,
+                                            block)
     o, _ = attention_lse(q, k_cache, v_cache, q_pos0, 0, causal=True)
     return o
 
 
-def cache_attend(cache_k, cache_v, pos0):
+def cache_attend(cache_k, cache_v, pos0, block=None):
     """The static cache's ``attend`` for :func:`attn_half`: append the T
     new keys and values (already rotated: cached keys are stored
     post-RoPE, the standard decode convention) to this layer's cache at
@@ -150,7 +157,8 @@ def cache_attend(cache_k, cache_v, pos0):
     arrays or :class:`_QuantSlot`s; the carry is the updated pair.
     Config-agnostic on purpose: the GPT/MoE block step, the serve tier's
     prefill chunk AND the T5 decoder (models/t5.py t5_decode_cached) share
-    this one cache-append path."""
+    this one cache-append path. ``block``: as :func:`_cached_attention`
+    takes it (a prefill chunk under a block-causal mask)."""
     from byteps_tpu.ops.backend import note_fallback
     from byteps_tpu.ops.flash_decode import (
         decode_supported, flash_decode, use_pallas)
@@ -166,7 +174,7 @@ def cache_attend(cache_k, cache_v, pos0):
         # per block in VMEM with _cache_read's rounding), dead blocks
         # skipped past the fill level.
         S_max = (ck.q if isinstance(ck, _QuantSlot) else ck).shape[1]
-        flash = T == 1 and use_pallas()
+        flash = T == 1 and block is None and use_pallas()
         if flash and not decode_supported(S_max, head_dim):
             note_fallback("flash_decode", (S_max, head_dim),
                           "cache length must tile into 8..256 key blocks "
@@ -174,7 +182,7 @@ def cache_attend(cache_k, cache_v, pos0):
             flash = False
         if not flash:
             o = _cached_attention(q, _cache_read(ck, q.dtype),
-                                  _cache_read(cv, q.dtype), pos0)
+                                  _cache_read(cv, q.dtype), pos0, block)
         elif isinstance(ck, _QuantSlot):
             o = flash_decode(q, ck.q, cv.q, pos0,
                              k_scale=ck.scale, v_scale=cv.scale)
@@ -187,18 +195,19 @@ def cache_attend(cache_k, cache_v, pos0):
 
 def _block_step(x, p, cache_k, cache_v, pos0, cfg, tp_axis, ep_axis,
                 norm_fn=_layernorm, norm_eps: float = 1e-5, rope=None,
-                ffn=None, attn=None):
+                ffn=None, attn=None, block=None):
     """One transformer block (dense-MLP or MoE, by param structure) over
     T new tokens with cache append: the shared halves of ``models/gpt.py``
     around :func:`cache_attend`. ``rope`` (default: the config's one base)
     and ``ffn`` (``h -> (out, aux)``; default: by param structure) are a
     caller's whose layers differ in either, ``attn`` one whose first half is
-    not :func:`attn_half` (same signature and ``attend`` contract). Returns ``(x, cache_k, cache_v)``,
+    not :func:`attn_half` (same signature and ``attend`` contract), ``block``
+    one whose mask is block-causal. Returns ``(x, cache_k, cache_v)``,
     and ``ffn``'s ``aux`` after them where one was given."""
     kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
     x, (cache_k, cache_v) = (attn_half if attn is None else attn)(
         x, p, cfg.head_dim, lambda: pos0 + jnp.arange(x.shape[1]),
-        cache_attend(cache_k, cache_v, pos0), tp_axis,
+        cache_attend(cache_k, cache_v, pos0, block), tp_axis,
         resolve_rope(cfg) if rope is None else rope, **kw)
     if ffn is not None:
         x, aux = ffn_half(x, p, tp_axis, ffn, **kw)

@@ -219,9 +219,15 @@ def _read_offsets(qoff_ref, koff_ref):
             koff_ref[0, 0].astype(jnp.int32))
 
 
-def _mask_tile(s, q_off, k_off, q_start, k_start, bq, bk, window=None):
+def _mask_tile(s, q_off, k_off, q_start, k_start, bq, bk, window=None,
+               block=None):
     rows = q_off + q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     cols = k_off + k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    if block is not None:
+        # block-causal: a query sees all of its own block of ``block``
+        # positions (a power of two, counted from position 0), so every key
+        # up to the block's last
+        return jnp.where((rows | (block - 1)) >= cols, s, _NEG)
     if window is None:
         return jnp.where(rows >= cols, s, _NEG)
     # a window beside the causal rule: the query's own position and the
@@ -235,7 +241,7 @@ def _mask_tile(s, q_off, k_off, q_start, k_start, bq, bk, window=None):
 # --------------------------------------------------------------------------
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, bq, bk, nk,
-                window=None, mask_ref=None):
+                window=None, mask_ref=None, block=None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -265,7 +271,8 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             # every head; it carries the causal rule
             s = jnp.where(mask_ref[...].astype(jnp.float32) > 0.5, s, _NEG)
         elif masked:
-            s = _mask_tile(s, q_off, k_off, q_start, k_start, bq, bk, window)
+            s = _mask_tile(s, q_off, k_off, q_start, k_start, bq, bk, window,
+                           block)
         m_prev = m_scr[:]                                    # (bq, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -290,6 +297,11 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # 1/nk fraction, so most tiles take the cheap path
         live = q_off + q_start + bq - 1 >= k_off + k_start
         interior = q_off + q_start >= k_off + k_start + bk - 1
+        if block is not None:
+            # the tile's last query sees to the end of its block; a tile
+            # every pair of which is causal is interior as it was
+            live = ((q_off + q_start + bq - 1) | (block - 1)) \
+                >= k_off + k_start
         if window is not None:
             # tiles wholly behind the window are skipped like those wholly
             # after the diagonal; an interior tile lies inside it for every
@@ -329,10 +341,10 @@ def _kv_index(heads: int, kv_heads: int):
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret",
                                              "heads", "kv_heads", "window",
-                                             "name"))
+                                             "name", "block"))
 def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
          heads: int, kv_heads: int, window: Optional[int] = None,
-         mask=None, name: str = "flash_fwd"):
+         mask=None, name: str = "flash_fwd", block: Optional[int] = None):
     """q3: (B·H, S, D), k3: (B·Hkv, S, D), v3: (B·Hkv, S, Dv) →
     (o (B·H, Sq, Dv), lse (B·H, Sq, 1) f32). The softmax scale is
     ``D ** -0.5``, the q/k width."""
@@ -351,6 +363,8 @@ def _fwd(q3, k3, v3, qoff, koff, causal: bool, interpret: bool,
                              bq=bq, bk=bk, nk=nk)
     if window is not None:         # the causal-only trace stays as it was
         kern = functools.partial(kern, window=window)
+    if block is not None:
+        kern = functools.partial(kern, block=block)
     extra_specs, extra = [], ()
     if mask is not None:
         # one (Sq, Sk) int8 plane of allowed pairs for every head: the
@@ -1007,6 +1021,54 @@ def flash_attention_window(q, k, v, q_offset, k_offset, window: int):
         _note_fallback("flash_attention_window", q.shape + k.shape,
                        _UNSUPPORTED)
     return attention_window_jnp(q, k, v, q_offset, k_offset, window)
+
+
+def attention_block_causal_jnp(q, k, v, q_offset, k_offset, block: int):
+    """jnp twin of :func:`flash_attention_block_causal`: query ``i`` (global
+    position ``q_offset + i``) sees the keys at global positions ``p`` with
+    ``p // block <= its own // block``; ``(B, S, H, D)`` layout, f32
+    softmax."""
+    B, Sq, H, D = q.shape
+    Hkv = k.shape[2]
+    qg = q.reshape(B, Sq, Hkv, H // Hkv, D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                   preferred_element_type=jnp.float32) * D ** -0.5
+    rows = (q_offset + jnp.arange(Sq)[:, None]) // block
+    cols = (k_offset + jnp.arange(k.shape[1])[None, :]) // block
+    p = jax.nn.softmax(jnp.where((rows >= cols)[None, None, None], s, _NEG),
+                       axis=-1)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
+
+
+def flash_attention_block_causal(q, k, v, q_offset, k_offset, block: int):
+    """Block-causal attention, forward only — the prefill of a model that
+    generates by diffusion over blocks: a query sees every key of an earlier
+    block of ``block`` positions (a power of two; blocks counted from global
+    position 0) and all of its own. The flash forward kernel with ``block`` a
+    trace-time constant of its mask and of its tile skip, as the window is.
+    Offsets are the global positions of element 0 of q and of k (traced
+    scalars). GQA by index. Falls back to
+    :func:`attention_block_causal_jnp` off the Pallas backend and for shapes
+    the kernel does not tile."""
+    B, Sq, H, D = q.shape
+    Hkv = k.shape[2]
+    if H % Hkv != 0 or v.shape[2] != Hkv:
+        raise ValueError(f"q heads ({H}) not a multiple of the k/v heads "
+                         f"({Hkv}, {v.shape[2]})")
+    if block < 1 or block & (block - 1):
+        raise ValueError(f"block must be a power of two; got {block}")
+    if use_pallas():
+        if supported(Sq, k.shape[1], D):
+            qoff = jnp.asarray(q_offset, jnp.float32).reshape(1, 1)
+            koff = jnp.asarray(k_offset, jnp.float32).reshape(1, 1)
+            o3, _ = _fwd(_to3(q), _to3(k), _to3(v), qoff, koff, True,
+                         _interpret(), H, Hkv, block=int(block))
+            return _from3(o3, B, H)
+        _note_fallback("flash_attention_block_causal", q.shape + k.shape,
+                       _UNSUPPORTED)
+    return attention_block_causal_jnp(q, k, v, q_offset, k_offset, block)
 
 
 def attention_masked_jnp(q, k, v, mask):

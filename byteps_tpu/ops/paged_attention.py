@@ -100,8 +100,18 @@ def unsupported_reason(block_size: int, kv_heads: int, head_dim: int,
     return None
 
 
+class _Shifted:
+    """A scalar-prefetch ref read ``base`` elements further on."""
+
+    def __init__(self, ref, base):
+        self._ref, self._base = ref, base
+
+    def __getitem__(self, i):
+        return self._ref[self._base + i]
+
+
 def _kernel(len_ref, *refs, scale: float, R: int, W: int, bs: int, ppb: int,
-            windowed: bool = False):
+            windowed: bool = False, groups: int = 1):
     # a windowed trace prefetches one scalar array more: each row's first
     # live key. Without it nothing below differs from the kernel as it was
     first_ref = refs[0] if windowed else None
@@ -110,6 +120,16 @@ def _kernel(len_ref, *refs, scale: float, R: int, W: int, bs: int, ppb: int,
     H, HD = acc_scr.shape
     T = bs * ppb
     layer = layer_ref[0]
+    if groups > 1:
+        # the batch in ``groups`` grid steps of ``R`` rows each (a block of
+        # queries a row makes q and o ``groups`` times what VMEM held): the
+        # scalars are read at the row's place in the batch, q and o at its
+        # place in the step's block
+        base = pl.program_id(0) * R
+        len_ref, tab_ref = _Shifted(len_ref, base), _Shifted(tab_ref,
+                                                             base * W)
+        if windowed:
+            first_ref = _Shifted(first_ref, base)
 
     def first_chunk(r):
         # the chunk that holds the row's first live key: the ones before it
@@ -204,33 +224,38 @@ def _kernel(len_ref, *refs, scale: float, R: int, W: int, bs: int, ppb: int,
     jax.lax.fori_loop(0, R, row, jnp.int32(0))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "groups"))
 def _paged(qbd, k_pool, v_pool, tables, lengths, layer, scale: float,
-           interpret: bool, first=None):
+           interpret: bool, first=None, groups: int = 1):
     """qbd: (R, H, Hkv·D) block-diagonal queries; pools (L, NB, bs,
     Hkv·D) → (R, H, Hkv·D), row ``h``'s output in its kv head's block.
     ``first (R,)``: each row's first live key (None: key 0, and the trace
-    of the kernel as it was without a window)."""
+    of the kernel as it was without a window). ``groups``: grid steps the
+    ``R`` rows are walked in (it divides ``R``)."""
     R, H, HD = qbd.shape
     bs = k_pool.shape[2]
     W = tables.shape[1]
     ppb = _pages_per_chunk(W, bs, HD * k_pool.dtype.itemsize)
     windowed = first is not None
+    R = R // groups                    # rows a grid step
     kern = functools.partial(_kernel, scale=scale, R=R, W=W, bs=bs, ppb=ppb)
     if windowed:
         kern = functools.partial(kern, windowed=True)
+    if groups > 1:                     # one group: the trace as it was
+        kern = functools.partial(kern, groups=groups)
     operands = _unify_vma(
         lengths.astype(jnp.int32),
         *((first.astype(jnp.int32),) if windowed else ()),
         tables.reshape(-1).astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1), qbd, k_pool, v_pool)
-    whole = pl.BlockSpec((R, H, HD), lambda i, *_: (0, 0, 0))
+    whole = pl.BlockSpec((R, H, HD), (lambda i, *_: (0, 0, 0))
+                         if groups == 1 else (lambda i, *_: (i, 0, 0)))
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             # lengths, [first,] tables, layer
             num_scalar_prefetch=4 if windowed else 3,
-            grid=(1,),
+            grid=(groups,),
             in_specs=[whole,
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -243,7 +268,7 @@ def _paged(qbd, k_pool, v_pool, tables, lengths, layer, scale: float,
                 pltpu.VMEM((H, 1), jnp.float32),      # l
                 pltpu.VMEM((H, HD), jnp.float32),     # acc
             ]),
-        out_shape=_out_struct((R, H, HD), qbd.dtype, *operands),
+        out_shape=_out_struct(qbd.shape, qbd.dtype, *operands),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -255,7 +280,10 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer,
                            first=None):
     """One layer's packed decode attention over the paged pool.
 
-    ``q (R, H, D)``: one query per row; ``k_pool``/``v_pool`` the WHOLE
+    ``q (R, H, D)``: one query per row — or ``(R, nq, H, D)``, a block of
+    ``nq`` queries a row that all see the same keys ``[0, lengths[r])`` (a
+    block-diffusion step: the block's own keys are scattered before the
+    read), returned as ``(R, nq, H, D)``; ``k_pool``/``v_pool`` the WHOLE
     ``(L, NB, bs, Hkv·D)`` pools (a float dtype) and ``layer`` which of
     the ``L`` to read; ``tables (R, W)`` int32 physical blocks;
     ``lengths (R,)`` int32 live keys per row (``pos + 1``, at least 1,
@@ -270,7 +298,14 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer,
     a key below it scores ``_NEG`` with ``p`` forced to 0. ``None`` traces
     the kernel without any of that. Callers gate on
     :func:`unsupported_reason` / ``backend.use_pallas``."""
+    nq = 1
+    if q.ndim == 4:
+        # a block of queries a row: its nq x H query rows ride the layout
+        # the H heads have, query-major (row j is head j % H of query j // H)
+        nq = q.shape[1]
+        q = q.reshape(q.shape[0], -1, q.shape[-1])
     R, H, D = q.shape
+    H //= nq
     bs, HD = k_pool.shape[2:]
     Hkv = HD // D
     if HD != Hkv * D or H % Hkv != 0:
@@ -281,12 +316,19 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer,
         raise ValueError(f"paged_attention_decode: {why}; gate on "
                          "unsupported_reason()")
     # head h belongs to kv head h // G (group-major, as flash_decode)
-    own = (jnp.arange(H)[:, None] // (H // Hkv)
-           == jnp.arange(Hkv)[None, :])[None, :, :, None]   # (1, H, Hkv, 1)
+    head = jnp.arange(nq * H)[:, None]
+    if nq > 1:
+        head = head % H
+    own = (head // (H // Hkv)
+           == jnp.arange(Hkv)[None, :])[None, :, :, None]  # (1, nq·H, Hkv, 1)
     qbd = jnp.where(own, q[:, :, None, :], jnp.zeros((), q.dtype))
-    o = _paged(qbd.reshape(R, H, HD), k_pool, v_pool, tables, lengths,
-               layer, 1.0 / (D ** 0.5), _interpret(), first=first)
+    # q and o are whole in VMEM: nq times the rows go in as many grid steps
+    groups = nq if R % nq == 0 else 1
+    o = _paged(qbd.reshape(R, nq * H, HD), k_pool, v_pool, tables, lengths,
+               layer, 1.0 / (D ** 0.5), _interpret(), first=first,
+               groups=groups)
     # the diagonal blocks; a select, so an off-diagonal product (some
     # other head's V) never meets arithmetic
-    o = jnp.where(own, o.reshape(R, H, Hkv, D), jnp.zeros((), o.dtype))
-    return o.sum(axis=2)
+    o = jnp.where(own, o.reshape(R, nq * H, Hkv, D), jnp.zeros((), o.dtype))
+    o = o.sum(axis=2)
+    return o if nq == 1 else o.reshape(R, nq, H, D)

@@ -18,6 +18,10 @@ same for every family.
 * :class:`RecurrentKVFamily` (``Qwen3NextConfig``): k/v pages for the full-
   attention layers and one slot of a state pool a request for the recurrent
   (Gated-DeltaNet) layers, under the same two programs.
+* :class:`BlockDiffusionFamily` (``SDARConfig``): k/v pages of one kind
+  under the same two programs told the block length — a decode row carries a
+  block of positions, rewritten in place pass after pass, and a chunk's mask
+  is block-causal; the scheduler holds each run's block state.
 """
 
 from __future__ import annotations
@@ -131,6 +135,11 @@ class GPTFamily:
     def validate_request(self, req, cfg) -> None:
         pass
 
+    def block(self, cfg) -> Optional[int]:
+        """Positions a decode row carries where the model generates by
+        diffusion over blocks (None: a token a row a step)."""
+        return None
+
     def layout(self, params, cfg, *, block_size, pool_blocks, max_batch,
                prefill_chunk, quant) -> PoolLayout:
         from byteps_tpu.serve.paged_cache import kv_pool_state
@@ -212,6 +221,8 @@ class LatentFamily(_Refusing):
 
     def decode_reads_pool_in_place(self, cfg, cache) -> bool:
         return False
+
+    block = GPTFamily.block
 
     def late_stats(self):
         from byteps_tpu.serve.latent_step import LateStats
@@ -364,12 +375,109 @@ class RecurrentKVFamily(WindowedKVFamily):
         return StepStats(STATS_STATE)
 
 
+@functools.lru_cache(maxsize=16)
+def _block_plan(cfg):
+    """The ``StepPlan`` of an ``SDARConfig``: every layer global at one
+    rotation, the q/k-normed first half, the expert FFN, and the block."""
+    from byteps_tpu.models.sdar import expert_ffn, sdar_attn_half
+    from byteps_tpu.serve.paged_cache import LayerKind, StepPlan
+
+    return StepPlan(tuple(LayerKind(li, None, cfg.rope_base)
+                          for li in range(cfg.n_layers)),
+                    expert_ffn, sdar_attn_half, block=cfg.block_length)
+
+
+@functools.lru_cache(maxsize=16)
+def _block_pick(B: int, mask_id: int):
+    import jax
+    import jax.numpy as jnp
+
+    from byteps_tpu.models.sdar import fix_positions
+
+    def pick(logits, state, n_fix, pass_no):
+        return jnp.concatenate(fix_positions(
+            logits, state[:, :B], state[:, B:], n_fix, pass_no, mask_id), 1)
+
+    return jax.jit(pick)
+
+
+class BlockDiffusionFamily(WindowedKVFamily):
+    """SDAR: generation by diffusion over blocks. One kind of k/v page under
+    ``paged_cache.py``'s two programs and a :class:`~paged_cache.StepPlan`
+    that names the block: the decode program runs a pass over a block of
+    positions a row and rewrites the block's rows in place until the pass
+    that commits it, the chunk program attends block-causally and reads out
+    nothing (the first block starts masked). The block state, its phases and
+    the commit are the scheduler's (``Scheduler._issue_decode``)."""
+
+    name = "block-rewritten k/v"
+    plan = staticmethod(_block_plan)
+
+    #: what a page that is rewritten until its block is final cannot carry
+    #: yet, each refused at construction: ``feature -> the message's
+    #: subject``
+    REFUSED = {
+        "prefix_cache": "the prefix cache (a page is rewritten until its "
+                        "block is final, and a prompt's last block is "
+                        "finished by the sampler)",
+        "speculation": "speculative decoding (a block is denoised in place: "
+                       "there is no draft to verify)",
+        "adapter_pool": "LoRA adapter slabs",
+        "quant_cache": "the int8 pool",
+        "role": "role='prefill'|'decode' and migration over kv_wire (a "
+                "ticket carries no block state)",
+        "tp_axis": "tensor parallelism",
+        "temperature": "sampling at a temperature (the served schedule is "
+                       "the greedy low-confidence one)",
+        "denoise_steps": "a number of denoising passes that does not divide "
+                         "the block",
+    }
+
+    def validate_request(self, req, cfg) -> None:
+        super().validate_request(req, cfg)
+        if req.temperature != 0.0:
+            self._refuse("temperature", cfg)
+        steps = req.denoise_steps or cfg.denoise_steps
+        if steps < 1 or cfg.block_length % steps:
+            self._refuse("denoise_steps", cfg)
+
+    def block(self, cfg) -> int:
+        return cfg.block_length
+
+    def block_pick(self, cfg):
+        """The jitted pick of a pass: ``(logits (R, B, V), state (R, 2B),
+        n_fix (R,), pass_no (R,)) -> state`` — a row's block state is its B
+        tokens (the mask token where open) and beside them the pass each was
+        fixed at; ``models/sdar.py::fix_positions`` is the rule."""
+        return _block_pick(cfg.block_length, cfg.mask_id)
+
+    def layout(self, params, cfg, *, block_size, pool_blocks, max_batch,
+               prefill_chunk, quant) -> PoolLayout:
+        import jax.numpy as jnp
+
+        from byteps_tpu.serve.paged_cache import STATS, kv_pool_state
+
+        if block_size % cfg.block_length or prefill_chunk % cfg.block_length:
+            raise ValueError(
+                f"block_size ({block_size}) and prefill_chunk "
+                f"({prefill_chunk}) must be whole blocks of "
+                f"{cfg.block_length} positions")
+        pool = kv_pool_state(cfg, block_size, pool_blocks, cfg.kv_heads,
+                             False)
+        return PoolLayout(
+            state=pool._replace(stats=jnp.zeros((len(STATS),), jnp.float32)),
+            kv_heads=cfg.kv_heads)
+
+
 def serve_family(cfg):
     """The family that serves ``cfg``, by its type."""
     from byteps_tpu.models.dots3 import Dots3Config
     from byteps_tpu.models.mellum2 import Mellum2Config
     from byteps_tpu.models.qwen3_next import Qwen3NextConfig
+    from byteps_tpu.models.sdar import SDARConfig
 
+    if isinstance(cfg, SDARConfig):
+        return BlockDiffusionFamily()
     if isinstance(cfg, Dots3Config):
         return LatentFamily()
     if isinstance(cfg, Mellum2Config):
@@ -380,5 +488,5 @@ def serve_family(cfg):
         return GPTFamily()
     raise TypeError(
         f"Scheduler: no serve family for a {type(cfg).__name__} "
-        "(GPTConfig, Dots3Config, Mellum2Config and Qwen3NextConfig are "
-        "served)")
+        "(GPTConfig, Dots3Config, Mellum2Config, Qwen3NextConfig and "
+        "SDARConfig are served)")

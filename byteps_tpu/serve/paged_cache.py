@@ -189,12 +189,17 @@ class StepPlan(NamedTuple):
     ``recur(cfg, x, p, pool.s, pool.conv, index, slots, fresh, norm_fn=,
     norm_eps=) -> (x, s, conv)`` — ``slots (R,)`` for the packed decode
     step's rows, ``()`` for a chunk of one request, which starts from a zero
-    state where ``fresh``. Hashable: it keys the programs' factories."""
+    state where ``fresh``. ``block``: the model generates by diffusion over
+    blocks of that many positions (a power of two that divides the page; every
+    layer global) — a decode row carries a whole block and a chunk's mask is
+    block-causal; None: a token a row, causal. Hashable: it keys the
+    programs' factories."""
 
     kinds: Tuple[LayerKind, ...]
     ffn: Optional[Callable] = None
     attn: Optional[Callable] = None
     recur: Optional[Callable] = None
+    block: Optional[int] = None
 
 
 def _window_of(plan: "StepPlan") -> Optional[int]:
@@ -1101,6 +1106,22 @@ def _window_attend_twin(q, k_pool, v_pool, wi, table, pos, window: int,
     return o.reshape(R, 1, H, D).astype(q.dtype)
 
 
+def _block_attend_twin(q, kk, vv, length):
+    """The jnp twin of the kernel call with a block of queries a row: every
+    query of ``q (R, B, H, D)`` sees the keys below its row's ``length`` of
+    the gathered views ``kk``/``vv (R, S, Hkv, D)`` (the block's own are
+    among them), softmax in f32."""
+    R, B, H, D = q.shape
+    Hkv = kk.shape[2]
+    qg = q.reshape(R, B, Hkv, H // Hkv, D)
+    s = jnp.einsum("rbhgd,rkhd->rhgbk", qg.astype(jnp.float32),
+                   kk.astype(jnp.float32)) * D ** -0.5
+    ok = jnp.arange(kk.shape[1])[None, :] < length[:, None]
+    pr = jax.nn.softmax(jnp.where(ok[:, None, None, None], s, -1e30), -1)
+    o = jnp.einsum("rhgbk,rkhd->rbhgd", pr, vv.astype(jnp.float32))
+    return o.reshape(R, B, H, D).astype(q.dtype)
+
+
 def decode_uses_paged_attn(cfg: GPTConfig, block_size: int,
                            kv_heads: int, quant: bool) -> bool:
     """Whether the packed decode step built for this pool attends
@@ -1199,6 +1220,15 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     ``tables`` is ``(R, 1 + W)``: column 0 each row's slot of the state pool
     (0, scratch, for a row that holds no request), which ``plan.recur``
     updates in place; such a layer reads no table and no key.
+    With ``plan.block`` = B the step is a pass of block diffusion: ``toks (R,
+    B)`` — row ``r``'s block as it stands, the mask token where a position is
+    open — at positions ``[pos[r], pos[r] + B)`` (a block boundary: the B rows
+    lie in one page). Their k/v are scattered there IN PLACE, over whatever
+    an earlier pass of the same block left, every one of the B queries sees
+    the ``pos + B`` keys (the kernel with ``B · H`` query rows a batch row),
+    and the step returns ``logits (R, B, vocab)``. A denoising pass and the
+    pass that commits the block are this one program: the host moves the fill
+    level after the second.
 
     Multi-tenant variant: ``lora_sig=(targets, rank_bucket,
     n_adapter_slots)`` makes the step accept two trailing arguments —
@@ -1223,6 +1253,11 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     half = attn_half if plan.attn is None \
         else functools.partial(plan.attn, cfg)
     n_full, n_window, n_state = _layers_by_kind(plan)
+    B = plan.block
+    if B is not None and (n_window or n_state or block_size % B):
+        raise ValueError(
+            f"a block of {B} positions a row needs every layer global and a "
+            f"page ({block_size}) of whole blocks")
 
     def _slab_delta(slabs, slots, li):
         # the block's per-projection delta hook: each row's OWN adapter,
@@ -1263,13 +1298,15 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                         v_scale=pool.v_scale.at[li, blk, off].set(vs[:, 0]),
                     )
                 else:
+                    # (R, h·D) rows at (blk, off) (R,), or a block's (R, B,
+                    # h·D) at (blk (R, 1), off (R, B))
                     kp, vp = getattr(pool, kn), getattr(pool, vn)
                     new = pool._replace(**{
                         kn: kp.at[li, blk, off].set(
-                            k.reshape(R, -1).astype(kp.dtype)),
+                            k.reshape(off.shape + (-1,)).astype(kp.dtype)),
                         vn: vp.at[li, blk, off].set(
-                            v.reshape(R, -1).astype(vp.dtype))})
-            length = pos + 1                       # new key included
+                            v.reshape(off.shape + (-1,)).astype(vp.dtype))})
+            length = pos + (1 if B is None else B)     # new keys included
             kp, vp = getattr(new, kn), getattr(new, vn)
             if decode_uses_paged_attn(cfg, block_size, kv_loc, quant):
                 # the pool is read where it lies: the WHOLE pool is the
@@ -1277,8 +1314,8 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                 # pool-sized copy per layer), the layer picked in its DMAs
                 with jax.named_scope("paged/attention"):
                     o = paged_attention_decode(
-                        q[:, 0], kp, vp, tables, length, li,
-                        first=None if kind.window is None
+                        q[:, 0] if B is None else q, kp, vp, tables, length,
+                        li, first=None if kind.window is None
                         else jnp.maximum(length - kind.window, 0))
             elif kind.window is not None:
                 with jax.named_scope("paged/attention"):
@@ -1293,7 +1330,10 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                         new.v[li], new.v_scale[li] if quant else None,
                         tables, length, q.dtype, head_dim)
                 with jax.named_scope("paged/attention"):
-                    o, _ = attention_lse(q, kk, vv, pos, 0, causal=True)
+                    if B is None:
+                        o, _ = attention_lse(q, kk, vv, pos, 0, causal=True)
+                    else:
+                        o = _block_attend_twin(q, kk, vv, length)
             return o, new
         return attend
 
@@ -1303,7 +1343,13 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     # measured ~45 ms/step of pure memcpy at serving sizes on CPU
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, pool, toks, pos, tables, slabs=None, slots=None):
-        x = _embed(params, toks[:, None], pos[:, None], cfg)  # (R, 1, d)
+        if B is None:
+            x = _embed(params, toks[:, None], pos[:, None], cfg)  # (R, 1, d)
+            at = lambda: pos[:, None]                          # noqa: E731
+        else:
+            where = pos[:, None] + jnp.arange(B)               # (R, B)
+            x = _embed(params, toks, where, cfg)               # (R, B, d)
+            at = lambda: where                                 # noqa: E731
         if n_state:
             state_slots, tables = tables[:, 0], tables[:, 1:]
         # one table a kind: (R, W), or (R, 2, W) with a window kind
@@ -1313,6 +1359,9 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
             t, (pos // block_size)[:, None], axis=1)[:, 0]
             for t in kind_tables]
         off = pos % block_size
+        if B is not None:          # the block's B rows, inside one page
+            blks = [b[:, None] for b in blks]
+            off = off[:, None] + jnp.arange(B)
         moe = None if plan.ffn is None else jnp.zeros((4,), jnp.float32)
         for li, (p, kind) in enumerate(zip(params["blocks"], plan.kinds)):
             delta = None if slabs is None else _slab_delta(slabs, slots, li)
@@ -1324,7 +1373,7 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                 pool = pool._replace(s=s, conv=conv)
             else:
                 x, pool = half(
-                    x, p, cfg.head_dim, lambda: pos[:, None],
+                    x, p, cfg.head_dim, at,
                     _pool_attend(pool, kind, blks[line], off, pos,
                                  kind_tables[line]), tp_axis,
                     kind.rope, delta=delta, **kw)
@@ -1336,9 +1385,12 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
         if pool.stats is not None:
             # keys each live row's attention must read (a padded row sits at
             # position 0, where no request decodes), by layer kind
-            live = pos > 0
-            keys = [jnp.sum(jnp.where(live, pos + 1 if w is None else
-                                      jnp.minimum(pos + 1, w), 0)) * n
+            # (a block's row may decode at position 0: it is live where its
+            # table names a block, and reads its whole block)
+            live = pos > 0 if B is None else kind_tables[0][:, 0] > 0
+            new = 1 if B is None else B
+            keys = [jnp.sum(jnp.where(live, pos + new if w is None else
+                                      jnp.minimum(pos + new, w), 0)) * n
                     if n else 0.0
                     for w, n in ((None, n_full),
                                  (_window_of(plan), n_window))]
@@ -1347,7 +1399,7 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                 [jnp.asarray(v, jnp.float32)
                  for v in (*keys, 0.0, 0.0, *rows)])]))
         logits = _readout(params, x, norm_fn, norm_eps)
-        return logits[:, 0], pool
+        return (logits[:, 0] if B is None else logits), pool
 
     return step
 
@@ -1392,6 +1444,8 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     the chunk from the slot's state — from zero at ``pos0 == 0``, whatever
     the slot's last owner left — and leaves there what the next chunk or
     the first decode step continues from.
+    With ``plan.block`` the chunk attends block-causally (the flash forward
+    with the block a constant of its mask; ``pos0`` and C whole blocks).
     ``with_readout=False`` skips the vocab projection (an intermediate
     prefill chunk's logits are never read — at real vocab sizes that
     projection is the biggest weight stream in the chunk) and returns
@@ -1483,7 +1537,8 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
                 cv = _QuantSlot(cv, _view(pool.v_scale, li, table, keep))
         x, ck, cv, *aux = _block_step(
             x, p, ck, cv, pos0, cfg, tp_axis, None, norm_fn=norm_fn,
-            norm_eps=norm_eps, rope=kind.rope, ffn=_ffn(p), attn=half)
+            norm_eps=norm_eps, rope=kind.rope, ffn=_ffn(p), attn=half,
+            block=plan.block)
         with jax.named_scope("paged/scatter_kv"):
             at = (li, blk, off)
             if quant:
@@ -1525,8 +1580,13 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
         if pool.stats is not None:
             # visible (query, key) pairs of the chunk, by layer kind: query
             # t sees t + 1 keys, or the window where that is fewer
-            pairs = [jnp.sum(positions + 1 if w is None
-                             else jnp.minimum(positions + 1, w)) * n
+            # (under a block-causal mask: to the end of its own block)
+            def reach():
+                return positions + 1 if plan.block is None \
+                    else (positions // plan.block + 1) * plan.block
+
+            pairs = [jnp.sum(reach() if w is None
+                             else jnp.minimum(reach(), w)) * n
                      if n else 0.0
                      for w, n in ((None, n_full),
                                   (_window_of(plan), n_window))]

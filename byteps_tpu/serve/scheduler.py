@@ -209,20 +209,75 @@ def _take(picked, first, host, table_shape):
             host[:, _SLOT])
 
 
+# a block-diffusion step's columns: what a pass fixes of the row's block and
+# which pass of the block it is (a greedy schedule needs neither the token
+# column nor a seed); the row's block state follows its table
+_NFIX, _PASS = _TOK, _SEED
+
+
+@functools.partial(jax.jit, static_argnames="table_shape")
+def _take_block(state, host, table_shape):
+    """:func:`_take` for a step of block diffusion: row ``i``'s block state
+    ``(2B,)`` — its B tokens, the mask token where open, and the pass each was
+    fixed at — is row ``src[i]`` of the unread step's states and of the ones
+    the host holds (a block's first pass, a step read already), laid end to
+    end. Returns ``(toks, state, pos, tables, n_fix, pass_no)``."""
+    n = _N_COLS + int(np.prod(table_shape))
+    state = jnp.concatenate([state, host[:, n:]])[host[:, _SRC]]
+    return (state[:, :state.shape[1] // 2], state, host[:, _POS],
+            host[:, _N_COLS:n].reshape((host.shape[0],) + table_shape),
+            host[:, _NFIX], host[:, _PASS])
+
+
+class _Block:
+    """The block of B positions a run of a block-diffusion family is
+    denoising, at ``[cache_len, cache_len + B)``: ``given`` leading positions
+    the host knew (a prompt's last ``len % B`` tokens; generated tokens start
+    behind them) and ``open`` ones it did not, ``per`` positions a denoising
+    pass fixes and ``need`` such passes before the one that commits,
+    ``issued`` passes issued so far, and
+    ``state (2B,)`` as the host last held it (the tokens, the mask token
+    where open, then the pass each was fixed at) — what a pass feeds on where
+    the pass before it is not the unread step."""
+
+    __slots__ = ("given", "open", "per", "need", "issued", "state")
+
+    def __init__(self, given, B: int, steps: int, mask_id: int):
+        self.given = len(given)
+        self.open = B - self.given
+        self.per = B // steps
+        self.need = -(-self.open // self.per)
+        self.issued = 0
+        self.state = np.zeros(2 * B, np.int32)
+        self.state[:B] = mask_id
+        self.state[:self.given] = given
+
+    def fixes(self) -> int:
+        """Positions the next pass fixes (0: it commits the block)."""
+        return max(0, min(self.per, self.open - self.issued * self.per))
+
+
 class _InFlight:
     """The packed decode step whose tokens the host has not read: the
     device array ``_pick`` returned, the runs of its rows, the position
     each row wrote, the row of each request in it, and the device step
-    that reading it ends (``Scheduler._wait``)."""
+    that reading it ends (``Scheduler._wait``). Of a block-diffusion step
+    also each row's :class:`_Block` and, by request, the ``given`` count of
+    the rows whose pass commits their block."""
 
-    __slots__ = ("picked", "runs", "pos", "rows", "step")
+    __slots__ = ("picked", "runs", "pos", "rows", "step", "blocks", "commit")
 
-    def __init__(self, picked, runs: List["_Run"], pos: np.ndarray, step):
+    def __init__(self, picked, runs: List["_Run"], pos: np.ndarray, step,
+                 blocks: Optional[List[_Block]] = None):
         self.picked = picked
         self.runs = runs
         self.pos = pos
         self.rows = {run.req.rid: i for i, run in enumerate(runs)}
         self.step = step
+        self.blocks = blocks
+        self.commit = {} if blocks is None else {
+            run.req.rid: b.given for run, b in zip(runs, blocks)
+            if b.issued > b.need}
 
 
 @dataclasses.dataclass
@@ -276,6 +331,11 @@ class Request:
     # params (None = the bare base model).
     tenant: Any = None
     adapter: Any = None
+    # a block-diffusion family's: denoising passes a block (it divides the
+    # block; 0: the configuration's), B / denoise_steps positions fixed a
+    # pass, one committing pass on top. Quality is traded against passes
+    # here; other families ignore it
+    denoise_steps: int = 0
 
 
 class _Run:
@@ -285,7 +345,7 @@ class _Run:
                  "prefill_done", "state", "t_submit", "t_origin", "t_admit",
                  "t_first", "t_last", "t_phase", "preemptions", "spec_rounds",
                  "draft_cache", "tok_s", "idx_seq", "streamed", "tenant",
-                 "slot")
+                 "slot", "blk", "given", "fixed_at")
 
     def __init__(self, req: Request, resume_tokens: List[int],
                  t_submit: float):
@@ -325,6 +385,13 @@ class _Run:
         # not admitted); acquired at admission, released on finish,
         # preempt, drain, and migration — mirrors the KV block table
         self.slot: Optional[int] = None
+        # a block-diffusion family's: the block being denoised (None between
+        # blocks), the input's last ``len % B`` tokens (the first block's
+        # given positions; cut off at admission), and the pass each emitted
+        # token was fixed at (0: it came with the request)
+        self.blk: Optional[_Block] = None
+        self.given: np.ndarray = self.full_input[:0]
+        self.fixed_at: List[int] = [0] * len(self.emitted)
 
 
 class NoProgressError(RuntimeError):
@@ -441,13 +508,19 @@ class Scheduler:
                 max_batch=self.max_batch, prefill_chunk=self.prefill_chunk,
                 quant=quant))
         self._late = self._family.late_stats()
+        # generation by diffusion over blocks: positions a decode row carries
+        # (None: a token a row a step, and nothing below differs)
+        self._blk = self._family.block(cfg)
         # the packed decode step is built LAZILY (first decode touch):
         # a prefill-only replica must never trace/compile it — that is
         # the dedicated replica's cold-start and HBM win, asserted in
         # tests/test_serve_disagg.py
         self._decode_fn = None
         self._decode_paged_attn = False   # set with _decode_fn
-        self._pick, self._pick_last = _make_pick_fn(cfg.vocab_size)
+        if self._blk is None:
+            self._pick, self._pick_last = _make_pick_fn(cfg.vocab_size)
+        else:
+            self._pick, self._pick_last = self._family.block_pick(cfg), None
         self._draft_steps: Dict[int, Any] = {}
         self._plan = fault_plan if fault_plan is not None \
             else plan_from_env(worker_id=replica_id)
@@ -539,6 +612,7 @@ class Scheduler:
             "token_ms": _reg.histogram("serve.token_ms"),
             "request_ms": _reg.histogram("serve.request_ms"),
             "batch_occupancy": _reg.histogram("serve.batch_occupancy"),
+            **self._block_metrics(_reg),
             # per-replica series (global instance sequence): two
             # replicas' queues must not mask each other
             "queue_depth": _reg.gauge(
@@ -571,8 +645,8 @@ class Scheduler:
         if req.max_new < 1:
             raise ValueError(f"max_new must be >= 1; got {req.max_new}")
         spec_k = 0
+        self._family.validate_request(req, self.cfg)
         if req.spec is not None:
-            self._family.validate_request(req, self.cfg)
             if req.temperature != 0.0:
                 raise ValueError(
                     "speculative policies are greedy-only "
@@ -583,6 +657,8 @@ class Scheduler:
                     f"effective spec_len must be >= 1; got {spec_k} "
                     "(policy spec_len or BYTEPS_SERVE_SPEC_LEN)")
         total = prompt.size + req.max_new + spec_k
+        if self._blk:                  # its last block is written whole
+            total = -(-total // self._blk) * self._blk
         if total > self.cfg.max_seq:
             raise ValueError(
                 f"prompt ({prompt.size}) + max_new ({req.max_new})"
@@ -901,7 +977,9 @@ class Scheduler:
             self._decode_paged_attn = \
                 self._family.decode_reads_pool_in_place(self.cfg, self.cache)
             # what _take reads where nothing is unread
-            self._no_picked = jnp.zeros(self.max_batch, jnp.int32)
+            self._no_picked = jnp.zeros(
+                self.max_batch if self._blk is None
+                else (self.max_batch, 2 * self._blk), jnp.int32)
             self._no_first = jnp.zeros(1, jnp.int32)
         return self._decode_fn
 
@@ -1082,6 +1160,16 @@ class Scheduler:
                     and tok == run.req.eos_id)):
             self._finish(run, now)
 
+    def _commit_tokens(self, run: _Run, toks, now: float) -> None:
+        """Commit the tokens one step yielded for ``run``, in order, one
+        :meth:`_commit_token` each — so ``max_new`` and ``eos_id`` stop the
+        request mid-way exactly as the dense sampler's output truncation
+        does (a speculative round's accepted block, a denoised block)."""
+        for t in toks:
+            if run.state != "decode":
+                return                       # finished mid-way
+            self._commit_token(run, int(t), now)
+
     def _finish(self, run: _Run, now: float) -> None:
         self._phase(run, "decode", now)
         self.cache.release(run.req.rid)
@@ -1104,6 +1192,11 @@ class Scheduler:
             "preemptions": run.preemptions,
             "spec_rounds": run.spec_rounds,
         }
+        if self._blk:
+            # the pass of its block each token was fixed at (1 ..): what a
+            # reference replays the sampler from
+            self.results[run.req.rid]["fixed_at"] = np.asarray(
+                run.fixed_at[:len(emitted)], np.int32)
         self._m["completed"].inc()
         self._m["request_ms"].observe((now - run.t_origin) * 1e3)
 
@@ -1130,6 +1223,7 @@ class Scheduler:
         run.prefill_done = 0
         run.streamed = 0
         run.draft_cache = None
+        run.blk = None             # an open block is denoised again
         run.full_input = np.concatenate(
             [np.asarray(run.req.prompt, np.int32),
              np.asarray(run.emitted, np.int32)])
@@ -1307,10 +1401,7 @@ class Scheduler:
         # the round emits [d_1..d_m (, correction)] then the NEXT round's
         # pending token; commit them one by one so eos/max_new stop
         # mid-block exactly like the dense sampler's output truncation
-        for t in block:
-            if run.state != "decode":
-                return                       # finished mid-block
-            self._commit_token(run, int(t), now)
+        self._commit_tokens(run, block, now)
         if run.state == "decode":
             run.pending = int(np.asarray(next_tok)[0])
 
@@ -1380,6 +1471,15 @@ class Scheduler:
             run = self._next_admission(now, deferred)
             if run is None:
                 break
+            if self._blk:
+                # whole blocks are prefilled; what is left of the input is
+                # the first block's given positions (from prompt + emitted
+                # each time: an admission rolled back cuts again)
+                full = np.concatenate(
+                    [np.asarray(run.req.prompt, np.int32).reshape(-1),
+                     np.asarray(run.emitted, np.int32)])
+                cut = len(full) // self._blk * self._blk
+                run.full_input, run.given = full[:cut], full[cut:]
             L = len(run.full_input)
             # a prefill-only replica writes exactly L rows (the decode
             # slot L+1 belongs to the decode target's pool)
@@ -1485,6 +1585,13 @@ class Scheduler:
             if run.state != "prefill":
                 continue
             L = len(run.full_input)
+            if L == run.prefill_done:
+                # an input shorter than a block (a block-diffusion family's:
+                # all of it is the first block's given positions)
+                self._m["prefill_ms"].observe(
+                    self._phase(run, "prefill", self._clock()))
+                run.state = "decode"
+                continue
             if (self._prefix_on and run.prefill_done < L - 1
                     and run.idx_seq != self.cache.index_version):
                 # re-consult the index mid-prefill: at saturation every
@@ -1515,6 +1622,10 @@ class Scheduler:
                     len(run.full_input) - run.prefill_done)
             toks = run.full_input[run.prefill_done:run.prefill_done + C]
             final = run.prefill_done + C == len(run.full_input)
+            # a final chunk's last position yields the first token — but not
+            # where generation starts from a masked block: no readout, no
+            # pick, and the chunk is read behind the decode step after it
+            reads = final and self._blk is None
             if self.cache.window is not None:
                 # the window kind grows chunk by chunk (and shrinks behind
                 # it): its blocks for this chunk's rows, or a preemption
@@ -1534,7 +1645,7 @@ class Scheduler:
                 # arrays go in as they are: the call transfers them, at
                 # less than half of what a jnp.asarray each costs
                 launch = tr.clock()
-                logits, self.cache.state = self._prefill_fn(C, final)(
+                logits, self.cache.state = self._prefill_fn(C, reads)(
                     self._params_for(run), self.cache.state,
                     toks[None], np.int32(run.prefill_done),
                     self.cache.table_row(run.req.rid, W))
@@ -1545,7 +1656,7 @@ class Scheduler:
                 # device step, whose time is then no one kind's
                 launch = self._ahead[0]
                 self._done = (self._done[0], False)
-            self._ahead = None if final else (launch, C, W)
+            self._ahead = None if reads else (launch, C, W)
             run.prefill_done += C
             run.cache_len = run.prefill_done
             self.cache.release_behind(run.req.rid, run.cache_len)
@@ -1573,7 +1684,11 @@ class Scheduler:
                                                    run.streamed, full))
                     run.streamed = full
             progress = True
-            if run.prefill_done == len(run.full_input):
+            if final and not reads:
+                self._m["prefill_ms"].observe(
+                    self._phase(run, "prefill", self._clock()))
+                run.state = "decode"
+            elif final:
                 run.state = "decode"
                 if (run.req.spec is not None
                         and run.req.spec.kind == "draft"
@@ -1610,6 +1725,13 @@ class Scheduler:
         """Tokens the device has picked for ``run``, read or not: whether
         it goes on is known from this count before the last one's value
         is."""
+        if self._blk:
+            # the unread pass commits its block: the positions behind the
+            # given ones
+            given = None if self._flight is None \
+                else self._flight.commit.get(run.req.rid)
+            return len(run.emitted) + (0 if given is None
+                                       else self._blk - given)
         return (len(run.emitted)
                 + (self._flight is not None
                    and run.req.rid in self._flight.rows)
@@ -1622,6 +1744,7 @@ class Scheduler:
         in flight before (None when there was none, or packing had to
         drain it), now the caller's to read, and whether a step was
         issued behind it."""
+        B = self._blk or 1             # positions a row writes
         with tr.span("serve.decode_pack", "SERVE"):
             packed: List[_Run] = []
             for run in list(self._running):
@@ -1631,9 +1754,9 @@ class Scheduler:
                     break
                 if self._tokens_picked(run) >= run.req.max_new:
                     continue               # its unread token is its last
-                if self._ensure_or_preempt(run, run.cache_len + 1,
+                if self._ensure_or_preempt(run, run.cache_len + B,
                                            run.cache_len,
-                                           run.cache_len + 1):
+                                           run.cache_len + B):
                     if run.state == "decode":  # survived any preemptions
                         packed.append(run)
             packed = [r for r in packed if r.state == "decode"]
@@ -1643,12 +1766,17 @@ class Scheduler:
             R = self.max_batch
             W = max(self._width(r.req.rid) for r in packed)
             rows = [self.cache.table_row(r.req.rid, W) for r in packed]
-            host = np.zeros((R, _N_COLS + rows[0].size), np.int32)
+            host = np.zeros((R, _N_COLS + rows[0].size
+                             + (2 * B if self._blk else 0)), np.int32)
             # where row i's input token is: a row of the unread step, the
-            # unread first token (R), or the host's own (R + 1 + i)
-            host[:, _SRC] = np.arange(R + 1, 2 * R + 1)
+            # unread first token (R), or the host's own (R + 1 + i); a block's
+            # state likewise, without a first token (R + i)
+            host[:, _SRC] = np.arange(R + 1, 2 * R + 1) - (self._blk
+                                                           is not None)
             temps = np.zeros(R, np.float32)
-            for i, run in enumerate(packed):
+            blocks = self._pack_blocks(packed, unread, host, rows) \
+                if self._blk else None
+            for i, run in enumerate(() if self._blk else packed):
                 if unread is not None and run.req.rid in unread.rows:
                     host[i, _SRC] = unread.rows[run.req.rid]
                 elif self._first is not None and self._first[0] is run:
@@ -1666,21 +1794,27 @@ class Scheduler:
                 host[i, _SLOT] = run.slot or 0
                 host[i, _N_COLS:] = rows[i].reshape(-1)
             host[:, _TEMP] = temps.view(np.int32)
-        with tr.span("serve.decode_dispatch", "SERVE",
-                     (len(packed), W)) as sp:
+        shape = (len(packed), W) + ((B,) if self._blk else ())
+        with tr.span("serve.decode_dispatch", "SERVE", shape) as sp:
             step = self._decode_step()
             t0 = tr.clock()
-            toks, pos, tables, seeds, pos1, temps, slots = _take(
-                unread.picked if unread is not None else self._no_picked,
-                self._first[1] if self._first is not None
-                else self._no_first, host, table_shape=rows[0].shape)
+            was = unread.picked if unread is not None else self._no_picked
+            if self._blk:
+                toks, state, pos, tables, n_fix, pass_no = _take_block(
+                    was, host, table_shape=rows[0].shape)
+                extra = ()
+            else:
+                toks, pos, tables, seeds, pos1, temps, slots = _take(
+                    was, self._first[1] if self._first is not None
+                    else self._no_first, host, table_shape=rows[0].shape)
+                extra = () if self.adapter_pool is None \
+                    else (self.adapter_pool.slabs, slots)
             t1 = tr.clock()
-            extra = () if self.adapter_pool is None \
-                else (self.adapter_pool.slabs, slots)
             logits, self.cache.state = step(
                 self._operands, self.cache.state, toks, pos, tables, *extra)
             t2 = tr.clock()
-            picked = self._pick(logits, seeds, pos1, temps)
+            picked = self._pick(logits, state, n_fix, pass_no) if self._blk \
+                else self._pick(logits, seeds, pos1, temps)
             picked.copy_to_host_async()
             t3 = tr.clock()
             if self._late is not None:
@@ -1692,17 +1826,21 @@ class Scheduler:
                     parent=sp.sid)
             tr.emit("serve.issue.pick", "SERVE", t2, t3 - t2, parent=sp.sid)
         # what the host knows without the tokens' values: each row wrote
-        # its position
+        # its position — a block's row its block, which becomes cache only
+        # with the pass that commits it
         for run in packed:
-            run.cache_len += 1
+            if not self._blk:
+                run.cache_len += 1
+            elif run.blk.issued > run.blk.need:
+                run.cache_len += B
+                run.blk = None
         if self._ahead is None:
-            dstep = ("decode", t0, (len(packed), W))
+            dstep = ("decode", t0, shape)
         else:
-            dstep = ("chunk_decode", self._ahead[0],
-                     self._ahead[1:] + (len(packed), W))
+            dstep = ("chunk_decode", self._ahead[0], self._ahead[1:] + shape)
             self._ahead = None
         self._flight = _InFlight(picked, packed, host[:len(packed), _POS],
-                                 dstep)
+                                 dstep, blocks)
         if unread is not None:
             self._m["decode_steps_overlapped"].inc()
         if self._decode_paged_attn:
@@ -1723,16 +1861,108 @@ class Scheduler:
             picked = self._wait(tr, flight.picked, flight.step)
         with tr.span("serve.commit", "SERVE"):
             now = self._clock()
-            live = 0
+            live = tokens = 0
             for i, run in enumerate(flight.runs):
                 if run.state != "decode":
                     continue
                 live += 1
-                self.cache.release_behind(run.req.rid, int(flight.pos[i]) + 1)
-                self._commit_token(run, int(picked[i]), now)
-        self._m["decode_tokens"].inc(live)
+                if flight.blocks is None:
+                    self.cache.release_behind(run.req.rid,
+                                              int(flight.pos[i]) + 1)
+                    self._commit_token(run, int(picked[i]), now)
+                elif run.req.rid in flight.commit:
+                    tokens += self._commit_block(run, flight.blocks[i],
+                                                 picked[i], now)
+                else:
+                    # a denoising pass commits nothing: the host keeps the
+                    # block as it stands, for a pass issued after a drain
+                    flight.blocks[i].state = picked[i]
+        self._m["decode_tokens"].inc(live if flight.blocks is None
+                                     else tokens)
         self._m["decode_rows_dropped"].inc(len(flight.runs) - live)
         return True
+
+    # -- generation by diffusion over blocks ---------------------------------
+    def _block_metrics(self, reg) -> Dict[str, Any]:
+        """The series of a block-diffusion family (docs/observability.md
+        §serve.block): none for the others."""
+        if self._blk is None:
+            return {}
+        return {
+            # row-passes issued, those of them that commit a block, blocks
+            # committed (at the read), positions the passes fixed
+            "block_row_passes": reg.counter("serve.block.row_passes"),
+            "block_commit_row_passes": reg.counter(
+                "serve.block.commit_row_passes"),
+            "block_commits": reg.counter("serve.block.commits"),
+            "block_positions_fixed": reg.counter(
+                "serve.block.positions_fixed"),
+            # rows of k/v (a layer each) that passes scattered and a later
+            # pass of the same block overwrote
+            "block_rows_rewritten": reg.counter(
+                "serve.kv.block_rows_rewritten"),
+            # passes a committed block took; what a client waits between two
+            # blocks of one request
+            "block_passes": reg.histogram("serve.block.passes"),
+            "block_ms": reg.histogram("serve.block_ms"),
+        }
+
+    def _pack_blocks(self, packed: List[_Run], unread, host,
+                     rows) -> List[_Block]:
+        """Each packed row's block and its columns of ``host`` (position and
+        table ``rows[i]`` as a token's row has them): a run between
+        blocks starts one (its state is the host's: the given tokens, the
+        rest masked); the next pass of an open block feeds on the unread
+        step's row of it where there is one, else on the state the host read.
+        The schedule is static, so which pass a row is at, what it fixes and
+        whether it commits are known here without any token's value."""
+        B, m = self._blk, self._m
+        blocks, fixed, commits = [], 0, 0
+        for i, run in enumerate(packed):
+            blk = run.blk
+            if blk is None:
+                blk = run.blk = _Block(
+                    run.given, B,
+                    run.req.denoise_steps or self.cfg.denoise_steps,
+                    self.cfg.mask_id)
+                run.given = run.given[:0]
+            if (blk.issued and unread is not None
+                    and run.req.rid in unread.rows):
+                host[i, _SRC] = unread.rows[run.req.rid]
+            else:
+                host[i, -2 * B:] = blk.state
+            n = blk.fixes()
+            host[i, _NFIX] = n
+            blk.issued += 1
+            host[i, _PASS] = blk.issued
+            host[i, _POS] = run.cache_len
+            host[i, _N_COLS:-2 * B] = rows[i].reshape(-1)
+            fixed += n
+            commits += n == 0
+            blocks.append(blk)
+        m["block_row_passes"].inc(len(packed))
+        m["block_commit_row_passes"].inc(commits)
+        m["block_positions_fixed"].inc(fixed)
+        m["block_rows_rewritten"].inc(
+            (len(packed) - commits) * B * self.cfg.n_layers)
+        return blocks
+
+    def _commit_block(self, run: _Run, blk: _Block, state: np.ndarray,
+                      now: float) -> int:
+        """The read of the pass that committed ``blk``: its tokens behind the
+        given ones are the run's next, in order (``max_new`` or ``eos_id``
+        may end the request inside the block). One latency observation a
+        token as everywhere — the block's first carries the whole gap, the
+        others 0 — and the gap between blocks on its own. Returns the tokens
+        committed."""
+        B, before = self._blk, len(run.emitted)
+        if run.t_first is not None:
+            self._m["block_ms"].observe((now - run.t_last) * 1e3)
+        self._m["block_passes"].observe(blk.issued)
+        self._m["block_commits"].inc()
+        run.fixed_at.extend(state[B + blk.given:].tolist())
+        self._commit_tokens(run, state[blk.given:B], now)
+        return len(run.emitted) - before
 
     def _read_first(self, tr) -> bool:
         """Read and commit the first token of the request whose final

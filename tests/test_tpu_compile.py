@@ -724,3 +724,83 @@ def test_qwen3next_serve_program_fits_and_leaves_the_pools_in_place(
         assert n == 6 + 2 + 3 * 8, n
         for name in ("gdn_decode", "paged_attn_decode"):
             assert name in hlo, name
+
+
+# --- the SDAR serving cell's two programs at the cut configuration ----------
+_SD_BS, _SD_BATCH, _SD_CHUNK, _SD_BLOCKS = 128, 128, 2048, 2305
+
+
+def _sdar_on(topo):
+    """The cut SDAR (``benchmark/configs/sdar-30b-a3b-chat-l6.json``: 6 of 48
+    layers, every layer whole, bf16), its parameters and its k/v pool as
+    shapes on the described chip."""
+    from byteps_tpu.models.sdar import SDARConfig, sdar_init
+    from byteps_tpu.serve.families import serve_family
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = SDARConfig(max_seq=8192, n_layers=6)
+    shapes = jax.eval_shape(lambda: sdar_init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), shapes)
+    family = serve_family(cfg)
+    pool = jax.eval_shape(lambda: family.layout(
+        shapes, cfg, block_size=_SD_BS, pool_blocks=_SD_BLOCKS,
+        max_batch=_SD_BATCH, prefill_chunk=_SD_CHUNK, quant=False).state)
+    pool = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), pool)
+    return cfg, family, params, pool, on_chip
+
+
+@pytest.mark.parametrize("program", ["chunk_c2048_w64", "decode_r128_b4_w64",
+                                     "decode_r128_b4_w4"])
+def test_sdar_serve_program_fits_and_leaves_the_pool_in_place(
+        topo, as_on_tpu, program):
+    """The 2,048-token block-causal chunk program and the 128-row pass over
+    blocks of 4 positions compile for the described v5e at the published
+    widths: 4,361,055,744 parameters (8.72 GB in bf16) and a pool of 2,305
+    pages over 6 layers (3.6 GB), donated and updated in place — a pass
+    scatters 4 rows a sequence and layer over what the pass before left;
+    weights + pool + temporaries fit the chip's 16 GB. A chunk: a flash call
+    and 3 grouped products a layer (the last layer's second half is not in a
+    program without readout); a pass: 6 paged-attention calls, each over
+    ``4 x 32`` query rows a sequence in 4 grid steps, and 18 grouped
+    products."""
+    cfg, family, params, pool, on_chip = _sdar_on(topo)
+    if program.startswith("chunk"):
+        compiled = family.prefill_fn(cfg, _SD_BS, _SD_CHUNK, None, False)\
+            .lower(params, pool, on_chip((1, _SD_CHUNK), I32),
+                   on_chip((), I32), on_chip((64,), I32)).compile()
+    else:
+        assert family.decode_reads_pool_in_place(
+            cfg, type("C", (), dict(block_size=_SD_BS, kv_heads=4,
+                                    quant=False)))
+        W = int(program.rsplit("w", 1)[1])
+        compiled = family.decode_fn(cfg, _SD_BS, None, None).lower(
+            params, pool, on_chip((_SD_BATCH, cfg.block_length), I32),
+            on_chip((_SD_BATCH,), I32),
+            on_chip((_SD_BATCH, W), I32)).compile()
+    weights, pages = _bytes(params), _bytes(pool)
+    assert weights == 2 * 4361055744, weights
+    assert pool.k.shape == (6, _SD_BLOCKS, 128, 512)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pages - 64            # donated, in place
+    assert mem.argument_size_in_bytes <= weights + pages + (1 << 20)
+    assert weights + pages + mem.temp_size_in_bytes < 15.5e9, \
+        mem.temp_size_in_bytes
+    hlo = compiled.as_text()
+    for name in ("k", "v"):
+        shape = "bf16[%s]" % ",".join(map(str, getattr(pool, name).shape))
+        made = [op for op, aliased in _ops_with_result(hlo, shape)
+                if op not in ("parameter", "tuple", "get-tuple-element",
+                              "bitcast") and not aliased]
+        assert not made, (name, made)
+    n = _n_pallas(compiled)
+    if program.startswith("chunk"):
+        assert n == 6 + 3 * 5, n
+        for name in ("flash_fwd", "moe_gmm_fwd"):
+            assert name in hlo, name
+    else:
+        assert n == 6 + 3 * 6, n
+        assert "paged_attn_decode" in hlo
