@@ -23,6 +23,7 @@ golden at CPU shapes.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -222,6 +223,138 @@ def test_shape_validation(hht):
 
 
 # ---------------------------------------------------------------------------
+# the backward's scan axis: a plan from the shapes (`_bwd_axis`), and the
+# vocab cut against the dense chain's gradients
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("shape,want", [
+    # the two measured training shapes, one on each side of the rule:
+    # GPT-2-medium carries dh (8192·1024·25 elements a pass, not
+    # 1024·50304·32), JoyAI keeps its rows (16384·16 > 16256·16)
+    ((8192, 1024, 50304, None, None), ("vocab", 2048)),
+    ((16384, 2048, 16256, None, None), ("rows", 1024)),
+    ((32768, 1024, 6288, None, None), ("rows", 2048)),   # gpt2m under tp=8
+    # a plane that fits one block is the row cut's single, un-scanned one
+    ((51, 24, 96, None, None), ("rows", 51)),
+    ((51, 24, 96, 64, None), ("rows", 64)),
+    ((51, 24, 96, 64, 32), ("rows", 64)),
+    # a row block of a few rows affords no 128-lane vocab block: the
+    # cases this file had before the vocab cut still run the row loop
+    ((51, 24, 96, 8, None), ("rows", 8)),
+    ((51, 24, 96, 16, None), ("rows", 16)),
+    ((51, 24, 96, 8, 32), ("rows", 8)),
+    ((51, 24, 24, 8, None), ("rows", 8)),               # …under tp=4
+    # one that affords whole lanes takes the vocab cut where N < v_loc
+    ((8192, 1024, 50304, 512, None), ("vocab", 3072)),
+    # `vocab_block` alone names the vocab cut's block, taken as it is
+    ((51, 24, 96, None, 32), ("vocab", 32)),
+    ((51, 24, 96, None, 40), ("vocab", 40)),
+    ((26, 24, 48, None, 16), ("vocab", 16)),
+    ((200, 24, 96, None, 32), ("rows", 200)),           # N ≥ v_loc: rows
+], ids=lambda v: "-".join(map(str, v)))
+def test_bwd_axis_plan(shape, want):
+    from byteps_tpu.ops.chunked_ce import _bwd_axis
+
+    assert _bwd_axis(*shape) == want
+
+
+def _lowered_scopes(fn, *args):
+    txt = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    return set(re.findall(r"readout_ce\.bwd_\w+", txt))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("with_bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("n_rows", [51, 53])
+@pytest.mark.parametrize("vocab_block", [32, 40, 64],
+                         ids=["3_equal", "2_and_tail_16", "1_and_tail_32"])
+def test_vocab_major_grads_match_dense(hht, vocab_block, n_rows, with_bias,
+                                       dtype):
+    """The vocab cut's dh / dhead / dbias against plain AD through the
+    dense chain, as `test_grads_match_dense` holds the row cut: equal
+    blocks, a ragged tail (V = 96 is no multiple of 40 or 64), a row count
+    that no block divides (51 = 3·17; 53 is prime: nothing pads rows)."""
+    h, head, tgt, bias = hht
+    h = h.reshape(-1, h.shape[-1])
+    tgt = tgt.reshape(-1)
+    if n_rows > h.shape[0]:
+        h = jnp.concatenate([h, _rand(4, (n_rows - h.shape[0], h.shape[1]))])
+        tgt = jnp.concatenate([tgt, tgt[:n_rows - tgt.shape[0]]])
+    h = h.astype(dtype)
+    b = bias if with_bias else None
+
+    def lc(h, hd, b):
+        return chunked_ce_nll(h, hd, tgt, bias=b,
+                              vocab_block=vocab_block).mean()
+
+    def ld(h, hd, b):
+        return dense_ce_nll(h, hd, tgt, bias=b).mean()
+
+    argnums = (0, 1, 2) if with_bias else (0, 1)
+    assert _lowered_scopes(jax.grad(lc, argnums=argnums), h, head, b) == {
+        "readout_ce.bwd_vocab"}
+    got = jax.jit(jax.grad(lc, argnums=argnums))(h, head, b)
+    want = jax.jit(jax.grad(ld, argnums=argnums))(h, head, b)
+    assert got[0].dtype == dtype and got[1].dtype == jnp.float32
+    # bf16: dz is rounded to the activation type before both products,
+    # as the row cut rounds it (`test_bf16_activations`' tolerance)
+    rtol, atol = (RTOL, ATOL) if dtype == jnp.float32 else (2e-2, 1e-4)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   rtol=rtol, atol=atol)
+
+
+def test_row_major_cases_keep_the_row_loop(hht):
+    """`row_block=8` (this file's lever since before the vocab cut) still
+    lowers the row loop, and says so in its scope."""
+    h, head, tgt, _ = hht
+    assert _lowered_scopes(
+        jax.grad(lambda h_: chunked_ce_nll(h_, head, tgt,
+                                           row_block=8).mean()), h) == {
+        "readout_ce.bwd_rows"}
+
+
+@pytest.mark.parametrize("check_vma", [True, False], ids=["vma", "novma"])
+@pytest.mark.parametrize("vocab_block", [16, 20],
+                         ids=["3_equal", "2_and_tail_8"])
+def test_vocab_major_tp_vocab_parallel(hht, vocab_block, check_vma):
+    """shard_map dp=2 × tp=2: each device scans ITS 48 columns in vocab
+    blocks over its 26 rows; dh takes its psum over tp after the loop and
+    dhead is scattered and summed, as in the row cut."""
+    from jax.sharding import PartitionSpec as P
+
+    _, head, _, bias = hht
+    d, V = head.shape
+    h = _rand(5, (4, 13, d))
+    tgt = jax.random.randint(jax.random.PRNGKey(6), (4, 13), 0, V)
+    mesh = jax.make_mesh((2, 2), ("dp", "tp"))
+
+    def per_dev(h, hd, b, t):
+        def loss(h, hd, b):
+            return chunked_ce_nll(h, hd, t, bias=b, tp_axis="tp",
+                                  vocab_block=vocab_block).sum() / tgt.size
+        dh, dhd, db = jax.grad(loss, argnums=(0, 1, 2))(h, hd, b)
+        if not check_vma:
+            # no vma to read: the op sums over tp alone, the caller over
+            # the axes its activations are split on (models/train.py)
+            dhd, db = jax.lax.psum((dhd, db), "dp")
+        return dh, dhd, db
+
+    f = jax.shard_map(
+        per_dev, mesh=mesh, in_specs=(P("dp"), P(), P(), P("dp")),
+        out_specs=(P("dp"), P(), P()), check_vma=check_vma)
+    assert _lowered_scopes(f, h, head, bias, tgt) == {"readout_ce.bwd_vocab"}
+    got = jax.jit(f)(h, head, bias, tgt)
+    want = jax.grad(
+        lambda *a: dense_ce_nll(a[0], a[1], tgt, bias=a[2]).mean(),
+        argnums=(0, 1, 2))(h, head, bias)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=RTOL, atol=ATOL)
+
+
+# ---------------------------------------------------------------------------
 # factory-level parity: chunked_ce=True vs the False escape hatch across
 # the parallel compositions the acceptance matrix names
 # ---------------------------------------------------------------------------
@@ -362,3 +495,45 @@ def test_moe_loss_parity():
     lc = moe_gpt_loss(params, tokens, targets, cfg, chunked_ce=True)
     ld = moe_gpt_loss(params, tokens, targets, cfg, chunked_ce=False)
     assert float(lc) == float(ld)
+
+
+# ---------------------------------------------------------------------------
+# the vocab cut inside whole train steps. No factory takes a block size (the
+# plan reads shapes alone), so the test shrinks the elements a block may hold
+# until the tiny configurations scan several 128-column blocks
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def axes_taken(monkeypatch):
+    from byteps_tpu.ops import chunked_ce as ce
+
+    taken = []
+    plan = ce._bwd_axis
+
+    def spy(*a):
+        taken.append(plan(*a))
+        return taken[-1]
+
+    monkeypatch.setattr(ce, "_bwd_axis", spy)
+    return taken
+
+
+@pytest.mark.parametrize("case", ["dp", "dp_untied", "remat", "pp"])
+def test_gpt_factory_parity_vocab_major(monkeypatch, axes_taken, case):
+    """Under `jax.checkpoint` and in the pipeline factory the VJP is traced
+    apart from its forward; the carries' vma must close there too."""
+    from byteps_tpu.models.train import (
+        make_gpt_pp_train_step, make_gpt_train_step)
+    from byteps_tpu.ops import chunked_ce as ce
+
+    # V = 256 against 64 rows a device: two blocks of 128 columns
+    monkeypatch.setattr(ce, "_BLOCK_ELEMS", 64 * 128)
+    cfg = GPTConfig.tiny()
+    if case == "dp_untied":
+        cfg = dataclasses.replace(cfg, tied_readout=False)
+    if case == "pp":
+        _run_two_steps(make_gpt_pp_train_step, dict(pp=2, dp=2), cfg,
+                       n_micro=2)
+    else:
+        _run_two_steps(make_gpt_train_step, dict(dp=2), cfg,
+                       remat=case == "remat")
+    assert axes_taken and set(axes_taken) == {("vocab", 128)}

@@ -804,3 +804,50 @@ def test_sdar_serve_program_fits_and_leaves_the_pool_in_place(
     else:
         assert n == 6 + 3 * 6, n
         assert "paged_attn_decode" in hlo
+
+
+# ---- the training readout's backward: which array the loop carries ----------
+def _readout_loss_and_grads(topo, B, S, d, V):
+    from byteps_tpu.ops.chunked_ce import chunked_ce_nll
+
+    one = SingleDeviceSharding(topo.devices[0])
+    fn = jax.value_and_grad(
+        lambda h, head, t: chunked_ce_nll(h, head, t).mean(), argnums=(0, 1))
+    args = (jax.ShapeDtypeStruct((B, S, d), BF16, sharding=one),
+            jax.ShapeDtypeStruct((d, V), F32, sharding=one),
+            jax.ShapeDtypeStruct((B, S), I32, sharding=one))
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _while_bodies(hlo: str):
+    """``(carried types, body text)`` of each ``while`` of a compiled
+    program."""
+    out = []
+    for m in re.finditer(r"= (\(.*?\)) while\(.*?body=(%[\w.\-]+)", hlo):
+        start = hlo.index(f"\n{m.group(2)} (")
+        out.append((m.group(1), hlo[start:hlo.index("\n}\n", start)]))
+    return out
+
+
+def test_readout_backward_carries_the_shorter_side(topo):
+    """GPT-2-medium's training cell (8 × 1,024 rows, V = 50,304 > N): the
+    backward scans vocab blocks, so the whole-array carry that a block
+    reads and adds to is dh, f32 (8192, 1024) — and the (1024, 50304) f32
+    dhead, 206 MB, is only written a slice at a time (a plain
+    dynamic-update-slice of the carried buffer, no add over it)."""
+    hlo = _readout_loss_and_grads(topo, 8, 1024, 1024, 50304)
+    assert "readout_ce.bwd_vocab" in hlo and "readout_ce.bwd_rows" not in hlo
+    loops = _while_bodies(hlo)
+    assert any("f32[8192,1024]" in carried for carried, _ in loops)
+    for carried, body in loops:
+        makers = {op for op, _ in _ops_with_result(body, "f32[1024,50304]")}
+        assert makers <= {"get-tuple-element", "dynamic-update-slice"}, (
+            makers, carried)
+
+
+def test_readout_backward_keeps_rows_where_they_are_shorter(topo):
+    """JoyAI's training shape (4 × 4,096 rows ≥ V = 16,256 as run): 16 row
+    blocks of a 133.2 MB carry against 16 vocab blocks of a 134.2 MB one —
+    the row loop stays."""
+    hlo = _readout_loss_and_grads(topo, 4, 4096, 2048, 16256)
+    assert "readout_ce.bwd_rows" in hlo and "readout_ce.bwd_vocab" not in hlo
