@@ -4,10 +4,13 @@ Reference analog: ``byteps/torch/__init__.py`` ``DistributedOptimizer``
 (wraps the user's optimizer, intercepts gradients, push_pulls them, then
 steps). The TPU-idiomatic form is an ``optax.GradientTransformation``
 wrapper whose ``update`` runs **inside the user's shard_map/pmap'd train
-step**: gradients are flattened, concatenated, partitioned into
+step**. Raw gradients are aggregated in BUCKETS of whole leaves of
+``BYTEPS_PARTITION_BYTES`` each, one all-reduce a bucket, chained in the
+order the backward yields them (:func:`_aggregate_buckets`); compressed
+gradients are flattened, concatenated, partitioned into
 ``BYTEPS_PARTITION_BYTES`` chunks (declaration = pytree order, so chunk
 issue order preserves the reference's priority semantics), and each chunk is
-aggregated with a psum or the compressed collective. Error-feedback and
+aggregated with the compressed collective. Error-feedback and
 Nesterov-momentum state live in the optimizer state pytree (per-device,
 sharded over dp — each device is a "worker" with its own residual), which is
 the pure-functional replacement for the reference's C++ side buffers.
@@ -15,21 +18,27 @@ the pure-functional replacement for the reference's C++ side buffers.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.extend.core import Var
 
 from byteps_tpu.common.config import get_config
+from byteps_tpu.common.logging import get_logger
 from byteps_tpu.comm.ici import (
     compressed_allreduce_local,
     compressed_reduce_scatter_local,
 )
 from byteps_tpu.compression import from_params
 from byteps_tpu.compression.error_feedback import CompressionSpec, momentum_step
+
+log = get_logger("jax.optimizer")
 
 
 def _flatten_concat(tree):
@@ -71,16 +80,19 @@ def _aggregate_flat(
     two_way: bool,
     chunk_id_offset: int = 0,
 ):
-    """Chunk a flat fp32 grad vector and aggregate each chunk over ``axis``.
+    """Chunk a flat fp32 grad vector and aggregate each chunk over ``axis``
+    with the compressed collective (``spec.enabled``; raw gradients never
+    take a flat form, see :func:`_aggregate_buckets`).
 
     Returns ``(agg_flat, new_ef_flat_or_None, num_chunks)``. The chunking is
     the reference's tensor partitioning (BYTEPS_PARTITION_BYTES,
-    operations.cc); under jit the chunk collectives are issued in order and
-    XLA overlaps them with surrounding compute.
+    operations.cc): a chunk is the codec's wire contract. The chunks all
+    depend on the whole flat vector, so none of them leaves before the
+    backward has ended.
     """
     total = flat.shape[0]
     bounds = _chunk_bounds(total, chunk_elems)
-    if spec.enabled and rng is None:
+    if rng is None:
         if spec.compressor.stochastic:
             raise ValueError(
                 f"{spec.compressor.name} requires an rng that advances "
@@ -122,7 +134,7 @@ def _aggregate_flat(
     group = int(os.environ.get("BYTEPS_COMPRESS_BATCH_CHUNKS", "1"))
     nfull = total // chunk_elems
     pre_added = False
-    if spec.enabled and nfull > 1 and group > 1:
+    if nfull > 1 and group > 1:
         # The EF add IS hoisted to ONE whole-flat pass here (the tail
         # chunks below then slice the pre-added flat and ask only for
         # the residual back — compressed_allreduce_local's documented
@@ -178,32 +190,24 @@ def _aggregate_flat(
 
     for ci, (off, ln) in enumerate(bounds, start=ci0):
         g = jax.lax.slice_in_dim(flat, off, off + ln)
-        if spec.enabled:
-            crng = jax.random.fold_in(rng, chunk_id_offset + ci)
-            if pre_added:
-                # flat already carries the residual (hoisted add above)
-                out, ne = compressed_allreduce_local(
-                    g, crng, spec.compressor, axis, n,
-                    average=average, two_way=two_way,
-                    return_residual=True,
-                )
-                new_e_chunks.append(ne)
-            else:
-                e = (
-                    jax.lax.slice_in_dim(ef_flat, off, off + ln)
-                    if ef_flat is not None
-                    else None
-                )
-                out, ne = one_chunk(g, crng, e)
-                if e is not None:
-                    new_e_chunks.append(ne)
+        crng = jax.random.fold_in(rng, chunk_id_offset + ci)
+        if pre_added:
+            # flat already carries the residual (hoisted add above)
+            out, ne = compressed_allreduce_local(
+                g, crng, spec.compressor, axis, n,
+                average=average, two_way=two_way,
+                return_residual=True,
+            )
+            new_e_chunks.append(ne)
         else:
-            s = jax.lax.psum(g, axis)
-            out = s / n if average else s
-            if new_e_chunks is not None:
-                # residual contract is fp32 regardless of the aggregation
-                # dtype (g may be bf16 under BYTEPS_REDUCE_DTYPE)
-                new_e_chunks.append(jnp.zeros(g.shape, jnp.float32))
+            e = (
+                jax.lax.slice_in_dim(ef_flat, off, off + ln)
+                if ef_flat is not None
+                else None
+            )
+            out, ne = one_chunk(g, crng, e)
+            if e is not None:
+                new_e_chunks.append(ne)
         out_chunks.append(out)
     agg = out_chunks[0] if len(out_chunks) == 1 else jnp.concatenate(out_chunks)
     new_e = None
@@ -213,6 +217,10 @@ def _aggregate_flat(
             else jnp.concatenate(new_e_chunks)
         )
     return agg, new_e, len(bounds) + ci0
+
+
+def _vma(x) -> frozenset:
+    return frozenset(getattr(jax.typeof(x), "vma", ()) or ())
 
 
 def _vma_groups(leaves):
@@ -226,9 +234,186 @@ def _vma_groups(leaves):
     """
     groups: Dict[frozenset, list] = {}
     for i, l in enumerate(leaves):
-        key = frozenset(getattr(jax.typeof(l), "vma", ()) or ())
-        groups.setdefault(key, []).append(i)
+        groups.setdefault(_vma(l), []).append(i)
     return list(groups.values())
+
+
+def _leaf_bytes(leaf, dtype) -> int:
+    return int(np.prod(leaf.shape, dtype=np.int64)) * jnp.dtype(dtype).itemsize
+
+
+def plan_buckets(leaves, partition_bytes: int, acc_dtype, order=None):
+    """The raw path's plan: lists of leaf indices, one list a bucket, in
+    the order their all-reduces are chained.
+
+    A bucket is whole leaves of one VMA group, walked in tree order; it
+    closes once it holds ``partition_bytes`` (counted in the reduce dtype).
+    A leaf is never split: inside one XLA program nothing pre-empts a
+    transfer for a partition to protect, and the all-reduce pipelines its
+    own pieces. ``order[i]`` is the place of leaf ``i`` in the backward
+    (:func:`value_and_grad_in_order`); a bucket is ready when its
+    last-produced leaf is, and the buckets go in that order. With no
+    backward in sight the order is the reversed tree order, which is the
+    backward's for a tree laid out as the forward reads it.
+    """
+    if order is None:
+        order = range(len(leaves) - 1, -1, -1)
+    buckets = []
+    for idxs in _vma_groups(leaves):
+        cur, held = [], 0
+        for i in idxs:
+            cur.append(i)
+            held += _leaf_bytes(leaves[i], acc_dtype)
+            if held >= partition_bytes:
+                buckets.append(cur)
+                cur, held = [], 0
+        if cur:
+            buckets.append(cur)
+    # stable: buckets ready at the same place keep their tree order
+    buckets.sort(key=lambda b: max(order[i] for i in b))
+    return buckets
+
+
+def _aggregate_buckets(leaves, buckets, axis, n: int, average: bool,
+                       acc_dtype):
+    """One all-reduce a bucket, each chained to the one before.
+
+    Independent all-reduces are merged by XLA's combiner into a few tuple
+    all-reduces that wait for the whole backward, every gradient live until
+    then. The chain is a data dependency the combiner respects: bucket
+    ``k + 1``'s gradients pass an ``optimization_barrier`` together with
+    bucket ``k``'s sums, so all-reduce ``k + 1`` follows all-reduce ``k``,
+    a bucket's leaves (jax binds one ``psum`` a leaf) are the only ones it
+    merges, and each all-reduce sits between the backward kernels that
+    yield its leaves. They stay SYNCHRONOUS: the wire is not hidden, but a
+    gradient is summed, and its buffer free for the update, soon after it
+    is made (v5e, PR 49: 2 ms of a 217-ms GPT-2-medium step against the
+    same buckets unchained; the TPU compiler's async all-reduce options hid
+    4 ms of wire for 5 ms of slower products and 1.4 GiB: not passed). The
+    ``/ n`` and the cast back are outside the chain, where they fuse into
+    what reads them.
+    """
+    sums = list(leaves)
+    after = {}      # VMA type -> the sums of the last bucket of that type
+    for b in buckets:
+        gs = [leaves[i] for i in b]
+        # a barrier gives every operand the union of their types, so the
+        # chain runs inside a VMA group (one group on a dp-only mesh and
+        # wherever check_vma is off), and only the gradients' side of it
+        # is read: the sums keep theirs, replicated over the axis
+        vma = _vma(gs[0])
+        if vma in after:
+            _, gs = jax.lax.optimization_barrier((after[vma], gs))
+        after[vma] = jax.lax.psum([g.astype(acc_dtype) for g in gs], axis)
+        for i, x in zip(b, after[vma]):
+            sums[i] = x
+    return [
+        (x / n if average else x).astype(leaf.dtype)
+        for x, leaf in zip(sums, leaves)
+    ]
+
+
+def value_and_grad_in_order(vag, *args):
+    """``vag(*args)`` traced once, and where the backward yields each leaf.
+
+    ``vag`` is a ``jax.value_and_grad`` (or anything returning ``(out,
+    grads)``). Returns ``(out, grads, order)``: ``order[i]`` is the index,
+    in the jaxpr of ``vag``, of the equation whose result is gradient leaf
+    ``i`` (``-1`` for a leaf that is an input or a constant). The jaxpr
+    that was inspected is the one evaluated, so the backward is traced
+    once. The order is the program's own: a tied embedding's gradient is
+    complete only after the embedding's backward, whatever its key says.
+    """
+    closed, shape = jax.make_jaxpr(vag, return_shape=True)(*args)
+    n_grads = len(jax.tree.leaves(shape[1]))
+    made_at = {}
+    for at, eqn in enumerate(closed.jaxpr.eqns):
+        for v in eqn.outvars:
+            made_at[v] = at
+    order = [
+        made_at.get(v, -1) if isinstance(v, Var) else -1
+        for v in closed.jaxpr.outvars[-n_grads:]
+    ] if n_grads else []
+    flat = jax.core.eval_jaxpr(
+        closed.jaxpr, closed.consts, *jax.tree.leaves(args))
+    out, grads = jax.tree.unflatten(jax.tree.structure(shape), flat)
+    return out, grads, order
+
+
+_TRACING = threading.local()
+
+
+@contextlib.contextmanager
+def backward_order(order):
+    """While the block is traced, the raw aggregation chains its buckets in
+    ``order`` (of :func:`value_and_grad_in_order`; ``None`` changes
+    nothing). Trace-time state of the calling thread, like
+    ``jax.named_scope``: the order belongs to the gradients of ONE traced
+    backward and reaches ``tx.update`` without a place in optax's
+    signature."""
+    was = getattr(_TRACING, "order", None)
+    _TRACING.order = order
+    try:
+        yield
+    finally:
+        _TRACING.order = was
+
+
+def _current_order(n_leaves: int):
+    order = getattr(_TRACING, "order", None)
+    if order is not None and len(order) != n_leaves:
+        raise ValueError(
+            f"backward_order holds {len(order)} places, the gradients "
+            f"being aggregated have {n_leaves} leaves: the order is of "
+            "another tree")
+    return order
+
+
+def _top_key(path) -> str:
+    """A leaf's top-level key, with the index below it where that is a
+    list's (``blocks[3]``)."""
+    k = path[0]
+    top = str(getattr(k, "key", getattr(k, "idx", getattr(k, "name", k))))
+    if len(path) > 1 and hasattr(path[1], "idx"):
+        top += f"[{path[1].idx}]"
+    return top
+
+
+def _push_pull_raw(grads, axis, n: int, average: bool, partition_bytes: int):
+    """The raw path of :func:`push_pull_inside` for ``n > 1``: returns the
+    aggregated tree and what the ``fused_step`` event says of the plan."""
+    # BYTEPS_REDUCE_DTYPE: the aggregation dtype of the raw psums —
+    # bfloat16 halves the ICI bytes (a bucket still closes at
+    # partition_bytes, so half as many buckets) at reduced summation
+    # precision (the reference PS always sums fp32; a TPU-only lever)
+    acc_dtype = jnp.dtype(get_config().reduce_dtype)
+    paths, treedef = jax.tree_util.tree_flatten_with_path(grads)
+    leaves = [leaf for _, leaf in paths]
+    buckets = plan_buckets(leaves, partition_bytes, acc_dtype,
+                           _current_order(len(leaves)))
+    plan = dict(
+        buckets=len(buckets),
+        bucket_bytes_max=max(
+            sum(_leaf_bytes(leaves[i], acc_dtype) for i in b)
+            for b in buckets),
+        chained=len(buckets) > 1,
+    )
+    # once a trace: the chain by top-level key, so that a reader sees what
+    # leaves last (GPT-2's tied wte); equal neighbours are counted
+    runs = []
+    for b in buckets:
+        name = "+".join(dict.fromkeys(_top_key(paths[i][0]) for i in b))
+        if runs and runs[-1][0] == name:
+            runs[-1][1] += 1
+        else:
+            runs.append([name, 1])
+    log.info(
+        "raw aggregation over %s: %d buckets of whole leaves (largest %d "
+        "bytes in %s), chained: %s", axis, plan["buckets"],
+        plan["bucket_bytes_max"], acc_dtype.name,
+        " > ".join(nm if k == 1 else f"{nm} x{k}" for nm, k in runs))
+    agg = _aggregate_buckets(leaves, buckets, axis, n, average, acc_dtype)
+    return jax.tree.unflatten(treedef, agg), plan
 
 
 def push_pull_inside(
@@ -249,8 +434,15 @@ def push_pull_inside(
     fp32 vector of the total parameter count, laid out in VMA-group order —
     treat it as opaque state).
 
-    This is the fused analog of per-tensor ``push_pull`` calls: one trace,
-    chunked collectives in declaration order, XLA overlaps them.
+    This is the fused analog of per-tensor ``push_pull`` calls, in one
+    trace. Raw gradients go in buckets of whole leaves, one all-reduce a
+    bucket, chained in the backward's order (what
+    :func:`value_and_grad_in_order` read and :func:`backward_order` holds
+    while the step is traced; reversed tree order without it), so a
+    bucket is summed among the backward kernels that follow it, not after
+    the last of them; no flat vector is built. Compressed gradients are
+    raveled into one flat vector and chunked: every chunk waits for the
+    whole backward.
     """
     cfg = get_config()
     axis = axis or cfg.dp_axis
@@ -268,18 +460,18 @@ def push_pull_inside(
             return grads, jnp.zeros_like(ef_residual)
         return grads
     partition_bytes = partition_bytes or cfg.partition_bytes
-    # BYTEPS_REDUCE_DTYPE: the aggregation dtype for uncompressed psums —
-    # bfloat16 halves TOTAL ICI bytes (chunks still carry partition_bytes
-    # each, so half as many chunks) at reduced summation precision (the
-    # reference PS always sums fp32; this is a TPU-only lever).
-    # Compression requires fp32 (kernel contract), and the EF residual
-    # stays fp32 either way.
-    acc_dtype = jnp.dtype(
-        "float32" if spec.enabled else cfg.reduce_dtype
-    )
-    chunk_elems = max(1, partition_bytes // acc_dtype.itemsize)
+    if not spec.enabled:
+        agg_tree, _ = _push_pull_raw(grads, axis, n, average,
+                                     partition_bytes)
+        if ef_residual is not None:
+            # nothing was compressed, so no error is carried forward
+            return agg_tree, jnp.zeros_like(ef_residual)
+        return agg_tree
 
     leaves, treedef = jax.tree.flatten(grads)
+    # compression requires fp32 (kernel contract), and so is the residual
+    acc_dtype = jnp.dtype("float32")
+    chunk_elems = max(1, partition_bytes // acc_dtype.itemsize)
     out_leaves = [None] * len(leaves)
     groups = _vma_groups(leaves)
     ef_off = 0
@@ -619,7 +811,13 @@ def DistributedOptimizer(
             else:
                 grads_in = grads
 
-            if spec.enabled and state.ef is not None:
+            plan = {}
+            if not spec.enabled and agg_n > 1:
+                agg, plan = _push_pull_raw(
+                    grads_in, agg_axis, agg_n, average,
+                    partition_bytes or cfg.partition_bytes)
+                new_ef = state.ef
+            elif spec.enabled and state.ef is not None:
                 agg, new_ef = push_pull_inside(
                     grads_in, agg_axis, agg_n, average, spec, rng,
                     ef_residual=state.ef, partition_bytes=partition_bytes,
@@ -646,7 +844,7 @@ def DistributedOptimizer(
             nchunks = -(-total * itemsize // pb)
             jax.debug.callback(
                 _fused_trace_callback, state.count,
-                total_elems=total, chunks=nchunks,
+                total_elems=total, chunks=nchunks, **plan,
             )
 
         with jax.named_scope("optimizer_update"):
@@ -662,12 +860,12 @@ def DistributedOptimizer(
     return optax.GradientTransformation(init_fn, update_fn)
 
 
-def _fused_trace_callback(count, total_elems: int, chunks: int) -> None:
+def _fused_trace_callback(count, **plan) -> None:
+    """``total_elems`` and ``chunks``; on the raw path also ``buckets``,
+    ``bucket_bytes_max`` and ``chained`` (docs/timeline.md)."""
     from byteps_tpu.common.tracing import get_tracer
 
-    get_tracer().fused_step(
-        int(count), {"total_elems": int(total_elems), "chunks": int(chunks)}
-    )
+    get_tracer().fused_step(int(count), {k: int(v) for k, v in plan.items()})
 
 
 def dp_state_specs(axis: Optional[str] = None,

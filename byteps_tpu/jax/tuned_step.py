@@ -9,8 +9,11 @@ machinery: it keeps one jitted executable per visited partition size
 (compiles are cached, the tuner's grid is small), times each step, feeds
 the tuner, and swaps executables when the tuner moves.
 
-Credit is not a fused-path knob — XLA schedules chunk-collective overlap
-itself — so the tuner searches ``knobs=("partition",)``.
+Credit is not a fused-path knob — inside one XLA program the compiler
+orders the collectives (the raw path chains its buckets behind the
+backward kernels that yield them; none of them is hidden under compute,
+``jax/optimizer.py::_aggregate_buckets``) — so the tuner searches
+``knobs=("partition",)``: on the raw path the size a bucket closes at.
 """
 
 from __future__ import annotations

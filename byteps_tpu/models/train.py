@@ -4,7 +4,8 @@ Builds full jitted train steps over a (dp, tp, sp) mesh: per-device
 loss+grad via ``shard_map`` (ring attention over sp, Megatron collectives
 over tp inside the models), and BytePS aggregation over dp through
 ``DistributedOptimizer`` (reference hot path, SURVEY §3.2 — here fused into
-one XLA program so chunk collectives overlap backward compute).
+one XLA program: raw gradients are all-reduced in buckets chained in the
+order the backward yields them, ``_vag_in_order`` / ``_update`` below).
 
 VMA notes (apply to every factory): per-device AD is exact under
 ``check_vma=True`` — replicated params' cotangents get their sp/tp psums
@@ -36,7 +37,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from byteps_tpu.common.flight_recorder import get_flight_recorder
 from byteps_tpu.common.tracing import get_tracer
-from byteps_tpu.jax.optimizer import DistributedOptimizer, dp_state_specs
+from byteps_tpu.jax.optimizer import (
+    DistributedOptimizer,
+    backward_order,
+    dp_state_specs,
+    value_and_grad_in_order,
+)
 from byteps_tpu.models.bert import BertConfig, bert_init, bert_mlm_loss
 from byteps_tpu.models.gpt import (
     GPTConfig,
@@ -491,13 +497,14 @@ def _build_pp_jit(mesh, pspecs, ospecs, batch_spec, loss_fn, tx, dp, pp,
     VMA-collapsed loss reporting. ``use_vma=False`` is the compressed /
     ZeRO mode (their collectives defeat VMA's replication analysis)."""
     resym = _make_resymmetrize(pspecs, dp, slc)
+    chains = _chains(mesh, use_vma, slc, dp)
 
     def per_device_step(params, opt_state, tokens, targets):
         grad_params = _pcast_dp(params, dp, mesh, use_vma, slc)
         # loss_fn returns the last-stage-masked loss: grading through an
         # already-replicated psum double-counts (psum transpose)
-        loss, grads = jax.value_and_grad(loss_fn)(
-            grad_params, tokens, targets
+        loss, grads, order = _vag_in_order(
+            jax.value_and_grad(loss_fn), chains, grad_params, tokens, targets
         )
         loss = jax.lax.psum(loss, pp)  # replicate for reporting
         if use_vma:
@@ -511,7 +518,7 @@ def _build_pp_jit(mesh, pspecs, ospecs, batch_spec, loss_fn, tx, dp, pp,
             grads = jax.tree.map(lambda g: g / ep_size, grads)
         grads = resym(grads)  # collapse conservative VMA widening (no-op
         # without VMA types, as is _collapse_vma below)
-        updates, opt_state = tx.update(grads, opt_state, params)
+        updates, opt_state = _update(tx, order, grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         if mean_axes:
             loss = jax.lax.pmean(loss, mean_axes)
@@ -538,6 +545,41 @@ def _pcast_dp(params, dp, mesh, use_vma, slc=None):
         return jax.tree.map(lambda x: jax.lax.pcast(x, axes, to="varying"),
                             params)
     return params
+
+
+def _chains(mesh, raw: bool, *axes) -> Optional[dict]:
+    """Where this step all-reduces RAW gradients over a data axis larger
+    than one the optimizer chains its buckets, and in what order is worth
+    reading: an empty memo for :func:`_vag_in_order` to keep the order in.
+    Elsewhere None (compressed and ZeRO-1 gradients travel as one flat
+    vector; a single worker aggregates nothing)."""
+    if raw and any(a is not None and mesh.shape[a] > 1 for a in axes):
+        return {}
+    return None
+
+
+def _vag_in_order(vag, chains: Optional[dict], *args):
+    """``(out, grads, order)`` of ``vag(*args)``: where the step chains its
+    buckets (``chains`` is its memo), ``order`` is the place the traced
+    backward yields each leaf at (:func:`value_and_grad_in_order`, which
+    traces the backward once and evaluates what it traced). A step is
+    traced again for its second call and for every size the tuner visits:
+    the same shapes give the same order, read once. Elsewhere ``vag`` is
+    called as ever, so a one-chip step's program text stays what it was,
+    and ``order`` is None."""
+    if chains is None:
+        return (*vag(*args), None)
+    key = tuple((l.shape, str(l.dtype)) for l in jax.tree.leaves(args))
+    if key in chains:
+        return (*vag(*args), chains[key])
+    out, grads, chains[key] = value_and_grad_in_order(vag, *args)
+    return out, grads, chains[key]
+
+
+def _update(tx, order, grads, opt_state, params):
+    """``tx.update`` traced under the backward's order."""
+    with backward_order(order):
+        return tx.update(grads, opt_state, params)
 
 
 def make_gpt_train_step(
@@ -621,6 +663,7 @@ def make_gpt_train_step(
     batch_spec = part.batch_spec()
     mean_axes = tuple(a for a in (slc, dp) if a is not None)
     resym = _make_resymmetrize(pspecs, dp, slc)
+    chains = _chains(mesh, use_vma, slc, dp)
 
     # Grad loss is dp-LOCAL (dp_axis=None): each dp replica is one reference
     # worker computing the grad of its own local mean loss; averaging across
@@ -639,12 +682,13 @@ def make_gpt_train_step(
 
         def per_device_step(params, opt_state, tokens, targets):
             grad_params = _pcast_dp(params, dp, mesh, use_vma, slc)
-            loss, grads = vag(grad_params, tokens, targets)
+            loss, grads, order = _vag_in_order(
+                vag, chains, grad_params, tokens, targets)
             if use_vma:
                 grads = resym(grads)
             else:
                 grads = _novma_collective_fix(grads, pspecs, mesh, (tp, sp))
-            updates, opt_state = tx.update(grads, opt_state, params)
+            updates, opt_state = _update(tx, order, grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             if mean_axes:
                 loss = jax.lax.pmean(loss, mean_axes)  # global mean loss
@@ -750,6 +794,7 @@ def make_gpt_lora_train_step(
     batch_spec = part.batch_spec()
     mean_axes = tuple(a for a in (slc, dp) if a is not None)
     resym = _make_resymmetrize(aspecs, dp, slc)
+    chains = _chains(mesh, use_vma, slc, dp)
 
     def loss_fn(adapters, base, tokens, targets_):
         grafted = graft_lora(base, adapters, scale)
@@ -768,12 +813,13 @@ def make_gpt_lora_train_step(
                 lambda a, tok, tgt: loss_fn(a, base, tok, tgt),
                 accum_steps)
             grad_adapters = _pcast_dp(adapters, dp, mesh, use_vma, slc)
-            loss, grads = vag(grad_adapters, tokens, targets_)
+            loss, grads, order = _vag_in_order(
+                vag, chains, grad_adapters, tokens, targets_)
             if use_vma:
                 grads = resym(grads)
             else:
                 grads = _novma_collective_fix(grads, aspecs, mesh, (tp, sp))
-            updates, opt_state = tx.update(grads, opt_state, adapters)
+            updates, opt_state = _update(tx, order, grads, opt_state, adapters)
             adapters = optax.apply_updates(adapters, updates)
             if mean_axes:
                 loss = jax.lax.pmean(loss, mean_axes)
@@ -984,6 +1030,7 @@ def make_gpt_moe_train_step(
         params = trainable
     batch_spec = part.batch_spec()
     resym = _make_resymmetrize(pspecs, dp, slc)
+    chains = _chains(mesh, use_vma, slc, dp)
     loss_fn = functools.partial(model_loss, cfg=cfg, ep_axis=ep,
                                 tp_axis=tp, sp_axis=sp, remat=remat,
                                 seq_layout=seq_layout,
@@ -996,10 +1043,10 @@ def make_gpt_moe_train_step(
         def per_device_step(all_params, opt_state, tokens, targets):
             params = _drop_buffers(all_params, buffer_keys)
             grad_params = _pcast_dp(params, dp, mesh, use_vma, slc)
-            out, grads = jax.value_and_grad(
+            out, grads, order = _vag_in_order(jax.value_and_grad(
                 lambda p: loss_fn(_with_buffers(all_params, p), tokens,
                                   targets), has_aux=bool(stats_names)
-            )(grad_params)
+            ), chains, grad_params)
             loss, (stats, buffer_aux) = (out if stats_names
                                          else (out, (None, None)))
             if not use_vma:
@@ -1015,7 +1062,7 @@ def make_gpt_moe_train_step(
                 # gives means
                 grads = jax.tree.map(lambda g: g / ep_size, grads)
             grads = resym(grads)  # collapse conservative VMA widening
-            updates, opt_state = tx.update(grads, opt_state, params)
+            updates, opt_state = _update(tx, order, grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             axes = tuple(a for a in (slc, dp, ep) if a is not None)
             if axes:
@@ -1218,6 +1265,7 @@ def make_bert_train_step(
     batch_spec = part.batch_spec()
     mean_axes = tuple(a for a in (slc, dp) if a is not None)
     resym = _make_resymmetrize(pspecs, dp, slc)
+    chains = _chains(mesh, use_vma, slc, dp)
     loss_fn = functools.partial(
         bert_mlm_loss, cfg=cfg, dp_axis=None, tp_axis=tp, sp_axis=sp,
         remat=remat, chunked_ce=chunked_ce,
@@ -1240,12 +1288,13 @@ def make_bert_train_step(
 
         def per_device_step(params, opt_state, tokens, targets, mask):
             grad_params = _pcast_dp(params, dp, mesh, use_vma, slc)
-            loss, grads = vag(grad_params, tokens, targets, mask)
+            loss, grads, order = _vag_in_order(
+                vag, chains, grad_params, tokens, targets, mask)
             if use_vma:
                 grads = resym(grads)
             else:
                 grads = _novma_collective_fix(grads, pspecs, mesh, (tp, sp))
-            updates, opt_state = tx.update(grads, opt_state, params)
+            updates, opt_state = _update(tx, order, grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             if mean_axes:
                 loss = jax.lax.pmean(loss, mean_axes)
@@ -1302,6 +1351,7 @@ def make_t5_train_step(
     batch_spec = part.batch_spec()
     mean_axes = tuple(a for a in (slc, dp) if a is not None)
     resym = _make_resymmetrize(pspecs, dp, slc)
+    chains = _chains(mesh, use_vma, slc, dp)
     loss_fn = functools.partial(
         t5_loss, cfg=cfg, dp_axis=None, tp_axis=tp, sp_axis=sp, remat=remat,
         chunked_ce=chunked_ce,
@@ -1314,12 +1364,13 @@ def make_t5_train_step(
 
         def per_device_step(params, opt_state, src, tgt_in, tgt_out):
             grad_params = _pcast_dp(params, dp, mesh, use_vma, slc)
-            loss, grads = vag(grad_params, src, tgt_in, tgt_out)
+            loss, grads, order = _vag_in_order(
+                vag, chains, grad_params, src, tgt_in, tgt_out)
             if use_vma:
                 grads = resym(grads)
             else:
                 grads = _novma_collective_fix(grads, pspecs, mesh, (tp, sp))
-            updates, opt_state = tx.update(grads, opt_state, params)
+            updates, opt_state = _update(tx, order, grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             if mean_axes:
                 loss = jax.lax.pmean(loss, mean_axes)
@@ -1372,6 +1423,7 @@ def make_vit_train_step(
     batch_spec = part.batch_spec()
     mean_axes = tuple(a for a in (slc, dp) if a is not None)
     resym = _make_resymmetrize(pspecs, dp, slc)
+    chains = _chains(mesh, use_vma, slc, dp)
     loss_fn = functools.partial(
         vit_loss, cfg=cfg, dp_axis=None, tp_axis=tp, remat=remat,
     )
@@ -1383,12 +1435,13 @@ def make_vit_train_step(
 
         def per_device_step(params, opt_state, images, labels):
             grad_params = _pcast_dp(params, dp, mesh, use_vma, slc)
-            loss, grads = vag(grad_params, images, labels)
+            loss, grads, order = _vag_in_order(
+                vag, chains, grad_params, images, labels)
             if use_vma:
                 grads = resym(grads)
             else:
                 grads = _novma_collective_fix(grads, pspecs, mesh, (tp,))
-            updates, opt_state = tx.update(grads, opt_state, params)
+            updates, opt_state = _update(tx, order, grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             if mean_axes:
                 loss = jax.lax.pmean(loss, mean_axes)
@@ -1446,6 +1499,7 @@ def make_resnet_train_step(
     # SyncBN statistics sync over every data axis (slice_ and dp)
     bn_axes = mean_axes if mean_axes else None
     resym = _make_resymmetrize(pspecs, dp, slc)
+    chains = _chains(mesh, use_vma, slc, dp)
 
     def loss_fn(params, bn_state, images, labels):
         return resnet_loss(params, bn_state, images, labels, cfg,
@@ -1457,7 +1511,8 @@ def make_resnet_train_step(
 
         def per_device_step(params, opt_state, bn_state, images, labels):
             grad_params = _pcast_dp(params, dp, mesh, use_vma, slc)
-            (loss, new_bn), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            (loss, new_bn), grads, order = _vag_in_order(
+                jax.value_and_grad(loss_fn, has_aux=True), chains,
                 grad_params, bn_state, images, labels
             )
             if use_vma:
@@ -1465,7 +1520,7 @@ def make_resnet_train_step(
                 # SyncBN pmean makes stats unvarying, but conservative VMA
                 # can widen the state type the same way it widens grads
                 new_bn = jax.tree.map(_collapse_vma, new_bn)
-            updates, opt_state = tx.update(grads, opt_state, params)
+            updates, opt_state = _update(tx, order, grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             if mean_axes:
                 loss = jax.lax.pmean(loss, mean_axes)

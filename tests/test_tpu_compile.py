@@ -851,3 +851,72 @@ def test_readout_backward_keeps_rows_where_they_are_shorter(topo):
     the row loop stays."""
     hlo = _readout_loss_and_grads(topo, 4, 4096, 2048, 16256)
     assert "readout_ce.bwd_rows" in hlo and "readout_ce.bwd_vocab" not in hlo
+
+
+# ---- the raw gradient aggregation across four chips --------------------------
+def _chained_aggregation_hlo(topo, chained: bool):
+    """A two-layer tied backward at width 2,048 and its dp=4 aggregation,
+    compiled for the 2x2 host from shapes; returns the entry computation's
+    instruction names in scheduled order."""
+    from byteps_tpu.jax.optimizer import (
+        backward_order, push_pull_inside, value_and_grad_in_order)
+
+    d, rows = 2048, 1024
+    mesh = Mesh(topo.devices, ("dp",))
+
+    def loss(p, x):
+        h = x @ p["emb"]
+        for blk in p["blocks"]:
+            h = jnp.tanh(h @ blk["w"] + blk["b"])
+        return jnp.mean((h @ p["emb"].T) ** 2)       # the tied readout
+
+    def per_device(p, x):
+        p = jax.tree.map(lambda l: jax.lax.pcast(l, ("dp",), to="varying"), p)
+        out, grads, order = value_and_grad_in_order(
+            jax.value_and_grad(loss), p, x)
+        with backward_order(order if chained else None):
+            agg = push_pull_inside(grads, axis="dp", n=4)
+        return jax.lax.pmean(out, "dp"), agg
+
+    rep, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    params = {"emb": _sds_on((d, d), F32, rep),
+              "blocks": [{"w": _sds_on((d, d), F32, rep),
+                          "b": _sds_on((d,), F32, rep)} for _ in range(2)]}
+    fn = jax.jit(jax.shard_map(per_device, mesh=mesh, in_specs=(P(), P("dp")),
+                               out_specs=(P(), P())))
+    hlo = fn.lower(params, _sds_on((4 * rows, d), F32, split)) \
+        .compile().as_text()
+    entry = hlo[hlo.index("\nENTRY "):]
+    return re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(",
+                      entry, re.M)
+
+
+def _sds_on(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _gradient_all_reduces(ops):
+    return [typ for _, typ, op in ops
+            if op == "all-reduce" and "2048,2048" in typ]
+
+
+def test_chained_buckets_stay_apart_and_the_tied_leaf_goes_last(
+        topo, monkeypatch):
+    """dp=4 on the described 2x2 host: the three 16.8 MB buckets (a block's
+    b + w twice, the tied emb) stay three all-reduces — the combiner does
+    not merge a chain — in the order the traced backward yields them, emb
+    last; by reversed tree order emb would go first and every bucket wait
+    behind it; without the barrier the combiner makes ONE tuple all-reduce
+    of every leaf, behind the whole backward. No compile option is passed:
+    the TPU compiler's async all-reduce options hid nothing net on the chip
+    (PERF.md §6, PR 49)."""
+    def has_bias(typ):          # a block's bucket; emb has none beside it
+        return "f32[2048]{" in typ
+
+    in_order = _gradient_all_reduces(_chained_aggregation_hlo(topo, True))
+    assert [has_bias(t) for t in in_order] == [True, True, False], in_order
+    reversed_tree = _gradient_all_reduces(_chained_aggregation_hlo(topo, False))
+    assert [has_bias(t) for t in reversed_tree] == [False, True, True]
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    merged = _gradient_all_reduces(_chained_aggregation_hlo(topo, True))
+    assert len(merged) == 1 and merged[0].count("f32[2048,2048]") == 3, merged

@@ -111,3 +111,9 @@ def test_mnist_example_fused_trace(tmp_path):
     assert len(fused) >= 4, doc["traceEvents"][:5]
     steps = {e["name"] for e in fused}
     assert "step2" in steps, steps
+    # the raw path says what it aggregated: whole-leaf buckets (PR 49)
+    for e in fused:
+        assert {"total_elems", "chunks", "buckets", "bucket_bytes_max",
+                "chained"} <= set(e["args"]), e
+        assert e["args"]["buckets"] >= 1
+        assert e["args"]["chained"] == int(e["args"]["buckets"] > 1)
