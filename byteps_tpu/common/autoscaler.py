@@ -158,8 +158,8 @@ class ScalingPolicy:
         self._up_streak = 0
         self._down_streak = 0
         self._straggler_streak = 0
-        # full decision history — what the deterministic-trace pin and
-        # the churn bench artifact read back
+        # full decision history — what the deterministic-trace pin
+        # (tests/test_join.py) reads back
         self.trace: List[Decision] = []
         self._m_hold = get_registry().counter(
             f"autoscaler.{domain}.hold")

@@ -5,7 +5,7 @@ process pays that unless jax's persistent cache is on. The cache's path is
 part of its key, so it must not move: ``JAX_COMPILATION_CACHE_DIR`` when
 the caller set it (jax reads the variable itself — no other path is set
 in code), else ``<checkout>/.jax_cache``, fixed by this file's location.
-Entry points (``chip_smoke.py``, ``bench.py``, the example scripts) call
+Entry points (``chip_smoke.py``, ``benchmark/``, the example scripts) call
 :func:`enable_compile_cache` once, before their first compile.
 """
 

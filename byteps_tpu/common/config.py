@@ -112,7 +112,7 @@ class Config:
     # > 0 paces every PSWorker's wire payload bytes through per-direction
     # token buckets at this many megabits/s, so loopback behaves like a
     # slow cross-pod link (the regime gradient compression exists for).
-    # 0 disables. See server/pacer.py and bench.py --mode throttled.
+    # 0 disables. See server/pacer.py and tests/test_throttled_dcn.py.
     dcn_throttle_mbps: float = 0.0
     # Sharded-wire hierarchical DCN tier (BytePS "use every link", OSDI'20
     # §hierarchical): the hybrid pipeline reduce-SCATTERs the pod instead
@@ -125,8 +125,8 @@ class Config:
     hybrid_sharded: bool = True
     # Controller NICs the pod is modeled with (each its own PSWorker:
     # connections, pacer, fault plan). 1 = the classic single-pusher
-    # wire. > 1 divides per-NIC DCN bytes by the count — the sharded
-    # race bench.py --mode hybrid measures. Deliberately its own knob
+    # wire. > 1 divides per-NIC DCN bytes by the count (byte counts in
+    # tests/test_sharded_hybrid.py). Deliberately its own knob
     # (NOT BYTEPS_LOCAL_SIZE, which counts launcher-spawned processes).
     pod_controllers: int = 1
     # Salt of the partition→owner rendezvous hash (reshuffles placement

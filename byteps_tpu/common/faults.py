@@ -30,7 +30,7 @@ Spec grammar (semicolon-separated rules)::
              # to every worker, so 'worker1:slow@ms=80' makes exactly
              # worker 1 a deterministic straggler (every one of its wire
              # attempts pays 80 ms) while its peers run clean — the
-             # bounded-staleness bench's slow-worker leg; 'replica' /
+             # bounded-staleness smoke's slow worker; 'replica' /
              # 'replica<N>' are the SERVE-tier twins: they match only
              # the serve scheduler's per-iteration intercept (op
              # 'serve'), never wire ops, so one spec string handed to
@@ -67,7 +67,7 @@ Spec grammar (semicolon-separated rules)::
              # + round-watermark adoption) once, when its plan step
              # first enters the window, then the intercepted op
              # proceeds under the adopted membership — the churn
-             # bench/tests schedule deterministic mid-stream joins with
+             # tests schedule deterministic mid-stream joins with
              # 'worker<N>:join@step=A'
     cond   = 'p=' FLOAT          # per-op Bernoulli (seeded RNG)
            | 'op=' A ['..' [B]]  # plan-op window, inclusive; open end ok
@@ -441,7 +441,7 @@ def churn_events(rules: List[FaultRule]) -> List[Tuple[int, int, str]]:
     """The deterministic membership SCHEDULE encoded by a spec's
     worker-scoped ``join``/``kill`` rules: ``[(step, worker_id, kind)]``
     sorted by window start. This is what a churn harness (the
-    ``bench.py --mode chaos`` churn leg, elasticity tests) drives worker
+    elasticity tests, tests/test_join.py) drives worker
     thread start/stop from — the same string each worker's plan parses,
     read once at the orchestration layer."""
     out = [

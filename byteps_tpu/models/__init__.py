@@ -3,7 +3,7 @@
 The reference ships no models of its own (SURVEY §1: "models come from the
 host framework") — its examples train torchvision/keras models. A
 standalone TPU framework needs its own: these functional JAX models are the
-benchmark/bench.py workloads (BASELINE configs: ResNet-50, BERT, GPT-2) and
+benchmark and test workloads (BASELINE configs: ResNet-50, BERT, GPT-2) and
 the flagship for the driver's compile checks.
 """
 

@@ -29,9 +29,9 @@ Two draft strategies:
 * ``make_speculative_generate_fn`` — a draft MODEL (any GPT-family
   config sharing the target's vocabulary, typically distilled/
   shallower). Wall-clock win ≈ f(draft_cost/target_cost, accept rate);
-  with draft == target it measures pure verify overhead (~1×), which
-  is why the bench labels that configuration an overhead probe, not a
-  ceiling.
+  with draft == target it measures pure verify overhead (~1×): that
+  configuration is an overhead probe, not a ceiling (not measured on
+  the chip; no cell).
 * ``make_lookup_generate_fn`` — prompt-lookup drafting (the
   "assisted generation" n-gram trick): propose the K tokens that
   followed the most recent occurrence of the current bigram in the

@@ -43,7 +43,7 @@ fixed-shape cache. This subsystem is the vLLM/Orca-shaped completion:
 Greedy outputs are pinned BIT-identical (token-for-token) to
 single-request ``make_generate_fn`` runs — batching and paging are
 pure throughput levers, never content changes (tests/test_serve.py).
-Measured by ``bench.py --mode serve`` (docs/serving.md).
+Measured by the serve cells of ``BENCHMARK.json`` (docs/serving.md).
 """
 
 from byteps_tpu.serve.adapter_pool import AdapterPool  # noqa: E402,F401

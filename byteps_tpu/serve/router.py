@@ -22,7 +22,7 @@ those semantics over serve replicas:
 Dispatch is least-loaded over the live set. The router is
 single-threaded by design (one ``run()`` loop steps every replica
 round-robin): replica parallelism in a real deployment is process- or
-host-level, and this in-process form is what the bench and the chaos
+host-level, and this in-process form is what the tests and the chaos
 pins drive deterministically.
 
 The router also closes the scale-UP loop (docs/robustness.md §scale-up

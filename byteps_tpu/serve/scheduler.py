@@ -16,8 +16,8 @@ four-phase iteration (``step()``):
    entries to shared read-only pages (committed by earlier prefills),
    CoWs the divergence block when the match ends mid-block, and starts
    chunked prefill at the divergence — the shared chunks are skipped
-   entirely, which is where the shared-prefix TTFT headline comes from
-   (``bench.py --mode serve``, prefix leg).
+   entirely, which is where a shared prefix saves time to first token
+   (streams held bit-identical by tests/test_serve_prefix.py).
 2. **Prefill** — one prompt chunk (``serve_prefill_chunk`` tokens) per
    iteration through the per-request paged prefill, so a long prompt
    interleaves with everyone else's decode steps instead of stalling
@@ -1209,8 +1209,8 @@ class Scheduler:
         # the recompute bill: every committed KV row thrown away here
         # must be re-prefilled on resume (the request's own prefix
         # commits may refund part of it if they survive the pressure
-        # that caused this evict) — the migrate-vs-recompute headline's
-        # "recompute" side (bench.py --mode serve, migrate leg)
+        # that caused this evict) — the migrate-vs-recompute comparison's
+        # "recompute" side (tests/test_serve_disagg.py holds both sides)
         self._m["recompute_tokens"].inc(run.cache_len)
         self._phase(run, "prefill" if run.state == "prefill" else "decode",
                     self._clock(), cut=True)

@@ -310,8 +310,8 @@ class PSWorker:
 
     With ``BYTEPS_DCN_THROTTLE_MBPS`` > 0 (or ``throttle_mbps=``), this
     worker's payload bytes are paced through an emulated full-duplex NIC
-    of that speed (``server/pacer.py``) — the bandwidth-throttled bench
-    and the compression fast-lane regime. The pacer is per-PSWorker, so
+    of that speed (``server/pacer.py``) — the bandwidth-throttled
+    regime of the compression fast lane. The pacer is per-PSWorker, so
     several workers emulated in one process each get their own NIC.
     """
 
@@ -530,7 +530,7 @@ class PSWorker:
                 # deterministic mid-stream admission (worker<N>:join@
                 # step=A): run the kJoin handshake once, then let the
                 # intercepted op proceed under the adopted membership —
-                # the churn bench/tests schedule joins this way
+                # the churn tests schedule joins this way
                 if not self._join_fired:
                     self._join_fired = True
                     self.join()
@@ -1334,7 +1334,7 @@ class PSWorker:
         """Robustness counters (+ per-kind injected counts when a fault
         plan is armed, + the health monitor's last-probe age and
         per-server miss counts so a stall report shows WHY failover did
-        or did not fire) — what the chaos smoke and the bench assert on."""
+        or did not fire) — what the chaos smokes assert on."""
         with self._counter_lock:
             out = dict(self.counters)
         out["live_pods"] = self.live_pods()

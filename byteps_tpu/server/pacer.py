@@ -6,7 +6,7 @@ host exposes only loopback — where raw fp32 trivially beats every codec
 because the "wire" runs at memcpy speed. ``BYTEPS_DCN_THROTTLE_MBPS``
 arms this pacer inside :class:`~byteps_tpu.server.PSWorker` (and therefore
 every consumer of the framed-TCP client path: ``DcnCore``, the jax hybrid
-pipeline, ``bench.py --mode throttled``): payload bytes are charged
+pipeline, tests/test_throttled_dcn.py): payload bytes are charged
 against per-direction token buckets before/after each wire operation, so
 loopback behaves like a NIC of the configured speed — no root, no netem,
 no tc, fully deterministic across hosts.

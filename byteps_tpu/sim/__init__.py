@@ -15,9 +15,9 @@ event rules (:mod:`~byteps_tpu.sim.engine`), and searched
 (:mod:`~byteps_tpu.sim.search`) so the AutoTuner and ScalingPolicy can
 SOLVE for a config instead of sweeping it live.
 
-Validation contract: ``bench.py --mode whatif`` replays one recorded
-leg and must predict the measured medians of the other bench
-configurations within 10% median error (docs/whatif.md).
+Validation: tests/test_sim.py holds determinism and the event rules
+against the real scheduler and pacer; no prediction is held against a
+measured run since PR 50 (docs/whatif.md; ROADMAP.md C9).
 """
 
 from byteps_tpu.sim.engine import SimConfig, SimResult, simulate

@@ -60,8 +60,8 @@ _DEFAULT_OVERHEAD_US = {"PUSH": 150.0, "PULL": 150.0, "PULL_REQ": 50.0}
 
 
 def codec_by_name(name: str) -> Optional[WireCodec]:
-    """The bench-canonical wire-codec instances (bench.py --mode
-    throttled races exactly these constructions)."""
+    """The canonical wire-codec instance for a recorded codec name
+    (calibration and replay both construct a codec only here)."""
     if name in (None, "", "raw", "none"):
         return None
     if name == "fp16":
@@ -388,7 +388,7 @@ def recorded_sim_config(recorded: Dict[str, Any], rounds: int = 3):
 
 def predict_step_s(model: CostModel, cfg) -> float:
     """Simulated median step time + the calibrated per-round slack —
-    THE number ``bench.py --mode whatif`` tables against measurement."""
+    THE number a validation run would table against measurement."""
     from byteps_tpu.sim.engine import simulate
 
     return simulate(model, cfg).step_time_s + model.round_slack_us * 1e-6

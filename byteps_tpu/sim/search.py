@@ -3,7 +3,7 @@
 Three consumers (ROADMAP item 3's payoff points):
 
 * :func:`rank_configs` — sweep/hill-climb a ``SimConfig`` grid and
-  return the ranked list (``bench.py --mode whatif`` prints the top);
+  return the ranked list (best first; ``make_proposer`` walks it);
 * :func:`make_proposer` — the :class:`~byteps_tpu.common.tuner.AutoTuner`
   ``proposer=`` hook: after the tuner's warmup window it asks the
   simulator for the next candidate instead of walking blind

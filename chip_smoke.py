@@ -321,7 +321,7 @@ def train_leg(phase: str, label: str, cfg, mesh, params0, batches,
 
 def gold_losses(cfg, params0, batches, n_steps: int):
     """The plain side: jax.jit + jax.numpy attention + dense softmax CE +
-    optax.adamw, no framework (bench.py's gold step, with the jnp twins
+    optax.adamw, no framework (the plain gold step, with the jnp twins
     forced and blocks rematerialized so the S*S score arrays of 24 layers
     need not live at once). Returns the first ``n_steps`` losses."""
     import functools

@@ -3,9 +3,8 @@
 # --child-worker OS processes; SIGKILL one mid-run and assert the
 # survivor still completes every round (the membership lease evicts the
 # dead id and re-targets the stalled round) AND that the supervisor
-# leaks zero child processes afterwards. This is the one-command version
-# of the bench proc_death leg — fast enough to run after any launcher /
-# server membership change.
+# leaks zero child processes afterwards. One command, fast enough to
+# run after any launcher / server membership change.
 #
 # Exit codes: 0 = survivor completed + no leaked children,
 # anything else = a real robustness regression.

@@ -15,8 +15,8 @@ when a toolchain is present.
 Slow tier: the acceptance sweep (5% timeouts + a 15-step server-down
 window, bit-identical sums vs the clean run), health-monitor failover
 onto the surviving server, graceful pure-local degradation when every
-server is dead, and the eviction→rejoin round-trip. The
-goodput-vs-fault-rate measurement lives in ``bench.py --mode chaos``.
+server is dead, and the eviction→rejoin round-trip. Goodput against
+fault rate has no measurement: no benchmark cell runs the DCN tier.
 """
 
 import dataclasses
@@ -112,12 +112,12 @@ def test_fault_spec_round_trip_every_documented_form():
         "worker:hang@step=3,ms=250",
         "worker:hang@step=3",  # default hang latency
         # per-worker straggler targeting (worker<N> scope): the bounded-
-        # staleness bench's slow-worker leg, plus kill/hang variants
+        # staleness smoke's slow worker, plus kill/hang variants
         "worker1:slow@ms=80",
         "worker0:kill@step=8..",
         "worker2:hang@step=3,ms=250",
         # deterministic mid-stream joins (scale-up elasticity): the
-        # churn bench leg's schedule forms
+        # schedule forms of tests/test_join.py
         "worker2:join@step=12",
         "worker0:join@step=3..5",
         "worker4:join@step=7..",

@@ -19,8 +19,8 @@ Tier-1 pins the subsystem's contracts:
   and flight dumps, ``--whatif-export``, flight dumps as
   ``load_events`` input.
 
-The full cross-leg validation sweep (live bench legs vs predictions)
-is the slow tier (`-m slow`; bench.py --mode whatif is the gating run).
+Predictions are not held against a live run here: the one validation
+sweep went with the pre-chip harness (ROADMAP.md C9).
 """
 
 import json
@@ -543,17 +543,3 @@ def test_goodput_estimator_from_model_is_sublinear_under_contention():
     assert g2 > g1                    # a second worker still pays
     assert g8 < 8 * g1                # ...but never linearly
     assert est(2) == g2               # memoized
-
-
-# ---- slow: live cross-leg validation ----------------------------------------
-@pytest.mark.slow
-def test_whatif_cross_leg_validation_under_10pct_median():
-    """The bench contract end-to-end (slow tier; bench.py --mode whatif
-    is the gating artifact): record raw@200, predict a codec x rate
-    spread, median |rel err| < 10%."""
-    import bench
-
-    res = bench.bench_whatif(reps=2)
-    assert res["pass"], res["median_rel_err"]
-    assert res["median_rel_err"] < 0.10
-    assert len(res["results"]) >= 6

@@ -6,10 +6,8 @@ push window of chunk i) asserted from the chrome trace.
 The pacer (``server/pacer.py``, ``BYTEPS_DCN_THROTTLE_MBPS``) emulates the
 slow cross-pod networks gradient compression exists for (SURVEY §6) on
 plain loopback — no root/netem — which is what lets CI exercise the
-compression-wins regime on every run. The full sweep lives in
-``bench.py --mode throttled``; the slow-tier test here runs a reduced
-sweep and asserts the headline claim (a compressed codec beats raw fp32
-end-to-end at ≤200 Mbps).
+compression-wins regime on every run. A sweep over rates and codecs is
+not measured anywhere: no benchmark cell runs the DCN tier.
 """
 
 import json
@@ -180,8 +178,7 @@ def test_throttled_smoke_raw_vs_onebit():
         # (partially overlapped) — while onebit's ~66 KB/dir costs ~5 ms
         # of wire plus codec+server CPU (~50-80 ms on a 2-core CI box):
         # the margin sits near 3x, so the 1.5x bound has real headroom
-        # (at 200 Mbps it measured 1.49x and flaked). The bench measures
-        # the real margin at real partition sizes.
+        # (at 200 Mbps it measured 1.49x and flaked).
         assert t_ob < t_raw / 1.5, (t_ob, t_raw)
     finally:
         core.shutdown()
@@ -239,28 +236,3 @@ def test_compress_push_overlap_visible_in_trace(tmp_path, monkeypatch):
         stop_server()
         tracing.reset_tracer()
         config_mod.reset_config()
-
-
-# ---- the full sweep (slow tier; the bench artifact's shape) ----------------
-@pytest.mark.slow
-def test_throttled_sweep_compressed_beats_raw():
-    """Reduced bench_throttled sweep: at 200 Mbps emulated DCN, onebit
-    (or fp8) must beat raw fp32 end-to-end by ≥1.3× — the acceptance
-    criterion of the compression fast lane, asserted in CI at reduced
-    payload (the published artifact runs the full 3-rate × 5-codec
-    sweep at 16 MB)."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    import bench
-
-    res = bench.bench_throttled(rates_mbps=(200,), reps=2, payload_mb=8)
-    r200 = res["results"]["200"]
-    best = max(r200["onebit"]["speedup_vs_raw"],
-               r200["fp8"]["speedup_vs_raw"])
-    assert best >= 1.3, r200
-    # raw must still be correct-side-up: fp16 between raw and fp8
-    assert (r200["fp16"]["speedup_vs_raw"]
-            >= r200["raw"]["speedup_vs_raw"]), r200
