@@ -18,6 +18,10 @@ same for every family.
 * :class:`RecurrentKVFamily` (``Qwen3NextConfig``): k/v pages for the full-
   attention layers and one slot of a state pool a request for the recurrent
   (Gated-DeltaNet) layers, under the same two programs.
+* :class:`HybridKVFamily` (``FalconH1Config``): every layer owns k/v pages
+  AND a slot of the state pool (a Mamba-2 mixer beside attention on one
+  normed input), under the same two programs; a final chunk reads out its
+  last position alone.
 * :class:`BlockDiffusionFamily` (``SDARConfig``): k/v pages of one kind
   under the same two programs told the block length — a decode row carries a
   block of positions, rewritten in place pass after pass, and a chunk's mask
@@ -352,20 +356,27 @@ class RecurrentKVFamily(WindowedKVFamily):
         "tp_axis": "tensor parallelism",
     }
 
+    def pool_shapes(self, cfg):
+        """``(k/v layers, state layers, a slot's state shape)`` of the two
+        pools (a subclass with other layers names its own)."""
+        from byteps_tpu.models.qwen3_next import FULL, LINEAR
+
+        return (len(cfg.layers_of(FULL)), len(cfg.layers_of(LINEAR)),
+                (cfg.linear_value_heads, cfg.linear_key_dim,
+                 cfg.linear_value_dim))
+
     def layout(self, params, cfg, *, block_size, pool_blocks, max_batch,
                prefill_chunk, quant) -> PoolLayout:
-        from byteps_tpu.models.qwen3_next import FULL, LINEAR
         from byteps_tpu.serve.paged_cache import (
             kv_pool_state, with_state_pool)
 
+        kv_layers, state_layers, state_shape = self.pool_shapes(cfg)
         slots = 1 + admitted_at_once(max_batch)
         pool = kv_pool_state(cfg, block_size, pool_blocks, cfg.kv_heads,
-                             False, layers=len(cfg.layers_of(FULL)))
+                             False, layers=kv_layers)
         return PoolLayout(
             state=with_state_pool(
-                pool, len(cfg.layers_of(LINEAR)), slots,
-                (cfg.linear_value_heads, cfg.linear_key_dim,
-                 cfg.linear_value_dim),
+                pool, state_layers, slots, state_shape,
                 ((cfg.conv_kernel - 1) * cfg.conv_channels,), cfg.dtype),
             kv_heads=cfg.kv_heads, state_slots=slots)
 
@@ -373,6 +384,43 @@ class RecurrentKVFamily(WindowedKVFamily):
         from byteps_tpu.serve.paged_cache import STATS_STATE, StepStats
 
         return StepStats(STATS_STATE)
+
+
+@functools.lru_cache(maxsize=16)
+def _hybrid_plan(cfg):
+    """The ``StepPlan`` of a ``FalconH1Config``: every layer hybrid — row
+    ``i`` of the k/v pool and row ``i`` of the state pool — the model's own
+    first half and MLP, its embedding and logit multipliers, and a chunk's
+    readout of one position (``num_logits_to_keep`` 1)."""
+    from byteps_tpu.models.falcon_h1 import mixer_half, mlp
+    from byteps_tpu.serve.paged_cache import LayerKind, StepPlan
+
+    return StepPlan(tuple(LayerKind(li, None, cfg.rope_base, hybrid=True)
+                          for li in range(cfg.n_layers)),
+                    mlp, mixer=mixer_half,
+                    embed_scale=cfg.embedding_multiplier,
+                    logit_scale=cfg.lm_head_multiplier, last_logits=True)
+
+
+class HybridKVFamily(RecurrentKVFamily):
+    """Falcon-H1: in every layer k/v pages for the attention branch and,
+    for the Mamba-2 branch beside it, a slot of a state pool a request (the
+    f32 state and the convolution's tail) — both pools ``n_layers`` deep —
+    under ``paged_cache.py``'s two programs and a
+    :class:`~paged_cache.StepPlan` whose layers are hybrid. What a slot
+    cannot do yet (``REFUSED``) is the recurrent family's."""
+
+    name = "k/v + recurrent state in every layer"
+    plan = staticmethod(_hybrid_plan)
+
+    def pool_shapes(self, cfg):
+        return (cfg.n_layers, cfg.n_layers,
+                (cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim))
+
+    def late_stats(self):
+        from byteps_tpu.serve.paged_cache import STATS_SSD, StepStats
+
+        return StepStats(STATS_SSD)
 
 
 @functools.lru_cache(maxsize=16)
@@ -472,6 +520,7 @@ class BlockDiffusionFamily(WindowedKVFamily):
 def serve_family(cfg):
     """The family that serves ``cfg``, by its type."""
     from byteps_tpu.models.dots3 import Dots3Config
+    from byteps_tpu.models.falcon_h1 import FalconH1Config
     from byteps_tpu.models.mellum2 import Mellum2Config
     from byteps_tpu.models.qwen3_next import Qwen3NextConfig
     from byteps_tpu.models.sdar import SDARConfig
@@ -484,9 +533,11 @@ def serve_family(cfg):
         return WindowedKVFamily()
     if isinstance(cfg, Qwen3NextConfig):
         return RecurrentKVFamily()
+    if isinstance(cfg, FalconH1Config):
+        return HybridKVFamily()
     if isinstance(cfg, GPTConfig):
         return GPTFamily()
     raise TypeError(
         f"Scheduler: no serve family for a {type(cfg).__name__} "
-        "(GPTConfig, Dots3Config, Mellum2Config, Qwen3NextConfig and "
-        "SDARConfig are served)")
+        "(GPTConfig, Dots3Config, Mellum2Config, Qwen3NextConfig, "
+        "FalconH1Config and SDARConfig are served)")

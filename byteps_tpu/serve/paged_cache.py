@@ -78,7 +78,10 @@ window`` and count what they did into ``PoolState.stats``
 (``LayerKind.state``; ``families.RecurrentKVFamily``) owns no table line —
 a request owns one SLOT of a state pool (``PoolState.s``/``conv``) from
 admission to release, its index rides at the head of the request's table
-row, and the plan's ``recur`` updates the slot in place.
+row, and the plan's ``recur`` updates the slot in place. And a layer may be
+both at once (``LayerKind.hybrid``; ``families.HybridKVFamily``): a table
+line AND a row of the state pool, the plan's ``mixer`` given the pool's
+``attend`` and the slot.
 """
 
 from __future__ import annotations
@@ -97,6 +100,7 @@ from byteps_tpu.models.generate import (
     _block_step,
     _embed,
     _quantize_block,
+    cache_attend,
 )
 from byteps_tpu.models.gpt import (
     GPTConfig,
@@ -170,12 +174,15 @@ class LayerKind(NamedTuple):
     window layer (``pool.wk``/``wv``, table line 1, keys below ``fill -
     window`` neither held nor read). ``rope``: what it rotates by (0: not at
     all). ``state``: a recurrent layer — no keys, no table line; ``index`` is
-    its row in the state pool (``pool.s``/``conv``)."""
+    its row in the state pool (``pool.s``/``conv``). ``hybrid``: a global
+    layer that keeps a recurrent state beside its keys — ``index`` is its row
+    in the k/v pool AND in the state pool."""
 
     index: int
     window: Optional[int] = None
     rope: Union[float, RopeFreqs] = 0.0
     state: bool = False
+    hybrid: bool = False
 
 
 class StepPlan(NamedTuple):
@@ -192,7 +199,13 @@ class StepPlan(NamedTuple):
     state where ``fresh``. ``block``: the model generates by diffusion over
     blocks of that many positions (a power of two that divides the page; every
     layer global) — a decode row carries a whole block and a chunk's mask is
-    block-causal; None: a token a row, causal. Hashable: it keys the
+    block-causal; None: a token a row, causal. ``mixer``: the first half of
+    a hybrid layer, ``mixer(cfg, x, p, head_dim, positions, attend, rope,
+    pool.s, pool.conv, index, slots, fresh, norm_fn=, norm_eps=) -> (x, carry,
+    s, conv)`` — ``attend`` and ``carry`` as ``attn`` has them, the rest as
+    ``recur``. ``embed_scale`` multiplies the embedding, ``logit_scale`` the
+    logits; ``last_logits``: a chunk reads out its last position alone,
+    ``(1, 1, vocab)`` (the scheduler keeps no other). Hashable: it keys the
     programs' factories."""
 
     kinds: Tuple[LayerKind, ...]
@@ -200,6 +213,10 @@ class StepPlan(NamedTuple):
     attn: Optional[Callable] = None
     recur: Optional[Callable] = None
     block: Optional[int] = None
+    mixer: Optional[Callable] = None
+    embed_scale: float = 1.0
+    logit_scale: float = 1.0
+    last_logits: bool = False
 
 
 def _window_of(plan: "StepPlan") -> Optional[int]:
@@ -211,10 +228,25 @@ def _window_of(plan: "StepPlan") -> Optional[int]:
 
 
 def _layers_by_kind(plan: "StepPlan") -> Tuple[int, int, int]:
-    """``(global, window, recurrent)`` layers of a plan."""
-    n_state = sum(k.state for k in plan.kinds)
+    """``(global, window, recurrent)`` layers of a plan: layers with a line
+    of the global table, of the window table, with a row of the state pool
+    (a hybrid layer is a global and a recurrent one)."""
+    n_state = sum(k.state or k.hybrid for k in plan.kinds)
     n_window = sum(k.window is not None for k in plan.kinds)
-    return len(plan.kinds) - n_state - n_window, n_window, n_state
+    n_keyless = sum(k.state for k in plan.kinds)
+    return len(plan.kinds) - n_keyless - n_window, n_window, n_state
+
+
+def _embed_in(plan: "StepPlan", x):
+    """The embedding as the plan scales it (1: nothing is traced)."""
+    return x if plan.embed_scale == 1.0 \
+        else x * jnp.asarray(plan.embed_scale, x.dtype)
+
+
+def _logits(plan: "StepPlan", params, x, norm_fn, norm_eps):
+    """The readout as the plan scales it (1: nothing is traced)."""
+    logits = _readout(params, x, norm_fn, norm_eps)
+    return logits if plan.logit_scale == 1.0 else logits * plan.logit_scale
 
 
 def one_kind_plan(cfg) -> StepPlan:
@@ -236,6 +268,8 @@ STATS = ("moe.pairs_here", "moe.experts_hit", "moe.layers",
 #: a pool with recurrent layers counts these too: live rows of a decode
 #: step and tokens of a chunk, each times the recurrent layers
 STATS_STATE = STATS + ("serve.gdn.decode_rows", "serve.gdn.prefill_tokens")
+#: the same two of a pool whose state is a Mamba-2 (SSD) mixer's
+STATS_SSD = STATS + ("serve.ssd.decode_rows", "serve.ssd.prefill_tokens")
 
 
 def kv_pool_state(cfg: GPTConfig, block_size: int, pool_blocks: int,
@@ -270,7 +304,7 @@ def with_state_pool(pool: PoolState, layers: int, slots: int,
     """``pool`` with a zeroed state pool of ``slots`` slots (slot 0 scratch)
     for ``layers`` recurrent layers beside it — a slot of a layer holds
     ``state_shape`` f32 and ``tail_shape`` of ``tail_dtype`` — and the
-    ``stats`` leaf of :data:`STATS_STATE`."""
+    ``stats`` leaf of :data:`STATS_STATE` (:data:`STATS_SSD` is as long)."""
     return pool._replace(
         s=jnp.zeros((layers, slots) + tuple(state_shape), jnp.float32),
         conv=jnp.zeros((layers, slots) + tuple(tail_shape), tail_dtype),
@@ -1219,7 +1253,9 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     row's first key, or :func:`_window_attend_twin`. With recurrent layers
     ``tables`` is ``(R, 1 + W)``: column 0 each row's slot of the state pool
     (0, scratch, for a row that holds no request), which ``plan.recur``
-    updates in place; such a layer reads no table and no key.
+    updates in place; such a layer reads no table and no key. A hybrid layer
+    does both: ``plan.mixer`` is given this step's ``attend`` over the layer's
+    row of the k/v pool and the rows' slots.
     With ``plan.block`` = B the step is a pass of block diffusion: ``toks (R,
     B)`` — row ``r``'s block as it stands, the mask token where a position is
     open — at positions ``[pos[r], pos[r] + B)`` (a block boundary: the B rows
@@ -1344,7 +1380,8 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, pool, toks, pos, tables, slabs=None, slots=None):
         if B is None:
-            x = _embed(params, toks[:, None], pos[:, None], cfg)  # (R, 1, d)
+            x = _embed_in(plan, _embed(params, toks[:, None], pos[:, None],
+                                       cfg))                   # (R, 1, d)
             at = lambda: pos[:, None]                          # noqa: E731
         else:
             where = pos[:, None] + jnp.arange(B)               # (R, B)
@@ -1370,6 +1407,14 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                 x, s, conv = plan.recur(
                     cfg, x, p, pool.s, pool.conv, kind.index, state_slots,
                     None, norm_fn=norm_fn, norm_eps=norm_eps)
+                pool = pool._replace(s=s, conv=conv)
+            elif kind.hybrid:
+                x, pool, s, conv = plan.mixer(
+                    cfg, x, p, cfg.head_dim, at,
+                    _pool_attend(pool, kind, blks[line], off, pos,
+                                 kind_tables[line]), kind.rope,
+                    pool.s, pool.conv, kind.index, state_slots, None,
+                    norm_fn=norm_fn, norm_eps=norm_eps)
                 pool = pool._replace(s=s, conv=conv)
             else:
                 x, pool = half(
@@ -1398,7 +1443,7 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
             pool = pool._replace(stats=jnp.concatenate([moe, jnp.stack(
                 [jnp.asarray(v, jnp.float32)
                  for v in (*keys, 0.0, 0.0, *rows)])]))
-        logits = _readout(params, x, norm_fn, norm_eps)
+        logits = _logits(plan, params, x, norm_fn, norm_eps)
         return (logits[:, 0] if B is None else logits), pool
 
     return step
@@ -1443,7 +1488,10 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     W,)``, the request's slot of the state pool first: ``plan.recur`` runs
     the chunk from the slot's state — from zero at ``pos0 == 0``, whatever
     the slot's last owner left — and leaves there what the next chunk or
-    the first decode step continues from.
+    the first decode step continues from. A hybrid layer runs the path above
+    over its table line with ``plan.mixer`` as its first half, which is given
+    the slot too. With ``plan.last_logits`` the readout is of the chunk's
+    last position alone, ``(1, 1, vocab)``.
     With ``plan.block`` the chunk attends block-causally (the flash forward
     with the block a constant of its mask; ``pos0`` and C whole blocks).
     ``with_readout=False`` skips the vocab projection (an intermediate
@@ -1525,7 +1573,7 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     # 36 traces of the block are most of a second of host time in each.
     # ``kind``, static, carries no index: a trace a kind
     @functools.partial(jax.jit, static_argnames="kind")
-    def _layer(x, p, pool, li, pos0, table, keep, blk, off, kind):
+    def _layer(x, p, pool, li, pos0, table, keep, blk, off, kind, slot=None):
         if kind.window is not None:
             return _window_layer(x, p, pool, li, pos0, table, blk, off, kind)
         quant = pool.k_scale is not None
@@ -1535,10 +1583,21 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
             if quant:
                 ck = _QuantSlot(ck, _view(pool.k_scale, li, table, keep))
                 cv = _QuantSlot(cv, _view(pool.v_scale, li, table, keep))
-        x, ck, cv, *aux = _block_step(
-            x, p, ck, cv, pos0, cfg, tp_axis, None, norm_fn=norm_fn,
-            norm_eps=norm_eps, rope=kind.rope, ffn=_ffn(p), attn=half,
-            block=plan.block)
+        if kind.hybrid:
+            # the layer's row of the state pool is its row of the k/v pool
+            x, (ck, cv), s, conv = plan.mixer(
+                cfg, x, p, cfg.head_dim, lambda: pos0 + jnp.arange(C),
+                cache_attend(ck, cv, pos0), kind.rope, pool.s, pool.conv, li,
+                slot, pos0 == 0, norm_fn=norm_fn, norm_eps=norm_eps)
+            pool = pool._replace(s=s, conv=conv)
+            x, aux = ffn_half(x, p, tp_axis, _ffn(p), norm_fn=norm_fn,
+                              norm_eps=norm_eps, use_bias=cfg.use_bias)
+        else:
+            x, ck, cv, *aux = _block_step(
+                x, p, ck, cv, pos0, cfg, tp_axis, None, norm_fn=norm_fn,
+                norm_eps=norm_eps, rope=kind.rope, ffn=_ffn(p), attn=half,
+                block=plan.block)
+            aux = aux[0] if aux else None
         with jax.named_scope("paged/scatter_kv"):
             at = (li, blk, off)
             if quant:
@@ -1550,7 +1609,7 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
             else:
                 pool = pool._replace(k=_put(pool.k, at, ck, pos0),
                                      v=_put(pool.v, at, cv, pos0))
-        return x, pool, (aux[0] if aux else None)
+        return x, pool, aux
 
     # pool donated for the same reason as the decode step
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -1563,7 +1622,7 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
         blks = [jnp.take(t, positions // block_size) for t in kind_tables]
         off = positions % block_size
         keep = jnp.arange(table.shape[-1] * block_size) < pos0
-        x = _embed(params, tokens, positions, cfg)
+        x = _embed_in(plan, _embed(params, tokens, positions, cfg))
         moe = None if plan.ffn is None else jnp.zeros((4,), jnp.float32)
         for p, kind in zip(params["blocks"], plan.kinds):
             line = 0 if kind.window is None else 1
@@ -1574,7 +1633,8 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
                 x, pool, aux = _layer(
                     x, p, pool, jnp.int32(kind.index), pos0,
                     kind_tables[line], keep, blks[line], off,
-                    kind=kind._replace(index=0))
+                    kind=kind._replace(index=0),
+                    **({"slot": slot} if kind.hybrid else {}))
             if aux is not None:
                 moe = _fold_moe(moe, aux)
         if pool.stats is not None:
@@ -1594,8 +1654,10 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
             pool = pool._replace(stats=jnp.concatenate([moe, jnp.stack(
                 [jnp.asarray(v, jnp.float32)
                  for v in (0.0, 0.0, *pairs, *toks)])]))
-        logits = (_readout(params, x, norm_fn, norm_eps) if with_readout
-                  else None)
+        logits = None
+        if with_readout:
+            logits = _logits(plan, params, x[:, -1:] if plan.last_logits
+                             else x, norm_fn, norm_eps)
         return logits, pool
 
     return chunk

@@ -186,3 +186,35 @@ def test_bert_compressed_dp_training(mesh_dp):
         losses.append(float(loss))
     assert np.isfinite(losses).all()
     assert losses[-1] < losses[0], losses
+
+def test_falcon_h1_family_keeps_pages_and_a_slot_in_every_layer():
+    """``serve_family(FalconH1Config)``: both pools are ``n_layers`` deep —
+    row ``i`` of the k/v pool and row ``i`` of the state pool are layer
+    ``i``'s — the state pool holds one slot a request the scheduler admits
+    at once and scratch, and every layer of the plan is hybrid."""
+    from byteps_tpu.models.falcon_h1 import FalconH1Config
+    from byteps_tpu.serve.families import (
+        HybridKVFamily, RecurrentKVFamily, admitted_at_once, serve_family)
+
+    cfg = FalconH1Config.tiny(n_layers=3)
+    family = serve_family(cfg)
+    assert isinstance(family, HybridKVFamily)
+    assert family.REFUSED is RecurrentKVFamily.REFUSED
+    lay = family.layout(None, cfg, block_size=4, pool_blocks=9, max_batch=8,
+                        prefill_chunk=8, quant=False)
+    slots = 1 + admitted_at_once(8)
+    assert lay.state_slots == slots == 11 and lay.kv_heads == cfg.n_kv_heads
+    assert lay.window is None and lay.window_blocks == 0
+    pool = lay.state
+    assert pool.k.shape == pool.v.shape == (
+        3, 9, 4, cfg.n_kv_heads * cfg.head_dim)
+    assert pool.s.shape == (3, slots, cfg.ssm_heads, cfg.ssm_state,
+                            cfg.ssm_head_dim) and pool.s.dtype == jnp.float32
+    assert pool.conv.shape == (3, slots, 3 * cfg.conv_channels)
+    assert pool.wk is None and pool.k_scale is None
+    plan = family.plan(cfg)
+    assert [k.index for k in plan.kinds] == [0, 1, 2]
+    assert all(k.hybrid and not k.state and k.window is None
+               for k in plan.kinds)
+    assert plan.last_logits and plan.embed_scale == cfg.embedding_multiplier
+    assert plan.logit_scale == cfg.lm_head_multiplier

@@ -806,6 +806,135 @@ def test_sdar_serve_program_fits_and_leaves_the_pool_in_place(
         assert "paged_attn_decode" in hlo
 
 
+# --- the Falcon-H1 serving cell's two programs at the cut configuration -----
+_FH_BS, _FH_BATCH, _FH_CHUNK, _FH_BLOCKS = 128, 128, 2048, 2049
+
+
+def _falconh1_on(topo):
+    """The cut Falcon-H1 (``benchmark/configs/falcon-h1-34b-instruct-l4
+    .json``: 4 of 72 identical layers, every layer whole, the whole
+    vocabulary, bf16), its parameters, its k/v pool and its state pool as
+    shapes on the described chip."""
+    from byteps_tpu.models.falcon_h1 import FalconH1Config, falcon_h1_init
+    from byteps_tpu.serve.families import serve_family
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = FalconH1Config(max_seq=8192, n_layers=4)
+    shapes = jax.eval_shape(
+        lambda: falcon_h1_init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), shapes)
+    family = serve_family(cfg)
+    pool = jax.eval_shape(lambda: family.layout(
+        shapes, cfg, block_size=_FH_BS, pool_blocks=_FH_BLOCKS,
+        max_batch=_FH_BATCH, prefill_chunk=_FH_CHUNK, quant=False).state)
+    pool = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), pool)
+    return cfg, family, params, pool, on_chip
+
+
+def _ssd_decode_case():
+    from byteps_tpu.ops.ssd import ssd_decode
+
+    def f(x, dt, A, B, C, D, pool, slots):
+        return ssd_decode(x, dt, A, B, C, D, pool, 3, slots)
+    R, H, G, N, P_ = _FH_BATCH, 32, 2, 256, 128
+    return f, [_sds((R, H, P_), F32), _sds((R, H), F32), _sds((H,), F32),
+               _sds((R, G, N), F32), _sds((R, G, N), F32), _sds((H,), F32),
+               _sds((4, 161, H, N, P_), F32), _sds((R,), I32)]
+
+
+def _fh_paged_decode(q, k, v, tables, lengths):
+    return paged_attention_decode(q, k, v, tables, lengths, 1)
+
+
+_FH_KERNELS = [
+    # the decode step's state update in place in the slot pool: 32 heads of
+    # 256 x 128 f32 in 2 groups, 8 heads a grid step
+    ("ssd_decode_r128_h32_n256_p128", *_ssd_decode_case(), 1),
+    # 20 query heads (not a multiple of 8 sublanes) on 4 k/v heads of 128
+    ("paged_attn_decode_falconh1_h20_kv4_w64", _fh_paged_decode,
+     [_sds((_FH_BATCH, 20, 128), BF16)]
+     + [_sds((4, _FH_BLOCKS, _FH_BS, 512), BF16)] * 2
+     + [_sds((_FH_BATCH, 64), I32), _sds((_FH_BATCH,), I32)], 1),
+]
+
+
+@pytest.mark.parametrize("case", _FH_KERNELS, ids=_case_id)
+def test_falconh1_kernel_compiles_for_v5e(topo, as_on_tpu, case):
+    test_kernel_compiles_for_v5e(topo, as_on_tpu, case)
+
+
+@pytest.mark.parametrize("program", ["chunk_c2048_w32", "decode_r128_w64",
+                                     "params"])
+def test_falconh1_serve_program_fits_and_leaves_the_pools_in_place(
+        topo, as_on_tpu, program):
+    """The cut configuration counts 4,394,354,048 parameters (8.79 GB in
+    bf16); its 2,048-token final chunk (ONE position read out) and its
+    128-row decode step compile for the described v5e with the k/v pool (4
+    layers x 2,049 blocks) and the state pool (4 layers x 161 slots x 4 MB,
+    f32) donated and updated in place, and weights + pools + temporaries fit
+    the chip's 16 GB. A decode step: 4 state updates, 4 paged-attention
+    calls; a chunk: 4 flash forwards."""
+    cfg, family, params, pool, on_chip = _falconh1_on(topo)
+    weights, pages = _bytes(params), _bytes(pool)
+    # bf16 but A_log, dt_bias and D (3 x 32 f32 a layer)
+    assert sum(a.size for a in jax.tree.leaves(params)) == 4394354048
+    assert weights == 2 * 4394354048 + 4 * 96 * 2, weights
+    assert pool.k.shape == (4, _FH_BLOCKS, 128, 512)
+    assert pool.s.shape == (4, 161, 32, 256, 128) and pool.s.dtype == F32
+    assert pool.conv.shape == (4, 161, 3 * 5120)
+    if program == "params":
+        return
+    if program.startswith("chunk"):
+        W = 32
+        compiled = family.prefill_fn(cfg, _FH_BS, _FH_CHUNK, None, True)\
+            .lower(params, pool, on_chip((1, _FH_CHUNK), I32),
+                   on_chip((), I32), on_chip((1 + W,), I32)).compile()
+    else:
+        W = 64
+        assert family.decode_reads_pool_in_place(
+            cfg, type("C", (), dict(block_size=_FH_BS, kv_heads=4,
+                                    quant=False)))
+        compiled = family.decode_fn(cfg, _FH_BS, None, None).lower(
+            params, pool, on_chip((_FH_BATCH,), I32),
+            on_chip((_FH_BATCH,), I32),
+            on_chip((_FH_BATCH, 1 + W), I32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pages - 64            # donated, in place
+    assert mem.argument_size_in_bytes <= weights + pages + (8 << 20)
+    assert weights + pages + mem.temp_size_in_bytes < 15.5e9, \
+        mem.temp_size_in_bytes
+    # the logits are the output beside the pools: one position of a chunk,
+    # one a row of a decode step — never the chunk's 2,048
+    rows = 1 if program.startswith("chunk") else _FH_BATCH
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        <= rows * cfg.vocab_size * 4 + (1 << 20)
+    hlo = compiled.as_text()
+    # (the chunk program's 19.8 MB tail pool is small enough for the compiler
+    # to stage through its fast memory, with copies of its own: PERF.md
+    # section 7; the three pools that size the chip stay where they lie)
+    for name in ("k", "v", "s") + (() if program.startswith("chunk")
+                                   else ("conv",)):
+        a = getattr(pool, name)
+        shape = "%s[%s]" % ("f32" if a.dtype == F32 else "bf16",
+                            ",".join(map(str, a.shape)))
+        made = [op for op, aliased in _ops_with_result(hlo, shape)
+                if op not in ("parameter", "tuple", "get-tuple-element",
+                              "bitcast") and not aliased]
+        assert not made, (name, made)
+    n = _n_pallas(compiled)
+    if program.startswith("chunk"):
+        assert n == 4, n
+        assert "flash_fwd" in hlo
+    else:
+        assert n == 4 + 4, n
+        for name in ("ssd_decode", "paged_attn_decode"):
+            assert name in hlo, name
+
+
 # ---- the training readout's backward: which array the loop carries ----------
 def _readout_loss_and_grads(topo, B, S, d, V):
     from byteps_tpu.ops.chunked_ce import chunked_ce_nll
