@@ -11,7 +11,7 @@ contract that makes the kernels plain:
   the row tile ``tm`` (0 is allowed: an empty group), and ``M`` is too, so
   a row tile belongs to exactly one group and no kernel masks rows. The
   caller pads each group with zero rows (``parallel/moe.py`` lays its
-  sorted token-expert pairs out so); zero rows give zero products and add
+  grouped token-expert pairs out so); zero rows give zero products and add
   nothing to a weight gradient. **The caller chooses ``tm``**, since the
   layout is its own: ``parallel/moe.py::dropless_row_tile`` derives it
   from the pairs its program holds, between :func:`min_row_tile` (a
@@ -152,12 +152,15 @@ def _compiler_params(semantics: Tuple[str, ...], need: int):
 def _tile_groups(group_sizes: jnp.ndarray, tm: int, n_tiles: int
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(group of each row tile, number of live tiles). Tiles past the
-    live ones carry the last group and are never visited."""
+    live ones carry the last group and are never visited. Tile ``i`` lies
+    in the group after those that end at or before it: one ``(n_tiles, G)``
+    compare and a count (no search: ``parallel/moe.py::_row_plan`` has
+    why)."""
     ends = jnp.cumsum(group_sizes.astype(jnp.int32)) // tm
-    tile_group = jnp.searchsorted(
-        ends, jnp.arange(n_tiles, dtype=jnp.int32), side="right")
-    return (jnp.minimum(tile_group, group_sizes.shape[0] - 1)
-            .astype(jnp.int32), ends[-1:])
+    tiles = jnp.arange(n_tiles, dtype=jnp.int32)
+    tile_group = jnp.sum((ends[None, :] <= tiles[:, None]).astype(jnp.int32),
+                         axis=1)
+    return jnp.minimum(tile_group, group_sizes.shape[0] - 1), ends[-1:]
 
 
 # --------------------------------------------------------------------------

@@ -18,7 +18,7 @@ dp replicas each contribute their local batch's slots.
 
 A second path, :func:`moe_ffn_dropless` (end of the file), routes as
 DeepSeek-V3-family models do — sigmoid scores, top-k on score + a
-correction bias, hundreds of experts, NO dropped token — by sorting the
+correction bias, hundreds of experts, NO dropped token — by grouping the
 (token, expert) pairs by expert and running grouped matrix products
 (``ops/grouped_matmul.py``) over the experts this device holds. It is told
 which experts those are and runs no exchange; it also counts the picks
@@ -233,7 +233,7 @@ def moe_specs(ep_axis: Optional[str], tp_axis: Optional[str] = None,
 
 # --------------------------------------------------------------------------
 # Dropless many-expert routing (DeepSeek-V3 style): sigmoid scores, top-k on
-# score + correction bias, pairs sorted by expert, grouped matrix products
+# score + correction bias, pairs grouped by expert, grouped matrix products
 # over the experts held here. The Switch path above is unchanged.
 # --------------------------------------------------------------------------
 def sigmoid_topk_route(xt: jnp.ndarray, wg: jnp.ndarray, bias: jnp.ndarray,
@@ -356,6 +356,42 @@ def dropless_row_tile(pairs: int, held: int, itemsize: int) -> int:
                ROW_TILE)
 
 
+def _row_plan(local: jnp.ndarray, held: int, tm: int):
+    """The dropless layer's two integer maps, from each pair's expert
+    ``local (P,)`` (``0 .. held - 1``; ``held`` = not held here). The groups
+    lie one after another in a buffer of ``(ceil(P / tm) + held) · tm`` rows
+    (the static worst case), each padded to the tile ``tm``, a group's
+    pairs in the order they came (the stable order: it fixes the order of
+    ``_combine``'s f32 sums). Returns ``pair_row (P,)`` (pair → row; the
+    buffer's length where the pair is not held), ``row_pair (rows,)`` (row →
+    pair; ``P`` where the row holds none), ``counts`` and ``padded``
+    ``(held,)``.
+
+    All of it is compare-and-count: a pair's row is its group's first row
+    plus the pairs of its expert before it, both picked out of the ``(P,
+    held)`` compare by a one-hot sum, and ``row_pair`` is that map's
+    inverse — ONE scatter of the pairs' numbers. No sort, no search and no
+    ``held``-sized table looked up by a gather: a TPU gathers scalars one
+    by one, and a ``searchsorted`` is a loop of such gathers, each waiting
+    for the one before. Which group a row lies in is only ever asked a TILE
+    at a time, by the kernels (``grouped_matmul._tile_groups``)."""
+    P_ = local.shape[0]
+    n_rows = (-(-P_ // tm) + held) * tm
+    onehot = local[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]
+    before = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
+    counts = before[-1]                                        # (held,)
+    padded = -(-counts // tm) * tm
+    starts = jnp.cumsum(padded) - padded
+    row = jnp.sum(jnp.where(onehot, starts[None, :] + before - 1, 0), axis=1)
+    # a pair not held goes past the buffer, each to a place of its own so
+    # that the indices are unique as promised, and is dropped
+    pairs = jnp.arange(P_, dtype=jnp.int32)
+    target = jnp.where(local < held, row, n_rows + pairs)
+    row_pair = jnp.full((n_rows,), P_, jnp.int32).at[target].set(
+        pairs, mode="drop", unique_indices=True)
+    return jnp.minimum(target, n_rows), row_pair, counts, padded
+
+
 def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
                      first_expert: int = 0, row_tile: Optional[int] = None,
                      route: str = "sigmoid_bias"):
@@ -371,16 +407,18 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     expert stacks ``w1``/``w3 (held, d, ff)``, ``w2 (held, ff, d)`` of the
     ``held`` experts ``first_expert .. first_expert + held - 1``. Every
     token is routed over all ``E``; the ``T·k`` (token, expert) pairs are
-    sorted by expert, the pairs of held experts laid out group after
-    group — each group padded with zero rows to the row tile of
-    ``ops/grouped_matmul.py`` — and gate/up, SwiGLU and down run as three
+    grouped by expert in the order they came (:func:`_row_plan`), the pairs
+    of held experts laid out group after group — each group padded with
+    zero rows to the row tile of ``ops/grouped_matmul.py`` — and gate/up,
+    SwiGLU and down run as three
     grouped products; the rows are then gathered back and added with
     their weights. The row buffer is sized for the worst case, all ``T·k``
     pairs held here, so its shape is static and nothing can overflow; the
     kernels visit only the tiles that hold pairs. The tile follows from
     the shapes (:func:`dropless_row_tile`; ``row_tile`` is the tests'
     override), and the trace counts it: ``moe.row_tile.<tm>`` once a trace
-    of the layer, the gauge ``moe.row_buffer_rows`` the buffer's rows.
+    of the layer, the gauges ``moe.row_buffer_rows`` the buffer's rows and
+    ``moe.row_plan_tiles`` its tiles.
     What the experts held elsewhere would add is left out (under expert
     parallelism their owners compute it; this layer runs no exchange).
 
@@ -402,30 +440,15 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
           if row_tile is None else row_tile)
     local = idx.reshape(P_) - first_expert
     is_held = (local >= 0) & (local < held)
-    local = jnp.where(is_held, local, held)          # the rest sort last
-    onehot = local[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]
-    before = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
-    counts = before[-1]                                        # (held,)
-    rank = jnp.sum(jnp.where(onehot, before - 1, 0), axis=1)   # in its group
-    padded = -(-counts // tm) * tm
-    starts = jnp.cumsum(padded) - padded
-    n_rows = (-(-P_ // tm) + held) * tm            # static worst case
+    pair_row, row_pair, counts, padded = _row_plan(
+        jnp.where(is_held, local, held), held, tm)
+    n_rows = row_pair.shape[0]
     # the tile is chosen while tracing, so it is counted there
     reg = get_registry()
     reg.counter(f"moe.row_tile.{tm}").inc()
     reg.gauge("moe.row_buffer_rows").set(n_rows)
-    pair_row = jnp.where(is_held, jnp.take(starts, local, mode="clip") + rank,
-                         n_rows).reshape(T, top_k)
-    # row -> pair, by gathers alone: the stable sort's position j of group
-    # g's r-th pair is (pairs before g) + r
-    order = jnp.argsort(local, stable=True).astype(jnp.int32)
-    rows = jnp.arange(n_rows, dtype=jnp.int32)
-    group = jnp.minimum(jnp.searchsorted(starts + padded, rows, side="right"),
-                        held - 1)
-    within = rows - jnp.take(starts, group)
-    sorted_pos = jnp.take(jnp.cumsum(counts) - counts, group) + within
-    row_pair = jnp.where(within < jnp.take(counts, group),
-                         jnp.take(order, sorted_pos, mode="clip"), P_)
+    reg.gauge("moe.row_plan_tiles").set(n_rows // tm)
+    pair_row = pair_row.reshape(T, top_k)
 
     xs = _dispatch(xt, row_pair // top_k, pair_row)
     gate = grouped_matmul(xs, params["w1"], padded, tm)

@@ -470,6 +470,140 @@ def test_a_trace_counts_its_row_tile_once():
     assert reg.gauge("moe.row_buffer_rows").value() == (1 + 16) * 256
 
 
+# -- the row plan: compare-and-count, one scatter, groups by the tile ---------
+# (program, pairs, experts held, tile): the cells' decode steps, Mellum2's
+# tail chunk and a cut of JoyAI's step (8,192 of its 131,072 pairs)
+_PLAN_SHAPES = [
+    ("dots3_decode", 128, 32, 16),
+    ("mellum2_decode", 192, 64, 16),
+    ("qwen3next_decode", 1280, 64, 32),
+    ("sdar_decode", 4096, 128, 32),
+    ("mellum2_tail", 8192, 64, 128),
+    ("joyai_step", 8192, 16, 256),
+]
+_PLAN_LOADS = ["even", "skewed", "some_empty", "some_not_held", "all_on_one"]
+
+
+def _plan_load(load, pairs, held, seed=0):
+    """Each pair's expert ``0 .. held - 1`` (``held``: not held here)."""
+    rng = np.random.default_rng(seed)
+    if load == "even":
+        local = rng.permutation(np.arange(pairs) % held)
+    elif load == "skewed":
+        p = 1.0 / np.arange(1, held + 1) ** 2
+        local = rng.choice(held, pairs, p=p / p.sum())
+    elif load == "some_empty":
+        local = rng.choice(np.arange(held)[1::3], pairs)
+    elif load == "some_not_held":
+        local = np.minimum(rng.integers(0, 2 * held, pairs), held)
+    else:
+        local = np.full(pairs, held // 3)
+    return local.astype(np.int32)
+
+
+def _np_row_plan(local, held, tm):
+    """The plan by a stable sort and a loop over the groups, in NumPy."""
+    P = len(local)
+    n_rows = (-(-P // tm) + held) * tm
+    order = np.argsort(local, kind="stable")
+    pair_row, row_pair = np.full(P, n_rows), np.full(n_rows, P)
+    row = 0
+    for g in range(held):
+        pairs = order[local[order] == g]
+        pair_row[pairs] = row + np.arange(len(pairs))
+        row_pair[row:row + len(pairs)] = pairs
+        row += -(-len(pairs) // tm) * tm
+    return pair_row, row_pair
+
+
+_plan_cases = pytest.mark.parametrize(
+    "pairs,held,tm,load",
+    [c[1:] + (load,) for c in _PLAN_SHAPES for load in _PLAN_LOADS],
+    ids=[f"{c[0]}-{load}" for c in _PLAN_SHAPES for load in _PLAN_LOADS])
+
+
+@_plan_cases
+def test_row_plan_is_the_plain_plan(pairs, held, tm, load):
+    """``pair_row`` and ``row_pair`` integer for integer what a stable sort
+    by expert and a walk over the groups give: which pair gets which row is
+    part of the result (it fixes the order of ``_combine``'s f32 sums)."""
+    from byteps_tpu.parallel.moe import _row_plan
+
+    local = _plan_load(load, pairs, held)
+    pair_row, row_pair, counts, padded = jax.jit(
+        _row_plan, static_argnums=(1, 2))(jnp.asarray(local), held, tm)
+    want_pair_row, want_row_pair = _np_row_plan(local, held, tm)
+    np.testing.assert_array_equal(pair_row, want_pair_row)
+    np.testing.assert_array_equal(row_pair, want_row_pair)
+    np.testing.assert_array_equal(counts, np.bincount(local, minlength=held +
+                                                      1)[:held])
+    np.testing.assert_array_equal(padded, -(-np.asarray(counts) // tm) * tm)
+
+
+@_plan_cases
+def test_tile_groups_against_searchsorted(pairs, held, tm, load):
+    """The kernels' tile -> group map, by compare-and-count, is the search's:
+    every tile's group (the last group past the live tiles) and the number
+    of live tiles."""
+    from byteps_tpu.ops.grouped_matmul import _tile_groups
+
+    counts = np.bincount(_plan_load(load, pairs, held),
+                         minlength=held + 1)[:held]
+    padded = (-(-counts // tm) * tm).astype(np.int32)
+    n_tiles = -(-pairs // tm) + held
+    tile_group, n_live = _tile_groups(jnp.asarray(padded), tm, n_tiles)
+    ends = np.cumsum(padded) // tm
+    np.testing.assert_array_equal(tile_group, np.minimum(
+        np.searchsorted(ends, np.arange(n_tiles), side="right"), held - 1))
+    assert tile_group.dtype == jnp.int32 and n_live.shape == (1,)
+    assert int(n_live[0]) == ends[-1] == padded.sum() // tm
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("T,tile", [(24, 8), (1024, 256)],
+                         ids=["decode", "chunk"])
+def test_dropless_layer_lowers_to_no_loop(T, tile, grad):
+    """The lowered layer holds no ``while`` (a ``searchsorted`` is one, of
+    dependent scalar gathers), no sort of the pairs and ONE gather or
+    scatter of integers: the scatter that inverts ``pair_row``. The rest of
+    its index arithmetic is compares, sums and broadcasts."""
+    import re
+
+    from byteps_tpu.parallel.moe import dropless_row_tile
+
+    x, p = _layer(T=T)
+    assert dropless_row_tile(T * 4, 16, 4) == tile
+
+    def f(x, p):
+        y = moe_ffn_dropless(x, p, 4, 2.5)[0]
+        return (y * y).sum()
+
+    text = jax.jit(jax.grad(f, (0, 1)) if grad else f).lower(x, p).as_text()
+    assert "while" not in text
+    # every gather and scatter with the type of its result (a scatter's
+    # follows its region, lines below)
+    index_ops = [m.group(1) for m in re.finditer(
+        r'"stablehlo\.(gather|scatter)"(?s:.*?)-> tensor<[0-9x]*x(\w+)>', text)
+        if m.group(2) == "i32"]
+    assert index_ops == ["scatter"]
+    assert "stablehlo.sort" not in text
+
+
+def test_a_trace_sets_the_tiles_its_plan_was_built_over():
+    """The gauge ``moe.row_plan_tiles``: the buffer's rows over the tile,
+    set while tracing beside ``moe.row_buffer_rows``."""
+    from byteps_tpu.common.metrics import get_registry
+
+    x, p = _layer(T=40)
+    reg = get_registry()
+    jax.jit(lambda x, p: moe_ffn_dropless(x, p, 4, 2.5)[0])(x, p)
+    assert reg.gauge("moe.row_plan_tiles").value() == 10 + 16
+    assert reg.gauge("moe.row_buffer_rows").value() == (10 + 16) * 16
+    jax.jit(lambda x, p: moe_ffn_dropless(x, p, 4, 2.5, row_tile=256)[0])(
+        x, p)
+    assert reg.gauge("moe.row_plan_tiles").value() == 1 + 16
+
+
 # -- the normal train step --------------------------------------------------
 def test_trains_through_make_gpt_moe_train_step():
     from byteps_tpu.common.metrics import get_registry
