@@ -15,39 +15,64 @@ lies:
   is one dense tile-aligned ``(bs, Hkv·D)`` plane, 40 KB contiguous at
   GPT-2-large's sizes.
 * **Dead blocks cost nothing.** One invocation walks the rows; row ``r``
-  walks ``ceil(length[r] / T)`` chunks of ``T = ppb·bs`` keys, and a
-  page is fetched (one DMA for K, one for V) only where it starts below
-  the fill level. A padded row (length 1, scratch table) reads one
-  page. Chunks are double buffered across rows: while chunk ``c`` is
-  computed, chunk ``c+1`` — or the next row's first — is in flight.
+  walks ``ceil(length[r] / T)`` chunks of ``T = ppb·bs`` keys — several
+  pages a chunk (:func:`_pages_per_chunk`: 512 keys where the table is as
+  wide), so that a loop step's own cost is paid once per 512 KB of K and
+  V and not once a page — and a page is fetched (one DMA for K, one for
+  V) only where it starts below the fill level. A padded row (length 1,
+  scratch table) reads one page. Chunks are double buffered across rows:
+  while chunk ``c`` is computed, chunk ``c+1`` — or the next row's first
+  — is in flight.
 * **A window layer's row starts where its window does.** With ``first``
   (one scalar-prefetched position a row) a row walks the chunks from the
   one holding ``first[r]`` on: pages wholly below it are not fetched (the
   cache has handed their blocks back; the table still has an entry a
   logical block, 0 where one was released), keys below it are masked like
-  keys past the fill level. Without ``first`` the trace is the kernel as
-  it was: the branch is taken in Python.
+  keys past the fill level. Without ``first`` the trace is the kernel
+  without a window: the branch is taken in Python.
 * **Garbage never reaches the output.** The gathered view zeroed every
   position at or past the fill level; here scores there take ``_NEG``
-  and their ``p`` is forced to 0, and V's rows there are selected to 0
-  before ``p·V``, so whatever a recycled block or a stale buffer holds
-  (NaN, inf) cannot enter (0 × NaN).
+  and their ``p`` is forced to 0, and V's rows there are 0 before
+  ``p·V``, so whatever a recycled block holds (NaN, inf) cannot enter
+  (0 × NaN). Only a chunk that holds a row's end pays for that — its
+  fill level, its first live key, a page that was not fetched; a chunk
+  whose every key is live takes the path with no compare and no select.
+  V is cleaned in the buffer: the buffers start as zeros, a dead page is
+  never fetched, and the one score tile of rows in which a fetched page
+  can have dead rows (the tile the row's end lies in) is selected to 0
+  before the chunk's products; so what a dead page's rows of a buffer
+  hold is zeros or an earlier chunk's cleaned rows, finite either way.
 
 A decode query is one row per head, which gives the MXU nothing to
-tile head by head. So the heads go through it together: the caller's
-``q (R, H, D)`` is laid out block-diagonally as ``(R, H, Hkv·D)`` (row
-``h`` holds its query in its kv head's ``D`` columns and zeros
-elsewhere), scores are ONE ``(H, Hkv·D) × (T, Hkv·D)ᵀ`` matmul per
-chunk — the zeros pick each head's own keys, and the ``G = H/Hkv`` query
-heads of a group ride their kv head's columns (GQA reads the narrow
-pool once) — and ``p·V`` is one ``(H, T) × (T, Hkv·D)`` matmul whose
-diagonal blocks are the output; the wrapper picks them out. The MXU
-does ``Hkv`` times the needed FLOPs and is still far from the limit:
-the chunk's time is its weight-tile loads, the same number as bytes/32
-KB. Online softmax ``(m, l, acc)`` in VMEM with keys on lanes, f32
-scores and accumulation, K and V in the pool's dtype (``p`` rounded to
-it for ``p·V``, as the MXU rounds the twin's), output in ``q.dtype``:
-the contract of ``ops/flash_decode.py``, and of ``_gather_view`` +
+tile head by head, so a chunk's two products take several heads' rows
+at once, in one of two layouts chosen from the head size while tracing
+(:func:`_form`; the DMA walk, the online softmax over all rows and the
+masking are shared, and only ``heads`` in the kernel knows which):
+
+* **grouped**, where a k/v head's ``D`` columns are whole lane tiles of
+  the page as it lies (``D % 128 == 0``): q goes in as ``(R, Hkv·M, D)``,
+  the ``M = nq·G`` query rows of k/v head ``j`` (its ``G = H/Hkv`` query
+  heads, of each of the row's ``nq`` queries, padded to whole sublanes)
+  in rows ``[j·M, (j+1)·M)``; scores are ``q[j] (M, D) × K[:, j·D:(j+1)·D]ᵀ``
+  and the output ``p[j] (M, T) × V[:, j·D:(j+1)·D]``, head by head. No zero
+  is multiplied and ``acc`` is ``D`` wide.
+* **diagonal**, for narrow heads (GPT-2-large: 20 heads of 64, ``G`` =
+  1): q is laid out block-diagonally as ``(R, nq·H, Hkv·D)`` (row ``h``
+  holds its query in its kv head's ``D`` columns and zeros elsewhere),
+  scores are ONE ``(H, Hkv·D) × (T, Hkv·D)ᵀ`` product per chunk — the
+  zeros pick each head's own keys — and ``p·V`` is one ``(H, T) × (T,
+  Hkv·D)`` product whose diagonal blocks are the output; the wrapper
+  picks them out. ``Hkv`` times the needed FLOPs, and one ``(20, 1280)``
+  product instead of twenty one-row ones.
+
+Either way the chunk's time is its bytes: K and V pass through the MXU
+as its stationary operand once, the same number of tile loads as
+bytes/32 KB, and at 512 keys a chunk a row of several chunks runs at
+about 90% of the memory's rate on a v5e (PERF.md §5, PR 53). Online
+softmax ``(m, l, acc)`` in VMEM with keys on lanes, f32 scores and
+accumulation, K and V in the pool's dtype (``p`` rounded to it for
+``p·V``, as the MXU rounds the twin's), output in ``q.dtype``: the
+contract of ``ops/flash_decode.py``, and of ``_gather_view`` +
 ``attention_lse_jnp`` restricted to the live prefix (pinned in
 ``tests/test_paged_attention.py``). Quantised pools (int8 + per-row
 scales) are not taken: :func:`unsupported_reason` says so and the step
@@ -64,21 +89,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from byteps_tpu.common.metrics import get_registry
 from byteps_tpu.ops.backend import interpret as _interpret
 from byteps_tpu.ops.flash_attention import _NEG, _out_struct, _unify_vma
 
 __all__ = ["paged_attention_decode", "unsupported_reason"]
 
-_CHUNK_TOKENS = 128                  # keys per chunk: one MXU tile wide
+_CHUNK_TOKENS = 512                  # keys per chunk: 2 MB of the budget
+_SCORE_TILE = 128                    # keys of one lane tile of scores
 _BUFFER_BUDGET = 8 * 1024 * 1024     # VMEM for the 2 slots of K and of V
+_SUBLANES = 8                        # a k/v head's query rows come in these
+
+
+def _tile_pages(bs: int) -> int:
+    """Pages of one lane tile of scores (a page of 128 rows or more: one)."""
+    return max(1, _SCORE_TILE // bs)
 
 
 def _pages_per_chunk(W: int, bs: int, row_bytes: int) -> int:
     """Pages fetched and computed per loop iteration: ``_CHUNK_TOKENS``
     keys, never more than the table is wide, the four chunk buffers
-    inside their budget (0: not even one page fits)."""
-    return min(W, max(1, _CHUNK_TOKENS // bs),
-               _BUFFER_BUDGET // (4 * bs * row_bytes))
+    inside their budget (0: not even one page fits); from one lane tile
+    of scores up, whole lane tiles."""
+    ppb = min(W, max(1, _CHUNK_TOKENS // bs),
+              _BUFFER_BUDGET // (4 * bs * row_bytes))
+    tile = _tile_pages(bs)
+    return ppb if ppb < tile else ppb // tile * tile
+
+
+def _form(head_dim: int) -> str:
+    """How a chunk's two products are laid out. ``grouped``: a k/v head's
+    query rows meet that head's columns of the page and nothing else; it
+    needs those columns to be whole lane tiles of the page as it lies.
+    ``diagonal``: all heads in one product over the whole row, each query
+    in its head's columns of a block-diagonal layout — the right form for
+    narrow heads, where a k/v head's columns are a fraction of a lane tile
+    and the heads' rows together are one MXU pass. The head size alone
+    decides: at every ``nq * G`` rows a head that the cells bring (8, 5
+    padded to 8, 32) grouped read as fast as diagonal or faster on the
+    chip (PERF.md §6, PR 53)."""
+    return "grouped" if head_dim % 128 == 0 else "diagonal"
 
 
 def unsupported_reason(block_size: int, kv_heads: int, head_dim: int,
@@ -111,14 +161,20 @@ class _Shifted:
 
 
 def _kernel(len_ref, *refs, scale: float, R: int, W: int, bs: int, ppb: int,
-            windowed: bool = False, groups: int = 1):
+            nh: int = 1, windowed: bool = False, groups: int = 1):
     # a windowed trace prefetches one scalar array more: each row's first
-    # live key. Without it nothing below differs from the kernel as it was
+    # live key. Without it nothing below differs from the kernel without
     first_ref = refs[0] if windowed else None
     (tab_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
      m_scr, l_scr, acc_scr) = refs[1:] if windowed else refs
-    H, HD = acc_scr.shape
+    # ``nh`` products a chunk, each of M query rows over Dw columns of the
+    # page: the k/v heads with their own columns (grouped), or one over
+    # the whole row (diagonal). The two calls of ``heads`` are all that
+    # knows the form: scores, softmax and accumulator are of all rows
+    H, Dw = acc_scr.shape
+    M = H // nh
     T = bs * ppb
+    tile = bs * min(ppb, _tile_pages(bs))             # rows a scrub visits
     layer = layer_ref[0]
     if groups > 1:
         # the batch in ``groups`` grid steps of ``R`` rows each (a block of
@@ -138,56 +194,96 @@ def _kernel(len_ref, *refs, scale: float, R: int, W: int, bs: int, ppb: int,
 
     def page_copies(r, c, slot, i):
         blk = tab_ref[r * W + jnp.minimum(c * ppb + i, W - 1)]
-        rows = pl.ds(i * bs, bs)
+        rows = pl.ds(pl.multiple_of(i * bs, bs), bs)
         return (pltpu.make_async_copy(k_hbm.at[layer, blk],
                                       kbuf.at[slot, rows], sems.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[layer, blk],
                                       vbuf.at[slot, rows], sems.at[1, slot]))
 
     def dma(r, c, slot, wait: bool):
-        # a page is live where it starts below the row's fill level;
-        # start and wait walk the SAME predicate, so every DMA issued
-        # is awaited and no dead page is ever fetched
-        for i in range(ppb):
-            live = (c * ppb + i) * bs < len_ref[r]
-            if windowed:           # and it ends above the first live key
-                live &= (c * ppb + i + 1) * bs > first_ref[r]
+        # a page is live where it starts below the row's fill level (and,
+        # under a window, ends above the first live key): the chunk's live
+        # pages are ``[lo, hi)`` of its ``ppb``. Start and wait walk the
+        # SAME pages, so every DMA issued is awaited and no dead page is
+        # ever fetched; a dead page's rows of the buffer keep what an
+        # earlier chunk left there
+        hi = jnp.clip(pl.cdiv(len_ref[r], bs) - c * ppb, 0, ppb)
+        lo = jnp.clip(first_ref[r] // bs - c * ppb, 0, ppb) if windowed else 0
 
-            @pl.when(live)
-            def _(i=i):
-                for cp in page_copies(r, c, slot, i):
-                    cp.wait() if wait else cp.start()
+        def page(i, carry):
+            for cp in page_copies(r, c, slot, i):
+                cp.wait() if wait else cp.start()
+            return carry
 
-    def chunk(r, c, slot, length, first):
-        k = kbuf[slot]                                    # (T, HD)
+        jax.lax.fori_loop(lo, hi, page, 0)
+
+    def heads(product, rows, pages):
+        # ``product`` of each head's rows of ``rows (H, ·)`` with its columns
+        # of the chunk ``pages (T, nh·Dw)``, the heads' results one under
+        # another again
+        out = [product(rows[j * M:(j + 1) * M], pages[:, j * Dw:(j + 1) * Dw])
+               for j in range(nh)]
+        return out[0] if nh == 1 else jnp.concatenate(out, axis=0)
+
+    def scrub(c, slot, length, first):
+        # V's rows at or past the fill level, or below the first live key,
+        # of a page that WAS fetched (a partly filled block's tail) may hold
+        # NaN/inf, and 0 x NaN is NaN: they are selected to 0 in the buffer,
+        # a score tile's rows at a time and only the tile a row's end lies
+        # in. The buffers start as zeros, so the rows of a page that was
+        # not fetched hold zeros or an earlier chunk's rows, scrubbed when
+        # they were its: finite either way
+        for lo in range(0, T, tile):
+            at = c * T + lo
+            ends = jnp.logical_and(at < length, at + tile > length)
+            if windowed:
+                ends |= jnp.logical_and(at < first, at + tile > first)
+
+            @pl.when(ends)
+            def _(lo=lo, at=at):
+                v = vbuf[slot, lo:lo + tile]
+                row_at = at + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+                rows_live = row_at < length
+                if windowed:
+                    rows_live &= row_at >= first
+                vbuf[slot, lo:lo + tile] = jnp.where(rows_live, v,
+                                                     jnp.zeros((), v.dtype))
+
+    def chunk(r, c, slot, length, first, masked: bool):
+        # ``masked``: the chunk holds a row's end (its fill level, its first
+        # live key, a page that was not fetched), so some keys are dead:
+        # their scores take ``_NEG`` and their ``p`` is forced to 0
+        if masked:
+            scrub(c, slot, length, first)
+            at = c * T + jax.lax.broadcasted_iota(jnp.int32, (H, T), 1)
+            live = at < length
+            if windowed:
+                live &= at >= first
+        q = q_ref[r]                                          # (H, Dw)
+        k = kbuf[slot]                                        # (T, nh·Dw)
         v = vbuf[slot]
-        q = q_ref[r]                                      # (H, HD)
         ct = jnp.promote_types(q.dtype, k.dtype)
-        s = jax.lax.dot_general(
-            q.astype(ct), k.astype(ct), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (H, T)
-        at = c * T + jax.lax.broadcasted_iota(jnp.int32, (H, T), 1)
-        live = at < length
-        if windowed:
-            live &= at >= first
-        s = jnp.where(live, s, _NEG)
-        m_prev = m_scr[...]                               # (H, 1)
+        s = heads(lambda q, k: jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32),
+            q.astype(ct), k.astype(ct)) * scale               # (H, T)
+        if masked:
+            s = jnp.where(live, s, _NEG)
+        m_prev = m_scr[...]                                   # (H, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - m_new)
+        if masked:
+            p = jnp.where(live, p, 0.0)
         l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
-        # rows at or past the fill level: a partly filled block's tail,
-        # or a page this chunk never fetched — either may hold NaN/inf
-        row_at = c * T + jax.lax.broadcasted_iota(jnp.int32, (T, HD), 0)
-        rows_live = row_at < length
-        if windowed:               # a page the window only partly covers,
-            rows_live &= row_at >= first    # or one behind it never fetched
-        v = jnp.where(rows_live, v, jnp.zeros((), v.dtype))
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (H, HD)
+        acc_scr[...] = acc_scr[...] * alpha + heads(
+            lambda p, v: jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32),
+            p.astype(v.dtype), v)                             # (H, Dw)
         m_scr[...] = m_new
 
+    vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
     dma(0, first_chunk(0), 0, wait=False)
 
     def row(r, n):
@@ -212,7 +308,19 @@ def _kernel(len_ref, *refs, scale: float, R: int, W: int, bs: int, ppb: int,
                 dma(nxt, first_chunk(nxt), 1 - slot, wait=False)
 
             dma(r, c, slot, wait=True)
-            chunk(r, c, slot, length, first)
+            # every key of the chunk is live: no mask, no select
+            whole = (c + 1) * T <= length
+            if windowed:
+                whole &= c * T >= first
+
+            @pl.when(whole)
+            def _():
+                chunk(r, c, slot, length, first, masked=False)
+
+            @pl.when(jnp.logical_not(whole))
+            def _():
+                chunk(r, c, slot, length, first, masked=True)
+
             return n + 1
 
         n = jax.lax.fori_loop(first_chunk(r), nchunks, step, n)
@@ -224,31 +332,36 @@ def _kernel(len_ref, *refs, scale: float, R: int, W: int, bs: int, ppb: int,
     jax.lax.fori_loop(0, R, row, jnp.int32(0))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "groups"))
-def _paged(qbd, k_pool, v_pool, tables, lengths, layer, scale: float,
-           interpret: bool, first=None, groups: int = 1):
-    """qbd: (R, H, Hkv·D) block-diagonal queries; pools (L, NB, bs,
-    Hkv·D) → (R, H, Hkv·D), row ``h``'s output in its kv head's block.
-    ``first (R,)``: each row's first live key (None: key 0, and the trace
-    of the kernel as it was without a window). ``groups``: grid steps the
-    ``R`` rows are walked in (it divides ``R``)."""
-    R, H, HD = qbd.shape
-    bs = k_pool.shape[2]
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "ppb", "groups"))
+def _paged(q, k_pool, v_pool, tables, lengths, layer, scale: float,
+           interpret: bool, ppb: int, first=None, groups: int = 1):
+    """q: (R, H, Dw) — ``nh = Hkv·D / Dw`` products a chunk, each of ``H /
+    nh`` query rows over ``Dw`` columns of a pool row (grouped: a k/v
+    head's rows together, ``Dw = D``; diagonal: one product, the queries
+    block-diagonal over the whole row); pools (L, NB, bs, Hkv·D) → o like q.
+    ``ppb``: pages a chunk (:func:`_pages_per_chunk`). ``first (R,)``: each
+    row's first live key (None: key 0, and the trace of the kernel without
+    a window). ``groups``: grid steps the ``R`` rows
+    are walked in (it divides ``R``)."""
+    R, H, Dw = q.shape
+    bs, HD = k_pool.shape[2:]
     W = tables.shape[1]
-    ppb = _pages_per_chunk(W, bs, HD * k_pool.dtype.itemsize)
     windowed = first is not None
     R = R // groups                    # rows a grid step
     kern = functools.partial(_kernel, scale=scale, R=R, W=W, bs=bs, ppb=ppb)
+    if HD != Dw:
+        kern = functools.partial(kern, nh=HD // Dw)
     if windowed:
         kern = functools.partial(kern, windowed=True)
-    if groups > 1:                     # one group: the trace as it was
+    if groups > 1:                     # one group: the trace without them
         kern = functools.partial(kern, groups=groups)
     operands = _unify_vma(
         lengths.astype(jnp.int32),
         *((first.astype(jnp.int32),) if windowed else ()),
         tables.reshape(-1).astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1), qbd, k_pool, v_pool)
-    whole = pl.BlockSpec((R, H, HD), (lambda i, *_: (0, 0, 0))
+        jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool, v_pool)
+    whole = pl.BlockSpec((R, H, Dw), (lambda i, *_: (0, 0, 0))
                          if groups == 1 else (lambda i, *_: (i, 0, 0)))
     return pl.pallas_call(
         kern,
@@ -263,12 +376,12 @@ def _paged(qbd, k_pool, v_pool, tables, lengths, layer, scale: float,
             scratch_shapes=[
                 pltpu.VMEM((2, ppb * bs, HD), k_pool.dtype),
                 pltpu.VMEM((2, ppb * bs, HD), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),      # (k|v, slot)
-                pltpu.VMEM((H, 1), jnp.float32),      # m
-                pltpu.VMEM((H, 1), jnp.float32),      # l
-                pltpu.VMEM((H, HD), jnp.float32),     # acc
+                pltpu.SemaphoreType.DMA((2, 2)),        # (k|v, slot)
+                pltpu.VMEM((H, 1), jnp.float32),        # m
+                pltpu.VMEM((H, 1), jnp.float32),        # l
+                pltpu.VMEM((H, Dw), jnp.float32),       # acc
             ]),
-        out_shape=_out_struct(qbd.shape, qbd.dtype, *operands),
+        out_shape=_out_struct(q.shape, q.dtype, *operands),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -296,16 +409,13 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer,
     wholly below ``first[r]`` is not visited, a page wholly below it not
     fetched (its table entry may be a released block's, whatever it reads),
     a key below it scores ``_NEG`` with ``p`` forced to 0. ``None`` traces
-    the kernel without any of that. Callers gate on
+    the kernel without any of that. The layout of a chunk's products
+    (:func:`_form`) and the keys a chunk (:func:`_pages_per_chunk`) follow
+    from the shapes, and the trace counts them: ``paged_attn.plan.<form>.
+    <keys>`` once a trace of a layer. Callers gate on
     :func:`unsupported_reason` / ``backend.use_pallas``."""
-    nq = 1
-    if q.ndim == 4:
-        # a block of queries a row: its nq x H query rows ride the layout
-        # the H heads have, query-major (row j is head j % H of query j // H)
-        nq = q.shape[1]
-        q = q.reshape(q.shape[0], -1, q.shape[-1])
-    R, H, D = q.shape
-    H //= nq
+    nq = q.shape[1] if q.ndim == 4 else 1
+    R, H, D = q.shape[0], q.shape[-2], q.shape[-1]
     bs, HD = k_pool.shape[2:]
     Hkv = HD // D
     if HD != Hkv * D or H % Hkv != 0:
@@ -315,20 +425,37 @@ def paged_attention_decode(q, k_pool, v_pool, tables, lengths, layer,
     if why is not None:
         raise ValueError(f"paged_attention_decode: {why}; gate on "
                          "unsupported_reason()")
-    # head h belongs to kv head h // G (group-major, as flash_decode)
-    head = jnp.arange(nq * H)[:, None]
-    if nq > 1:
-        head = head % H
-    own = (head // (H // Hkv)
-           == jnp.arange(Hkv)[None, :])[None, :, :, None]  # (1, nq·H, Hkv, 1)
-    qbd = jnp.where(own, q[:, :, None, :], jnp.zeros((), q.dtype))
+    G = H // Hkv             # head h belongs to kv head h // G (group-major)
+    form = _form(D)
+    ppb = _pages_per_chunk(tables.shape[1], bs, HD * k_pool.dtype.itemsize)
+    get_registry().counter(f"paged_attn.plan.{form}.{ppb * bs}").inc()
+    # a block of queries a row: its nq query rows of a head ride beside the
+    # head's own, query-major
+    qh = q.reshape(R, nq, Hkv, G, D)
+    if form == "grouped":
+        # (R, Hkv, nq·G, D): a k/v head's rows together, padded to whole
+        # sublanes (zero queries: finite, and dropped below)
+        M = nq * G
+        qk = qh.transpose(0, 2, 1, 3, 4).reshape(R, Hkv, M, D)
+        qk = jnp.pad(qk, ((0, 0), (0, 0), (0, -M % _SUBLANES), (0, 0)))
+        qk = qk.reshape(R, -1, D)
+    else:
+        # (R, nq·H, Hkv·D): row h holds its query in its kv head's D columns
+        # and zeros elsewhere
+        own = (jnp.arange(Hkv)[:, None, None]
+               == jnp.arange(Hkv)[None, None, :])[..., None]  # (Hkv,1,Hkv,1)
+        qk = jnp.where(own, qh[:, :, :, :, None, :], jnp.zeros((), q.dtype))
+        qk = qk.reshape(R, nq * H, HD)
     # q and o are whole in VMEM: nq times the rows go in as many grid steps
     groups = nq if R % nq == 0 else 1
-    o = _paged(qbd.reshape(R, nq * H, HD), k_pool, v_pool, tables, lengths,
-               layer, 1.0 / (D ** 0.5), _interpret(), first=first,
-               groups=groups)
-    # the diagonal blocks; a select, so an off-diagonal product (some
-    # other head's V) never meets arithmetic
-    o = jnp.where(own, o.reshape(R, nq * H, Hkv, D), jnp.zeros((), o.dtype))
-    o = o.sum(axis=2)
-    return o if nq == 1 else o.reshape(R, nq, H, D)
+    o = _paged(qk, k_pool, v_pool, tables, lengths, layer, 1.0 / (D ** 0.5),
+               _interpret(), ppb, first=first, groups=groups)
+    if form == "grouped":
+        o = o.reshape(R, Hkv, -1, D)[:, :, :M]
+        o = o.reshape(R, Hkv, nq, G, D).transpose(0, 2, 1, 3, 4)
+    else:
+        # the diagonal blocks; a select, so an off-diagonal product (some
+        # other head's V) never meets arithmetic
+        o = jnp.where(own, o.reshape(R, nq, Hkv, G, Hkv, D),
+                      jnp.zeros((), o.dtype)).sum(axis=4)
+    return o.reshape(q.shape)

@@ -433,7 +433,7 @@ def test_pages_and_chunks_are_whole_blocks(params):
 def test_paged_decode_with_a_block_of_queries_against_its_twin(monkeypatch,
                                                                dtype, tol):
     """32 query heads on 4 kv heads of 128, blocks of 16, 4 queries a row: the
-    ``4 x 32`` query rows of a batch row ride the heads' layout, the batch in
+    ``4 x 8`` query rows of a k/v head meet that head's columns, the batch in
     4 grid steps. Rows of one block, rows many pages long, a padded row, the
     scratch block poisoned; every query of a row sees all of the row's
     keys."""

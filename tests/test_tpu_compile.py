@@ -129,6 +129,17 @@ def _paged_decode(W):
                _sds((8,), I32)]
 
 
+def _paged_decode_cell(q_tail, pool, W, windowed=False, R=128):
+    # a drawn cell's decode attention: pages of 128 rows x 512 values, a
+    # chunk of 4 of them; ``q_tail`` the query's shape after the rows
+    def f(q, k, v, tables, lengths, *first):
+        return paged_attention_decode(q, k, v, tables, lengths, 1,
+                                      first=first[0] if first else None)
+    pool = _sds(pool + (128, 512), BF16)
+    return f, [_sds((R,) + q_tail, BF16), pool, pool, _sds((R, W), I32),
+               _sds((R,), I32)] + [_sds((R,), I32)] * windowed
+
+
 def _gmm_fwd_bwd(lhs, w_up, w_down, sizes):
     # one expert stack up and one down, as parallel/moe.py chains them
     def loss(lhs, w_up, w_down):
@@ -221,6 +232,15 @@ ONE_CHIP = [
     ("flash_decode_int8", *_decode(True), 1),
     ("paged_attn_decode_gpt2l_w64", *_paged_decode(64), 1),
     ("paged_attn_decode_gpt2l_w2", *_paged_decode(2), 1),
+    # heads of 128: a k/v head's rows with that head's columns (grouped).
+    # SDAR's block of 4 queries a row, 32 rows a k/v head, the batch in 4
+    # grid steps; Mellum2's window layers, 8 rows a head and a first key
+    ("paged_attn_decode_sdar_b4_w64",
+     *_paged_decode_cell((4, 32, 128), (6, 2305), 64), 1),
+    ("paged_attn_decode_sdar_b4_w2",
+     *_paged_decode_cell((4, 32, 128), (6, 2305), 2), 1),
+    ("paged_attn_decode_mellum2_window_w256",
+     *_paged_decode_cell((32, 128), (6, 318), 256, windowed=True, R=24), 1),
     ("onebit_pack", ob.onebit_pack, [_sds((PART,), F32)], 1),
     *[(f"onebit_unpack_sum_k{k}",
        functools.partial(ob.onebit_unpack_sum, n=PART),
