@@ -33,18 +33,20 @@ def merged(base: Dict, over: Dict) -> Dict:
     return out
 
 
-def layer_metrics_for(driver: str, reports: List[str], chips: int,
+def layer_metrics_for(sets: List[str], reports: List[str], chips: int,
                       metrics: Optional[List[Dict]] = None) -> List[Dict]:
     """The rule that picks a cell's per-layer metrics: every file in
-    ``benchmark/layer_metrics/`` whose ``driver`` is the cell's (or
-    ``any``), whose ``moves`` metric the cell's traffic reports, and whose
-    ``min_chips`` (default 1) the cell has."""
+    ``benchmark/layer_metrics/`` whose ``set`` is ``any`` or among ``sets``
+    (the ``metric_sets`` of the cell's traffic file), whose ``moves`` metric
+    the cell's traffic reports, and whose ``min_chips`` (default 1) the cell
+    has. The binding is on the cell's side, so a new cell joins a shared
+    metric from files of its own."""
     if metrics is None:
         metrics = [load_json(p) for p in sorted(
             glob.glob(os.path.join(HERE, "layer_metrics", "*.json")))]
     return [m for m in metrics
-            if m["driver"] in (driver, "any") and m["moves"] in reports
-            and chips >= m.get("min_chips", 1)]
+            if (m["set"] == "any" or m["set"] in sets)
+            and m["moves"] in reports and chips >= m.get("min_chips", 1)]
 
 
 class Run:
@@ -209,7 +211,7 @@ class Run:
                 device["window_s"] = self.reduced.window_s
                 line["breakdown"] = self.reduced.breakdown()
             observed = dict(observed, compiles=self.compiles)
-            for m in layer_metrics_for(self.traffic["driver"],
+            for m in layer_metrics_for(self.traffic["metric_sets"],
                                        self.traffic["reports"], self.chips):
                 reader = importlib.import_module(
                     f"benchmark.readers.{m['reader']}")

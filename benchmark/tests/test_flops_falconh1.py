@@ -1,13 +1,13 @@
 """``benchmark/flops_falconh1.py`` on shapes small enough to count by hand and
 at the published sizes, and the reader that feeds it
-(``readers/fh_kernel_roofline.py``) on a made-up trace: what it divides, and
+(``readers/kernel_roofline.py``) on a made-up trace: what it divides, and
 that it returns nothing (and does not raise) where the program keeps no such
 series — the parent of the PR that added it."""
 
 import pytest
 
 from benchmark import flops_falconh1 as ff
-from benchmark.readers import fh_kernel_roofline
+from benchmark.readers import kernel_roofline
 
 G = {"n_heads": 4, "n_kv_heads": 2, "head_dim": 2, "n_layers": 3,
      "ssm_heads": 4, "ssm_groups": 2, "ssm_state": 3, "ssm_head_dim": 5}
@@ -53,7 +53,8 @@ class _Reduced:
 class _Run:
     reduced = _Reduced()
     device = {"kind": "TPU v5 lite"}
-    config = {"gpt_config": PUB}
+    config = {"flops": "flops_falconh1",
+              "gpt_config": PUB}
 
 
 def _observed(rows):
@@ -66,15 +67,15 @@ def test_reader_divides_the_roofline_time_by_the_named_events_time():
     # 128 (row, layer) pairs x 8,425,856 B = 1.0785 GB: 1.317 ms at 819
     # GB/s, over the 2 ms of the two events named ssd_decode
     observed = _observed(128)
-    pct = fh_kernel_roofline.read(_Run(), observed, ["ssd_decode"],
-                                  "ssd_decode")
+    pct = kernel_roofline.read(_Run(), observed, ["ssd_decode"],
+                               "ssd_decode")
     assert pct == pytest.approx(100 * 128 * 8425856 / 819e9 / 2e-3)
-    assert observed["notes"]["fh_ssd_decode_roofline_bound"] == "bytes"
+    assert observed["notes"]["ssd_decode_roofline_bound"] == "bytes"
 
 
 def test_reader_returns_nothing_where_there_is_nothing_to_read():
     run, names = _Run(), ["ssd_decode"]
-    read = fh_kernel_roofline.read
+    read = kernel_roofline.read
     assert read(run, {}, names, "ssd_decode") is None
     assert read(run, {"counters": {"end": {}}, "histograms": {"end": {}}},
                 names, "ssd_decode") is None

@@ -1,10 +1,10 @@
 """``benchmark/flops_mellum2.py`` on shapes small enough to count by hand, and
-the reader that feeds it (``readers/mel_kernel_roofline.py``) on a made-up
+the reader that feeds it (``readers/kernel_roofline.py``) on a made-up
 trace: what it divides, and that it returns nothing (and does not raise) where
 the program keeps no such series — the parent of the PR that added it."""
 
 from benchmark import flops_mellum2 as fm
-from benchmark.readers import mel_kernel_roofline
+from benchmark.readers import kernel_roofline
 
 G = {"d_model": 8, "d_ff_expert": 4, "n_heads": 4, "n_kv_heads": 2,
      "head_dim": 2, "n_layers": 3}
@@ -56,7 +56,8 @@ class _Reduced:
 class _Run:
     reduced = _Reduced()
     device = {"kind": "TPU v5 lite"}
-    config = {"gpt_config": dict(G, n_heads=32, n_kv_heads=4, head_dim=128)}
+    config = {"flops": "flops_mellum2",
+              "gpt_config": dict(G, n_heads=32, n_kv_heads=4, head_dim=128)}
 
 
 def _observed(keys):
@@ -71,15 +72,15 @@ def _observed(keys):
 def test_reader_divides_the_roofline_time_by_the_named_events_time():
     # 819,000 keys x 2,048 B = 1.677 GB: 2.048 ms at 819 GB/s, over 2 ms
     observed = _observed(819000)
-    pct = mel_kernel_roofline.read(_Run(), observed, ["paged_attn_decode"],
-                                   "paged_attention")
+    pct = kernel_roofline.read(_Run(), observed, ["paged_attn_decode"],
+                               "paged_attention")
     assert abs(pct - 102.4) < 1e-6
-    assert observed["notes"]["mel_paged_attention_roofline_bound"] == "bytes"
+    assert observed["notes"]["paged_attention_roofline_bound"] == "bytes"
 
 
 def test_reader_returns_nothing_where_there_is_nothing_to_read():
     run, names = _Run(), ["paged_attn_decode"]
-    read = mel_kernel_roofline.read
+    read = kernel_roofline.read
     # no counters at all (a program without them); no trace start marked
     assert read(run, {}, names, "paged_attention") is None
     assert read(run, {"counters": {"end": {}}, "histograms": {"end": {}}},
@@ -89,8 +90,13 @@ def test_reader_returns_nothing_where_there_is_nothing_to_read():
     # no such event in the trace; another model's configuration; no trace
     assert read(run, _observed(5), ["moe_gmm_fwd"], "paged_attention") is None
     other = _Run()
-    other.config = {"gpt_config": {"d_model": 8}}
+    other.config = {"gpt_config": {"d_model": 8}}      # names no module
     assert read(other, _observed(5), names, "paged_attention") is None
+    # a configuration whose module has no such function (Falcon-H1 has no
+    # experts): nothing to read, where the parent's reader was another file
+    other.config = dict(_Run.config, flops="flops_falconh1")
+    assert read(other, _observed(5), names, "expert_products") is None
+    assert read(other, _observed(5), names, "paged_attention") is not None
     other = _Run()
     other.reduced = None
     assert read(other, _observed(5), names, "paged_attention") is None

@@ -1,12 +1,12 @@
 """``benchmark/flops_qwen3next.py`` on shapes small enough to count by hand,
-and the reader that feeds it (``readers/qn_kernel_roofline.py``) on a made-up
+and the reader that feeds it (``readers/kernel_roofline.py``) on a made-up
 trace: what it divides, and that it returns nothing (and does not raise) where
 the program keeps no such series — the parent of the PR that added it."""
 
 import pytest
 
 from benchmark import flops_qwen3next as fq
-from benchmark.readers import qn_kernel_roofline
+from benchmark.readers import kernel_roofline
 
 G = {"d_model": 8, "d_ff_expert": 4, "n_heads": 4, "n_kv_heads": 2,
      "head_dim": 2, "n_layers": 3, "linear_value_heads": 2,
@@ -56,7 +56,8 @@ class _Reduced:
 class _Run:
     reduced = _Reduced()
     device = {"kind": "TPU v5 lite"}
-    config = {"gpt_config": PUB}
+    config = {"flops": "flops_qwen3next",
+              "gpt_config": PUB}
 
 
 def _observed(rows):
@@ -69,15 +70,15 @@ def test_reader_divides_the_roofline_time_by_the_named_events_time():
     # 192 (row, layer) pairs x 32 heads x 133,120 B = 817.9 MB: 0.9986 ms at
     # 819 GB/s, over the 2 ms of the two events named gdn_decode
     observed = _observed(192)
-    pct = qn_kernel_roofline.read(_Run(), observed, ["gdn_decode"],
-                                  "gdn_decode")
+    pct = kernel_roofline.read(_Run(), observed, ["gdn_decode"],
+                               "gdn_decode")
     assert pct == pytest.approx(100 * 192 * 32 * 133120 / 819e9 / 2e-3)
-    assert observed["notes"]["qn_gdn_decode_roofline_bound"] == "bytes"
+    assert observed["notes"]["gdn_decode_roofline_bound"] == "bytes"
 
 
 def test_reader_returns_nothing_where_there_is_nothing_to_read():
     run, names = _Run(), ["gdn_decode"]
-    read = qn_kernel_roofline.read
+    read = kernel_roofline.read
     assert read(run, {}, names, "gdn_decode") is None
     assert read(run, {"counters": {"end": {}}, "histograms": {"end": {}}},
                 names, "gdn_decode") is None

@@ -1,8 +1,8 @@
 """``benchmark/flops_sdar.py`` on shapes small enough to count by hand, the
-reader that feeds it (``readers/sd_kernel_roofline.py``) on a made-up trace —
+reader that feeds it (``readers/kernel_roofline.py``) on a made-up trace —
 what it divides, and that it returns nothing (and does not raise) where the
 program keeps no such series: the parent of the PR that added it — and the
-metric files that name the cell's driver."""
+metrics the rule gives the cell."""
 
 import glob
 import json
@@ -10,7 +10,7 @@ import os
 
 from benchmark import flops_sdar as fs
 from benchmark import harness
-from benchmark.readers import sd_kernel_roofline
+from benchmark.readers import kernel_roofline
 
 G = {"d_model": 8, "d_ff_expert": 4, "n_heads": 4, "n_kv_heads": 2,
      "head_dim": 2, "n_layers": 3, "block_length": 4}
@@ -65,7 +65,8 @@ class _Reduced:
 class _Run:
     reduced = _Reduced()
     device = {"kind": "TPU v5 lite"}
-    config = {"gpt_config": PUB}
+    config = {"flops": "flops_sdar",
+              "gpt_config": PUB}
 
 
 def _observed(keys):
@@ -78,15 +79,15 @@ def test_reader_divides_the_roofline_time_by_the_named_events_time():
     # 819,000 keys x 2,048 B = 1.677 GB: 2.048 ms at 819 GB/s, over 2 ms
     # (their 53.7 GFLOP take 0.27 ms at 197 TFLOP/s: bytes bind)
     observed = _observed(819000)
-    pct = sd_kernel_roofline.read(_Run(), observed, ["paged_attn_decode"],
-                                  "paged_attention")
+    pct = kernel_roofline.read(_Run(), observed, ["paged_attn_decode"],
+                               "paged_attention")
     assert abs(pct - 102.4) < 1e-6
-    assert observed["notes"]["sd_paged_attention_roofline_bound"] == "bytes"
+    assert observed["notes"]["paged_attention_roofline_bound"] == "bytes"
 
 
 def test_reader_returns_nothing_where_there_is_nothing_to_read():
     run, names = _Run(), ["paged_attn_decode"]
-    read = sd_kernel_roofline.read
+    read = kernel_roofline.read
     # no counters at all (a program without them); no trace start marked
     assert read(run, {}, names, "paged_attention") is None
     assert read(run, {"counters": {"end": {}}, "histograms": {"end": {}}},
@@ -105,20 +106,25 @@ def test_reader_returns_nothing_where_there_is_nothing_to_read():
     assert read(run, _observed(5), names, "expert_products") is None
 
 
-def test_every_metric_file_of_the_cell_names_its_driver():
-    """The 19 ``sd_*`` files are the cell's alone: each names ``serve_sdar``
-    (the rule then gives them to no other cell), moves the one end-to-end
-    metric the cell reports, and BENCHMARK.json lists each for this cell."""
-    files = [harness.load_json(p) for p in sorted(glob.glob(os.path.join(
-        harness.HERE, "layer_metrics", "sd_*.json")))]
-    assert len(files) == 19
-    bm = harness.load_json(harness.ROOT, "BENCHMARK.json")
-    listed = {m["name"]: m for m in bm["per_layer"]}
-    for m in files:
-        assert m["driver"] == "serve_sdar", m["name"]
-        assert m["moves"] == "serve_tokens_per_s", m["name"]
-        assert listed[m["name"]]["workloads"] == ["sdar-serve-blockgen-sat"]
+def test_every_metric_the_cell_reports_is_listed_for_it():
+    """The cell's mix names the sets its program emits; the rule gives it 19
+    metrics, four of them in a set of its own (``block_diffusion``, which no
+    other cell names), each moves the one end-to-end metric the cell
+    reports, and BENCHMARK.json lists each for this cell."""
     t = harness.load_json(harness.HERE, "traffic",
                           "chat-blockgen-backlog-sat.json")
     assert t["driver"] == "serve_sdar"
     assert json.dumps(t).count("PLACEHOLDER") == 0
+    files = [m for m in harness.layer_metrics_for(
+        t["metric_sets"], t["reports"], 1) if m["set"] != "any"]
+    assert len(files) == 19
+    own = [m["name"] for m in files if m["set"] == "block_diffusion"]
+    assert own == sorted(os.path.basename(p)[:-5] for p in glob.glob(
+        os.path.join(harness.HERE, "layer_metrics", "sd_*.json")))
+    bm = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    listed = {m["name"]: m for m in bm["per_layer"]}
+    for m in files:
+        assert m["moves"] == "serve_tokens_per_s", m["name"]
+        cells = listed[m["name"]]["workloads"]
+        assert "sdar-serve-blockgen-sat" in cells
+        assert (cells == ["sdar-serve-blockgen-sat"]) == (m["name"] in own)

@@ -27,5 +27,5 @@ def test_a_rehearsal_of_the_cell_is_correct():
     assert n["decode_steps_overlapped"] >= n["decode_steps_in_window"] - 1
     want = {"sd_passes_per_block_mean", "sd_block_gap_mean_ms",
             "sd_blocks_committed_per_s", "sd_blocks_in_use_mean",
-            "sd_moe_pairs_per_program", "compiles_in_window"}
+            "serve_moe_pairs_per_program", "compiles_in_window"}
     assert want <= set(line["rehearsal"]["would_report"])

@@ -109,7 +109,7 @@ IDLE = {
 def test_an_idle_device_reduces_to_busy_zero(shape):
     from benchmark.readers import (attention_roofline, program_idle_ms,
                                    trace_ms_per_step,
-                                   trace_named_ms_per_step, trace_share)
+                                   trace_named_ms_per_step)
 
     r = tr.Reduced(IDLE[shape], chips=1)
     assert (r.w0, r.w1) == (1000.0, 5000.0)
@@ -126,15 +126,15 @@ def test_an_idle_device_reduces_to_busy_zero(shape):
         tr.NO_SPAN: pytest.approx(100e-9)}
     assert sum(s for _, s in b["idle_gaps"]) == pytest.approx(r.window_s)
     json.dumps(b)
-    # shares of nothing and kernels that did not run are left out
+    # kernels and kinds of operation that did not run are left out: a
+    # slice with step spans and no such operation reads nothing, not 0.0
     run = types.SimpleNamespace(reduced=r, chips=1)
-    assert trace_share.read(run, {}, ["data formatting"]) is None
     assert trace_named_ms_per_step.read(
         run, {}, ["flash_fwd"], "serve.step") is None
     assert attention_roofline.read(
         run, {}, "serve.step", ["custom-call"]) is None
     assert trace_ms_per_step.read(run, {}, "serve.step",
-                                  ["all-reduce"]) == 0.0
+                                  ["all-reduce"]) is None
     assert program_idle_ms.idle_ms(
         [], r, ["serve.admit"], "serve.iteration", "serve.iteration",
         "serve.step") is None
@@ -179,8 +179,10 @@ def test_a_traced_run_of_an_idle_device_still_prints_its_line(monkeypatch,
     assert line["device"]["busy_s"] == 0
     assert line["device"]["window_s"] == pytest.approx(4000e-9)
     assert line["breakdown"]["device_ops"] == []
-    assert line["metrics"]["sat_requests_per_s"] == {"value": 0.06,
-                                                     "unit": "req/s"}
+    assert line["metrics"]["serve_requests_per_s"] == {"value": 0.06,
+                                                       "unit": "req/s"}
     assert line["metrics"]["compiles_in_window"]["value"] == 0
+    # a time nothing was spent on is left out, by name and by kind alike
     assert "sat_paged_attn_ms_per_iteration" not in line["metrics"]
+    assert "sat_weight_path_ms_per_iteration" not in line["metrics"]
     json.dumps(line)
