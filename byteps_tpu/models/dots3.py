@@ -42,12 +42,18 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
-from byteps_tpu.models.gpt import _layernorm, _mlp, _rmsnorm, rope_rotate
+from byteps_tpu.models.gpt import (
+    RopeFreqs,
+    _layernorm,
+    _mlp,
+    _rmsnorm,
+    rope_rotate,
+)
 from byteps_tpu.models.joyai import mla_latents
 from byteps_tpu.parallel.moe import moe_dropless_init, moe_ffn_dropless
 
@@ -63,7 +69,7 @@ class AttnDims(NamedTuple):
     nope: int
     rope: int
     v: int
-    theta: float
+    theta: Union[float, RopeFreqs]    # a base, or frequencies as data
     q_scale: float
     kv_scale: float
     window: Optional[int]
@@ -262,10 +268,12 @@ def latents(h, p, pos, cfg: Dots3Config, kind: str, expand: bool = False):
 def _index_rope(x, pos, cfg: Dots3Config):
     """The indexer rotates the first ``qk_rope_dim`` dims of a head, in the
     half-split convention (DeepSeek-V3.2's indexer), at the full layers'
-    base. ``x (B, S, H, Di)``."""
+    rotation (their base, or their scaled frequencies). ``x (B, S, H,
+    Di)``."""
     r = cfg.qk_rope_dim
     return jnp.concatenate(
-        [rope_rotate(x[..., :r], pos, cfg.rope_base), x[..., r:]], axis=-1)
+        [rope_rotate(x[..., :r], pos, cfg.dims(FULL).theta), x[..., r:]],
+        axis=-1)
 
 
 def index_queries(c_q, h, idx, pos, cfg: Dots3Config):
@@ -312,6 +320,32 @@ def fold_moe_stats(total, layer):
     """Pairs add over layers; the load ratio keeps its worst layer."""
     return jnp.stack([total[0] + layer[0], total[1] + layer[1],
                       jnp.maximum(total[2], layer[2])])
+
+
+class LatentModel(NamedTuple):
+    """What the two programs of ``serve/latent_step.py`` take from a model
+    of latent pages, beside its configuration (``layer_types``,
+    ``layers_of``, ``dims``, ``window``, the indexer's sizes): the pieces of
+    a block that differ between models, each a function of this module's
+    signatures. What a token leaves in the cache and both forms of the
+    attention over it (:func:`cache_row`, :func:`absorb_q`,
+    :func:`latent_attend`, :func:`unabsorb_v`) are the same for every such
+    model and are not in here."""
+
+    #: ``(h, p, pos, cfg, kind) -> (c_q, q, c_kv, k_rope)``
+    latents: Callable
+    #: ``(c_q, h, idx, pos, cfg) -> (qI, w)`` and ``(h, idx, pos, cfg) -> kI``
+    index_queries: Callable
+    index_keys: Callable
+    #: ``(o (B, S, H, v), h, p) -> (B, S, d)``: a gate or none, then ``wo``
+    attn_out: Callable
+    #: ``(x, p, cfg) -> (x, moe stats)``; ``moe_stats`` names the stats,
+    #: ``fold`` adds a layer's to the program's
+    ffn: Callable
+    moe_stats: Tuple[str, ...]
+    fold: Callable
+    #: ``(params, x, cfg) -> logits f32``
+    readout: Callable
 
 
 # --------------------------------------------------------------------------
@@ -428,3 +462,12 @@ def unabsorb_v(o_lat, p, a: AttnDims):
     wv = p["wkv_b"].reshape(a.kv_rank, a.heads, a.nope + a.v)[..., a.nope:]
     return jnp.einsum("...hr,rhv->...hv", o_lat, wv.astype(o_lat.dtype),
                       preferred_element_type=jnp.float32).astype(o_lat.dtype)
+
+
+#: dots3 as the latent programs see it
+MODEL = LatentModel(
+    latents=latents, index_queries=index_queries, index_keys=index_keys,
+    attn_out=headwise_gate, ffn=ffn,
+    moe_stats=("moe.pairs_here", "moe.pairs_total",
+               "moe.load_max_over_mean"),
+    fold=fold_moe_stats, readout=readout)

@@ -29,7 +29,7 @@ stays ``MoEGPTConfig``'s.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -236,6 +236,22 @@ def moe_specs(ep_axis: Optional[str], tp_axis: Optional[str] = None,
 # score + correction bias, pairs grouped by expert, grouped matrix products
 # over the experts held here. The Switch path above is unchanged.
 # --------------------------------------------------------------------------
+def _router_logits(xt: jnp.ndarray, wg: jnp.ndarray):
+    """``x·wg`` in f32 at full precision: a pick must not turn on bf16
+    rounding."""
+    return jax.lax.dot_general(
+        xt.astype(jnp.float32), wg.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _picked_weights(score: jnp.ndarray, idx: jnp.ndarray, scale: float):
+    """``(idx int32, scale · score_i / Σ_picked score)`` of the picks."""
+    picked = jnp.take_along_axis(score, idx, axis=-1)
+    return (idx.astype(jnp.int32),
+            scale * picked / jnp.sum(picked, axis=-1, keepdims=True))
+
+
 def sigmoid_topk_route(xt: jnp.ndarray, wg: jnp.ndarray, bias: jnp.ndarray,
                        k: int, scale: float):
     """``(idx (T, k) int32, weight (T, k) f32)`` of ``noaux_tc`` routing
@@ -244,14 +260,47 @@ def sigmoid_topk_route(xt: jnp.ndarray, wg: jnp.ndarray, bias: jnp.ndarray,
     the ``k`` experts with the largest ``s + bias``, and weights taken
     from ``s`` alone — ``scale · s_i / Σ_picked s``. ``bias`` is a buffer:
     it steers the picks and takes no gradient."""
-    s = jax.nn.sigmoid(jax.lax.dot_general(
-        xt.astype(jnp.float32), wg.astype(jnp.float32),
-        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32))
+    s = jax.nn.sigmoid(_router_logits(xt, wg))
     _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias)[None, :], k)
-    picked = jnp.take_along_axis(s, idx, axis=-1)
-    weight = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), weight
+    return _picked_weights(s, idx, scale)
+
+
+def sigmoid_group_topk_route(xt: jnp.ndarray, wg: jnp.ndarray,
+                             bias: jnp.ndarray, k: int, scale: float,
+                             n_group: int, topk_group: int):
+    """``(idx (T, k) int32, weight (T, k) f32)`` of ``noaux_tc`` routing WITH
+    its group limit (DeepSeek-V3's ``n_group`` / ``topk_group``): the ``E``
+    experts lie in ``n_group`` groups of ``E / n_group`` neighbours; a
+    group's score is the sum of its two largest choice scores ``s + bias``;
+    the ``topk_group`` groups of largest score stay and every other group's
+    experts are out (``-inf``); the ``k`` largest choice scores among those
+    left are the picks, and the weights come from ``s`` alone, as
+    :func:`sigmoid_topk_route`'s. A token's picks so lie in at most
+    ``topk_group`` groups: under expert parallelism it reaches that many
+    nodes at most. Of equal scores the lower index wins, groups and experts
+    alike (``jax.lax.top_k``). With one group the rule is
+    :func:`sigmoid_topk_route`. The routing product is f32 at full
+    precision, for that function's reason."""
+    s = jax.nn.sigmoid(_router_logits(xt, wg))
+    choice = s + jax.lax.stop_gradient(bias)[None, :]
+    T, E = choice.shape
+    per = E // n_group
+    if per * n_group != E or not 1 <= topk_group <= n_group:
+        raise ValueError(
+            f"{E} experts do not lie in {n_group} equal groups of which "
+            f"{topk_group} stay")
+    if k > topk_group * per:
+        raise ValueError(
+            f"top_k {k} > the {topk_group * per} experts of {topk_group} "
+            "groups")
+    group_score = jnp.sum(jax.lax.top_k(
+        choice.reshape(T, n_group, per), min(2, per))[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)          # (T, kept)
+    stays = jnp.any(kept[:, :, None] == jnp.arange(
+        n_group, dtype=kept.dtype)[None, None, :], axis=1)    # (T, n_group)
+    _, idx = jax.lax.top_k(jnp.where(
+        jnp.repeat(stays, per, axis=1), choice, -jnp.inf), k)
+    return _picked_weights(s, idx, scale)
 
 
 def softmax_topk_route(xt: jnp.ndarray, wg: jnp.ndarray, k: int,
@@ -261,20 +310,21 @@ def softmax_topk_route(xt: jnp.ndarray, wg: jnp.ndarray, k: int,
     over all experts in f32 (the product at full precision, as
     :func:`sigmoid_topk_route` and for its reason), the ``k`` largest, and
     ``scale · p_i / Σ_picked p``. No bias steers the picks."""
-    p = jax.nn.softmax(jax.lax.dot_general(
-        xt.astype(jnp.float32), wg.astype(jnp.float32),
-        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32), axis=-1)
+    p = jax.nn.softmax(_router_logits(xt, wg), axis=-1)
     picked, idx = jax.lax.top_k(p, k)
     weight = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), weight
 
 
 #: the routing rules of :func:`moe_ffn_dropless`: ``name -> (xt, params, k,
-#: scale) -> (idx, weight)``
+#: scale) -> (idx, weight)``; ``sigmoid_bias_groups`` takes its ``n_group``
+#: and ``topk_group`` besides (the layer's ``group_limit``)
 ROUTES = {
     "sigmoid_bias": lambda xt, p, k, scale: sigmoid_topk_route(
         xt, p["wg"], p["router_bias"], k, scale),
+    "sigmoid_bias_groups": lambda xt, p, k, scale, n_group, topk_group:
+        sigmoid_group_topk_route(xt, p["wg"], p["router_bias"], k, scale,
+                                 n_group, topk_group),
     "softmax": lambda xt, p, k, scale: softmax_topk_route(
         xt, p["wg"], k, scale),
 }
@@ -394,12 +444,14 @@ def _row_plan(local: jnp.ndarray, held: int, tm: int):
 
 def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
                      first_expert: int = 0, row_tile: Optional[int] = None,
-                     route: str = "sigmoid_bias"):
+                     route: str = "sigmoid_bias",
+                     group_limit: Optional[Tuple[int, int]] = None):
     """Top-k MoE feed-forward in which no token is ever dropped, over the
     experts THIS device holds; ``route`` names the rule that picks and
     weighs (:data:`ROUTES`: ``sigmoid_bias``, DeepSeek-V3's ``noaux_tc``,
-    or ``softmax`` with renormalised top-k weights), and everything after
-    the picks is one path.
+    ``sigmoid_bias_groups``, the same under its group limit ``group_limit
+    = (n_group, topk_group)``, or ``softmax`` with renormalised top-k
+    weights), and everything after the picks is one path.
 
     ``params``: ``wg (d, E)`` the router over all ``E`` routed experts,
     ``router_bias (E,)`` the correction bias (a buffer; ``sigmoid_bias``
@@ -427,14 +479,18 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     pair), pairs in all (those plus the pairs routed elsewhere: ``T·k``
     unless a held pair lost its row), heaviest held expert over the mean
     held expert; ``load`` f32 ``(E,)`` the tokens each of ALL routed
-    experts was picked by (what :func:`noaux_bias_step` balances)."""
+    experts was picked by (what :func:`noaux_bias_step` balances). Under a
+    ``group_limit`` ``stats`` has a fourth value: the groups that hold at
+    least one of a token's picks, the mean over the tokens (at most
+    ``topk_group``: the nodes a token reaches)."""
     from byteps_tpu.ops.grouped_matmul import grouped_matmul
 
     held = params["w1"].shape[0]
     lead, d = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, d)
     T = xt.shape[0]
-    idx, weight = ROUTES[route](xt, params, top_k, scale)
+    idx, weight = ROUTES[route](xt, params, top_k, scale,
+                                *(group_limit or ()))
     P_ = T * top_k
     tm = (dropless_row_tile(P_, held, x.dtype.itemsize)
           if row_tile is None else row_tile)
@@ -462,8 +518,15 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     stats = jnp.stack([
         here, here + jnp.sum(~is_held).astype(jnp.float32),
         jnp.max(counts).astype(jnp.float32) * held / jnp.maximum(here, 1.0)])
+    n_routed = params["wg"].shape[1]
+    if group_limit is not None:
+        of_group = idx // (n_routed // group_limit[0])         # (T, k)
+        hit = jnp.any(of_group[:, :, None] == jnp.arange(
+            group_limit[0], dtype=jnp.int32)[None, None, :], axis=1)
+        stats = jnp.concatenate([stats, jnp.mean(
+            jnp.sum(hit, axis=-1).astype(jnp.float32))[None]])
     load = jnp.sum(idx.reshape(P_, 1) == jnp.arange(
-        params["wg"].shape[1], dtype=jnp.int32)[None, :], axis=0)
+        n_routed, dtype=jnp.int32)[None, :], axis=0)
     return y.reshape(*lead, d).astype(x.dtype), stats, load.astype(jnp.float32)
 
 

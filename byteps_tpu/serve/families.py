@@ -10,8 +10,11 @@ same for every family.
 
 * :class:`GPTFamily` (``GPTConfig``): the k/v pool and the two programs of
   ``paged_cache.py``, as they were.
-* :class:`LatentFamily` (``Dots3Config``): latent pages of two layer kinds
-  and the programs of ``latent_step.py``.
+* :class:`LatentFamily` (``Dots3Config``, ``DeepSeekV32Config``): latent
+  pages of up to two layer kinds and the programs of ``latent_step.py``,
+  which take the model's own pieces as a ``LatentModel``
+  (:func:`latent_model`). A configuration with no window layer shares its
+  pages through the prefix index.
 * :class:`WindowedKVFamily` (``Mellum2Config``): k/v pages of two layer
   kinds under ``paged_cache.py``'s own two programs, each layer told its
   kind, with a dropless expert FFN in every block.
@@ -179,17 +182,35 @@ class GPTFamily:
         return None
 
 
+def latent_model(cfg):
+    """The ``models/dots3.py::LatentModel`` of a latent configuration, by
+    its type: the pieces of a block ``latent_step.py``'s programs take from
+    the model."""
+    from byteps_tpu.models import deepseek_v32, dots3
+
+    if isinstance(cfg, deepseek_v32.DeepSeekV32Config):
+        return deepseek_v32.MODEL
+    return dots3.MODEL
+
+
 class LatentFamily(_Refusing):
-    """dots3 over latent pages of two layer kinds (``latent_step.py``)."""
+    """A model of latent attention over latent pages (``latent_step.py``):
+    dots3, with full and sliding layers, and DeepSeek-V3.2-Exp, whose layers
+    are all full. A configuration with window layers does not share
+    prefixes (a window layer's released blocks cannot be shared); one
+    without shares its pages — latent rows and indexer keys, same blocks,
+    same table — through the radix index as the GPT family shares k/v
+    pages."""
 
     name = "latent"
-    shares_prefixes = False         # the configuration's default is not applied
 
     #: what the latent layout does not carry yet, each refused at
     #: construction: ``feature -> the message's subject``
+    #: (``prefix_cache``: a configuration with window layers alone)
     REFUSED = {
-        "prefix_cache": "the prefix cache (a window layer's released blocks "
-                        "cannot be shared)",
+        "prefix_cache": "the prefix cache (a configuration with window "
+                        "layers: a window layer's released blocks cannot be "
+                        "shared)",
         "speculation": "speculative decoding (the chunk program returns no "
                        "rewindable window state)",
         "adapter_pool": "LoRA adapter slabs",
@@ -199,10 +220,26 @@ class LatentFamily(_Refusing):
         "tp_axis": "tensor parallelism",
     }
 
+    def __init__(self, cfg):
+        from byteps_tpu.models.dots3 import SLIDING
+
+        self._model = latent_model(cfg)
+        self.windowed = bool(cfg.layers_of(SLIDING))
+        #: the radix index over latent pages, where every page is global
+        self.shares_prefixes = not self.windowed
+
+    def validate(self, params, cfg, features) -> None:
+        super().validate(params, cfg, {
+            k: v for k, v in features.items()
+            if k != "prefix_cache" or self.windowed})
+
     def layout(self, params, cfg, *, block_size, pool_blocks, max_batch,
                prefill_chunk, quant) -> PoolLayout:
         from byteps_tpu.serve.latent_step import init_pool
 
+        if not self.windowed:
+            return PoolLayout(state=init_pool(cfg, block_size, pool_blocks,
+                                              0))
         wb = window_pool_blocks(cfg.window, block_size, max_batch,
                                 prefill_chunk)
         return PoolLayout(
@@ -231,7 +268,7 @@ class LatentFamily(_Refusing):
     def late_stats(self):
         from byteps_tpu.serve.latent_step import LateStats
 
-        return LateStats()
+        return LateStats(self._model)
 
 
 @functools.lru_cache(maxsize=16)
@@ -519,6 +556,7 @@ class BlockDiffusionFamily(WindowedKVFamily):
 
 def serve_family(cfg):
     """The family that serves ``cfg``, by its type."""
+    from byteps_tpu.models.deepseek_v32 import DeepSeekV32Config
     from byteps_tpu.models.dots3 import Dots3Config
     from byteps_tpu.models.falcon_h1 import FalconH1Config
     from byteps_tpu.models.mellum2 import Mellum2Config
@@ -527,8 +565,8 @@ def serve_family(cfg):
 
     if isinstance(cfg, SDARConfig):
         return BlockDiffusionFamily()
-    if isinstance(cfg, Dots3Config):
-        return LatentFamily()
+    if isinstance(cfg, (Dots3Config, DeepSeekV32Config)):
+        return LatentFamily(cfg)
     if isinstance(cfg, Mellum2Config):
         return WindowedKVFamily()
     if isinstance(cfg, Qwen3NextConfig):
@@ -539,5 +577,5 @@ def serve_family(cfg):
         return GPTFamily()
     raise TypeError(
         f"Scheduler: no serve family for a {type(cfg).__name__} "
-        "(GPTConfig, Dots3Config, Mellum2Config, Qwen3NextConfig, "
-        "FalconH1Config and SDARConfig are served)")
+        "(GPTConfig, Dots3Config, DeepSeekV32Config, Mellum2Config, "
+        "Qwen3NextConfig, FalconH1Config and SDARConfig are served)")

@@ -1,14 +1,23 @@
-"""The second model step of the paged serve tier: ``models/dots3.py`` over a
-*latent* paged cache of two layer kinds.
+"""The second model step of the paged serve tier: a model of latent
+attention (MLA) over a *latent* paged cache of up to two layer kinds. One
+pair of programs for every such model: what differs between models — the
+projections, the indexer, a gate on the attention output or none, the
+feed-forward half, the readout — comes in a ``models/dots3.py::LatentModel``
+(``dots3.MODEL``, ``deepseek_v32.MODEL``; ``families.latent_model`` finds a
+configuration's), as ``paged_cache.py``'s programs take a ``StepPlan``; the
+layer kinds, their shapes and the window come from the configuration
+(``layer_types``, ``layers_of``, ``dims``, ``window``).
 
 Same contract as ``paged_cache.make_paged_decode_fn`` /
 ``make_paged_prefill_fn`` — ``(params, pool, toks, pos, tables) -> (logits,
 pool)``, the pool donated — with a pool of another shape
-(:class:`LatentPool`) and table rows of two lines: line 0 the request's
-blocks of the *global* kind (full layers keep every block), line 1 those of
-the *window* kind (sliding layers keep the blocks that hold the last
-``window`` positions; the cache hands the others back while the request
-runs, and their entries read 0, the scratch block).
+(:class:`LatentPool`). A configuration with sliding layers has table rows
+of two lines: line 0 the request's blocks of the *global* kind (full layers
+keep every block), line 1 those of the *window* kind (sliding layers keep
+the blocks that hold the last ``window`` positions; the cache hands the
+others back while the request runs, and their entries read 0, the scratch
+block). One with full layers alone (DeepSeek-V3.2-Exp) has the one line
+every family has, no window pool, and pages the prefix index may share.
 
 A token leaves in the cache, per full layer, its latent row ``[c_kv; k_rope]``
 and one indexer key; per sliding layer its (wider) latent row. Nothing is
@@ -29,14 +38,14 @@ other outputs, a step late (:class:`LateStats`): no sync is added.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from byteps_tpu.common.metrics import get_registry
 from byteps_tpu.models import dots3
-from byteps_tpu.models.dots3 import FULL, SLIDING, Dots3Config
+from byteps_tpu.models.dots3 import FULL, SLIDING, LatentModel
 from byteps_tpu.models.gpt import _rmsnorm
 from byteps_tpu.models.joyai import mla_expand
 from byteps_tpu.ops.dsa_index import index_scores
@@ -61,10 +70,14 @@ _KEY_BUCKET = 8192
 #: 32 of 128 heads x 32,768 keys x (192 + 128) values is 0.67 GB in bf16
 _HEAD_GROUP = 32
 
-#: ``pool.stats``: what one program counted, f32
-STATS = ("moe.pairs_here", "moe.pairs_total", "moe.load_max_over_mean",
-         "dsa.scored_pairs", "dsa.selected_keys", "dsa.queries",
-         "dsa.prefill_scored_pairs", "dsa.prefill_selected_keys")
+#: ``pool.stats``: what one program counted, f32 — the model's own
+#: ``moe_stats`` and after them these
+DSA_STATS = ("dsa.scored_pairs", "dsa.selected_keys", "dsa.queries",
+             "dsa.prefill_scored_pairs", "dsa.prefill_selected_keys")
+
+
+def stats_names(model: LatentModel) -> tuple:
+    return tuple(model.moe_stats) + DSA_STATS
 
 
 class LatentPool(NamedTuple):
@@ -74,39 +87,48 @@ class LatentPool(NamedTuple):
     full layers (``[c_kv; k_rope]`` and zeros to whole lane tiles); ki: ``(full layers, blocks, block_size,
     index_head_dim)`` their indexer keys (same blocks, same tables); wkv:
     ``(sliding layers, window blocks, block_size, page_row)`` the sliding
-    layers' rows, in a pool of its own, far smaller; stats:
-    :data:`STATS` of the program that last wrote the pool."""
+    layers' rows, in a pool of its own, far smaller (None without sliding
+    layers); stats: :func:`stats_names` of the program that last wrote the
+    pool."""
 
     kv: jnp.ndarray
     ki: jnp.ndarray
-    wkv: jnp.ndarray
+    wkv: Optional[jnp.ndarray]
     stats: jnp.ndarray
 
+    #: the leaves a block of the global kind has a page in, each ``(layers,
+    #: blocks, ...)``: what a copy of one block copies
+    #: (``PagedKVCache.ensure_writable``)
+    block_leaves = ("kv", "ki")
 
-def init_pool(cfg: Dots3Config, block_size: int, pool_blocks: int,
+
+def init_pool(cfg, block_size: int, pool_blocks: int,
               window_blocks: int) -> LatentPool:
+    model = families.latent_model(cfg)
     nf, nw = len(cfg.layers_of(FULL)), len(cfg.layers_of(SLIDING))
     return LatentPool(
         kv=jnp.zeros((nf, pool_blocks, block_size, cfg.dims(FULL).page_row),
                      cfg.dtype),
         ki=jnp.zeros((nf, pool_blocks, block_size, cfg.index_head_dim),
                      cfg.dtype),
-        wkv=jnp.zeros((max(nw, 1), window_blocks, block_size,
-                       cfg.dims(SLIDING).page_row), cfg.dtype),
-        stats=jnp.zeros((len(STATS),), jnp.float32))
+        wkv=jnp.zeros((nw, window_blocks, block_size,
+                       cfg.dims(SLIDING).page_row), cfg.dtype) if nw
+        else None,
+        stats=jnp.zeros((len(stats_names(model)),), jnp.float32))
 
 
 class LateStats(families.LateStats):
-    """:data:`STATS` of each dispatched program into the ``moe.*`` and
-    ``serve.dsa.*`` series."""
+    """:func:`stats_names` of each dispatched program into the ``moe.*``
+    and ``serve.dsa.*`` series."""
 
-    names = STATS
-
-    def __init__(self):
+    def __init__(self, model: LatentModel):
         super().__init__()
+        self.names = stats_names(model)
         reg = get_registry()
         self._pairs_here = reg.histogram("moe.pairs_here")
         self._load = reg.histogram("moe.load_max_over_mean")
+        self._groups = reg.histogram("moe.groups_hit") \
+            if "moe.groups_hit" in self.names else None
         self._per_query = reg.histogram("serve.dsa.selected_per_query")
         self._scored = reg.counter("serve.dsa.scored_pairs")
         self._selected = reg.counter("serve.dsa.selected_keys")
@@ -117,6 +139,8 @@ class LateStats(families.LateStats):
     def observe(self, s: dict) -> None:
         self._pairs_here.observe(s["moe.pairs_here"])
         self._load.observe(s["moe.load_max_over_mean"])
+        if self._groups is not None:
+            self._groups.observe(s["moe.groups_hit"])
         self._scored.inc(int(s["dsa.scored_pairs"]))
         self._selected.inc(int(s["dsa.selected_keys"]))
         self._prefill_scored.inc(int(s["dsa.prefill_scored_pairs"]))
@@ -191,6 +215,15 @@ def _full_decode_scores(qi, w, keys, pos):
     return jnp.where(live, sc, _NEG)
 
 
+def _lines(tables, windowed: bool):
+    """``(global line, window line)`` of a table or a batch of tables: the
+    second axis from the end holds the two lines of a configuration with
+    sliding layers; one without has the one line and no such axis."""
+    if windowed:
+        return tables[..., 0, :], tables[..., 1, :]
+    return tables, None
+
+
 def _window_rows(pool, wi, table, pos, P: int, block_size: int):
     """``(rows (N, P + 1, row), valid)`` of the positions ``pos - P .. pos``
     of each of N requests (``table (N, W)``)."""
@@ -206,40 +239,43 @@ def _stats(moe, scored, selected, queries, prefill_scored, prefill_selected):
 
 
 @functools.lru_cache(maxsize=64)
-def make_latent_decode_fn(cfg: Dots3Config, block_size: int):
+def make_latent_decode_fn(cfg, block_size: int):
     """The jitted packed decode step: R requests feed one token each at
-    their own positions. ``tables (R, 2, W)``."""
+    their own positions. ``tables (R, 2, W)``, or ``(R, W)`` without
+    sliding layers."""
     bs = block_size
+    model = families.latent_model(cfg)
     full_of = {li: i for i, li in enumerate(cfg.layers_of(FULL))}
     win_of = {li: i for i, li in enumerate(cfg.layers_of(SLIDING))}
-    P = cfg.window - 1
+    P = cfg.window - 1 if win_of else None
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, pool, toks, pos, tables):
-        g_tab, w_tab = tables[:, 0], tables[:, 1]
+        g_tab, w_tab = _lines(tables, bool(win_of))
         R, W = g_tab.shape
         off = pos % bs
         blk_g = jnp.take_along_axis(g_tab, (pos // bs)[:, None], 1)[:, 0]
-        blk_w = jnp.take_along_axis(w_tab, (pos // bs)[:, None], 1)[:, 0]
+        if win_of:
+            blk_w = jnp.take_along_axis(w_tab, (pos // bs)[:, None], 1)[:, 0]
         x = params["wte"][toks][:, None].astype(cfg.dtype)      # (R, 1, d)
-        moe = jnp.zeros((3,), jnp.float32)
+        moe = jnp.zeros((len(model.moe_stats),), jnp.float32)
         selected = jnp.zeros((), jnp.float32)
         for li, p in enumerate(params["blocks"]):
             kind = cfg.layer_types[li]
             a = cfg.dims(kind)
             h = _rmsnorm(x, p["ln1_g"], eps=cfg.norm_eps)
-            c_q, q, c_kv, k_rope = dots3.latents(h, p, pos[:, None], cfg,
+            c_q, q, c_kv, k_rope = model.latents(h, p, pos[:, None], cfg,
                                                  kind)
             row = dots3.cache_row(c_kv, k_rope[:, :, 0], a)[:, 0]
             q_abs = dots3.absorb_q(q[:, 0], p, a)               # (R, H, row)
             if kind == FULL:
                 fi = full_of[li]
-                ki = dots3.index_keys(h, p["idx"], pos[:, None], cfg)[:, 0]
+                ki = model.index_keys(h, p["idx"], pos[:, None], cfg)[:, 0]
                 with jax.named_scope("latent/scatter"):
                     pool = pool._replace(
                         kv=pool.kv.at[fi, blk_g, off].set(row),
                         ki=pool.ki.at[fi, blk_g, off].set(ki))
-                qi, w = dots3.index_queries(c_q, h, p["idx"], pos[:, None],
+                qi, w = model.index_queries(c_q, h, p["idx"], pos[:, None],
                                             cfg)
                 with jax.named_scope("latent/index_scores"):
                     keys = pool.ki[fi, g_tab].reshape(R, W * bs, -1)
@@ -258,26 +294,28 @@ def make_latent_decode_fn(cfg: Dots3Config, block_size: int):
             with jax.named_scope("latent/attention"):
                 o = dots3.unabsorb_v(
                     dots3.latent_attend(q_abs, rows, valid, a), p, a)
-            x = x + dots3.headwise_gate(o[:, None], h, p)
-            x, layer = dots3.ffn(x, p, cfg)
-            moe = dots3.fold_moe_stats(moe, layer)
+            x = x + model.attn_out(o[:, None], h, p)
+            x, layer = model.ffn(x, p, cfg)
+            moe = model.fold(moe, layer)
         nf = len(full_of)
         pool = pool._replace(stats=_stats(
             moe, jnp.sum(pos + 1) * nf, selected, R * nf, 0.0, 0.0))
-        return dots3.readout(params, x, cfg)[:, 0], pool
+        return model.readout(params, x, cfg)[:, 0], pool
 
     return step
 
 
 @functools.lru_cache(maxsize=256)
-def make_latent_prefill_fn(cfg: Dots3Config, block_size: int, chunk_len: int,
+def make_latent_prefill_fn(cfg, block_size: int, chunk_len: int,
                            with_readout: bool = True):
     """The jitted prefill chunk of one request: ``C`` tokens at ``pos0``,
-    ``table (2, W)``. ``with_readout=False`` returns ``(None, pool)``."""
+    ``table (2, W)``, or ``(W,)`` without sliding layers.
+    ``with_readout=False`` returns ``(None, pool)``."""
     bs, C = block_size, chunk_len
+    model = families.latent_model(cfg)
     full_of = {li: i for i, li in enumerate(cfg.layers_of(FULL))}
     win_of = {li: i for i, li in enumerate(cfg.layers_of(SLIDING))}
-    P = cfg.window - 1
+    P = cfg.window - 1 if win_of else None
     a_full = cfg.dims(FULL)
 
     def full_attend(p, q, qi, w, pool, fi, g_tab, pos0):
@@ -334,28 +372,29 @@ def make_latent_prefill_fn(cfg: Dots3Config, block_size: int, chunk_len: int,
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def chunk(params, pool, tokens, pos0, table):
-        g_tab, w_tab = table[0], table[1]
+        g_tab, w_tab = _lines(table, bool(win_of))
         positions = pos0 + jnp.arange(C)
         off = positions % bs
         blk_g = jnp.take(g_tab, positions // bs)
-        blk_w = jnp.take(w_tab, positions // bs)
+        if win_of:
+            blk_w = jnp.take(w_tab, positions // bs)
         x = params["wte"][tokens].astype(cfg.dtype)             # (1, C, d)
-        moe = jnp.zeros((3,), jnp.float32)
+        moe = jnp.zeros((len(model.moe_stats),), jnp.float32)
         selected = jnp.zeros((), jnp.float32)
         for li, p in enumerate(params["blocks"]):
             kind = cfg.layer_types[li]
             a = cfg.dims(kind)
             h = _rmsnorm(x, p["ln1_g"], eps=cfg.norm_eps)
-            c_q, q, c_kv, k_rope = dots3.latents(h, p, positions, cfg, kind)
+            c_q, q, c_kv, k_rope = model.latents(h, p, positions, cfg, kind)
             row = dots3.cache_row(c_kv, k_rope[:, :, 0], a)[0]
             if kind == FULL:
                 fi = full_of[li]
-                ki = dots3.index_keys(h, p["idx"], positions, cfg)[0]
+                ki = model.index_keys(h, p["idx"], positions, cfg)[0]
                 with jax.named_scope("latent/scatter"):
                     pool = pool._replace(
                         kv=pool.kv.at[fi, blk_g, off].set(row),
                         ki=pool.ki.at[fi, blk_g, off].set(ki))
-                qi, w = dots3.index_queries(c_q, h, p["idx"], positions, cfg)
+                qi, w = model.index_queries(c_q, h, p["idx"], positions, cfg)
                 with jax.named_scope("latent/sparse_attention"):
                     o, picked = full_attend(p, q, qi[0], w[0], pool, fi,
                                             g_tab, pos0)
@@ -379,14 +418,14 @@ def make_latent_prefill_fn(cfg: Dots3Config, block_size: int, chunk_len: int,
                         n_heads=a.heads, nope=a.nope, v_dim=a.v)
                     o = flash_attention_window(q, k, v, pos0, pos0 - P,
                                                cfg.window)
-            x = x + dots3.headwise_gate(o, h, p)
-            x, layer = dots3.ffn(x, p, cfg)
-            moe = dots3.fold_moe_stats(moe, layer)
+            x = x + model.attn_out(o, h, p)
+            x, layer = model.ffn(x, p, cfg)
+            moe = model.fold(moe, layer)
         nf = len(full_of)
         scored = (C * pos0 + C * (C + 1) // 2) * nf
         pool = pool._replace(stats=_stats(
             moe, scored, selected, C * nf, scored, selected))
-        logits = dots3.readout(params, x, cfg) if with_readout else None
+        logits = model.readout(params, x, cfg) if with_readout else None
         return logits, pool
 
     return chunk

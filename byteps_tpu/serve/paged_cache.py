@@ -166,6 +166,11 @@ class PoolState(NamedTuple):
     s: Optional[jnp.ndarray] = None
     conv: Optional[jnp.ndarray] = None
 
+    #: the leaves a block of the global kind has a page in, each ``(layers,
+    #: blocks, ...)`` or None: what a copy of one block copies
+    #: (``PagedKVCache.ensure_writable``; a latent pool names its own)
+    block_leaves = ("k", "v", "k_scale", "v_scale")
+
 
 class LayerKind(NamedTuple):
     """How one layer uses the cache. ``index``: its row in its pool.
@@ -311,6 +316,14 @@ def with_state_pool(pool: PoolState, layers: int, slots: int,
         stats=jnp.zeros((len(STATS_STATE),), jnp.float32))
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _copy_block(leaf, src, dst):
+    """Block ``src`` of a pool leaf ``(layers, blocks, ...)`` copied over
+    block ``dst``, in place: the leaf is donated (a latent pool's rows are
+    gigabytes; an undonated update would hold two of them)."""
+    return leaf.at[:, dst].set(leaf[:, src])
+
+
 class PoolExhausted(RuntimeError):
     """A block allocation could not be satisfied — the scheduler's cue
     to preempt (it should never escape to callers)."""
@@ -449,6 +462,7 @@ class PagedKVCache:
                               for c in ("admit", "preempt")}
         self._c_alloc_fail = _reg.counter("serve.kv_alloc_failures")
         self._c_prefix_evict = _reg.counter("serve.prefix_evictions")
+        self._c_cow = _reg.counter("serve.prefix.cow_blocks")
 
     # -- accounting ---------------------------------------------------------
     @property
@@ -739,10 +753,12 @@ class PagedKVCache:
     def ensure_writable(self, rid, lo: int, hi: int) -> int:
         """Copy-on-write every block covering token positions
         ``[lo, hi)``: a table entry with refcount > 1 gets a fresh
-        block with the shared contents copied (dense and int8
-        ``_QuantSlot`` paths — k/v and their scales), the shared page's
-        refcount drops, and the table points at the private copy.
-        Returns the number of blocks copied. Raises
+        block with the shared contents copied — every leaf the pool keeps a
+        page of the block in (``state.block_leaves``: k/v and, in the int8
+        ``_QuantSlot`` pool, their scales; a latent pool's rows and indexer
+        keys) — the shared page's refcount drops, and the table points at
+        the private copy. Returns the number of blocks copied
+        (``serve.prefix.cow_blocks`` counts them). Raises
         :class:`PoolExhausted` when no fresh block can be found even
         after LRU eviction."""
         if hi <= lo:
@@ -761,18 +777,15 @@ class PagedKVCache:
                 raise PoolExhausted(self._exhausted_msg(rid, 1))
             nb = self._alloc_block()
             st = self.state
-            self.state = st._replace(
-                k=st.k.at[:, nb].set(st.k[:, b]),
-                v=st.v.at[:, nb].set(st.v[:, b]),
-                k_scale=(None if st.k_scale is None
-                         else st.k_scale.at[:, nb].set(st.k_scale[:, b])),
-                v_scale=(None if st.v_scale is None
-                         else st.v_scale.at[:, nb].set(st.v_scale[:, b])),
-            )
+            self.state = st._replace(**{
+                name: _copy_block(leaf, b, nb)
+                for name in st.block_leaves
+                for leaf in (getattr(st, name),) if leaf is not None})
             self._decref(b)
             table[bi] = nb
             copied += 1
         if copied:
+            self._c_cow.inc(copied)
             self._g_in_use.set(self.blocks_in_use)
         return copied
 

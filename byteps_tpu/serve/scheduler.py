@@ -344,8 +344,8 @@ class _Run:
     __slots__ = ("req", "full_input", "emitted", "pending", "cache_len",
                  "prefill_done", "state", "t_submit", "t_origin", "t_admit",
                  "t_first", "t_last", "t_phase", "preemptions", "spec_rounds",
-                 "draft_cache", "tok_s", "idx_seq", "streamed", "tenant",
-                 "slot", "blk", "given", "fixed_at")
+                 "draft_cache", "tok_s", "idx_seq", "prefix_hit", "streamed",
+                 "tenant", "slot", "blk", "given", "fixed_at")
 
     def __init__(self, req: Request, resume_tokens: List[int],
                  t_submit: float):
@@ -376,6 +376,9 @@ class _Run:
         # prefix-index version this run last matched against: the
         # mid-prefill re-match is skipped until a new commit bumps it
         self.idx_seq = -1
+        # positions of its input this admission mapped from shared pages
+        # instead of computing (at admission and mid-prefill together)
+        self.prefix_hit = 0
         # full blocks already streamed to the decode target (prefill
         # replicas only): the stream callback sends [streamed, full)
         # after each chunk, so each block crosses the wire exactly once
@@ -1191,6 +1194,7 @@ class Scheduler:
             "token_s": np.asarray(run.tok_s[:run.req.max_new]),
             "preemptions": run.preemptions,
             "spec_rounds": run.spec_rounds,
+            "prefix_hit_tokens": run.prefix_hit,
         }
         if self._blk:
             # the pass of its block each token was fixed at (1 ..): what a
@@ -1490,8 +1494,10 @@ class Scheduler:
                 # consult the radix index — capped at L-1 tokens so the
                 # final prefill chunk always runs (its last-position
                 # logits yield the first generated token / TTFT commit)
-                hit_blocks, hit_tokens = self.cache.match_prefix(
-                    run.full_input[:L - 1])
+                with get_tracer().span("serve.prefix.match", "SERVE",
+                                       (run.req.rid, "admit")):
+                    hit_blocks, hit_tokens = self.cache.match_prefix(
+                        run.full_input[:L - 1])
                 run.idx_seq = self.cache.index_version
             partial = 1 if hit_tokens % self.cache.block_size else 0
             need = (self.cache.blocks_for(reserve) - len(hit_blocks)
@@ -1550,6 +1556,7 @@ class Scheduler:
             # shared chunks are never recomputed
             run.prefill_done = hit_tokens
             run.cache_len = hit_tokens
+            run.prefix_hit = hit_tokens
             run.state = "prefill"
             run.t_admit = now
             self._m["queue_wait_ms"].observe(self._phase(run, "queued", now))
@@ -1607,8 +1614,10 @@ class Scheduler:
                 # nothing and a re-match never pays the divergence scan.
                 bs = self.cache.block_size
                 run.idx_seq = self.cache.index_version
-                hit_blocks, hit_tokens = self.cache.match_prefix(
-                    run.full_input[:L - 1], full_blocks_only=True)
+                with tr.span("serve.prefix.match", "SERVE",
+                             (run.req.rid, "prefill")):
+                    hit_blocks, hit_tokens = self.cache.match_prefix(
+                        run.full_input[:L - 1], full_blocks_only=True)
                 jump = hit_tokens
                 if jump > run.prefill_done:
                     bp = run.prefill_done // bs
@@ -1616,6 +1625,7 @@ class Scheduler:
                         run.req.rid, hit_blocks[bp:jump // bs], bp)
                     self._m["prefix_hits"].inc()
                     self._m["prefix_saved"].inc(jump - run.prefill_done)
+                    run.prefix_hit += jump - run.prefill_done
                     run.prefill_done = jump
                     run.cache_len = jump
             C = min(self.prefill_chunk,

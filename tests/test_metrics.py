@@ -174,7 +174,9 @@ def test_span_hot_path_per_span_budget():
             emitted[e[4]] += 1
         elif not e[0].startswith("serve.request."):
             per_iteration[e[4]] += 1
-    assert max(per_iteration.values()) <= 8
+    # 8, and one more in an iteration whose prefill lane walks the prefix
+    # index again before its chunk (serve.prefix.match, PR 55)
+    assert max(per_iteration.values()) <= 9
     assert max(emitted.values()) <= 6 and set(emitted) <= set(per_iteration)
     assert len(ring) / len(per_iteration) <= 16
 
