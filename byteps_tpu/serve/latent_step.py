@@ -48,7 +48,13 @@ from byteps_tpu.models import dots3
 from byteps_tpu.models.dots3 import FULL, SLIDING, LatentModel
 from byteps_tpu.models.gpt import _rmsnorm
 from byteps_tpu.models.joyai import mla_expand
-from byteps_tpu.ops.dsa_index import index_scores
+# select_mask: the name under which the drivers and the tests take the
+# program's indexer pick from here
+from byteps_tpu.ops.dsa_index import (  # noqa: F401
+    index_scores,
+    select_mask,
+    select_mask_counted,
+)
 from byteps_tpu.ops.flash_attention import (
     flash_attention_masked,
     flash_attention_window,
@@ -73,7 +79,8 @@ _HEAD_GROUP = 32
 #: ``pool.stats``: what one program counted, f32 — the model's own
 #: ``moe_stats`` and after them these
 DSA_STATS = ("dsa.scored_pairs", "dsa.selected_keys", "dsa.queries",
-             "dsa.prefill_scored_pairs", "dsa.prefill_selected_keys")
+             "dsa.prefill_scored_pairs", "dsa.prefill_selected_keys",
+             "dsa.select_tie_tiles")
 
 
 def stats_names(model: LatentModel) -> tuple:
@@ -135,6 +142,7 @@ class LateStats(families.LateStats):
         self._prefill_scored = reg.counter("serve.dsa.prefill_scored_pairs")
         self._prefill_selected = reg.counter(
             "serve.dsa.prefill_selected_keys")
+        self._tie_tiles = reg.counter("serve.dsa.select_tie_tiles")
 
     def observe(self, s: dict) -> None:
         self._pairs_here.observe(s["moe.pairs_here"])
@@ -145,6 +153,7 @@ class LateStats(families.LateStats):
         self._selected.inc(int(s["dsa.selected_keys"]))
         self._prefill_scored.inc(int(s["dsa.prefill_scored_pairs"]))
         self._prefill_selected.inc(int(s["dsa.prefill_selected_keys"]))
+        self._tie_tiles.inc(int(s["dsa.select_tie_tiles"]))
         if s["dsa.queries"] > 0:
             self._per_query.observe(
                 s["dsa.selected_keys"] / s["dsa.queries"])
@@ -160,49 +169,6 @@ def _pick_rows(scores, topk: int):
                                  scores.shape), scores > _NEG / 2)
     top, sel = jax.lax.top_k(scores, topk)
     return sel.astype(jnp.int32), top > _NEG / 2
-
-
-def select_mask(scores, topk: int):
-    """``(N, L)`` bool: for each query the ``topk`` keys of largest score
-    among its live ones (score above -1e30), every live key while there are
-    no more than ``topk`` — exactly the set ``jax.lax.top_k`` picks (of equal
-    scores the lower position first), as a mask and with no sort: the
-    ``topk``-th largest score of a row is found bit by bit on the scores'
-    order-preserving integer image (32 counting passes), then the position
-    up to which its ties are in (one pass a bit of ``L``)."""
-    L = scores.shape[-1]
-    live = scores > _NEG / 2
-    if topk >= L:
-        return live
-    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
-    # float order as unsigned order; a dead key sorts below everything
-    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7fffffff), bits)
-    u = jnp.where(live, jax.lax.bitcast_convert_type(key, jnp.uint32)
-                  ^ jnp.uint32(0x80000000), jnp.uint32(0))
-
-    def count(m):
-        return jnp.sum(m, axis=-1, dtype=jnp.int32)
-
-    def value_bit(i, thr):
-        cand = thr | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        return jnp.where(count(u >= cand[:, None]) >= topk, cand, thr)
-
-    thr = jax.lax.fori_loop(0, 32, value_bit,
-                            jnp.zeros(scores.shape[:-1], jnp.uint32))
-    above = (u > thr[:, None]) & live
-    tied = (u == thr[:, None]) & live
-    need = topk - count(above)               # ties that are in: the first
-    pos = jnp.arange(L, dtype=jnp.int32)
-    nbits = max(1, (L - 1).bit_length())
-
-    def pos_bit(i, last):
-        cand = last | (jnp.int32(1) << (nbits - 1 - i))
-        return jnp.where(count(tied & (pos < cand[:, None])) < need, cand,
-                         last)
-
-    last = jax.lax.fori_loop(0, nbits, pos_bit,
-                             jnp.zeros(scores.shape[:-1], jnp.int32))
-    return above | (tied & (pos <= last[:, None]))
 
 
 def _full_decode_scores(qi, w, keys, pos):
@@ -232,10 +198,12 @@ def _window_rows(pool, wi, table, pos, P: int, block_size: int):
     return rows, at >= 0
 
 
-def _stats(moe, scored, selected, queries, prefill_scored, prefill_selected):
+def _stats(moe, scored, selected, queries, prefill_scored, prefill_selected,
+           tie_tiles):
     return jnp.concatenate([moe, jnp.stack([
         jnp.asarray(v, jnp.float32) for v in (
-            scored, selected, queries, prefill_scored, prefill_selected)])])
+            scored, selected, queries, prefill_scored, prefill_selected,
+            tie_tiles)])])
 
 
 @functools.lru_cache(maxsize=64)
@@ -299,7 +267,7 @@ def make_latent_decode_fn(cfg, block_size: int):
             moe = model.fold(moe, layer)
         nf = len(full_of)
         pool = pool._replace(stats=_stats(
-            moe, jnp.sum(pos + 1) * nf, selected, R * nf, 0.0, 0.0))
+            moe, jnp.sum(pos + 1) * nf, selected, R * nf, 0.0, 0.0, 0.0))
         return model.readout(params, x, cfg)[:, 0], pool
 
     return step
@@ -320,9 +288,10 @@ def make_latent_prefill_fn(cfg, block_size: int, chunk_len: int,
 
     def full_attend(p, q, qi, w, pool, fi, g_tab, pos0):
         """A full layer's attention for the chunk: ``(o (1, C, H, v), keys
-        picked)``. Indexer scores against the cached keys
-        (``ops/dsa_index.py``), the picked set as a mask
-        (:func:`select_mask`), k and v materialised from the request's
+        picked, row tiles whose pick had ties to place)``. Indexer scores
+        against the cached keys and the picked set as a mask, found a row
+        tile at a time in VMEM (``ops/dsa_index.py``: ``dsa_index_scores``,
+        ``dsa_select_mask``), k and v materialised from the request's
         latent rows a group of heads at a time, and the flash forward kernel
         over exactly the picked pairs (``mla_sparse_attn``). No row is
         gathered one by one: XLA's gather of 2,048 rows a query ran at a
@@ -340,8 +309,8 @@ def make_latent_prefill_fn(cfg, block_size: int, chunk_len: int,
             def attend(kv_pool, ki_pool):
                 tab = g_tab[:n_keys // bs]
                 keys = ki_pool[fi, tab].reshape(n_keys, -1)
-                mask = select_mask(index_scores(qi, keys, w, pos0),
-                                   cfg.index_topk)
+                mask, picked, tie_tiles = select_mask_counted(
+                    index_scores(qi, keys, w, pos0), cfg.index_topk)
                 rows = kv_pool[fi, tab].reshape(1, n_keys, -1)
                 c_kv, k_rope = rows[..., :a.kv_rank], rows[..., a.kv_rank:a.row]
 
@@ -353,14 +322,15 @@ def make_latent_prefill_fn(cfg, block_size: int, chunk_len: int,
                     k = jnp.concatenate([kv[..., :a.nope], jnp.broadcast_to(
                         k_rope[:, :, None, :], (1, n_keys, Hg, a.rope))], -1)
                     return flash_attention_masked(
-                        qq, k, kv[..., a.nope:], mask.astype(jnp.int8), pos0,
-                        0, name="mla_sparse_attn")
+                        qq, k, kv[..., a.nope:], mask, pos0, 0,
+                        name="mla_sparse_attn")
 
                 o = jax.lax.map(group, (jnp.moveaxis(wkv, 1, 0),
                                         jnp.moveaxis(qg, 2, 0)))
                 # (groups, 1, C, Hg, v) -> (1, C, H, v)
                 return (jnp.moveaxis(o, 0, 2).reshape(1, C, H, a.v),
-                        jnp.sum(mask).astype(jnp.float32))
+                        picked.astype(jnp.float32),
+                        tie_tiles.astype(jnp.float32))
             return attend
 
         if Lw <= G or Lw % G or G % bs:
@@ -380,7 +350,7 @@ def make_latent_prefill_fn(cfg, block_size: int, chunk_len: int,
             blk_w = jnp.take(w_tab, positions // bs)
         x = params["wte"][tokens].astype(cfg.dtype)             # (1, C, d)
         moe = jnp.zeros((len(model.moe_stats),), jnp.float32)
-        selected = jnp.zeros((), jnp.float32)
+        selected = tie_tiles = jnp.zeros((), jnp.float32)
         for li, p in enumerate(params["blocks"]):
             kind = cfg.layer_types[li]
             a = cfg.dims(kind)
@@ -396,9 +366,9 @@ def make_latent_prefill_fn(cfg, block_size: int, chunk_len: int,
                         ki=pool.ki.at[fi, blk_g, off].set(ki))
                 qi, w = model.index_queries(c_q, h, p["idx"], positions, cfg)
                 with jax.named_scope("latent/sparse_attention"):
-                    o, picked = full_attend(p, q, qi[0], w[0], pool, fi,
-                                            g_tab, pos0)
-                selected = selected + picked
+                    o, picked, ties = full_attend(p, q, qi[0], w[0], pool,
+                                                  fi, g_tab, pos0)
+                selected, tie_tiles = selected + picked, tie_tiles + ties
             else:
                 wi = win_of[li]
                 with jax.named_scope("latent/scatter"):
@@ -424,7 +394,7 @@ def make_latent_prefill_fn(cfg, block_size: int, chunk_len: int,
         nf = len(full_of)
         scored = (C * pos0 + C * (C + 1) // 2) * nf
         pool = pool._replace(stats=_stats(
-            moe, scored, selected, C * nf, scored, selected))
+            moe, scored, selected, C * nf, scored, selected, tie_tiles))
         logits = model.readout(params, x, cfg) if with_readout else None
         return logits, pool
 

@@ -384,10 +384,21 @@ def test_flash_with_a_window_against_its_twin(monkeypatch, backend, pos0):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
+def _top_k_mask(sc, K):
+    """What ``jax.lax.top_k`` picks among live keys, as a mask."""
+    N, L = sc.shape
+    top, sel = jax.lax.top_k(jnp.asarray(sc), min(K, L))
+    want = np.zeros((N, L), bool)
+    for t in range(N):
+        want[t, np.asarray(sel[t])[np.asarray(top[t]) > -1e29]] = True
+    return want
+
+
 @pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
 def test_select_mask_is_top_k_without_a_sort(ties):
-    """Few live keys, none past position 150, whole rows of one value: the
-    mask holds exactly what ``jax.lax.top_k`` returns, ties by position."""
+    """The twin. Few live keys, none past position 150, whole rows of one
+    value: the mask holds exactly what ``jax.lax.top_k`` returns, ties by
+    position."""
     rng = np.random.default_rng(0)
     N, L, K = 37, 200, 24
     sc = rng.normal(size=(N, L)).astype(np.float32)
@@ -396,12 +407,144 @@ def test_select_mask_is_top_k_without_a_sort(ties):
     sc[:, 150:] = -1e30
     sc[3, 10:] = -1e30
     sc[5, :100] = 0.0
-    got = np.asarray(select_mask(jnp.asarray(sc), K))
-    top, sel = jax.lax.top_k(jnp.asarray(sc), K)
-    want = np.zeros((N, L), bool)
-    for t in range(N):
-        want[t, np.asarray(sel[t])[np.asarray(top[t]) > -1e29]] = True
+    got = np.asarray(dsa_index.select_mask_jnp(jnp.asarray(sc), K))
+    np.testing.assert_array_equal(got, _top_k_mask(sc, K))
+
+
+def _kernel_calls(monkeypatch):
+    """The shapes ``dsa_select_mask`` is traced for from here on."""
+    ran, kernel = [], dsa_index._select
+    monkeypatch.setattr(dsa_index, "_select", lambda scores, *a: (
+        ran.append(scores.shape), kernel(scores, *a))[1])
+    return ran
+
+
+def _select_case(name):
+    """``(scores (N, L) f32, topk)``: whole tiles of the kernel."""
+    rng = np.random.default_rng(7)
+    N, L, K = 64, 384, 24
+    if name == "one_sub_tile":
+        L = 128
+    elif name == "several_trips":          # two trips of sixteen slices
+        N, L, K = 32, 4096, 300
+    elif name == "topk_covers_every_key":
+        L, K = 128, 128
+    sc = rng.normal(size=(N, L)).astype(np.float32)
+    if name in ("ties", "several_trips"):
+        sc = np.round(sc * 2) / 2
+    elif name == "a_row_of_one_value":
+        sc[5] = 1.5
+        sc[6] = -0.25
+    elif name == "few_live_keys":
+        sc[3, 10:] = -1e30                 # fewer than topk
+        sc[4, K:] = -1e30                  # exactly topk
+        sc[:, 300:] = -1e30
+    elif name == "a_row_with_none":
+        sc[0] = sc[9] = sc[63] = -1e30
+    elif name == "causal_tail":            # a chunk at pos0 = 200
+        sc[np.arange(L)[None, :] > 200 + np.arange(N)[:, None]] = -1e30
+    elif name == "signed_zeros":
+        sc = rng.choice(np.array([-1.5, -0.0, 0.0, 0.5, -1e-38, 1e-38],
+                                 np.float32), size=(N, L))
+        sc[:, 350:] = -1e30
+    return sc, K
+
+
+@pytest.mark.parametrize("case", [
+    "distinct", "ties", "a_row_of_one_value", "few_live_keys",
+    "a_row_with_none", "causal_tail", "signed_zeros", "one_sub_tile",
+    "several_trips", "topk_covers_every_key"])
+def test_select_kernel_is_its_twin_and_top_k(monkeypatch, case):
+    """``dsa_select_mask`` in interpret mode: the same set as the twin's and
+    as ``jax.lax.top_k``'s, key for key."""
+    monkeypatch.setenv("BYTEPS_KERNEL_BACKEND", "pallas")
+    sc, K = _select_case(case)
+    assert dsa_index.select_unsupported_reason(*sc.shape) is None
+    ran = _kernel_calls(monkeypatch)
+    got, picked, _ = dsa_index.select_mask_counted(jnp.asarray(sc), K)
+    assert got.dtype == jnp.int8 and got.shape == sc.shape
+    assert bool(ran) == (K < sc.shape[1])      # every key: no kernel
+    got = np.asarray(got).astype(bool)
+    assert int(picked) == got.sum()
+    np.testing.assert_array_equal(
+        got, np.asarray(dsa_index.select_mask_jnp(jnp.asarray(sc), K)))
+    np.testing.assert_array_equal(got, _top_k_mask(sc, K))
+
+
+def test_select_kernel_declines_what_it_does_not_tile(monkeypatch):
+    """37 x 200 is no whole tile: the dispatcher says so and the twin
+    runs."""
+    monkeypatch.setenv("BYTEPS_KERNEL_BACKEND", "pallas")
+    said = []
+    monkeypatch.setattr(dsa_index, "note_fallback",
+                        lambda *a: said.append(a))
+    monkeypatch.setattr(dsa_index, "_select", None)     # not to be called
+    sc = np.random.default_rng(0).normal(size=(37, 200)).astype(np.float32)
+    sc[:, 150:] = -1e30
+    got, picked, tie_tiles = dsa_index.select_mask_counted(jnp.asarray(sc),
+                                                           24)
+    assert said and said[0][:2] == ("dsa_select_mask", (37, 200))
+    assert int(tie_tiles) == 0 and int(picked) == np.asarray(got).sum()
+    np.testing.assert_array_equal(np.asarray(got).astype(bool),
+                                  _top_k_mask(sc, 24))
+
+
+def test_select_kernel_counts_the_tiles_that_placed_ties(monkeypatch):
+    """The position passes run in a row tile only where some row has more
+    keys at or above its threshold than it takes; the kernel says in how
+    many, and the series ``serve.dsa.select_tie_tiles`` carries it."""
+    from byteps_tpu.serve.latent_step import LateStats
+
+    monkeypatch.setenv("BYTEPS_KERNEL_BACKEND", "pallas")
+    rng = np.random.default_rng(2)
+    sc = rng.normal(size=(128, 256)).astype(np.float32)     # two tiles
+    sc[3, 10:] = -1e30
+    sc[70, :] = -1e30
+    _, _, untied = dsa_index.select_mask_counted(jnp.asarray(sc), 24)
+    assert int(untied) == 0
+    sc[100] = np.round(sc[100] * 2) / 2     # the second tile's alone
+    got, picked, tied = dsa_index.select_mask_counted(jnp.asarray(sc), 24)
+    assert int(tied) == 1
+    assert int(picked) == 126 * 24 + 10     # a row of 10 live keys, one of 0
+    np.testing.assert_array_equal(np.asarray(got).astype(bool),
+                                  _top_k_mask(sc, 24))
+    late = LateStats(dots3.MODEL)
+    series = get_registry().counter("serve.dsa.select_tie_tiles")
+    before = series.value()
+    late.observe(dict(dict.fromkeys(late.names, 0.0),
+                      **{"dsa.select_tie_tiles": float(tied)}))
+    assert series.value() == before + 1
+
+
+def test_chunk_program_picks_through_the_kernel(monkeypatch):
+    """A 100-token prompt in chunks of 32 against 128 cached keys, shapes
+    the selection kernel tiles: the chunk programs call it, count its tie
+    tiles into ``pool.stats``, and serve the twin's tokens."""
+    from byteps_tpu.serve import latent_step
+
+    cfg = Dots3Config.tiny(experts_held=8, first_expert=4, max_seq=128)
+    params = dots3_init(jax.random.PRNGKey(0), cfg)
+    prompt = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, 100).astype(np.int32)
+
+    def run():
+        latent_step.make_latent_prefill_fn.cache_clear()
+        sched = Scheduler(params, cfg, max_batch=2, block_size=4,
+                          pool_blocks=72, prefill_chunk=32)
+        sched.submit(Request(rid=0, max_new=3, prompt=prompt))
+        while not sched.finished:
+            sched.step()
+        sched.flush_stats()
+        return sched.results[0]["emitted"]
+
+    want = run()
+    monkeypatch.setenv("BYTEPS_KERNEL_BACKEND", "pallas")
+    ran = _kernel_calls(monkeypatch)
+    got = run()
+    latent_step.make_latent_prefill_fn.cache_clear()
+    assert ran and set(ran) == {(32, 128)}
     np.testing.assert_array_equal(got, want)
+    assert "dsa.select_tie_tiles" in latent_step.stats_names(dots3.MODEL)
 
 
 @pytest.mark.parametrize("pos0", [0, 64])

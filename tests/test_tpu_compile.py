@@ -148,6 +148,12 @@ def _gmm_fwd_bwd(lhs, w_up, w_down, sizes):
     return jax.grad(loss, argnums=(0, 1, 2))(lhs, w_up, w_down)
 
 
+def _select_mask(topk, scores):
+    from byteps_tpu.ops.dsa_index import select_mask
+
+    return select_mask(scores, topk)
+
+
 def _gmm_up_down(lhs, w_up, w_down, sizes, tm=256):
     # a serve program's expert layer: forward alone, up then down
     return grouped_matmul(grouped_matmul(lhs, w_up, sizes, tm), w_down,
@@ -241,6 +247,13 @@ ONE_CHIP = [
      *_paged_decode_cell((4, 32, 128), (6, 2305), 2), 1),
     ("paged_attn_decode_mellum2_window_w256",
      *_paged_decode_cell((32, 128), (6, 318), 256, windowed=True, R=24), 1),
+    # the indexer's pick of a prefill chunk: a row tile of f32 scores, its
+    # integer image and the int8 mask, over the scoped default at the widest
+    # table, so the call states its limit; the full chunk, the two ends of a
+    # final chunk and the narrowest key bucket
+    *[(f"dsa_select_mask_c{c}_l{l}", functools.partial(_select_mask, 2048),
+       [_sds((c, l), F32)], 1)
+      for c, l in ((2048, 32768), (1280, 32768), (512, 32768), (256, 8192))],
     ("onebit_pack", ob.onebit_pack, [_sds((PART,), F32)], 1),
     *[(f"onebit_unpack_sum_k{k}",
        functools.partial(ob.onebit_unpack_sum, n=PART),
@@ -515,15 +528,20 @@ def test_dots3_serve_program_fits_and_leaves_the_pool_in_place(
     assert not re.search(r"\[64,2048,32768\]|\[2048,64,32768\]", hlo)
     n = _n_pallas(compiled)
     if program.startswith("chunk"):
-        # an indexer-score call and a selected-attention call for each of
-        # the 4 key-bucket branches of each full layer, 3 window-flash
-        # calls, 3 grouped products for each expert layer but the last (no
-        # readout asks for its output, so its second half is not in the
-        # program)
-        assert n == 2 * 4 * 2 + 3 + 9, n
-        for name in ("dsa_index_scores", "mla_sparse_attn", "flash_fwd",
-                     "moe_gmm_fwd"):
+        # an indexer-score call, a selection call and a selected-attention
+        # call for each of the 4 key-bucket branches of each full layer, 3
+        # window-flash calls, 3 grouped products for each expert layer but
+        # the last (no readout asks for its output, so its second half is
+        # not in the program)
+        assert n == 3 * 4 * 2 + 3 + 9, n
+        for name in ("dsa_index_scores", "dsa_select_mask",
+                     "mla_sparse_attn", "flash_fwd", "moe_gmm_fwd"):
             assert name in hlo, name
+        # the selection's image and its masks stay in the kernel's VMEM
+        # (PR 55's program held 88, 70 and 98 instructions of these shapes
+        # and 1,965,927,424 bytes of temporaries)
+        assert not re.search(r"(u32|s32|pred)\[2048,32768\]", hlo)
+        assert mem.temp_size_in_bytes <= 1_965_927_424
     else:
         assert n == 12, n                   # the grouped products alone
 
