@@ -31,12 +31,15 @@ One span primitive, three consumers (docs/observability.md §spans):
 from __future__ import annotations
 
 import collections
+import functools
 import itertools
 import json
 import os
+import re
 import sys
 import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional
 
 from byteps_tpu.common.config import get_config
@@ -360,6 +363,327 @@ class _Span:
         rec._record(self.name, self.stage, self.t0, t1 - self.t0,
                     self.sid, self.parent, self.args)
         return False
+
+
+# -- regions on the device (docs/observability.md §Regions on the device) ---
+#
+# The models and the serve steps mark their regions with ``jax.named_scope``.
+# The names end up in ``metadata={op_name="..."}`` of every instruction of a
+# compiled program, and a device event of a profiler trace is named after its
+# instruction. ``scope_table`` is the join's left side: instruction -> scope.
+
+# path components of an ``op_name`` that are JAX's own wrappers, not a region
+_WRAPPER_WORDS = frozenset((
+    "shard_map", "checkpoint", "remat", "remat2", "rematted_computation",
+    "while", "cond", "body", "closed_call", "core_call", "custom_jvp_call",
+    "custom_vjp_call", "custom_vjp_call_jaxpr", "custom_lin", "pjit",
+    "xla_call", "scan", "named_call"))
+_BRANCH = re.compile(r"^branch_\d+_fun$")
+# wrappers whose parentheses hold a FUNCTION's name; the others (jvp,
+# transpose, vmap, ...) hold the scopes open when the transformation began
+_NAMES_A_FUNCTION = frozenset(("jit", "pjit", "xla_call", "named_call"))
+_HLO_INSTR = re.compile(
+    r"^\s+(?:ROOT )?%?([^\s=]+) = .*?\s([a-z][a-z0-9\-]*)\(")
+_HLO_COMP = re.compile(r"^(ENTRY )?%?([^\s(]+) \(.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([^\s,}]+)")
+_APPLIES = re.compile(r"\bto_apply=%?([^\s,}]+)")
+_NO_EVENT = frozenset(("parameter", "constant", "tuple", "get-tuple-element",
+                       "bitcast"))
+# what names a computation, not an operand, in an instruction's line
+_NAMES_A_COMPUTATION = re.compile(
+    r"\b(?:calls|to_apply|body|condition|true_computation|"
+    r"false_computation)=%?[^\s,}]+|"
+    r"\b(?:branch_computations|called_computations)=\{[^}]*\}")
+_OPERAND = re.compile(r"%([^\s,(){}]+)")
+
+
+def _split_path(path: str) -> List[str]:
+    """``path`` cut at the slashes outside any parentheses."""
+    parts, depth, start = [], 0, 0
+    for i, c in enumerate(path):
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+        elif c == "/" and depth == 0:
+            parts.append(path[start:i])
+            start = i + 1
+    parts.append(path[start:])
+    return parts
+
+
+def _regions(path: str) -> List[str]:
+    out: List[str] = []
+    for part in _split_path(path):
+        head, paren, inner = part.partition("(")
+        if paren and part.endswith(")"):
+            if head not in _NAMES_A_FUNCTION:
+                out.extend(_regions(inner[:-1]))
+        elif part and part not in _WRAPPER_WORDS and "->" not in part \
+                and "<" not in part and not _BRANCH.match(part):
+            out.append(part)
+    return out
+
+
+@functools.lru_cache(maxsize=65536)
+def scope_of(op_name: str) -> str:
+    """The region of the source an instruction belongs to, from its
+    ``op_name``: the ``/``-joined run of path components that are not
+    ``jit(..)``, ``jvp(..)``, ``transpose(..)``, ``vmap(..)``,
+    ``shard_map``, ``checkpoint`` / ``remat``, ``while`` / ``cond`` /
+    ``body`` wrappers (the scopes INSIDE a ``jvp(..)`` or ``transpose(..)``
+    are kept: the forward and the backward of a region land on one scope),
+    an ``einsum``'s own ``ab,bc->ac``, a Python qualified name
+    (``f.<locals>.g``) or the trailing primitive name.
+    ``jit(step)/transpose(jvp(block/attn))/paged/attention/dot_general`` is
+    ``block/attn/paged/attention``; ``""`` where nothing is left. JAX names
+    a few components after the Python function a lowering rule ran in
+    (``moe/plan/_row_plan``) or prints a region twice (the transpose of a
+    ``jax.checkpoint``: ``block/mlp/block/mlp``): they are kept as they
+    come, under the region that was open."""
+    return "/".join(_regions("/".join(_split_path(op_name)[:-1])))
+
+
+def _common_scope(scopes) -> str:
+    """The scope all of ``scopes`` agree on, else their longest common
+    prefix of whole components."""
+    return "/".join(os.path.commonprefix([s.split("/") for s in scopes]))
+
+
+def _parse_hlo(hlo_text: str):
+    """(module name, {computation: [(instruction, opcode, scope or None,
+    called fused computation or None, operands)]}, entry computation,
+    computations that are fused or applied)."""
+    module, comps, entry, inner = "", {}, None, set()
+    cur = None
+    for line in hlo_text.splitlines():
+        if cur is None:
+            if line.startswith("HloModule "):
+                module = line.split()[1].rstrip(",")
+                continue
+            m = _HLO_COMP.match(line)
+            if m:
+                cur = comps[m.group(2)] = []
+                if m.group(1):
+                    entry = m.group(2)
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        name, op = m.group(1), m.group(2)
+        meta = _OP_NAME.search(line)
+        calls = None
+        if op == "fusion":
+            c = _CALLS.search(line)
+            calls = c and c.group(1)
+            if calls:
+                inner.add(calls)
+        elif op != "call":
+            a = _APPLIES.search(line)
+            if a:
+                inner.add(a.group(1))
+        operands = _OPERAND.findall(_NAMES_A_COMPUTATION.sub(
+            "", line[m.end():].split(", metadata=")[0]))
+        cur.append((name, op, scope_of(meta.group(1)) if meta else None,
+                    calls, operands))
+    return module, comps, entry, inner
+
+
+def _scopes_and_order(hlo_text: str) -> Dict[str, Any]:
+    """``{"module": the module's name, "scopes": {instruction: scope},
+    "order": [the entry computation's instructions in schedule order],
+    "mixed": {fusion: the distinct scopes of its fused instructions} for
+    the fusions across regions}`` of one optimized HLO text."""
+    module, comps, entry, inner = _parse_hlo(hlo_text)
+    table: Dict[str, str] = {}
+    mixed: Dict[str, List[str]] = {}
+    for cname, instrs in comps.items():
+        if cname in inner:
+            continue
+        own: Dict[str, Optional[str]] = {}
+        users: Dict[str, List[str]] = {}
+        for name, op, scope, calls, operands in instrs:
+            if calls in comps:
+                fused = sorted({i[2] for i in comps[calls] if i[2]})
+                if fused:
+                    scope = _common_scope(fused)
+                if len(fused) > 1:
+                    mixed[name] = fused
+            own[name] = scope
+            for o in operands:
+                users.setdefault(o, []).append(name)
+
+        def resolve(name):
+            # an instruction the compiler made (a weight's prefetch, a
+            # copy, an async pair: no metadata) belongs where its result
+            # goes; instructions come in schedule order, users later
+            if own[name] is None:
+                own[name] = ""                       # a cycle cannot be
+                scopes = [resolve(u) for u in users.get(name, ())
+                          if u in own]
+                own[name] = _common_scope(scopes) if scopes else ""
+            return own[name]
+
+        for name in reversed([i[0] for i in instrs]):
+            table[name] = resolve(name)
+    order = [i[0] for i in comps.get(entry, ()) if i[1] not in _NO_EVENT]
+    return {"module": module, "scopes": table, "order": order,
+            "mixed": mixed}
+
+
+def scope_table(hlo_text: str) -> Dict[str, str]:
+    """``{instruction short name: scope}`` of one compiled program, from its
+    optimized HLO text (``jax.stages.Compiled.as_text()``): every
+    instruction of the entry computation, of ``while`` bodies and
+    conditions, of branches and of called computations — a device trace's
+    "XLA Ops" line nests their events under the container's — with
+    :func:`scope_of` its ``op_name``. A fusion takes the scope all its fused
+    instructions that carry one agree on, else their longest common prefix
+    of whole components (its own where none carries one): an instruction of
+    no region fused into a region's goes with it, one fused across two
+    regions goes to what they share. An instruction WITHOUT metadata —
+    the compiler's own: a weight's prefetch (``copy-start`` / ``copy-done``),
+    an async pair, a layout copy — takes the scope the instructions that
+    use its result agree on (their common prefix; through chains of such
+    instructions): the time a region waits for its operands is the
+    region's. ``""`` is a legal answer: an instruction in no region, or
+    one that feeds several; nothing is guessed.
+    The instructions INSIDE fused and applied computations produce no event
+    and are left out."""
+    return _scopes_and_order(hlo_text)["scopes"]
+
+
+# every traced program still alive, and the newest few kept alive: a train
+# step its caller let go of can still be asked about after the run, and a
+# process that builds steps by the hundred (the tests) does not keep them
+_programs: "weakref.WeakSet" = weakref.WeakSet()
+_recent: collections.deque = collections.deque(maxlen=16)
+
+
+class _TracedProgram:
+    """What :func:`traced_program` returns."""
+
+    __slots__ = ("name", "_jitted", "_size", "_n", "_key", "_statics",
+                 "signatures", "_tables", "__weakref__")
+
+    def __init__(self, name, jitted, key, statics):
+        self.name = name
+        self._jitted = jitted
+        self._size = jitted._cache_size
+        self._n = self._size()
+        self._key = key
+        self._statics = frozenset(statics)
+        #: {label: (args, kwargs) as ShapeDtypeStructs}, one an executable
+        self.signatures: Dict[str, tuple] = {}
+        self._tables: Dict[str, Dict[str, Any]] = {}     # program_scopes'
+        _programs.add(self)
+        _recent.append(self)
+
+    def __call__(self, *args, **kwargs):
+        out = self._jitted(*args, **kwargs)
+        if self._size() != self._n:
+            self._note(args, kwargs)
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+    def _note(self, args, kwargs) -> None:
+        """The jit wrapper holds another executable than at the last look:
+        keep this call's abstract signature (shapes, dtypes, weak types,
+        the sharding of a committed array; a donated array still says all
+        of these; ``statics`` as given) under its label."""
+        import jax
+
+        self._n = self._size()
+        leaves = jax.tree_util.tree_leaves((args, kwargs))
+        if any(isinstance(x, jax.core.Tracer) for x in leaves):
+            return                       # traced into a larger program
+
+        def abstract(x):
+            aval = jax.api_util.shaped_abstractify(x)
+            return jax.ShapeDtypeStruct(
+                aval.shape, aval.dtype, weak_type=aval.weak_type,
+                sharding=x.sharding if getattr(x, "_committed", False)
+                else None)
+
+        try:
+            sig = (jax.tree_util.tree_map(abstract, args),
+                   {k: v if k in self._statics
+                    else jax.tree_util.tree_map(abstract, v)
+                    for k, v in kwargs.items()})
+            label = str(len(self.signatures)) if self._key is None \
+                else self._key(*args, **kwargs)
+        except (TypeError, ValueError, AttributeError, IndexError) as e:
+            # an argument with no abstract value (a static passed by
+            # position): the call itself succeeded and must not fail here
+            log.debug("traced_program %s: signature not kept: %s",
+                      self.name, e)
+            return
+        self.signatures.setdefault(label, sig)
+
+
+def traced_program(name: str, jitted, key=None, statics=()):
+    """``jitted`` (a ``jax.jit`` wrapper) behind a thin callable that
+    remembers WHAT IT COMPILED: after each call it compares the wrapper's
+    executable count (``jitted._cache_size()``) with the count it last saw
+    and, only when that moved, keeps the call's abstract signature —
+    nothing is lowered, no text is held. :func:`program_scopes` turns the
+    signatures into scope tables when someone asks. A call costs one C++
+    method call and one comparison more than the jitted function's own
+    (tests/test_scope_table.py pins it under a microsecond); every other
+    attribute (``lower``, ``_cache_size`` ...) is the jitted function's.
+
+    ``key(*args, **kwargs) -> str`` labels an executable in the words the
+    program's spans use for it (a decode step's table width ``W=8``); by
+    default executables are numbered. ``statics``: the keyword arguments
+    that are ``static_argnames`` of the jit, kept as given. A callable
+    that is no jit wrapper (it counts no executables) comes back as it is."""
+    if not hasattr(jitted, "_cache_size"):
+        return jitted
+    return _TracedProgram(name, jitted, key, statics)
+
+
+def program_scopes(only=None) -> Dict[str, Dict[str, Any]]:
+    """The scope tables of the programs this process has run through
+    :func:`traced_program`: ``{"<name>[<label>]": {"module": the HLO
+    module's name, "signature": the executable's label, "scopes":
+    {instruction: scope}, "order": [the entry computation's instructions
+    in schedule order], "mixed": {fusion: [the distinct scopes of its fused
+    instructions]} for the fusions that lie across regions (their scope is
+    what those share, often ``""``)}}``. ``only``: program names
+    (``serve.decode``), full keys (``serve.decode[W=8]``) or the wrappers
+    themselves, to restrict the work to.
+
+    Built when asked and memoised; the package itself never asks. Each
+    table comes from ``jitted.lower(*signature).compile().as_text()``: the
+    text of the executable the process LOADED for that signature — a hit in
+    JAX's caches, no compile, where the persistent compile cache is on —
+    and so certain to name its instructions as the device events do, which
+    neither ``lower()``'s pre-optimization text nor a dump directory of
+    another compile is. The persistent cache leaves metadata out of its
+    key: on a hit the scopes are those of the tree that COMPILED the entry,
+    so after renaming a scope in the source clear the cache
+    (``.jax_cache``) before looking for the new name."""
+    want = None if only is None else set(only)
+    out: Dict[str, Dict[str, Any]] = {}
+    for prog in sorted(_programs, key=lambda p: p.name):
+        for label, (args, kwargs) in list(prog.signatures.items()):
+            full = f"{prog.name}[{label}]"
+            if want is not None and not {prog, prog.name, full} & want:
+                continue
+            if label not in prog._tables:
+                text = prog._jitted.lower(*args, **kwargs).compile().as_text()
+                prog._tables[label] = dict(_scopes_and_order(text),
+                                           signature=label)
+            while full in out:           # two wrappers under one name
+                full += "'"
+            out[full] = prog._tables[label]
+    return out
 
 
 _tracer: Optional[TraceRecorder] = None

@@ -24,7 +24,7 @@ from typing import Callable, Dict, Optional
 import jax
 
 from byteps_tpu.common.logging import get_logger
-from byteps_tpu.common.tracing import get_tracer
+from byteps_tpu.common.tracing import get_tracer, traced_program
 from byteps_tpu.common.tuner import AutoTuner
 
 log = get_logger("jax.tuned_step")
@@ -91,7 +91,7 @@ class AutoTunedStep:
         get_flight_recorder().tick()
         step = self._compiled.get(self._pb)
         if step is None:
-            step = self._build(self._pb)
+            step = traced_program("train.step", self._build(self._pb))
             self._compiled[self._pb] = step
             self.retraces += 1
         t0 = time.perf_counter()

@@ -330,7 +330,8 @@ def ssm_branch(cfg: FalconH1Config, p, h, s_pool, c_pool, layer, slots,
             -1, cfg.conv_kernel - 1, cfg.conv_channels)
         if chunk:
             tail = jnp.where(fresh, jnp.zeros((), tail.dtype), tail)
-        x, dt, Bm, Cm, z, tail = ssm_inputs(cfg, p, h, tail)
+        with jax.named_scope("ssm/in"):
+            x, dt, Bm, Cm, z, tail = ssm_inputs(cfg, p, h, tail)
         tail = tail.reshape(tail.shape[0], -1)
         A, D = _rule_constants(p)
         if chunk:
@@ -347,7 +348,9 @@ def ssm_branch(cfg: FalconH1Config, p, h, s_pool, c_pool, layer, slots,
                                        Cm[:, 0], D, s_pool, layer, slots)
             c_pool = c_pool.at[layer, slots].set(tail)
             y = y[:, None]
-        return ssm_output(cfg, p, y, z, h.dtype), s_pool, c_pool
+        with jax.named_scope("ssm/out"):
+            out = ssm_output(cfg, p, y, z, h.dtype)
+        return out, s_pool, c_pool
 
 
 def mixer_half(cfg: FalconH1Config, x, p, head_dim, positions, attend, rope,

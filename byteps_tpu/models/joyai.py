@@ -353,7 +353,8 @@ def joyai_loss(params, tokens, targets, cfg: JoyAIConfig,
                 f"JoyAIConfig trains on dp meshes only; got a {name} axis "
                 "(experts_held says which experts this device computes)")
     block = maybe_remat(lambda x, p: joyai_block(x, p, cfg), remat)
-    x = _embed(params, tokens, cfg, None, seq_layout)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, cfg, None, seq_layout)
     stats = jnp.zeros((3,), jnp.float32)
     loads = []
     for p in params["blocks"]:
@@ -367,7 +368,8 @@ def joyai_loss(params, tokens, targets, cfg: JoyAIConfig,
     if cfg.n_mtp:
         m = params["mtp"]
         # Emb(tok_{t+1}) is the embedding of this position's target
-        e = _embed(params, targets, cfg, None, seq_layout)
+        with jax.named_scope("embed"):
+            e = _embed(params, targets, cfg, None, seq_layout)
         h = jnp.concatenate(
             [_rmsnorm(e, m["enorm_g"], eps=cfg.norm_eps),
              _rmsnorm(x, m["hnorm_g"], eps=cfg.norm_eps)], axis=-1)

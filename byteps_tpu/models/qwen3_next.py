@@ -365,7 +365,8 @@ def gdn_half(cfg: Qwen3NextConfig, x, p, s_pool, c_pool, layer, slots,
             -1, cfg.conv_kernel - 1, cfg.conv_channels)
         if chunk:
             tail = jnp.where(fresh, jnp.zeros((), tail.dtype), tail)
-        q, k, v, g, beta, z, tail = gdn_inputs(cfg, p, h, tail)
+        with jax.named_scope("gdn/in"):
+            q, k, v, g, beta, z, tail = gdn_inputs(cfg, p, h, tail)
         tail = tail.reshape(tail.shape[0], -1)
         if chunk:
             S = jnp.where(fresh, 0.0, s_pool[layer, slots])
@@ -381,7 +382,9 @@ def gdn_half(cfg: Qwen3NextConfig, x, p, s_pool, c_pool, layer, slots,
                                        beta[:, 0], s_pool, layer, slots)
             c_pool = c_pool.at[layer, slots].set(tail)
             o = o[:, None]
-        return x + gdn_output(cfg, p, o, z, x.dtype), s_pool, c_pool
+        with jax.named_scope("gdn/out"):
+            out = gdn_output(cfg, p, o, z, x.dtype)
+        return x + out, s_pool, c_pool
 
 
 # --------------------------------------------------------------------------

@@ -36,7 +36,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from byteps_tpu.common.flight_recorder import get_flight_recorder
-from byteps_tpu.common.tracing import get_tracer
+from byteps_tpu.common.tracing import get_tracer, traced_program
 from byteps_tpu.jax.optimizer import (
     DistributedOptimizer,
     backward_order,
@@ -411,7 +411,7 @@ class _TickingStep:
     _HELD_MAX = 8       # steps of run-ahead before a read may wait
 
     def __init__(self, jitted, stats_names=()):
-        self._jitted = jitted
+        self._jitted = traced_program("train.step", jitted)
         self._stats_names = tuple(stats_names)
         self._held = collections.deque()
 

@@ -489,15 +489,19 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     lead, d = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, d)
     T = xt.shape[0]
-    idx, weight = ROUTES[route](xt, params, top_k, scale,
-                                *(group_limit or ()))
+    # sub-regions of the caller's block/moe, for a device trace's scope
+    # table (docs/observability.md §Regions on the device): metadata only
+    with jax.named_scope("moe/route"):
+        idx, weight = ROUTES[route](xt, params, top_k, scale,
+                                    *(group_limit or ()))
     P_ = T * top_k
     tm = (dropless_row_tile(P_, held, x.dtype.itemsize)
           if row_tile is None else row_tile)
-    local = idx.reshape(P_) - first_expert
-    is_held = (local >= 0) & (local < held)
-    pair_row, row_pair, counts, padded = _row_plan(
-        jnp.where(is_held, local, held), held, tm)
+    with jax.named_scope("moe/plan"):
+        local = idx.reshape(P_) - first_expert
+        is_held = (local >= 0) & (local < held)
+        pair_row, row_pair, counts, padded = _row_plan(
+            jnp.where(is_held, local, held), held, tm)
     n_rows = row_pair.shape[0]
     # the tile is chosen while tracing, so it is counted there
     reg = get_registry()
@@ -506,11 +510,15 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     reg.gauge("moe.row_plan_tiles").set(n_rows // tm)
     pair_row = pair_row.reshape(T, top_k)
 
-    xs = _dispatch(xt, row_pair // top_k, pair_row)
-    gate = grouped_matmul(xs, params["w1"], padded, tm)
-    up = grouped_matmul(xs, params["w3"], padded, tm)
-    ys = grouped_matmul(jax.nn.silu(gate) * up, params["w2"], padded, tm)
-    y = _combine(ys, weight, row_pair, pair_row)
+    with jax.named_scope("moe/plan"):
+        xs = _dispatch(xt, row_pair // top_k, pair_row)
+    with jax.named_scope("moe/experts"):
+        gate = grouped_matmul(xs, params["w1"], padded, tm)
+        up = grouped_matmul(xs, params["w3"], padded, tm)
+        ys = grouped_matmul(jax.nn.silu(gate) * up, params["w2"], padded,
+                            tm)
+    with jax.named_scope("moe/combine"):
+        y = _combine(ys, weight, row_pair, pair_row)
 
     # counted from the row buffer the kernels ran over, not from the
     # router's ids: a held pair that got no row is missing from both

@@ -477,13 +477,14 @@ def _block_pick(B: int, mask_id: int):
     import jax
     import jax.numpy as jnp
 
+    from byteps_tpu.common.tracing import traced_program
     from byteps_tpu.models.sdar import fix_positions
 
     def pick(logits, state, n_fix, pass_no):
         return jnp.concatenate(fix_positions(
             logits, state[:, :B], state[:, B:], n_fix, pass_no, mask_id), 1)
 
-    return jax.jit(pick)
+    return traced_program("serve.pick", jax.jit(pick))
 
 
 class BlockDiffusionFamily(WindowedKVFamily):

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from byteps_tpu.common.metrics import get_registry
+from byteps_tpu.common.tracing import traced_program
 from byteps_tpu.models import dots3
 from byteps_tpu.models.dots3 import FULL, SLIDING, LatentModel
 from byteps_tpu.models.gpt import _rmsnorm
@@ -225,52 +226,60 @@ def make_latent_decode_fn(cfg, block_size: int):
         blk_g = jnp.take_along_axis(g_tab, (pos // bs)[:, None], 1)[:, 0]
         if win_of:
             blk_w = jnp.take_along_axis(w_tab, (pos // bs)[:, None], 1)[:, 0]
-        x = params["wte"][toks][:, None].astype(cfg.dtype)      # (R, 1, d)
+        with jax.named_scope("embed"):
+            x = params["wte"][toks][:, None].astype(cfg.dtype)  # (R, 1, d)
         moe = jnp.zeros((len(model.moe_stats),), jnp.float32)
         selected = jnp.zeros((), jnp.float32)
         for li, p in enumerate(params["blocks"]):
             kind = cfg.layer_types[li]
             a = cfg.dims(kind)
-            h = _rmsnorm(x, p["ln1_g"], eps=cfg.norm_eps)
-            c_q, q, c_kv, k_rope = model.latents(h, p, pos[:, None], cfg,
-                                                 kind)
-            row = dots3.cache_row(c_kv, k_rope[:, :, 0], a)[:, 0]
-            q_abs = dots3.absorb_q(q[:, 0], p, a)               # (R, H, row)
-            if kind == FULL:
-                fi = full_of[li]
-                ki = model.index_keys(h, p["idx"], pos[:, None], cfg)[:, 0]
-                with jax.named_scope("latent/scatter"):
-                    pool = pool._replace(
-                        kv=pool.kv.at[fi, blk_g, off].set(row),
-                        ki=pool.ki.at[fi, blk_g, off].set(ki))
-                qi, w = model.index_queries(c_q, h, p["idx"], pos[:, None],
-                                            cfg)
-                with jax.named_scope("latent/index_scores"):
-                    keys = pool.ki[fi, g_tab].reshape(R, W * bs, -1)
-                    sel, valid = _pick_rows(_full_decode_scores(
-                        qi[:, 0], w[:, 0], keys, pos), cfg.index_topk)
-                with jax.named_scope("latent/gather"):
-                    rows = _gather_rows(pool.kv, fi, g_tab, sel, bs)
-                selected = selected + jnp.sum(valid)
-            else:
-                wi = win_of[li]
-                with jax.named_scope("latent/scatter"):
-                    pool = pool._replace(
-                        wkv=pool.wkv.at[wi, blk_w, off].set(row))
-                with jax.named_scope("latent/gather"):
-                    rows, valid = _window_rows(pool, wi, w_tab, pos, P, bs)
-            with jax.named_scope("latent/attention"):
-                o = dots3.unabsorb_v(
-                    dots3.latent_attend(q_abs, rows, valid, a), p, a)
-            x = x + model.attn_out(o[:, None], h, p)
+            with jax.named_scope("block/mla"):
+                h = _rmsnorm(x, p["ln1_g"], eps=cfg.norm_eps)
+                c_q, q, c_kv, k_rope = model.latents(h, p, pos[:, None], cfg,
+                                                     kind)
+                row = dots3.cache_row(c_kv, k_rope[:, :, 0], a)[:, 0]
+                q_abs = dots3.absorb_q(q[:, 0], p, a)           # (R, H, row)
+                if kind == FULL:
+                    fi = full_of[li]
+                    ki = model.index_keys(h, p["idx"], pos[:, None],
+                                          cfg)[:, 0]
+                    with jax.named_scope("latent/scatter"):
+                        pool = pool._replace(
+                            kv=pool.kv.at[fi, blk_g, off].set(row),
+                            ki=pool.ki.at[fi, blk_g, off].set(ki))
+                    qi, w = model.index_queries(c_q, h, p["idx"],
+                                                pos[:, None], cfg)
+                    with jax.named_scope("latent/index_scores"):
+                        keys = pool.ki[fi, g_tab].reshape(R, W * bs, -1)
+                        sel, valid = _pick_rows(_full_decode_scores(
+                            qi[:, 0], w[:, 0], keys, pos), cfg.index_topk)
+                    with jax.named_scope("latent/gather"):
+                        rows = _gather_rows(pool.kv, fi, g_tab, sel, bs)
+                    selected = selected + jnp.sum(valid)
+                else:
+                    wi = win_of[li]
+                    with jax.named_scope("latent/scatter"):
+                        pool = pool._replace(
+                            wkv=pool.wkv.at[wi, blk_w, off].set(row))
+                    with jax.named_scope("latent/gather"):
+                        rows, valid = _window_rows(pool, wi, w_tab, pos, P,
+                                                   bs)
+                with jax.named_scope("latent/attention"):
+                    o = dots3.unabsorb_v(
+                        dots3.latent_attend(q_abs, rows, valid, a), p, a)
+                x = x + model.attn_out(o[:, None], h, p)
             x, layer = model.ffn(x, p, cfg)
             moe = model.fold(moe, layer)
         nf = len(full_of)
         pool = pool._replace(stats=_stats(
             moe, jnp.sum(pos + 1) * nf, selected, R * nf, 0.0, 0.0, 0.0))
-        return model.readout(params, x, cfg)[:, 0], pool
+        with jax.named_scope("readout"):
+            logits = model.readout(params, x, cfg)[:, 0]
+        return logits, pool
 
-    return step
+    return traced_program(
+        "serve.decode", step,
+        key=lambda params, pool, toks, pos, tables: f"W={tables.shape[-1]}")
 
 
 @functools.lru_cache(maxsize=256)
@@ -348,54 +357,64 @@ def make_latent_prefill_fn(cfg, block_size: int, chunk_len: int,
         blk_g = jnp.take(g_tab, positions // bs)
         if win_of:
             blk_w = jnp.take(w_tab, positions // bs)
-        x = params["wte"][tokens].astype(cfg.dtype)             # (1, C, d)
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens].astype(cfg.dtype)         # (1, C, d)
         moe = jnp.zeros((len(model.moe_stats),), jnp.float32)
         selected = tie_tiles = jnp.zeros((), jnp.float32)
         for li, p in enumerate(params["blocks"]):
             kind = cfg.layer_types[li]
             a = cfg.dims(kind)
-            h = _rmsnorm(x, p["ln1_g"], eps=cfg.norm_eps)
-            c_q, q, c_kv, k_rope = model.latents(h, p, positions, cfg, kind)
-            row = dots3.cache_row(c_kv, k_rope[:, :, 0], a)[0]
-            if kind == FULL:
-                fi = full_of[li]
-                ki = model.index_keys(h, p["idx"], positions, cfg)[0]
-                with jax.named_scope("latent/scatter"):
-                    pool = pool._replace(
-                        kv=pool.kv.at[fi, blk_g, off].set(row),
-                        ki=pool.ki.at[fi, blk_g, off].set(ki))
-                qi, w = model.index_queries(c_q, h, p["idx"], positions, cfg)
-                with jax.named_scope("latent/sparse_attention"):
-                    o, picked, ties = full_attend(p, q, qi[0], w[0], pool,
-                                                  fi, g_tab, pos0)
-                selected, tie_tiles = selected + picked, tie_tiles + ties
-            else:
-                wi = win_of[li]
-                with jax.named_scope("latent/scatter"):
-                    pool = pool._replace(
-                        wkv=pool.wkv.at[wi, blk_w, off].set(row))
-                with jax.named_scope("latent/window_attention"):
-                    # the window - 1 rows before the chunk (those before
-                    # position 0 are padding the mask never lets through)
-                    # and the chunk's own, k and v materialised
-                    before = pos0 - P + jnp.arange(P)
-                    prev = _gather_rows(pool.wkv, wi, w_tab,
-                                        jnp.maximum(before, 0), bs)
-                    lat = jnp.concatenate([prev, row])[None]
-                    k, v = mla_expand(
-                        lat[..., :a.kv_rank],
-                        lat[:, :, None, a.kv_rank:a.row], p,
-                        n_heads=a.heads, nope=a.nope, v_dim=a.v)
-                    o = flash_attention_window(q, k, v, pos0, pos0 - P,
-                                               cfg.window)
-            x = x + model.attn_out(o, h, p)
+            with jax.named_scope("block/mla"):
+                h = _rmsnorm(x, p["ln1_g"], eps=cfg.norm_eps)
+                c_q, q, c_kv, k_rope = model.latents(h, p, positions, cfg,
+                                                     kind)
+                row = dots3.cache_row(c_kv, k_rope[:, :, 0], a)[0]
+                if kind == FULL:
+                    fi = full_of[li]
+                    ki = model.index_keys(h, p["idx"], positions, cfg)[0]
+                    with jax.named_scope("latent/scatter"):
+                        pool = pool._replace(
+                            kv=pool.kv.at[fi, blk_g, off].set(row),
+                            ki=pool.ki.at[fi, blk_g, off].set(ki))
+                    qi, w = model.index_queries(c_q, h, p["idx"], positions,
+                                                cfg)
+                    with jax.named_scope("latent/sparse_attention"):
+                        o, picked, ties = full_attend(p, q, qi[0], w[0],
+                                                      pool, fi, g_tab, pos0)
+                    selected, tie_tiles = selected + picked, tie_tiles + ties
+                else:
+                    wi = win_of[li]
+                    with jax.named_scope("latent/scatter"):
+                        pool = pool._replace(
+                            wkv=pool.wkv.at[wi, blk_w, off].set(row))
+                    with jax.named_scope("latent/window_attention"):
+                        # the window - 1 rows before the chunk (those before
+                        # position 0 are padding the mask never lets
+                        # through) and the chunk's own, k and v materialised
+                        before = pos0 - P + jnp.arange(P)
+                        prev = _gather_rows(pool.wkv, wi, w_tab,
+                                            jnp.maximum(before, 0), bs)
+                        lat = jnp.concatenate([prev, row])[None]
+                        k, v = mla_expand(
+                            lat[..., :a.kv_rank],
+                            lat[:, :, None, a.kv_rank:a.row], p,
+                            n_heads=a.heads, nope=a.nope, v_dim=a.v)
+                        o = flash_attention_window(q, k, v, pos0, pos0 - P,
+                                                   cfg.window)
+                x = x + model.attn_out(o, h, p)
             x, layer = model.ffn(x, p, cfg)
             moe = model.fold(moe, layer)
         nf = len(full_of)
         scored = (C * pos0 + C * (C + 1) // 2) * nf
         pool = pool._replace(stats=_stats(
             moe, scored, selected, C * nf, scored, selected, tie_tiles))
-        logits = model.readout(params, x, cfg) if with_readout else None
+        logits = None
+        if with_readout:
+            with jax.named_scope("readout"):
+                logits = model.readout(params, x, cfg)
         return logits, pool
 
-    return chunk
+    return traced_program(
+        "serve.prefill", chunk,
+        key=lambda params, pool, tokens, pos0, table:
+        f"C={C},W={table.shape[-1]},readout={int(with_readout)}")

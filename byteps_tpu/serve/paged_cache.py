@@ -95,6 +95,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from byteps_tpu.common.metrics import get_registry
+from byteps_tpu.common.tracing import traced_program
 from byteps_tpu.models.generate import (
     _QuantSlot,
     _block_step,
@@ -242,16 +243,20 @@ def _layers_by_kind(plan: "StepPlan") -> Tuple[int, int, int]:
     return len(plan.kinds) - n_keyless - n_window, n_window, n_state
 
 
-def _embed_in(plan: "StepPlan", x):
+def _embed_in(plan: "StepPlan", params, tokens, positions, cfg):
     """The embedding as the plan scales it (1: nothing is traced)."""
-    return x if plan.embed_scale == 1.0 \
-        else x * jnp.asarray(plan.embed_scale, x.dtype)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, positions, cfg)
+        return x if plan.embed_scale == 1.0 \
+            else x * jnp.asarray(plan.embed_scale, x.dtype)
 
 
 def _logits(plan: "StepPlan", params, x, norm_fn, norm_eps):
     """The readout as the plan scales it (1: nothing is traced)."""
-    logits = _readout(params, x, norm_fn, norm_eps)
-    return logits if plan.logit_scale == 1.0 else logits * plan.logit_scale
+    with jax.named_scope("readout"):
+        logits = _readout(params, x, norm_fn, norm_eps)
+        return logits if plan.logit_scale == 1.0 \
+            else logits * plan.logit_scale
 
 
 def one_kind_plan(cfg) -> StepPlan:
@@ -1393,12 +1398,13 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, pool, toks, pos, tables, slabs=None, slots=None):
         if B is None:
-            x = _embed_in(plan, _embed(params, toks[:, None], pos[:, None],
-                                       cfg))                   # (R, 1, d)
+            x = _embed_in(plan, params, toks[:, None], pos[:, None],
+                          cfg)                                 # (R, 1, d)
             at = lambda: pos[:, None]                          # noqa: E731
         else:
             where = pos[:, None] + jnp.arange(B)               # (R, B)
-            x = _embed(params, toks, where, cfg)               # (R, B, d)
+            with jax.named_scope("embed"):
+                x = _embed(params, toks, where, cfg)           # (R, B, d)
             at = lambda: where                                 # noqa: E731
         if n_state:
             state_slots, tables = tables[:, 0], tables[:, 1:]
@@ -1459,7 +1465,12 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
         logits = _logits(plan, params, x, norm_fn, norm_eps)
         return (logits[:, 0] if B is None else logits), pool
 
-    return step
+    # an executable a table width (less the slot column of a state pool):
+    # the W of the step's serve.decode_dispatch / serve.device_step.* args
+    return traced_program(
+        "serve.decode", step,
+        key=lambda params, pool, toks, pos, tables, *_:
+        f"W={tables.shape[-1] - bool(n_state)}")
 
 
 @functools.lru_cache(maxsize=256)
@@ -1635,7 +1646,7 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
         blks = [jnp.take(t, positions // block_size) for t in kind_tables]
         off = positions % block_size
         keep = jnp.arange(table.shape[-1] * block_size) < pos0
-        x = _embed_in(plan, _embed(params, tokens, positions, cfg))
+        x = _embed_in(plan, params, tokens, positions, cfg)
         moe = None if plan.ffn is None else jnp.zeros((4,), jnp.float32)
         for p, kind in zip(params["blocks"], plan.kinds):
             line = 0 if kind.window is None else 1
@@ -1673,4 +1684,9 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
                              else x, norm_fn, norm_eps)
         return logits, pool
 
-    return chunk
+    # the C and W of the chunk's serve.prefill_dispatch args
+    return traced_program(
+        "serve.prefill", chunk,
+        key=lambda params, pool, tokens, pos0, table:
+        f"C={C},W={table.shape[-1] - bool(n_state)},"
+        f"readout={int(with_readout)}")

@@ -126,7 +126,7 @@ from byteps_tpu.common.faults import FaultPlan, WorkerKilledError, plan_from_env
 from byteps_tpu.common.flight_recorder import get_flight_recorder
 from byteps_tpu.common.logging import get_logger
 from byteps_tpu.common.metrics import get_registry
-from byteps_tpu.common.tracing import get_tracer
+from byteps_tpu.common.tracing import get_tracer, traced_program
 from byteps_tpu.models.generate import gpt_apply_cached, init_cache
 from byteps_tpu.models.gpt import GPTConfig
 from byteps_tpu.models.speculative import _verify_commit
@@ -179,9 +179,10 @@ def _make_pick_fn(vocab_size: int):
     # the same pick on a chunk's last position, sliced on the device inside
     # the one call: only vocab floats would cross to host, never the whole
     # (1, C, vocab) chunk, and no eager slice is dispatched for it
-    return jax.jit(pick), jax.jit(
-        lambda logits, seeds, pos, temps: pick(logits[:, -1], seeds, pos,
-                                               temps))
+    return traced_program("serve.pick", jax.jit(pick)), traced_program(
+        "serve.pick_last", jax.jit(
+            lambda logits, seeds, pos, temps: pick(logits[:, -1], seeds, pos,
+                                                   temps)))
 
 
 # columns of the one host array a packed decode step is issued with (a
@@ -209,6 +210,9 @@ def _take(picked, first, host, table_shape):
             host[:, _SLOT])
 
 
+_take = traced_program("serve.take", _take, statics=("table_shape",))
+
+
 # a block-diffusion step's columns: what a pass fixes of the row's block and
 # which pass of the block it is (a greedy schedule needs neither the token
 # column nor a seed); the row's block state follows its table
@@ -227,6 +231,10 @@ def _take_block(state, host, table_shape):
     return (state[:, :state.shape[1] // 2], state, host[:, _POS],
             host[:, _N_COLS:n].reshape((host.shape[0],) + table_shape),
             host[:, _NFIX], host[:, _PASS])
+
+
+_take_block = traced_program("serve.take", _take_block,
+                             statics=("table_shape",))
 
 
 class _Block:
@@ -575,7 +583,6 @@ class Scheduler:
             "admitted": _reg.counter("serve.admitted"),
             "completed": _reg.counter("serve.completed"),
             "preempted": _reg.counter("serve.preempted"),
-            "resumed": _reg.counter("serve.resumed"),
             "prefill_tokens": _reg.counter("serve.prefill_tokens"),
             "decode_tokens": _reg.counter("serve.decode_tokens"),
             "decode_steps_paged_attn": _reg.counter(
@@ -700,7 +707,6 @@ class Scheduler:
         self._runs[req.rid] = run
         if resume_tokens:
             self._waiting.appendleft(run)   # failover work is oldest
-            self._m["resumed"].inc()
         else:
             self._waiting.append(run)
         self._m["queue_depth"].set(len(self._waiting))
