@@ -25,6 +25,14 @@ same for every family.
   AND a slot of the state pool (a Mamba-2 mixer beside attention on one
   normed input), under the same two programs; a final chunk reads out its
   last position alone.
+* :class:`SharedKVFamily` (``Phi4FlashConfig``): FOUR cache behaviours in one
+  plan — one full-attention layer's k/v pages (a global pool one layer deep),
+  window pages that come back, a slot of the state pool a request for the
+  Mamba-1 layers, and layers that own nothing: cross-attention layers that
+  read the one full layer's pages through the same table, and Gated Memory
+  Units fed the last Mamba layer's scan output. A prompt chunk that reads
+  nothing out ends after the full layer; a final chunk runs the layers above
+  it on its last position alone.
 * :class:`BlockDiffusionFamily` (``SDARConfig``): k/v pages of one kind
   under the same two programs told the block length — a decode row carries a
   block of positions, rewritten in place pass after pass, and a chunk's mask
@@ -461,6 +469,93 @@ class HybridKVFamily(RecurrentKVFamily):
 
 
 @functools.lru_cache(maxsize=16)
+def _shared_kv_plan(cfg):
+    """The ``StepPlan`` of a ``Phi4FlashConfig``: each Mamba layer's row in
+    the state pool, each window layer's in the window pool, the ONE full
+    layer's row 0 of the global pool — which the cross layers read — and the
+    GMUs fed; no rotation anywhere; the model's own first halves and MLP, and
+    a chunk's readout of one position."""
+    from byteps_tpu.models.phi4_flash import (
+        CROSS, FULL, GMU, MAMBA, WINDOW, diff_attn_half, gmu_half,
+        layer_kinds, mamba_half, mlp)
+    from byteps_tpu.serve.paged_cache import LayerKind, StepPlan
+
+    seen = {MAMBA: 0, WINDOW: 0}
+
+    def kind_of(kind):
+        if kind in seen:
+            seen[kind] += 1
+            return LayerKind(seen[kind] - 1, state=kind == MAMBA,
+                             window=cfg.window if kind == WINDOW else None)
+        return LayerKind(0, reader=kind == CROSS, fed=kind == GMU)
+
+    kinds = layer_kinds(cfg)
+    assert kinds.count(FULL) == 1
+    return StepPlan(tuple(kind_of(k) for k in kinds), mlp, diff_attn_half,
+                    mamba_half, last_logits=True, fed=gmu_half,
+                    attn_takes_kind=True)
+
+
+class SharedKVFamily(RecurrentKVFamily):
+    """Phi-4-mini-flash (SambaY): one full-attention layer's k/v pages that
+    seven cross-attention layers read, a window pool for the sliding layers,
+    a slot of a state pool a request for the Mamba-1 layers (the f32 state
+    and the convolution's tail), and Gated Memory Units that keep nothing —
+    under ``paged_cache.py``'s two programs and a :class:`~paged_cache.
+    StepPlan` with reader and fed layers. Preemption recomputes from position
+    0, as every family with a slot."""
+
+    name = "shared k/v + window k/v + recurrent state"
+    plan = staticmethod(_shared_kv_plan)
+
+    #: what three pools at once cannot do yet, each refused at construction:
+    #: ``feature -> the message's subject``
+    REFUSED = {
+        "prefix_cache": "the prefix cache (a shared prefix needs the "
+                        "recurrent state as it was at the sharing point and "
+                        "window blocks that were given back: neither is "
+                        "kept)",
+        "speculation": "speculative decoding (a rejected draft cannot "
+                       "rewind a recurrent state, nor window blocks given "
+                       "back)",
+        "adapter_pool": "LoRA adapter slabs",
+        "quant_cache": "the int8 pool",
+        "role": "role='prefill'|'decode' and migration over kv_wire (no "
+                "payload for a state slot or a window page)",
+        "tp_axis": "tensor parallelism",
+    }
+
+    def layout(self, params, cfg, *, block_size, pool_blocks, max_batch,
+               prefill_chunk, quant) -> PoolLayout:
+        import jax.numpy as jnp
+
+        from byteps_tpu.models.phi4_flash import MAMBA, WINDOW, layer_kinds
+        from byteps_tpu.serve.paged_cache import (
+            STATS_SAMBAY, kv_pool_state, with_state_pool)
+
+        kinds = layer_kinds(cfg)
+        wb = window_pool_blocks(cfg.window, block_size, max_batch,
+                                prefill_chunk)
+        slots = 1 + admitted_at_once(max_batch)
+        pool = kv_pool_state(
+            cfg, block_size, pool_blocks, cfg.kv_heads, False, layers=1,
+            window_layers=kinds.count(WINDOW), window_blocks=wb)
+        pool = with_state_pool(
+            pool, kinds.count(MAMBA), slots, (cfg.ssm_state, cfg.d_inner),
+            ((cfg.conv_kernel - 1) * cfg.d_inner,), cfg.dtype)
+        return PoolLayout(
+            state=pool._replace(
+                stats=jnp.zeros((len(STATS_SAMBAY),), jnp.float32)),
+            kv_heads=cfg.kv_heads, window=cfg.window, window_blocks=wb,
+            state_slots=slots)
+
+    def late_stats(self):
+        from byteps_tpu.serve.paged_cache import STATS_SAMBAY, StepStats
+
+        return StepStats(STATS_SAMBAY)
+
+
+@functools.lru_cache(maxsize=16)
 def _block_plan(cfg):
     """The ``StepPlan`` of an ``SDARConfig``: every layer global at one
     rotation, the q/k-normed first half, the expert FFN, and the block."""
@@ -561,6 +656,7 @@ def serve_family(cfg):
     from byteps_tpu.models.dots3 import Dots3Config
     from byteps_tpu.models.falcon_h1 import FalconH1Config
     from byteps_tpu.models.mellum2 import Mellum2Config
+    from byteps_tpu.models.phi4_flash import Phi4FlashConfig
     from byteps_tpu.models.qwen3_next import Qwen3NextConfig
     from byteps_tpu.models.sdar import SDARConfig
 
@@ -574,9 +670,12 @@ def serve_family(cfg):
         return RecurrentKVFamily()
     if isinstance(cfg, FalconH1Config):
         return HybridKVFamily()
+    if isinstance(cfg, Phi4FlashConfig):
+        return SharedKVFamily()
     if isinstance(cfg, GPTConfig):
         return GPTFamily()
     raise TypeError(
         f"Scheduler: no serve family for a {type(cfg).__name__} "
         "(GPTConfig, Dots3Config, DeepSeekV32Config, Mellum2Config, "
-        "Qwen3NextConfig, FalconH1Config and SDARConfig are served)")
+        "Qwen3NextConfig, FalconH1Config, Phi4FlashConfig and SDARConfig "
+        "are served)")

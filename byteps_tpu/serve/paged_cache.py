@@ -81,7 +81,14 @@ admission to release, its index rides at the head of the request's table
 row, and the plan's ``recur`` updates the slot in place. And a layer may be
 both at once (``LayerKind.hybrid``; ``families.HybridKVFamily``): a table
 line AND a row of the state pool, the plan's ``mixer`` given the pool's
-``attend`` and the slot.
+``attend`` and the slot. And a layer may own NOTHING (``families.
+SharedKVFamily``): a *reader* (``LayerKind.reader``) projects a query only and
+attends over the pages another layer wrote, through that layer's table line
+(:func:`_reader_attend`); a *fed* layer (``LayerKind.fed``) is given an
+activation an earlier recurrent layer of the same step handed out. A plan
+whose LAST layers are of those two sorts writes no cache above a certain
+layer, so a prompt chunk that reads nothing out ends there, and one that reads
+out runs them on its last position alone (:func:`_cacheless_tail`).
 """
 
 from __future__ import annotations
@@ -182,13 +189,21 @@ class LayerKind(NamedTuple):
     all). ``state``: a recurrent layer — no keys, no table line; ``index`` is
     its row in the state pool (``pool.s``/``conv``). ``hybrid``: a global
     layer that keeps a recurrent state beside its keys — ``index`` is its row
-    in the k/v pool AND in the state pool."""
+    in the k/v pool AND in the state pool. ``reader``: a layer that owns no
+    page: it projects a query only and attends over the pages ANOTHER layer
+    wrote — ``index`` is that layer's row of the global pool, read through
+    the same table line, and nothing is scattered. ``fed``: a layer with no
+    cache at all, whose first half (``plan.fed``) is given an activation of an
+    earlier layer of the same step: what the last recurrent layer before it
+    handed out; ``index`` means nothing."""
 
     index: int
     window: Optional[int] = None
     rope: Union[float, RopeFreqs] = 0.0
     state: bool = False
     hybrid: bool = False
+    reader: bool = False
+    fed: bool = False
 
 
 class StepPlan(NamedTuple):
@@ -211,8 +226,16 @@ class StepPlan(NamedTuple):
     s, conv)`` — ``attend`` and ``carry`` as ``attn`` has them, the rest as
     ``recur``. ``embed_scale`` multiplies the embedding, ``logit_scale`` the
     logits; ``last_logits``: a chunk reads out its last position alone,
-    ``(1, 1, vocab)`` (the scheduler keeps no other). Hashable: it keys the
-    programs' factories."""
+    ``(1, 1, vocab)`` (the scheduler keeps no other). ``fed``: the first half
+    of a ``fed`` layer, ``fed(cfg, x, p, m, norm_fn=, norm_eps=) -> x`` — ``m``
+    the fourth value the last ``recur`` before it returned (a plan with such
+    layers has a ``recur`` that returns four). ``attn_takes_kind``: ``attn``
+    is also given ``kind=``, the layer's :class:`LayerKind` (its index zeroed:
+    the chunk program's index is data). A plan whose LAST layers are readers
+    and fed ones only — they write no cache — runs them on a chunk's last
+    position alone, and not at all in a chunk that reads nothing out
+    (:func:`make_paged_prefill_fn`). Hashable: it keys the programs'
+    factories."""
 
     kinds: Tuple[LayerKind, ...]
     ffn: Optional[Callable] = None
@@ -223,6 +246,8 @@ class StepPlan(NamedTuple):
     embed_scale: float = 1.0
     logit_scale: float = 1.0
     last_logits: bool = False
+    fed: Optional[Callable] = None
+    attn_takes_kind: bool = False
 
 
 def _window_of(plan: "StepPlan") -> Optional[int]:
@@ -234,13 +259,25 @@ def _window_of(plan: "StepPlan") -> Optional[int]:
 
 
 def _layers_by_kind(plan: "StepPlan") -> Tuple[int, int, int]:
-    """``(global, window, recurrent)`` layers of a plan: layers with a line
-    of the global table, of the window table, with a row of the state pool
-    (a hybrid layer is a global and a recurrent one)."""
+    """``(global, window, recurrent)`` layers of a plan: layers that read a
+    line of the global table (a reader among them), of the window table, with
+    a row of the state pool (a hybrid layer is a global and a recurrent
+    one)."""
     n_state = sum(k.state or k.hybrid for k in plan.kinds)
     n_window = sum(k.window is not None for k in plan.kinds)
-    n_keyless = sum(k.state for k in plan.kinds)
+    n_keyless = sum(k.state or k.fed for k in plan.kinds)
     return len(plan.kinds) - n_keyless - n_window, n_window, n_state
+
+
+def _cacheless_tail(plan: "StepPlan") -> int:
+    """How many of a plan's LAST layers write no cache (readers and fed
+    layers): what a chunk runs on its last position alone (0: none)."""
+    n = 0
+    for k in reversed(plan.kinds):
+        if not (k.reader or k.fed):
+            break
+        n += 1
+    return n
 
 
 def _embed_in(plan: "StepPlan", params, tokens, positions, cfg):
@@ -280,6 +317,12 @@ STATS = ("moe.pairs_here", "moe.experts_hit", "moe.layers",
 STATS_STATE = STATS + ("serve.gdn.decode_rows", "serve.gdn.prefill_tokens")
 #: the same two of a pool whose state is a Mamba-2 (SSD) mixer's
 STATS_SSD = STATS + ("serve.ssd.decode_rows", "serve.ssd.prefill_tokens")
+#: and of one whose state is a Mamba-1 selective scan's, in a plan whose last
+#: layers write no cache: positions of a chunk those layers ran over (1 in a
+#: chunk that reads out, 0 in one that does not)
+STATS_SAMBAY = STATS + ("serve.sscan.decode_rows",
+                        "serve.sscan.prefill_tokens",
+                        "sambay.cross_positions")
 
 
 def kv_pool_state(cfg: GPTConfig, block_size: int, pool_blocks: int,
@@ -946,11 +989,16 @@ class PagedKVCache:
             raise ValueError(f"width {w} < live table {len(t)}")
         if self.window is not None:
             # line 0 the global kind, line 1 the window kind (0, the
-            # scratch block, where a block was released or never held)
-            rows = np.zeros((2, w), np.int32)
-            rows[0, :len(t)] = t
+            # scratch block, where a block was released or never held); with
+            # a recurrent kind too, a column before them: the request's slot
+            # at the head of line 0
+            s = 1 if self.state_slots else 0
+            rows = np.zeros((2, s + w), np.int32)
+            if s:
+                rows[0, 0] = self._slots[rid]
+            rows[0, s:s + len(t)] = t
             wt = self._wtables[rid]
-            rows[1, list(wt)] = list(wt.values())
+            rows[1, [s + b for b in wt]] = list(wt.values())
             return rows
         if self.state_slots:
             # the request's slot of the state pool rides at the head of its
@@ -1111,7 +1159,7 @@ class StepStats(LateStats):
         self._experts_hit = reg.histogram("moe.experts_hit")
         self._load = reg.histogram("moe.load_max_over_mean")
         self._counters = {n: reg.counter(n) for n in names
-                          if n.startswith("serve.")}
+                          if n.startswith(("serve.", "sambay."))}
 
     def observe(self, s: dict) -> None:
         if s["moe.layers"] > 0:
@@ -1172,6 +1220,40 @@ def _block_attend_twin(q, kk, vv, length):
     pr = jax.nn.softmax(jnp.where(ok[:, None, None, None], s, -1e30), -1)
     o = jnp.einsum("rhgbk,rkhd->rbhgd", pr, vv.astype(jnp.float32))
     return o.reshape(R, B, H, D).astype(q.dtype)
+
+
+def _reader_attend(cfg, block_size: int, pool, li, tables, length):
+    """A reader layer's ``attend`` (:class:`LayerKind`): nothing is written;
+    each row's one query attends over keys ``[0, length[r])`` of row ``li`` of
+    the global pool — another layer's, which wrote them earlier in this
+    program — through the row's table ``tables (R, W)``. The kernel where the
+    packed decode step takes it, else the gathered view. The carry is the
+    pool, as it was."""
+    def attend(q, k, v):
+        del k, v                          # a reader projects a query only
+        head_dim = q.shape[-1]
+        if decode_uses_paged_attn(cfg, block_size,
+                                  pool.k.shape[-1] // head_dim, False):
+            with jax.named_scope("paged/attention"):
+                o = paged_attention_decode(q[:, 0], pool.k, pool.v, tables,
+                                           length, li)
+        else:
+            with jax.named_scope("paged/gather_kv"):
+                kk = _gather_view(pool.k[li], None, tables, length, q.dtype,
+                                  head_dim)
+                vv = _gather_view(pool.v[li], None, tables, length, q.dtype,
+                                  head_dim)
+            with jax.named_scope("paged/attention"):
+                o, _ = attention_lse(q, kk, vv, length - 1, 0, causal=True)
+        return o, pool
+    return attend
+
+
+def _kind_kw(plan: "StepPlan"):
+    """``kind -> the keywords plan.attn is given beside attn_half's``."""
+    if plan.attn_takes_kind:
+        return lambda kind: {"kind": kind._replace(index=0)}
+    return lambda kind: {}
 
 
 def decode_uses_paged_attn(cfg: GPTConfig, block_size: int,
@@ -1273,7 +1355,10 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     (0, scratch, for a row that holds no request), which ``plan.recur``
     updates in place; such a layer reads no table and no key. A hybrid layer
     does both: ``plan.mixer`` is given this step's ``attend`` over the layer's
-    row of the k/v pool and the rows' slots.
+    row of the k/v pool and the rows' slots. With window AND recurrent
+    layers ``tables`` is ``(R, 2, 1 + W)``, the slot at the head of line 0. A
+    reader layer attends over another layer's row of the global pool and
+    writes nothing (:func:`_reader_attend`); a fed layer touches no pool.
     With ``plan.block`` = B the step is a pass of block diffusion: ``toks (R,
     B)`` — row ``r``'s block as it stands, the mask token where a position is
     open — at positions ``[pos[r], pos[r] + B)`` (a block boundary: the B rows
@@ -1307,6 +1392,7 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
     half = attn_half if plan.attn is None \
         else functools.partial(plan.attn, cfg)
     n_full, n_window, n_state = _layers_by_kind(plan)
+    kind_kw = _kind_kw(plan)
     B = plan.block
     if B is not None and (n_window or n_state or block_size % B):
         raise ValueError(
@@ -1406,7 +1492,9 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
             with jax.named_scope("embed"):
                 x = _embed(params, toks, where, cfg)           # (R, B, d)
             at = lambda: where                                 # noqa: E731
-        if n_state:
+        if n_state and tables.ndim == 3:
+            state_slots, tables = tables[:, 0, 0], tables[:, :, 1:]
+        elif n_state:
             state_slots, tables = tables[:, 0], tables[:, 1:]
         # one table a kind: (R, W), or (R, 2, W) with a window kind
         kind_tables = (tables,) if tables.ndim == 2 \
@@ -1423,10 +1511,20 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
             delta = None if slabs is None else _slab_delta(slabs, slots, li)
             line = 0 if kind.window is None else 1
             if kind.state:
-                x, s, conv = plan.recur(
+                # (a fourth value: what the fed layers after it are given)
+                x, s, conv, *fed = plan.recur(
                     cfg, x, p, pool.s, pool.conv, kind.index, state_slots,
                     None, norm_fn=norm_fn, norm_eps=norm_eps)
                 pool = pool._replace(s=s, conv=conv)
+            elif kind.fed:
+                x = plan.fed(cfg, x, p, fed[0], norm_fn=norm_fn,
+                             norm_eps=norm_eps)
+            elif kind.reader:
+                x, pool = half(
+                    x, p, cfg.head_dim, at,
+                    _reader_attend(cfg, block_size, pool, kind.index,
+                                   kind_tables[0], pos + 1), tp_axis,
+                    kind.rope, delta=delta, **kw, **kind_kw(kind))
             elif kind.hybrid:
                 x, pool, s, conv = plan.mixer(
                     cfg, x, p, cfg.head_dim, at,
@@ -1440,7 +1538,7 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                     x, p, cfg.head_dim, at,
                     _pool_attend(pool, kind, blks[line], off, pos,
                                  kind_tables[line]), tp_axis,
-                    kind.rope, delta=delta, **kw)
+                    kind.rope, delta=delta, **kw, **kind_kw(kind))
             x, aux = ffn_half(
                 x, p, tp_axis, None if plan.ffn is None
                 else functools.partial(plan.ffn, cfg, p), delta=delta, **kw)
@@ -1459,6 +1557,8 @@ def make_paged_decode_fn(cfg: GPTConfig, block_size: int,
                     for w, n in ((None, n_full),
                                  (_window_of(plan), n_window))]
             rows = (jnp.sum(live) * n_state, 0.0) if n_state else ()
+            if _cacheless_tail(plan):     # STATS_SAMBAY: a chunk's count
+                rows += (0.0,)
             pool = pool._replace(stats=jnp.concatenate([moe, jnp.stack(
                 [jnp.asarray(v, jnp.float32)
                  for v in (*keys, 0.0, 0.0, *rows)])]))
@@ -1515,7 +1615,16 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     the first decode step continues from. A hybrid layer runs the path above
     over its table line with ``plan.mixer`` as its first half, which is given
     the slot too. With ``plan.last_logits`` the readout is of the chunk's
-    last position alone, ``(1, 1, vocab)``.
+    last position alone, ``(1, 1, vocab)``. With window AND recurrent layers
+    ``table`` is ``(2, 1 + W)``, the slot at the head of line 0.
+    **A plan whose last layers write no cache** (readers and fed layers:
+    :func:`_cacheless_tail`) **does not run them on a chunk**: with
+    ``with_readout=False`` the program ENDS after the last layer that writes
+    one — nothing above it leaves anything a later chunk or decode step reads
+    — and with ``with_readout=True`` those layers run on the chunk's LAST
+    position alone (a fed layer on the last position of what the recurrent
+    layer handed out, a reader with one query over the ``pos0 + C`` keys its
+    layer has just written), which is all the readout takes.
     With ``plan.block`` the chunk attends block-causally (the flash forward
     with the block a constant of its mask; ``pos0`` and C whole blocks).
     ``with_readout=False`` skips the vocab projection (an intermediate
@@ -1527,6 +1636,9 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     norm_fn, norm_eps = resolve_norm(cfg)
     half = None if plan.attn is None else functools.partial(plan.attn, cfg)
     n_full, n_window, n_state = _layers_by_kind(plan)
+    kind_kw = _kind_kw(plan)
+    n_tail = _cacheless_tail(plan)
+    n_readers = sum(k.reader for k in plan.kinds)
 
     def _view(pool_a, li, table, keep, *tail):
         # this request's (1, W * bs, h[, D]) view of one layer, zero past
@@ -1576,20 +1688,22 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
                     q, kk, vv, pos0, pos0 - before, kind.window), new
 
         kw = dict(norm_fn=norm_fn, norm_eps=norm_eps, use_bias=cfg.use_bias)
-        x, pool = attn_half(x, p, cfg.head_dim,
-                            lambda: pos0 + jnp.arange(C), attend, tp_axis,
-                            kind.rope, **kw)
+        x, pool = (attn_half if half is None else half)(
+            x, p, cfg.head_dim, lambda: pos0 + jnp.arange(C), attend, tp_axis,
+            kind.rope, **kw, **kind_kw(kind))
         x, aux = ffn_half(x, p, tp_axis, _ffn(p), **kw)
         return x, pool, aux
 
     @jax.jit
     def _state_layer(x, p, pool, li, pos0, slot):
-        """A recurrent layer of the chunk: ``(x, pool, aux)``."""
-        x, s, conv = plan.recur(cfg, x, p, pool.s, pool.conv, li, slot,
-                                pos0 == 0, norm_fn=norm_fn, norm_eps=norm_eps)
+        """A recurrent layer of the chunk: ``(x, pool, aux)``, and after
+        them what ``plan.recur`` hands to fed layers, where it does."""
+        x, s, conv, *fed = plan.recur(
+            cfg, x, p, pool.s, pool.conv, li, slot, pos0 == 0,
+            norm_fn=norm_fn, norm_eps=norm_eps)
         x, aux = ffn_half(x, p, tp_axis, _ffn(p), norm_fn=norm_fn,
                           norm_eps=norm_eps, use_bias=cfg.use_bias)
-        return x, pool._replace(s=s, conv=conv), aux
+        return (x, pool._replace(s=s, conv=conv), aux, *fed)
 
     # jitted, the layer index DATA: layers of one shape share one trace.
     # A replica traces and lowers a chunk program for every tail chunk x
@@ -1619,8 +1733,9 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
         else:
             x, ck, cv, *aux = _block_step(
                 x, p, ck, cv, pos0, cfg, tp_axis, None, norm_fn=norm_fn,
-                norm_eps=norm_eps, rope=kind.rope, ffn=_ffn(p), attn=half,
-                block=plan.block)
+                norm_eps=norm_eps, rope=kind.rope, ffn=_ffn(p),
+                attn=functools.partial(half, **kind_kw(kind))
+                if plan.attn_takes_kind else half, block=plan.block)
             aux = aux[0] if aux else None
         with jax.named_scope("paged/scatter_kv"):
             at = (li, blk, off)
@@ -1639,7 +1754,9 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
     @functools.partial(jax.jit, donate_argnums=(1,))
     def chunk(params, pool, tokens, pos0, table):
         positions = pos0 + jnp.arange(C)
-        if n_state:
+        if n_state and table.ndim == 2:
+            slot, table = table[0, 0], table[:, 1:]
+        elif n_state:
             slot, table = table[0], table[1:]
         # one table a kind: (W,), or (2, W) with a window kind
         kind_tables = (table,) if table.ndim == 1 else (table[0], table[1])
@@ -1648,10 +1765,11 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
         keep = jnp.arange(table.shape[-1] * block_size) < pos0
         x = _embed_in(plan, params, tokens, positions, cfg)
         moe = None if plan.ffn is None else jnp.zeros((4,), jnp.float32)
-        for p, kind in zip(params["blocks"], plan.kinds):
+        n_body = len(plan.kinds) - n_tail
+        for p, kind in zip(params["blocks"][:n_body], plan.kinds[:n_body]):
             line = 0 if kind.window is None else 1
             if kind.state:
-                x, pool, aux = _state_layer(
+                x, pool, aux, *fed = _state_layer(
                     x, p, pool, jnp.int32(kind.index), pos0, slot)
             else:
                 x, pool, aux = _layer(
@@ -1675,9 +1793,39 @@ def make_paged_prefill_fn(cfg: GPTConfig, block_size: int, chunk_len: int,
                      for w, n in ((None, n_full),
                                   (_window_of(plan), n_window))]
             toks = (0.0, float(C * n_state)) if n_state else ()
+            if n_tail:
+                # the readers' one query each sees every key so far. In a
+                # chunk that reads nothing out, the global layer right below
+                # the tail writes its rows and its queries feed nothing: they
+                # are not computed, and not counted. Then the positions the
+                # tail ran over (STATS_SAMBAY)
+                below = plan.kinds[n_body - 1]
+                idle = not with_readout and below.window is None \
+                    and not (below.state or below.hybrid)
+                pairs[0] = jnp.sum(reach()) * (n_full - n_readers - idle) + (
+                    (pos0 + C) * n_readers if with_readout else 0)
+                toks += (float(with_readout),)
             pool = pool._replace(stats=jnp.concatenate([moe, jnp.stack(
                 [jnp.asarray(v, jnp.float32)
                  for v in (0.0, 0.0, *pairs, *toks)])]))
+        if n_tail and with_readout:
+            # the layers that write no cache, on the last position alone
+            kw = dict(norm_fn=norm_fn, norm_eps=norm_eps,
+                      use_bias=cfg.use_bias)
+            x, last = x[:, -1:], (pos0 + C)[None]
+            mem = fed[0][:, -1:] if fed else None
+            for p, kind in zip(params["blocks"][n_body:],
+                               plan.kinds[n_body:]):
+                if kind.fed:
+                    x = plan.fed(cfg, x, p, mem, norm_fn=norm_fn,
+                                 norm_eps=norm_eps)
+                else:
+                    x, pool = half(
+                        x, p, cfg.head_dim, lambda: last[:, None] - 1,
+                        _reader_attend(cfg, block_size, pool, kind.index,
+                                       kind_tables[0][None], last), tp_axis,
+                        kind.rope, **kw, **kind_kw(kind))
+                x, aux = ffn_half(x, p, tp_axis, _ffn(p), **kw)
         logits = None
         if with_readout:
             logits = _logits(plan, params, x[:, -1:] if plan.last_logits

@@ -973,6 +973,153 @@ def test_falconh1_serve_program_fits_and_leaves_the_pools_in_place(
             assert name in hlo, name
 
 
+# --- the Phi-4-mini-flash serving cell's programs at the published sizes -----
+_P4_BS, _P4_BATCH, _P4_CHUNK, _P4_BLOCKS = 64, 96, 2048, 6401
+
+
+def _phi4flash_on(topo):
+    """Phi-4-mini-flash-reasoning whole (``benchmark/configs/
+    phi-4-mini-flash-reasoning.json``: 32 layers, the whole vocabulary,
+    bf16), its parameters and its three pools as shapes on the described
+    chip."""
+    from byteps_tpu.models.phi4_flash import Phi4FlashConfig, phi4_flash_init
+    from byteps_tpu.serve.families import serve_family
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = Phi4FlashConfig(max_seq=8192)
+    shapes = jax.eval_shape(
+        lambda: phi4_flash_init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), shapes)
+    family = serve_family(cfg)
+    pool = jax.eval_shape(lambda: family.layout(
+        shapes, cfg, block_size=_P4_BS, pool_blocks=_P4_BLOCKS,
+        max_batch=_P4_BATCH, prefill_chunk=_P4_CHUNK, quant=False).state)
+    pool = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), pool)
+    return cfg, family, params, pool, on_chip
+
+
+def _sscan_decode_case():
+    from byteps_tpu.ops.selective_scan import sscan_decode
+
+    def f(u, delta, A, B, C, D, pool, slots):
+        return sscan_decode(u, delta, A, B, C, D, pool, 8, slots)
+    R, N, Dn = _P4_BATCH, 16, 5120
+    return f, [_sds((R, Dn), F32), _sds((R, Dn), F32), _sds((N, Dn), F32),
+               _sds((R, N), F32), _sds((R, N), F32), _sds((Dn,), F32),
+               _sds((9, 121, N, Dn), F32), _sds((R,), I32)]
+
+
+def _p4_paged_decode(q, k, v, tables, lengths):
+    return paged_attention_decode(q, k, v, tables, lengths, 0)
+
+
+def _p4_window_decode(q, k, v, tables, lengths):
+    return paged_attention_decode(q, k, v, tables, lengths, 7,
+                                  first=jnp.maximum(lengths - 512, 0))
+
+
+_P4_KERNELS = [
+    # the decode step's state update in place in the slot pool: 16 x 5120
+    # f32 a row and layer, one row a grid step
+    ("sscan_decode_r96_n16_dn5120", *_sscan_decode_case(), 1),
+    # 40 padded query heads on 10 k/v PAIRS of 128: the full layer's pages,
+    # read by it and by each cross layer
+    ("paged_attn_decode_phi4flash_h40_kv10_w128", _p4_paged_decode,
+     [_sds((_P4_BATCH, 40, 128), BF16)]
+     + [_sds((1, _P4_BLOCKS, _P4_BS, 1280), BF16)] * 2
+     + [_sds((_P4_BATCH, 128), I32), _sds((_P4_BATCH,), I32)], 1),
+    # the same heads over a window layer's last 512 keys
+    ("paged_attn_decode_phi4flash_window512_w128", _p4_window_decode,
+     [_sds((_P4_BATCH, 40, 128), BF16)]
+     + [_sds((8, 1235, _P4_BS, 1280), BF16)] * 2
+     + [_sds((_P4_BATCH, 128), I32), _sds((_P4_BATCH,), I32)], 1),
+]
+
+
+@pytest.mark.parametrize("case", _P4_KERNELS, ids=_case_id)
+def test_phi4flash_kernel_compiles_for_v5e(topo, as_on_tpu, case):
+    test_kernel_compiles_for_v5e(topo, as_on_tpu, case)
+
+
+@pytest.mark.parametrize("program", [
+    "chunk_c2048_w64_stops", "chunk_c2048_w64_reads_out", "decode_r96_w64",
+    "params"])
+def test_phi4flash_serve_program_fits_and_leaves_the_pools_in_place(
+        topo, as_on_tpu, program):
+    """The whole model counts 3,852,562,944 parameters (7.71 GB in bf16); its
+    2,048-token chunks and its 96-row decode step compile for the described
+    v5e with the three pools (one layer of 6,401 pages, 8 window layers, 9
+    layers of 121 f32 slots) donated and updated in place, and weights +
+    pools + temporaries fit the chip's 16 GB. A decode step: 9 state updates
+    and 16 paged-attention calls (the full layer, 8 window layers, 7 readers
+    of the full layer's pages). A chunk that reads nothing out ENDS with
+    layer 17's k and v rows: 8 flash forwards, the window layers'; one that
+    reads out runs layer 17's own and adds 7 one-query paged reads."""
+    cfg, family, params, pool, on_chip = _phi4flash_on(topo)
+    weights, pages = _bytes(params), _bytes(pool)
+    n_const = 16                                   # lambda_init, f32
+    assert sum(a.size for a in jax.tree.leaves(params)) - n_const \
+        == 3852562944
+    assert pool.k.shape == (1, _P4_BLOCKS, 64, 1280)
+    assert pool.wk.shape[0] == 8 and pool.wk.shape[2:] == (64, 1280)
+    assert pool.s.shape == (9, 121, 16, 5120) and pool.s.dtype == F32
+    assert pool.conv.shape == (9, 121, 3 * 5120)
+    assert 12e9 < weights + pages < 14.5e9, (weights, pages)
+    if program == "params":
+        return
+    W = 64
+    if program.startswith("chunk"):
+        compiled = family.prefill_fn(
+            cfg, _P4_BS, _P4_CHUNK, None, program.endswith("reads_out"))\
+            .lower(params, pool, on_chip((1, _P4_CHUNK), I32),
+                   on_chip((), I32), on_chip((2, 1 + W), I32)).compile()
+    else:
+        assert family.decode_reads_pool_in_place(
+            cfg, type("C", (), dict(block_size=_P4_BS, kv_heads=10,
+                                    quant=False)))
+        compiled = family.decode_fn(cfg, _P4_BS, None, None).lower(
+            params, pool, on_chip((_P4_BATCH,), I32),
+            on_chip((_P4_BATCH,), I32),
+            on_chip((_P4_BATCH, 2, 1 + W), I32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pages - 64            # donated, in place
+    assert weights + pages + mem.temp_size_in_bytes < 15.5e9, \
+        mem.temp_size_in_bytes
+    rows = {"chunk_c2048_w64_stops": 0, "chunk_c2048_w64_reads_out": 1}.get(
+        program, _P4_BATCH)
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes \
+        <= rows * cfg.vocab_size * 4 + (1 << 20)
+    hlo = compiled.as_text()
+    for name in ("k", "v", "wk", "wv", "s"):
+        a = getattr(pool, name)
+        shape = "%s[%s]" % ("f32" if a.dtype == F32 else "bf16",
+                            ",".join(map(str, a.shape)))
+        made = [op for op, aliased in _ops_with_result(hlo, shape)
+                if op not in ("parameter", "tuple", "get-tuple-element",
+                              "bitcast") and not aliased]
+        assert not made, (name, made)
+    # the tied readout contracts the embedding as it lies: no transposed or
+    # upcast copy of the 200,064 x 2,560 table
+    assert not [op for op, _ in _ops_with_result(hlo, "f32[200064,2560]")]
+    assert not [op for op, _ in _ops_with_result(hlo, "bf16[2560,200064]")]
+    n = _n_pallas(compiled)
+    if program == "chunk_c2048_w64_stops":
+        # the 8 window layers' flash forwards: layer 17 writes its k and v
+        # and nothing reads what its attention or its MLP would give
+        assert n == 8, n
+        assert "paged_attn_decode" not in hlo
+    elif program.startswith("chunk"):
+        assert n == 9 + 7, n
+    else:
+        assert n == 9 + 16, n
+        for name in ("sscan_decode", "paged_attn_decode"):
+            assert name in hlo, name
+
+
 # ---- the training readout's backward: which array the loop carries ----------
 def _readout_loss_and_grads(topo, B, S, d, V):
     from byteps_tpu.ops.chunked_ce import chunked_ce_nll
