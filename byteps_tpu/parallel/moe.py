@@ -388,6 +388,110 @@ def _combine_bwd(res, dout):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+# The same four moves as Pallas kernels over the live prefix of the buffer
+# (``ops/moe_rows.py``), for a buffer that is mostly empty. ``slots`` is the
+# row plan's third map (:func:`_slots`), ``n_live (1,)`` the tiles that hold
+# a pair. A tile past them is NOT written by ``_dispatch_live`` nor by
+# ``_combine_live``'s ``dys``; :func:`moe_ffn_dropless` says why none is read.
+@jax.custom_vjp
+def _dispatch_live(x, row_pair, slots, n_live):
+    """:func:`_dispatch` on the live tiles."""
+    from byteps_tpu.ops.moe_rows import moe_rows_to_buffer
+
+    return moe_rows_to_buffer(x, row_pair, slots[1].shape[1], None, n_live)
+
+
+def _dispatch_live_fwd(x, row_pair, slots, n_live):
+    return _dispatch_live(x, row_pair, slots, n_live), (slots, n_live)
+
+
+def _dispatch_live_bwd(res, dxs):
+    from byteps_tpu.ops.moe_rows import moe_rows_to_tokens
+
+    (count, rows, _), n_live = res
+    ones = (jnp.arange(rows.shape[1])[None, :] < count[:, None])
+    return (moe_rows_to_tokens(dxs, count, rows, ones.astype(jnp.float32),
+                               n_live), None, None, None)
+
+
+_dispatch_live.defvjp(_dispatch_live_fwd, _dispatch_live_bwd)
+
+
+@jax.custom_vjp
+def _combine_live(ys, weight, row_pair, slots, n_live):
+    """:func:`_combine` over the pairs that have a row: the same f32 sums
+    in the same order, so the same bits."""
+    from byteps_tpu.ops.moe_rows import moe_rows_to_tokens
+
+    del row_pair
+    count, rows, slot_of = slots
+    return moe_rows_to_tokens(ys, count, rows, _by_slot(weight, slot_of),
+                              n_live)
+
+
+def _combine_live_fwd(ys, weight, row_pair, slots, n_live):
+    return (_combine_live(ys, weight, row_pair, slots, n_live),
+            (ys, weight, row_pair, slots, n_live))
+
+
+def _combine_live_bwd(res, dout):
+    from byteps_tpu.ops.moe_rows import moe_rows_dweight, moe_rows_to_buffer
+
+    ys, weight, row_pair, (count, rows, slot_of), n_live = res
+    k = weight.shape[1]
+    dys = moe_rows_to_buffer(dout, row_pair, k, weight, n_live)
+    # back from slots to pairs as it went (:func:`_by_slot`): a compare
+    # and a sum, where a gather would look 131,072 scalars up one by one
+    dweight = jnp.sum(jnp.where(
+        _lands(slot_of),
+        moe_rows_dweight(ys, count, rows, dout, n_live)[:, None, :], 0.0),
+        axis=2)
+    return dys, dweight.astype(weight.dtype), None, None, None
+
+
+_combine_live.defvjp(_combine_live_fwd, _combine_live_bwd)
+
+
+def _slots(pair_row: jnp.ndarray, n_rows: int):
+    """The row plan's third map, for the kernels: a token's pairs that have
+    a row, pushed to the front in the order they came. ``pair_row (T, k)``
+    → ``count (T,)`` such pairs, ``rows (T, k)`` their rows by slot (the
+    buffer's length in an empty slot) and ``slot_of (T, k)`` each pair's
+    slot (``k``: it has no row). Compare-and-count, as :func:`_row_plan`."""
+    k = pair_row.shape[1]
+    has = pair_row < n_rows
+    before = jnp.cumsum(has.astype(jnp.int32), axis=1)
+    slot_of = jnp.where(has, before - 1, k)
+    rows = jnp.sum(jnp.where(_lands(slot_of), pair_row[:, :, None], 0),
+                   axis=1)
+    empty = jnp.arange(k, dtype=jnp.int32)[None, :] >= before[:, -1:]
+    return before[:, -1], jnp.where(empty, n_rows, rows), slot_of
+
+
+def _lands(slot_of: jnp.ndarray):
+    """``(T, k pairs, k slots)``: pair ``j`` of token ``t`` lands in slot
+    ``r`` (a pair with no row in none)."""
+    return slot_of[:, :, None] == jnp.arange(slot_of.shape[1],
+                                             dtype=jnp.int32)
+
+
+def _by_slot(weight: jnp.ndarray, slot_of: jnp.ndarray):
+    """``weight (T, k)`` by slot: zero in an empty slot (one value and
+    zeros summed: exact)."""
+    return jnp.sum(jnp.where(_lands(slot_of), weight[:, :, None], 0.0),
+                   axis=1)
+
+
+#: Tiles of the worst-case row buffer from which the row kernels run (THE
+#: RULE of :func:`moe_ffn_dropless`): a training step's buffer (JoyAI's: 528
+#: tiles, +17.6% tokens/s, set-up unmoved) and not a serve chunk's (72–144
+#: tiles). There the kernels paid too (dots3 +8.1%, DeepSeek-V3.2 +1.7%
+#: tokens/s) but a serve cell lowers ~40 chunk programs before it serves,
+#: each with the kernels of its own, and warm ``setup_s`` rose 5% and 9.6%
+#: against a bound of 10% (PERF.md §6, PR 62; ROADMAP A4b has what lifts it).
+ROW_KERNEL_TILES = 256
+
+
 def dropless_row_tile(pairs: int, held: int, itemsize: int) -> int:
     """The row tile :func:`moe_ffn_dropless` lays its groups out by, from
     the program's static shapes alone: the rows a held expert would get if
@@ -474,6 +578,38 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     What the experts held elsewhere would add is left out (under expert
     parallelism their owners compute it; this layer runs no exchange).
 
+    **How the rows move — THE RULE.** Rows go to the buffer and back, forward
+    and in the backward, either as XLA gathers over the whole buffer
+    (:func:`_dispatch` / :func:`_combine`) or as the Pallas kernels of
+    ``ops/moe_rows.py`` (:func:`_dispatch_live` / :func:`_combine_live`),
+    which visit only the tiles that hold a pair. The kernels run where the
+    buffer is sparse by construction and a tile is worth a kernel's grid
+    step: ``held < n_routed`` (``w1``'s experts against ``wg``'s: an
+    expert-parallel share, JoyAI's step 6% full), the 256-row tile and a
+    buffer of :data:`ROW_KERNEL_TILES` tiles or more (a training step's: a
+    decode step's 640–3,328 rows in 16- or 32-row tiles are not worth five
+    kernel launches, and a serve chunk's 72–144 tiles gained tokens/s but
+    cost their cell's set-up 5–10%, every chunk program lowering kernels of
+    its own), the Pallas backend (``ops/backend.py::use_pallas``) and rows
+    the kernels can pack (``moe_rows.rows_supported``). Everywhere else —
+    every expert held (SDAR, Mellum2), a serve program, the jnp twins — the
+    gathers run, untouched; they remain only until the serve chunks and the
+    dense-buffer cells move to the kernels too (ROADMAP A4b). Both paths give the same bits: the same
+    pairs in the same rows, the same f32 sums in the same order. Counted
+    once a trace: ``moe.row_move.kernel`` or ``moe.row_move.gather``.
+
+    **What a dead tile holds** (a tile past the last group's; the live
+    ones are a prefix). Under the gathers ``xs`` is zero there. Under the
+    kernels ``xs`` and, in the backward, ``dys`` are NOT WRITTEN there: they
+    hold what the allocation held, NaN for all anyone knows. Nothing reads
+    them: ``grouped_matmul`` and its backward visit live tiles only and
+    zero the dead rows of their own results (``gate``, ``up``, ``ys``, the
+    products' ``dlhs``: zero on both paths; the SwiGLU between them is row
+    by row, zero where both are), ``_combine_live`` and ``_dispatch_live``'s
+    backward fetch only rows that hold a pair, and ``stats`` counts from the
+    integer maps (``tests/test_joyai.py::
+    test_nothing_reads_a_dead_tile_of_the_row_buffer`` poisons every one).
+
     Returns ``(y, stats, load)``: ``y`` shaped like ``x``, ``stats`` f32
     ``(3,)`` = pairs computed here (the rows of the buffer that hold a
     pair), pairs in all (those plus the pairs routed elsewhere: ``T·k``
@@ -483,7 +619,9 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     ``group_limit`` ``stats`` has a fourth value: the groups that hold at
     least one of a token's picks, the mean over the tokens (at most
     ``topk_group``: the nodes a token reaches)."""
+    from byteps_tpu.ops.backend import use_pallas
     from byteps_tpu.ops.grouped_matmul import grouped_matmul
+    from byteps_tpu.ops.moe_rows import ROWS, rows_supported
 
     held = params["w1"].shape[0]
     lead, d = x.shape[:-1], x.shape[-1]
@@ -503,22 +641,34 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
         pair_row, row_pair, counts, padded = _row_plan(
             jnp.where(is_held, local, held), held, tm)
     n_rows = row_pair.shape[0]
-    # the tile is chosen while tracing, so it is counted there
+    n_routed = params["wg"].shape[1]
+    # THE RULE (docstring): the tile and the path are chosen while tracing
+    # from static shapes, so they are counted there
+    live = (held < n_routed and tm == ROWS
+            and n_rows // tm >= ROW_KERNEL_TILES and use_pallas()
+            and rows_supported(T, d, x.dtype))
     reg = get_registry()
     reg.counter(f"moe.row_tile.{tm}").inc()
+    reg.counter(f"moe.row_move.{'kernel' if live else 'gather'}").inc()
     reg.gauge("moe.row_buffer_rows").set(n_rows)
     reg.gauge("moe.row_plan_tiles").set(n_rows // tm)
     pair_row = pair_row.reshape(T, top_k)
 
     with jax.named_scope("moe/plan"):
-        xs = _dispatch(xt, row_pair // top_k, pair_row)
+        if live:
+            slots = _slots(pair_row, n_rows)
+            n_live = (jnp.sum(padded) // tm).reshape(1)
+            xs = _dispatch_live(xt, row_pair, slots, n_live)
+        else:
+            xs = _dispatch(xt, row_pair // top_k, pair_row)
     with jax.named_scope("moe/experts"):
         gate = grouped_matmul(xs, params["w1"], padded, tm)
         up = grouped_matmul(xs, params["w3"], padded, tm)
         ys = grouped_matmul(jax.nn.silu(gate) * up, params["w2"], padded,
                             tm)
     with jax.named_scope("moe/combine"):
-        y = _combine(ys, weight, row_pair, pair_row)
+        y = (_combine_live(ys, weight, row_pair, slots, n_live) if live
+             else _combine(ys, weight, row_pair, pair_row))
 
     # counted from the row buffer the kernels ran over, not from the
     # router's ids: a held pair that got no row is missing from both
@@ -526,7 +676,6 @@ def moe_ffn_dropless(x: jnp.ndarray, params, top_k: int, scale: float,
     stats = jnp.stack([
         here, here + jnp.sum(~is_held).astype(jnp.float32),
         jnp.max(counts).astype(jnp.float32) * held / jnp.maximum(here, 1.0)])
-    n_routed = params["wg"].shape[1]
     if group_limit is not None:
         of_group = idx // (n_routed // group_limit[0])         # (T, k)
         hit = jnp.any(of_group[:, :, None] == jnp.arange(

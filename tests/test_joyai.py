@@ -208,6 +208,15 @@ def pallas(monkeypatch):
     monkeypatch.setenv("BYTEPS_KERNEL_BACKEND", "pallas")
 
 
+@pytest.fixture
+def small_buffers(monkeypatch):
+    """THE RULE admits a training step's buffer (``ROW_KERNEL_TILES`` tiles);
+    the tests' buffers are six tiles, so the test says they count."""
+    from byteps_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "ROW_KERNEL_TILES", 1)
+
+
 def test_flash_kernels_with_a_value_width_of_their_own(pallas):
     """MLA's shapes in miniature (q/k 48 wide, v 32): forward and the three
     gradients against attention_jnp."""
@@ -364,6 +373,155 @@ def test_dropless_layer_through_the_kernels(pallas):
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(a, b, atol=2e-4 * float(
             jnp.abs(b).max()) + 1e-7)
+
+
+# -- the row moves as kernels over the live prefix (ops/moe_rows.py) ---------
+def _share(dtype, T=256, d=256, ff=128, E=32, held=2, bias=()):
+    """A 1/16 share: experts 4 and 5 of 32 held; ``bias`` = (expert, value)
+    pairs laid on the router's correction bias."""
+    p = moe_dropless_init(jax.random.PRNGKey(0), d, ff, E, held, std=0.05,
+                          bias_std=0.01)
+    for e, v in bias:
+        p["router_bias"] = p["router_bias"].at[e].set(v)
+    p = {k: v.astype(dtype) if v.ndim == 3 else v for k, v in p.items()}
+    return jax.random.normal(jax.random.PRNGKey(1), (T, d), dtype), p
+
+
+def _layer_and_grads(x, p, tile=None):
+    def f(x, p):
+        y, stats, _ = moe_ffn_dropless(x, p, 4, 2.5, first_expert=4,
+                                       row_tile=tile)
+        return (y.astype(jnp.float32) ** 2).sum(), (y, stats)
+
+    (_, (y, stats)), g = jax.value_and_grad(f, (0, 1), has_aux=True)(x, p)
+    return y, stats, g
+
+
+def _row_move_counts():
+    from byteps_tpu.common.metrics import get_registry
+
+    reg = get_registry()
+    return tuple(reg.counter(f"moe.row_move.{path}").value()
+                 for path in ("kernel", "gather"))
+
+
+# (id, dtype, bias on the router, tile, pairs held here, path)
+_ROW_MOVE_CASES = [
+    # one held expert takes a pair of (nearly) every token, the other its
+    # even share: a group of more than one tile beside one that ends mid-tile
+    ("collapsed_router_bf16", jnp.bfloat16, ((5, 10.0),), None, "many",
+     "kernel"),
+    ("collapsed_router_f32", jnp.float32, ((5, 10.0),), None, "many",
+     "kernel"),
+    ("group_ends_mid_tile_bf16", jnp.bfloat16, (), None, "some", "kernel"),
+    ("held_expert_with_no_pair_f32", jnp.float32, ((4, -10.0),), None,
+     "some", "kernel"),
+    ("no_pair_held_bf16", jnp.bfloat16, ((4, -10.0), (5, -10.0)), None,
+     "none", "kernel"),
+    # THE RULE asks for the 256-row tile: a buffer laid out by a smaller one
+    # (a decode step's) keeps the gathers, share or not
+    ("tile_16_keeps_the_gathers_bf16", jnp.bfloat16, (), 16, "some",
+     "gather"),
+]
+
+
+@pytest.mark.parametrize("dtype,bias,tile,pairs,path",
+                         [c[1:] for c in _ROW_MOVE_CASES],
+                         ids=[c[0] for c in _ROW_MOVE_CASES])
+def test_row_kernels_are_the_gathers_bit_for_bit(pallas, small_buffers,
+                                                 monkeypatch, dtype, bias,
+                                                 tile, pairs, path):
+    """Under an expert share the four row moves run as kernels
+    (``moe_rows_*``, interpreted here); ``y`` and every gradient — ``dx``,
+    the router's (which carries ``dweight``), ``dw1``/``dw2``/``dw3`` — are
+    the ``jnp.take`` path's, bit for bit."""
+    from byteps_tpu.ops import moe_rows
+
+    x, p = _share(dtype, bias=bias)
+    before = _row_move_counts()
+    y, stats, g = _layer_and_grads(x, p, tile)
+    took = tuple(b - a for a, b in zip(before, _row_move_counts()))
+    assert took == ((1, 0) if path == "kernel" else (0, 1))
+    here = int(stats[0])
+    assert {"many": here > 256, "some": 0 < here < 256,
+            "none": here == 0}[pairs], here
+    if pairs == "some" and not bias:
+        assert here % 256 != 0 and here % 16 != 0      # a group ends mid-tile
+    monkeypatch.setattr(moe_rows, "rows_supported", lambda *a: False)
+    y0, stats0, g0 = _layer_and_grads(x, p, tile)
+    np.testing.assert_array_equal(stats, stats0)
+    np.testing.assert_array_equal(y, y0)
+    assert pairs == "none" or float(jnp.abs(y0.astype(jnp.float32)).max()) > 0
+    for (path_, a), b in zip(jax.tree_util.tree_leaves_with_path(g),
+                             jax.tree.leaves(g0)):
+        np.testing.assert_array_equal(a, b, err_msg=str(path_))
+    assert float(jnp.abs(g0[1]["wg"]).max()) > 0 or pairs == "none"
+
+
+def test_nothing_reads_a_dead_tile_of_the_row_buffer(pallas, small_buffers,
+                                                     monkeypatch):
+    """The kernels leave the tiles past the live ones unwritten. Poisoned:
+    every operand and every result of the three grouped products — ``xs``,
+    ``gate``, ``up``, the SwiGLU's product, ``ys`` — and, on the way back,
+    each of their cotangents (``dys``, ``dxs``) holds NaN on its dead
+    tiles, and ``y``, ``stats`` and every gradient are what they were."""
+    import importlib
+
+    # (the package re-exports a function named like the module)
+    gm = importlib.import_module("byteps_tpu.ops.grouped_matmul")
+
+    @jax.custom_vjp
+    def poison(a, live):
+        rows = jax.lax.broadcasted_iota(jnp.int32, (a.shape[0], 1), 0)
+        return jnp.where(rows < live, a, jnp.nan)
+
+    poison.defvjp(lambda a, live: (poison(a, live), live),
+                  lambda live, da: (poison(da, live), None))
+    real = gm.grouped_matmul
+
+    def poisoned(lhs, rhs, sizes, tm):
+        live = jnp.sum(sizes)
+        return poison(real(poison(lhs, live), rhs, sizes, tm), live)
+
+    x, p = _share(jnp.bfloat16, bias=((5, 10.0),))
+    want = _layer_and_grads(x, p)
+    monkeypatch.setattr(gm, "grouped_matmul", poisoned)
+    before = _row_move_counts()
+    got = _layer_and_grads(x, p)
+    assert _row_move_counts()[0] == before[0] + 1
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("held,T,path", [
+    (2, 4096, "kernel"), (32, 4096, "gather"), (2, 2048, "gather")],
+    ids=["a_share", "all_experts_held", "a_share_in_a_chunk"])
+def test_row_kernels_only_under_an_expert_share(pallas, held, T, path):
+    """THE RULE: with every routed expert held the buffer is dense and the
+    layer's program holds no ``moe_rows_*`` call (SDAR's and Mellum2's
+    programs are the parent's); under a share it holds them from a buffer of
+    ``ROW_KERNEL_TILES`` tiles (4,096 tokens x top-16: 256 + 2), and a serve
+    chunk's 2,048 tokens keep the gathers. The counters say which path a
+    trace took."""
+    p = moe_dropless_init(jax.random.PRNGKey(0), 128, 64, 32, held)
+    x = jnp.zeros((T, 128), jnp.float32)
+    before = _row_move_counts()
+    text = str(jax.make_jaxpr(jax.grad(lambda x: moe_ffn_dropless(
+        x, p, 16, 2.5, row_tile=256)[0].sum()))(x))
+    took = tuple(b - a for a, b in zip(before, _row_move_counts()))
+    assert took == ((1, 0) if path == "kernel" else (0, 1))
+    for name in ("moe_rows_pack", "moe_rows_to_buffer", "moe_rows_to_tokens",
+                 "moe_rows_dweight"):
+        assert (name in text) == (path == "kernel"), name
+    assert "moe_gmm_fwd" in text
+
+
+def test_row_kernels_wait_for_the_pallas_backend():
+    """Off the TPU (the jnp twins) the layer keeps its gathers."""
+    x, p = _share(jnp.float32)
+    before = _row_move_counts()
+    moe_ffn_dropless(x, p, 4, 2.5, first_expert=4)
+    assert _row_move_counts() == (before[0], before[1] + 1)
 
 
 # -- the row tile follows from the pairs a program holds ----------------------

@@ -168,6 +168,29 @@ def _gmm_shapes(rows, held, d, ff):
             _sds((held, ff, d), BF16), _sds((held,), I32)]
 
 
+def _row_moves(tokens, d, k, rows):
+    """The dropless layer's row kernels (ops/moe_rows.py) at a program's
+    shapes, bf16: ``[(id suffix, function, argument shapes, Pallas calls)]``
+    — each call packs its source first, so two calls a move."""
+    from byteps_tpu.ops import moe_rows as mr
+
+    live = _sds((1,), I32)
+    slots = [_sds((tokens,), I32), _sds((tokens, k), I32)]
+    return [
+        ("to_buffer", lambda x, row_pair, n: mr.moe_rows_to_buffer(
+            x, row_pair, k, None, n),
+         [_sds((tokens, d), BF16), _sds((rows,), I32), live], 2),
+        ("to_buffer_weighted", lambda x, row_pair, w, n:
+         mr.moe_rows_to_buffer(x, row_pair, k, w, n),
+         [_sds((tokens, d), BF16), _sds((rows,), I32),
+          _sds((tokens, k), F32), live], 2),
+        ("to_tokens", mr.moe_rows_to_tokens,
+         [_sds((rows, d), BF16), *slots, _sds((tokens, k), F32), live], 2),
+        ("dweight", mr.moe_rows_dweight,
+         [_sds((rows, d), BF16), *slots, _sds((tokens, d), BF16), live], 2),
+    ]
+
+
 # the JoyAI-LLM-Flash cell's routed experts: 16 held, 2048 -> 768 -> 2048,
 # the worst-case row buffer of 4 x 4096 tokens x top-8 (+ a tile a group)
 _GMM = _gmm_shapes(135168, 16, 2048, 768)
@@ -198,6 +221,14 @@ ONE_CHIP = [
     # the first product forward (the second's output is not needed for
     # its gradient), then dx and dw of each
     ("moe_gmm_fwd_bwd_joyai", _gmm_fwd_bwd, _GMM, 5),
+    # the row moves round them under an expert share: JoyAI's step (16,384
+    # tokens of 2,048, top-8, the 135,168-row buffer) and dots3's chunk
+    # (2,048 tokens of 5,120, top-8 over 32 held: 96 tiles), whose packed row
+    # is 20 lane blocks of words, not a multiple of the 8 sublanes
+    *[(f"moe_rows_{what}_joyai", fn, args, n)
+      for what, fn, args, n in _row_moves(16384, 2048, 8, 135168)],
+    *[(f"moe_rows_{what}_dots3_chunk", fn, args, n)
+      for what, fn, args, n in _row_moves(2048, 5120, 8, 96 * 256)],
     # blocks as wide as an expert's matrix must fit v5e's scoped VMEM:
     # Mellum2's 64 experts of 2304 x 896 under a 24-row decode step (192
     # pairs: 65 tiles) and a 2,048-token chunk (128 tiles), whole matrices;
@@ -318,6 +349,41 @@ def test_kernel_compiles_for_v5e(topo, as_on_tpu, case):
                                      sharding=NamedSharding(mesh, P("dp")))]
     compiled = jax.jit(fn).lower(*args).compile()
     assert _n_pallas(compiled) == n_calls, compiled.as_text()[:2000]
+
+
+def test_row_kernels_compile_under_a_checked_shard_map(topo, as_on_tpu):
+    """The training factories run the layer under ``shard_map(check_vma=
+    True)``: a value read from a ref varies there, and an array constant
+    met with it inside a kernel needs a ``pvary`` that Mosaic does not lower
+    (jnp's floor division makes such constants; PR 62 met it on the chip).
+    The layer's forward and gradient under an expert share, four chips."""
+    from byteps_tpu.parallel.moe import moe_ffn_dropless
+
+    T, d, ff, held, k = 8192, 2048, 768, 16, 8      # 256 + 16 tiles a chip
+    mesh = Mesh(topo.devices, ("dp",))
+    p = {"wg": _sds((d, 256), F32), "router_bias": _sds((256,), F32),
+         "w1": _sds((held, d, ff), BF16), "w3": _sds((held, d, ff), BF16),
+         "w2": _sds((held, ff, d), BF16)}
+
+    def loss(x, p):
+        y = moe_ffn_dropless(x, p, k, 2.5, first_expert=held)[0]
+        return (y.astype(F32) ** 2).sum()
+
+    def body(x, p):
+        dx, dp = jax.grad(loss, (0, 1))(x, p)
+        return dx, jax.tree.map(lambda a: jax.lax.psum(a, "dp"), dp)
+
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P("dp"), P()),
+                       out_specs=(P("dp"), P()), check_vma=True)
+    args = (jax.ShapeDtypeStruct((4 * T, d), BF16,
+                                 sharding=NamedSharding(mesh, P("dp"))),
+            {name: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=NamedSharding(mesh, P()))
+             for name, a in p.items()})
+    compiled = jax.jit(fn).lower(*args).compile()
+    # 3 + 6 grouped products; a pack and a move each for x and ys forward,
+    # dout, ys and dxs back, the two packs of ys merged by the compiler
+    assert _n_pallas(compiled) == 9 + 2 * 5 - 1, _n_pallas(compiled)
 
 
 # ---- whole programs: the serve cells' prefill chunk and decode step ---------
